@@ -30,15 +30,3 @@ Layer map (mirrors SURVEY.md section 1):
 """
 
 __version__ = "0.1.0"
-
-# Child-process platform policy, applied at the earliest possible import
-# point: `python -m karmada_tpu.<component>` executes package __init__s
-# BEFORE the entry module, and submodule imports materialize jax constants
-# that would initialize the (single-client) accelerator backend. No-op
-# unless the parent set KARMADA_TPU_PLATFORM (see utils/platform.py).
-import os as _os
-
-if _os.environ.get("KARMADA_TPU_PLATFORM"):
-    from .utils.platform import apply_child_platform as _acp
-
-    _acp()

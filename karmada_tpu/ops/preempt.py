@@ -153,12 +153,17 @@ def preempt_select(
     ).any(axis=1)
     victims = jnp.zeros((b,), bool).at[v_order].set(sel_sorted)
 
-    # freed capacity lands on the victims' clusters: one [B,C]x[B,R]
-    # contraction — int64 to keep exact integer semantics
+    # freed capacity lands on the victims' clusters: the [B,C]x[B,R]
+    # contraction over B in exact int64, as one multiply + row reduction
+    # per resource dim (R is small and static; unrolled under jit). Not a
+    # dot: the TPU compiler has no 64-bit integer dot and refuses one.
     sel_assigned = jnp.where(victims[:, None], assigned, 0).astype(jnp.int64)
-    freed_caps = jnp.einsum(
-        "bc,br->cr", sel_assigned, requests,
-        preferred_element_type=jnp.int64,
+    freed_caps = jnp.stack(
+        [
+            (sel_assigned * requests[:, d][:, None]).sum(axis=0)
+            for d in range(r)
+        ],
+        axis=1,
     )
     if mesh is not None:
         freed_caps = lax.with_sharding_constraint(

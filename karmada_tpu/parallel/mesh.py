@@ -11,9 +11,9 @@ This module is everything AROUND that mesh:
   from ``KARMADA_TPU_MESH_DEVICES`` / ``KARMADA_TPU_MESH_CLUSTER_AXIS``
   (the trace-manifest resolution pattern: an explicit Mesh passes
   through, ``False`` forces single-device even with the env set, None
-  falls back to the env default). CPU CI dry-runs honor
-  ``--xla_force_host_platform_device_count`` — ``ensure_host_devices``
-  writes the flag when backends have not initialized yet.
+  falls back to the env default). CPU CI dry-runs set
+  ``JAX_PLATFORMS=cpu`` and ``--xla_force_host_platform_device_count``
+  before the first jax import.
 - **Identity** (``mesh_shape``/``mesh_from_shape``): the canonical,
   JSON-serializable shape of a mesh — ``(("b", nb), ("c", nc))`` — used
   by the fleet trace keys, the prewarm manifest records, the solver
@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import logging
 import os
-import re
 from typing import Optional
 
 log = logging.getLogger("karmada_tpu")
@@ -62,39 +61,15 @@ MESH_ENV = "KARMADA_TPU_MESH_DEVICES"
 CLUSTER_AXIS_ENV = "KARMADA_TPU_MESH_CLUSTER_AXIS"
 
 
-def ensure_host_devices(n: int) -> None:
-    """Best-effort: make >= n virtual CPU devices available by writing
-    ``--xla_force_host_platform_device_count`` into XLA_FLAGS. Effective
-    only before the first backend initialization; harmless afterwards
-    (callers that need certainty check ``len(jax.devices())``)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    if m and int(m.group(1)) >= n:
-        return
-    opt = f"--xla_force_host_platform_device_count={n}"
-    if m:
-        flags = flags.replace(m.group(0), opt)
-    else:
-        flags = (flags + " " + opt).strip()
-    os.environ["XLA_FLAGS"] = flags
-
-
 def scheduling_mesh(
-    n_devices: Optional[int] = None,
-    *,
-    cluster_axis: int = 1,
-    allow_cpu_fallback: bool = False,
+    n_devices: Optional[int] = None, *, cluster_axis: int = 1
 ):
     """A ("b", "c") mesh over the first n visible devices (the
     binding-parallel axis carries n // cluster_axis). Thin delegate to
     ``solver.default_mesh`` so the two construction paths cannot drift."""
     from .solver import default_mesh
 
-    return default_mesh(
-        n_devices,
-        cluster_axis=cluster_axis,
-        allow_cpu_fallback=allow_cpu_fallback,
-    )
+    return default_mesh(n_devices, cluster_axis=cluster_axis)
 
 
 def resolve_mesh(spec=None):

@@ -795,11 +795,10 @@ class WaveTracer:
             counts[s.name] = counts.get(s.name, 0) + 1
             if s.attrs.get("kind") == "device":
                 device += s.duration
-            # compile attribution is a FLAG, not a kind: a synchronous
-            # backend compiles inside the dispatch window, an async
-            # tunnel inside the device fence — the fleet marks both spans
-            # of a fresh-trace pass, so compile_s upper-bounds the
-            # compile-bearing time on either backend
+            # compile attribution is a FLAG, not a kind: a fresh trace's
+            # compile runs inside the dispatch window or surfaces at the
+            # device fence — the fleet marks both spans of a fresh-trace
+            # pass, so compile_s upper-bounds the compile-bearing time
             if s.attrs.get("compile"):
                 compile_s += s.duration
         attributed = sum(phases.values())

@@ -18,6 +18,6 @@ def kernel(x, n, flag: bool):
     scale = float(x[0])  # BAD: host conversion of a traced value
     print("tracing", flag)  # BAD: trace-time print
     t0 = time.time()  # BAD: clock read baked into the trace
-    plat = os.environ.get("KARMADA_TPU_PLATFORM", "")  # BAD: env in trace
+    plat = os.environ.get("KARMADA_TPU_MESH_DEVICES", "")  # BAD: env in trace
     y = x.item()  # BAD: host sync
     return jnp.asarray([scale, t0, float(len(plat)), y])

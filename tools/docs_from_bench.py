@@ -6,9 +6,9 @@ Usage: python tools/docs_from_bench.py BENCH_SELF_r05.json
 Rewrites the text between ``<!-- bench:begin -->`` / ``<!-- bench:end -->``
 markers in docs/OPERATIONS.md and BASELINE.md from the JSON line bench.py
 printed (either the raw line or the driver's ``{"parsed": ...}`` wrapper).
-Round 4 shipped docs claiming ~10 s where the recorded JSON said 71.6 s
-(VERDICT r4 weak #2); with this tool the prose can never drift from the
-record again — regenerate, don't hand-edit.
+Round 4 shipped docs claiming ~10 s where the recorded JSON said 71.6 s;
+with this tool the prose can never drift from the record again —
+regenerate, don't hand-edit.
 
 The same contract covers the environment-variable table: the block between
 ``<!-- envflags:begin -->`` / ``<!-- envflags:end -->`` in

@@ -729,7 +729,7 @@ class _Analyzer:
             st.kind, st.axis, st.off, st.reasons, st.plane, fully_repl,
         ))
 
-    def _p_pjit(self, eqn, states):
+    def _p_jit(self, eqn, states):
         closed = eqn.params.get("jaxpr")
         if closed is None:
             self._write_all(eqn, self._conservative(states))
@@ -738,9 +738,9 @@ class _Analyzer:
         for v, s in zip(eqn.outvars, out):
             self.write(v, s)
 
-    _p_closed_call = _p_pjit
-    _p_core_call = _p_pjit
-    _p_remat = _p_pjit
+    _p_closed_call = _p_jit
+    _p_core_call = _p_jit
+    _p_remat = _p_jit
 
     def _p_custom_jvp_call(self, eqn, states):
         closed = eqn.params.get("call_jaxpr") or eqn.params.get("jaxpr")

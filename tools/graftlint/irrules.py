@@ -20,6 +20,7 @@ TRACING step (ir.py) needs a live jax.
 
 from __future__ import annotations
 
+import math
 from typing import Iterator
 
 from .core import Finding, Rule, rule
@@ -28,7 +29,7 @@ from .core import Finding, Rule, rule
 
 
 def _subjaxprs(params: dict):
-    """Jaxpr objects nested in an eqn's params (scan/cond/pjit bodies)."""
+    """Jaxpr objects nested in an eqn's params (scan/cond/jit bodies)."""
     for value in params.values():
         items = value if isinstance(value, (list, tuple)) else (value,)
         for item in items:
@@ -41,13 +42,27 @@ def _subjaxprs(params: dict):
 
 def walk_eqns(jaxpr, _depth: int = 0):
     """Every eqn of ``jaxpr`` and its nested sub-jaxprs (scan bodies,
-    cond branches, inner pjit calls), depth-first."""
+    cond branches, inner jit calls), depth-first."""
     if _depth > 32:  # defensive: malformed self-referential params
         return
     for eqn in jaxpr.eqns:
         yield eqn
         for sub in _subjaxprs(eqn.params):
             yield from walk_eqns(sub, _depth + 1)
+
+
+def walk_consts(closed_jaxpr):
+    """Every captured constant of ``closed_jaxpr`` and of the closed
+    sub-jaxprs nested anywhere in its eqns. jax keeps a jitted
+    function's captured constants on the INNER jaxpr of its ``jit`` eqn —
+    the outer trace's ``consts`` is empty for every registered kernel."""
+    yield from closed_jaxpr.consts
+    for eqn in walk_eqns(closed_jaxpr.jaxpr):
+        for value in eqn.params.values():
+            items = value if isinstance(value, (list, tuple)) else (value,)
+            for item in items:
+                if hasattr(item, "consts") and hasattr(item, "jaxpr"):
+                    yield from item.consts
 
 
 def _aval(var):
@@ -171,12 +186,14 @@ class ConstCapture(IRRule):
         threshold = getattr(
             ctx, "const_bytes_threshold", CONST_BYTES_THRESHOLD
         )
-        for i, const in enumerate(traced.closed_jaxpr.consts):
-            nbytes = getattr(const, "nbytes", 0)
-            if nbytes <= threshold:
-                continue
+        for i, const in enumerate(walk_consts(traced.closed_jaxpr)):
+            # sized from shape x itemsize: a captured numpy array arrives
+            # as jax's TypedNdArray literal, which carries no ``nbytes``
             shape = tuple(getattr(const, "shape", ()))
             dtype = getattr(const, "dtype", type(const).__name__)
+            nbytes = math.prod(shape) * getattr(dtype, "itemsize", 0)
+            if nbytes <= threshold:
+                continue
             yield traced.finding(
                 self.id,
                 f"{traced.label}: closed-over constant #{i} "
@@ -284,12 +301,12 @@ class DonationAudit(IRRule):
 
     def check(self, traced, ctx) -> Iterator[Finding]:
         # donation is declared on the jit wrapper, so it surfaces on the
-        # top-level pjit eqn of the outer trace; XLA can only alias a
+        # top-level jit eqn of the outer trace; XLA can only alias a
         # donated input into an output of IDENTICAL shape+dtype — a
         # donated buffer with no such output is silently copied, doubling
         # its HBM footprint (the dense resident is the largest tenant)
         for eqn in traced.closed_jaxpr.jaxpr.eqns:
-            if eqn.primitive.name != "pjit":
+            if eqn.primitive.name != "jit":
                 continue
             donated = eqn.params.get("donated_invars") or ()
             if not any(donated):
