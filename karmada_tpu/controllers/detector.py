@@ -120,15 +120,14 @@ class ResourceDetector:
     # -- reconcile ---------------------------------------------------------
 
     def _reconcile_batch(self, keys) -> dict:
-        out: dict = {}
         self._buffering = True
         try:
-            for key in keys:
-                out[key] = self._reconcile(key)
+            return self.worker.reconcile_each(
+                keys, self._reconcile, lambda: len(self._pending)
+            )
         finally:
             self._buffering = False
             self._flush()
-        return out
 
     def _apply(self, obj) -> None:
         if self._buffering:

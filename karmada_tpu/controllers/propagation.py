@@ -393,15 +393,16 @@ class BindingController:
         write sets instead of per-object applies). Safe under the
         worker's poisoned-key bisect — reconciles are idempotent and the
         signature gate no-ops re-runs of already-flushed work."""
-        out: dict = {}
         self._buffering = True
         try:
-            for kind_key in kind_keys:
-                out[kind_key] = self._reconcile(kind_key)
+            return self.worker.reconcile_each(
+                kind_keys, self._reconcile,
+                lambda: len(self._pending_applies)
+                + len(self._pending_deletes),
+            )
         finally:
             self._buffering = False
             self._flush()
-        return out
 
     def _apply_work(self, work: Work) -> None:
         if self._buffering:
@@ -913,15 +914,15 @@ class ExecutionController:
             self.worker.enqueue(("apply", event.key, None))
 
     def _reconcile_batch(self, items) -> dict:
-        out: dict = {}
         self._buffering = True
         try:
-            for item in items:
-                out[item] = self._reconcile(item)
+            return self.worker.reconcile_each(
+                items, self._reconcile,
+                lambda: len(self._pending_applies),
+            )
         finally:
             self._buffering = False
             self._flush()
-        return out
 
     def _apply_status(self, work: Work) -> None:
         if self._buffering:
@@ -1184,15 +1185,14 @@ class BindingStatusController:
             self.worker.enqueue(key)
 
     def _reconcile_batch(self, refs) -> dict:
-        out: dict = {}
         self._buffering = True
         try:
-            for ref in refs:
-                out[ref] = self._reconcile(ref)
+            return self.worker.reconcile_each(
+                refs, self._reconcile, lambda: len(self._pending)
+            )
         finally:
             self._buffering = False
             self._flush()
-        return out
 
     def _commit(self, rb) -> None:
         if self._buffering:

@@ -433,6 +433,10 @@ class SchedulerController:
                 out[kind_key] = DONE
                 continue
             todo.append((kind_key, rb, self._problem_for(key, rb, fresh), fresh))
+        # the keys the gate turned away before the engine: this drain's
+        # no-ops (a Cluster status event queues every binding, and all but
+        # the ones a rebalancer named stop here)
+        self.worker.note_noops(len(kind_keys) - len(todo))
         if not todo:
             return out
         # priority-descending wave ordering (ISSUE 14): higher priority

@@ -96,7 +96,12 @@ class ControlPlane:
                 ),
             )
         self.runtime = Runtime()
-        self.members = MemberClientRegistry()
+        # one write count for all the plane's state: every write a
+        # reconcile can make lands in the store or in a member, and the
+        # workers' no-op counts read it (a bus facade has none, and its
+        # drains then carry no write-based count)
+        self.runtime.write_count = getattr(self.store, "write_count", None)
+        self.members = MemberClientRegistry(self.runtime.write_count)
         self.interpreter = default_interpreter()
         self.estimators = EstimatorRegistry()
 

@@ -921,6 +921,12 @@ class TensorScheduler:
         says so. Disarmed (``KARMADA_TPU_DELTA_SOLVE=0``) or absent, the
         pass costs one ``is None`` check over the existing paths."""
         self.last_preemption = None
+        # new-trace flags are per PASS: cleared here, so a pass that never
+        # reaches the fleet table (the host path's small waves) does not
+        # go on reporting the compile of an earlier one
+        self._engine_new_trace = False
+        if self._fleet is not None:
+            self._fleet.new_trace_last_pass = False
         self._dirty_keys = set(dirty_keys) if dirty_keys else None
         try:
             results = self._schedule_quota(problems)
@@ -971,7 +977,6 @@ class TensorScheduler:
         QuotaExceeded result without being solved, admitted ones ride the
         unchanged batched paths below. Disarmed quota costs one `is None`
         check."""
-        self._engine_new_trace = False
         q = self.quota
         if q is not None and q.active:
             part, debit = self._quota_admission(problems)
@@ -1164,7 +1169,7 @@ class TensorScheduler:
         if not victim_mask.any():
             outcome.still_unschedulable = [p.key for p in demanders]
             _tracer.record(
-                "scheduler.preempt", _time.perf_counter() - t0,
+                "scheduler.preempt", _time.perf_counter() - t0, start=t0,
                 demanders=len(demanders), victims=0,
             )
             return results
@@ -1192,7 +1197,7 @@ class TensorScheduler:
             else:
                 outcome.still_unschedulable.append(res.key)
         _tracer.record(
-            "scheduler.preempt", _time.perf_counter() - t0,
+            "scheduler.preempt", _time.perf_counter() - t0, start=t0,
             demanders=len(demanders), victims=len(outcome.victims),
         )
         return results
@@ -1323,7 +1328,8 @@ class TensorScheduler:
             self.explain.add(self._explain_chunk(chunk, res, wave))
             rows += len(chunk)
         _tracer.record(
-            "scheduler.explain", _time.perf_counter() - t0, rows=rows
+            "scheduler.explain", _time.perf_counter() - t0, start=t0,
+            rows=rows,
         )
 
     def _explain_chunk(self, problems, results, wave: int):
@@ -1737,7 +1743,7 @@ class TensorScheduler:
             if res is not None:
                 return res
 
-        t0 = _time.perf_counter()
+        t0 = t_pack = _time.perf_counter()
         compiled = [self._compiled(p.placement) for p in problems]
         self.last_breakdown = {"compile": _time.perf_counter() - t0}
         # engine-level features that the device-resident path does not
@@ -1774,8 +1780,9 @@ class TensorScheduler:
             self.last_breakdown["eligible"] = _time.perf_counter() - t0
             # the host prologue (placement compile + spread selection +
             # eligibility partition) is the wave tree's "pack" phase —
-            # recorded as one span so a storm's pass decomposes into
-            # pack / solve(dispatch/device/fetch) under scheduler.pass
+            # recorded as one span, from where the prologue began, so a
+            # storm's pass decomposes into pack / solve(dispatch/device/
+            # fetch) under scheduler.pass
             from ..utils.tracing import tracer as _tracer
 
             _tracer.record(
@@ -1784,7 +1791,7 @@ class TensorScheduler:
                     self.last_breakdown.get(k, 0.0)
                     for k in ("compile", "select", "eligible")
                 ),
-                rows=len(problems),
+                start=t_pack, rows=len(problems),
             )
             if len(fast_idx) >= self.fleet_threshold:
                 from .fleet import FleetTable

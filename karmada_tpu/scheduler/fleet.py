@@ -56,6 +56,7 @@ placements.
 from __future__ import annotations
 
 import logging
+import time
 
 from functools import partial
 from typing import Optional, Sequence
@@ -276,50 +277,59 @@ def _fleet_solve(
             a, NamedSharding(mesh, P(*axes))
         )
 
-    valid = rows >= 0
-    r = jnp.maximum(rows, 0)
-    # compact per-pass state ([n_pad]), gathered outside the scan
-    cp = cp_idx[r]
-    gv = gvk_idx[r]
-    pf = prof_idx[r]
-    reps = jnp.where(valid, replicas[r], 0)
-    st = strategy[r]
-    fr = fresh[r] & valid
-    ps = prev_sites[r]
-    pc = jnp.where(valid[:, None], prev_counts[r], 0)
+    with jax.named_scope("fleet.gather"):
+        valid = rows >= 0
+        r = jnp.maximum(rows, 0)
+        # compact per-pass state ([n_pad]), gathered outside the scan
+        cp = cp_idx[r]
+        gv = gvk_idx[r]
+        pf = prof_idx[r]
+        reps = jnp.where(valid, replicas[r], 0)
+        st = strategy[r]
+        fr = fresh[r] & valid
+        ps = prev_sites[r]
+        pc = jnp.where(valid[:, None], prev_counts[r], 0)
 
     def body(carry, i):
-        sl = lambda a: lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=0)
-        cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
-        repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-        psc, pcc = sl(ps), sl(pc)
-        repsc, stc, frc, vc = (
-            shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
-            shard(vc, "b"),
-        )
-        cpc, gvc, pfc = shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
-        psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
+        with jax.named_scope("fleet.gather"):
+            sl = lambda a: lax.dynamic_slice_in_dim(
+                a, i * chunk, chunk, axis=0
+            )
+            cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
+            repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
+            psc, pcc = sl(ps), sl(pc)
+            repsc, stc, frc, vc = (
+                shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
+                shard(vc, "b"),
+            )
+            cpc, gvc, pfc = (
+                shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
+            )
+            psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
         # mask composition — same algebra as TensorScheduler._pack_chunk,
         # via the shared helper every feasibility consumer uses
-        prev, static_w, feasible = _row_masks(
-            cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-            pcc, vc, chunk, c,
-        )
-        prev = shard(prev, "b", c_ax)
-        feasible = shard(feasible, "b", c_ax)
-        general = prof_table[pfc]
-        avail = shard(merge_estimates(repsc, (general,)), "b", c_ax)
-        assignment, unsched = _divide_batch(
-            stc, repsc, feasible, static_w, avail, prev, frc,
-            has_aggregated, wide, fast,
-        )
-        # Duplicated rows are represented by the feasible bitset (their
-        # count is just `replicas` everywhere feasible); zero their
-        # dense rows so the entry stream carries only Divided placements
-        assignment = shard(
-            jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
-            "b", c_ax,
-        )
+        with jax.named_scope("fleet.masks"):
+            prev, static_w, feasible = _row_masks(
+                cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
+                pcc, vc, chunk, c,
+            )
+            prev = shard(prev, "b", c_ax)
+            feasible = shard(feasible, "b", c_ax)
+        with jax.named_scope("fleet.estimate"):
+            general = prof_table[pfc]
+            avail = shard(merge_estimates(repsc, (general,)), "b", c_ax)
+        with jax.named_scope("fleet.divide"):
+            assignment, unsched = _divide_batch(
+                stc, repsc, feasible, static_w, avail, prev, frc,
+                has_aggregated, wide, fast,
+            )
+            # Duplicated rows are represented by the feasible bitset (their
+            # count is just `replicas` everywhere feasible); zero their dense
+            # rows so the entry stream carries only Divided placements
+            assignment = shard(
+                jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
+                "b", c_ax,
+            )
         # compact each row's placed sites (<= k_out of them: every placed
         # site holds >= 1 of <= max-replicas <= k_out replicas): the packed
         # (site << 8 | count) word sorts by site, so one ascending
@@ -327,15 +337,16 @@ def _fleet_solve(
         # vector. Measured on the v5e at C=5k: sort 0.29s vs 1.8s for
         # binary-search position extraction (batched gathers) and 2.5s for
         # scatter compaction.
-        selected = assignment > 0
-        n_placed = selected.sum(axis=1).astype(jnp.int32)
-        idxs = jnp.arange(c, dtype=jnp.int32)[None, :]
-        packed_full = jnp.where(
-            selected, (idxs << 8) | assignment, jnp.int32(2**31 - 1)
-        )
-        srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
-        entries = shard(jnp.where(srt == 2**31 - 1, 0, srt), "b", None)
-        has_cand = feasible.any(axis=1)
+        with jax.named_scope("fleet.compact"):
+            selected = assignment > 0
+            n_placed = selected.sum(axis=1).astype(jnp.int32)
+            idxs = jnp.arange(c, dtype=jnp.int32)[None, :]
+            packed_full = jnp.where(
+                selected, (idxs << 8) | assignment, jnp.int32(2**31 - 1)
+            )
+            srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
+            entries = shard(jnp.where(srt == 2**31 - 1, 0, srt), "b", None)
+            has_cand = feasible.any(axis=1)
         return carry, (entries, n_placed.astype(jnp.int32), unsched, has_cand)
 
     _, outs = lax.scan(body, 0, jnp.arange(n_chunks))
@@ -352,30 +363,31 @@ def _fleet_solve(
     # The resident base is k_res wide (grow-only across batches) so a
     # straggler batch with a smaller per-batch k_out neither wipes the base
     # nor leaves stale columns: its vectors are zero-padded to k_res.
-    if k_res > k_out:
-        entries = jnp.pad(entries, ((0, 0), (0, k_res - k_out)))
-    if all_rows:
-        # int32 offsets: the SPMD partitioner mixes the shard-offset
-        # arithmetic (s32) with the slice start, and an x64-default s64
-        # start fails HLO verification on the row-sharded resident
-        z32 = jnp.int32(0)
-        pe = lax.dynamic_slice_in_dim(
-            prev_entries, z32, entries.shape[0], 0
-        )
-        changed = (entries != pe).any(axis=1) & valid
-        new_resident = lax.dynamic_update_slice_in_dim(
-            prev_entries, entries, z32, 0
-        )
-    else:
-        changed = (entries != prev_entries[r]).any(axis=1) & valid
-        new_resident = prev_entries.at[
-            jnp.where(valid, r, prev_entries.shape[0])
-        ].set(entries, mode="drop")
-    # pin the updated resident to the layout it was allocated with
-    # (row-sharded under a mesh): donation aliases input->output only
-    # when the shardings agree, so the constraint is what keeps the
-    # persistent base buffer-stable across passes
-    new_resident = shard(new_resident, "b", None)
+    with jax.named_scope("fleet.diff"):
+        if k_res > k_out:
+            entries = jnp.pad(entries, ((0, 0), (0, k_res - k_out)))
+        if all_rows:
+            # int32 offsets: the SPMD partitioner mixes the shard-offset
+            # arithmetic (s32) with the slice start, and an x64-default s64
+            # start fails HLO verification on the row-sharded resident
+            z32 = jnp.int32(0)
+            pe = lax.dynamic_slice_in_dim(
+                prev_entries, z32, entries.shape[0], 0
+            )
+            changed = (entries != pe).any(axis=1) & valid
+            new_resident = lax.dynamic_update_slice_in_dim(
+                prev_entries, entries, z32, 0
+            )
+        else:
+            changed = (entries != prev_entries[r]).any(axis=1) & valid
+            new_resident = prev_entries.at[
+                jnp.where(valid, r, prev_entries.shape[0])
+            ].set(entries, mode="drop")
+        # pin the updated resident to the layout it was allocated with
+        # (row-sharded under a mesh): donation aliases input->output only
+        # when the shardings agree, so the constraint is what keeps the
+        # persistent base buffer-stable across passes
+        new_resident = shard(new_resident, "b", None)
 
     # compact changed rows' (site, count) pairs into one row-major entry
     # stream; zero entries are the padding the per-row vectors carry.
@@ -384,41 +396,42 @@ def _fleet_solve(
     # back-propagates into the cumsum/scatter and the partitioned scan
     # emits a corrupt stream (observed on the CPU SPMD partitioner:
     # changed-entry totals beyond the theoretical bound)
-    entries_w = shard(entries, None, None)
-    changed_w = shard(changed, None)
-    valid_e = ((entries_w > 0) & changed_w[:, None]).reshape(-1)
-    offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
-    total = offs[-1] + valid_e[-1].astype(jnp.int32)
-    packed = entries_w.reshape(-1)
-    write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
-    buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
-    stream = buf[:e_cap]
+    with jax.named_scope("fleet.wire"):
+        entries_w = shard(entries, None, None)
+        changed_w = shard(changed, None)
+        valid_e = ((entries_w > 0) & changed_w[:, None]).reshape(-1)
+        offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
+        total = offs[-1] + valid_e[-1].astype(jnp.int32)
+        packed = entries_w.reshape(-1)
+        write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
+        buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
+        stream = buf[:e_cap]
 
-    # one metadata word per row:
-    # n_placed | unsched<<8 | has_cand<<9 | changed<<10
-    meta = (
-        n_placed
-        | (unsched.astype(jnp.int32) << 8)
-        | (has_cand.astype(jnp.int32) << 9)
-        | (changed_w.astype(jnp.int32) << 10)
-    )
-    c_total = cp_static.shape[1]
-    if c_total <= 0xFFFF:
-        # byte wire: transfer bytes are the pass's budget, and a packed
-        # entry fits 3 bytes when the site index fits 16 bits (counts are
-        # <= MAX_REPLICAS_FAST < 256, meta words < 2^11). Bytes are
-        # decomposed with shifts, not bitcasts, so the layout is
-        # endianness-independent.
-        total_u8 = jnp.stack(
-            [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
-        ).astype(jnp.uint8)
-        meta_u8 = jnp.stack(
-            [meta & 0xFF, (meta >> 8) & 0xFF], axis=-1
-        ).astype(jnp.uint8).reshape(-1)
-        e_u8 = _entry_wire(stream, e_cap, pack21)
-        flat = jnp.concatenate([total_u8, meta_u8, e_u8])
-    else:
-        flat = jnp.concatenate([total[None], meta, stream])
+        # one metadata word per row:
+        # n_placed | unsched<<8 | has_cand<<9 | changed<<10
+        meta = (
+            n_placed
+            | (unsched.astype(jnp.int32) << 8)
+            | (has_cand.astype(jnp.int32) << 9)
+            | (changed_w.astype(jnp.int32) << 10)
+        )
+        c_total = cp_static.shape[1]
+        if c_total <= 0xFFFF:
+            # byte wire: transfer bytes are the pass's budget, and a packed
+            # entry fits 3 bytes when the site index fits 16 bits (counts
+            # are <= MAX_REPLICAS_FAST < 256, meta words < 2^11). Bytes are
+            # decomposed with shifts, not bitcasts, so the layout is
+            # endianness-independent.
+            total_u8 = jnp.stack(
+                [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
+            ).astype(jnp.uint8)
+            meta_u8 = jnp.stack(
+                [meta & 0xFF, (meta >> 8) & 0xFF], axis=-1
+            ).astype(jnp.uint8).reshape(-1)
+            e_u8 = _entry_wire(stream, e_cap, pack21)
+            flat = jnp.concatenate([total_u8, meta_u8, e_u8])
+        else:
+            flat = jnp.concatenate([total[None], meta, stream])
     return flat, new_resident
 
 
@@ -537,176 +550,192 @@ def _fleet_pass(
             return a
         return lax.with_sharding_constraint(a, NamedSharding(mesh, P(*axes)))
 
-    valid = rows >= 0
-    r = jnp.maximum(rows, 0)
-    cp = cp_idx[r]
-    gv = gvk_idx[r]
-    pf = prof_idx[r]
-    reps = jnp.where(valid, replicas[r], 0)
-    st = strategy[r]
-    fr = fresh[r] & valid
-    ps = prev_sites[r]
-    pc = jnp.where(valid[:, None], prev_counts[r], 0)
+    with jax.named_scope("fleet.gather"):
+        valid = rows >= 0
+        r = jnp.maximum(rows, 0)
+        cp = cp_idx[r]
+        gv = gvk_idx[r]
+        pf = prof_idx[r]
+        reps = jnp.where(valid, replicas[r], 0)
+        st = strategy[r]
+        fr = fresh[r] & valid
+        ps = prev_sites[r]
+        pc = jnp.where(valid[:, None], prev_counts[r], 0)
 
     def body(carry, i):
         rd, rm = carry
-        sl = lambda a: lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=0)
-        cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
-        repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-        psc, pcc = sl(ps), sl(pc)
-        rc = sl(r)
-        repsc, stc, frc, vc = (
-            shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
-            shard(vc, "b"),
-        )
-        cpc, gvc, pfc = shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
-        psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
-        prev, static_w, feasible = _row_masks(
-            cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-            pcc, vc, chunk, c,
-        )
-        prev = shard(prev, "b", c_ax)
-        feasible = shard(feasible, "b", c_ax)
-        general = prof_table[pfc]
-        avail = shard(merge_estimates(repsc, (general,)), "b", c_ax)
-        assignment, unsched = _divide_batch(
-            stc, repsc, feasible, static_w, avail, prev, frc,
-            has_aggregated, wide, fast,
-        )
-        # Duplicated rows ride the feasibility bitset; their dense rows are
-        # zero so the resident diff ignores them (meta carries their state)
-        assignment = shard(
-            jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
-            "b", c_ax,
-        )
-        dense8 = assignment.astype(jnp.uint8)  # counts <= MAX_REPLICAS_FAST
-        n_placed = (assignment > 0).sum(axis=1).astype(jnp.int32)
-        has_cand = feasible.any(axis=1)
-        meta = (
-            n_placed
-            | (unsched.astype(jnp.int32) << 8)
-            | (has_cand.astype(jnp.int32) << 9)
-        )
-        # diff + in-place resident update. all_rows reads/writes contiguous
-        # slices; partial batches use row gather/scatter (few rows: the
-        # per-row scatter overhead is what made this form wrong for the
-        # 100k storm, which is exactly the all_rows case)
-        if all_rows:
-            # int32 shard-safe offsets (see _fleet_solve: the partitioner
-            # rejects s64 starts on the row-sharded residents)
-            off = (i * chunk).astype(jnp.int32)
-            z32 = jnp.int32(0)
-            old_d = lax.dynamic_slice(rd, (off, z32), (chunk, c))
-            old_m = lax.dynamic_slice_in_dim(rm, off, chunk, 0)
-            rd = lax.dynamic_update_slice(rd, dense8, (off, z32))
-            rm = lax.dynamic_update_slice_in_dim(rm, meta, off, 0)
-        else:
-            old_d = rd[rc]
-            old_m = rm[rc]
-            safe_r = jnp.where(vc, rc, cap)
-            rd = rd.at[safe_r].set(dense8, mode="drop")
-            rm = rm.at[safe_r].set(meta, mode="drop")
-        cell_changed = (dense8 != old_d) & vc[:, None]
-        dcount = cell_changed.sum(axis=1).astype(jnp.int32)
-        changed = (cell_changed.any(axis=1) | (meta != old_m)) & vc
-        if d_cap:
-            # per-row delta compaction via sort, skipped entirely on
-            # steady chunks (the sort over [chunk, C] is the only
-            # non-trivial cost and a steady pass has no changed cells)
-            idxs32 = jnp.arange(c, dtype=jnp.int32)[None, :]
-
-            def _deltas(op):
-                d8, chm = op
-                dp = jnp.where(
-                    chm,
-                    (idxs32 << 9) | (d8.astype(jnp.int32) + 1),
-                    jnp.int32(2**31 - 1),
-                )
-                srt = lax.sort(dp, is_stable=False)[:, :d_slots]
-                return jnp.where(srt == 2**31 - 1, 0, srt)
-
-            deltas = lax.cond(
-                cell_changed.any(),
-                _deltas,
-                lambda op: jnp.zeros((chunk, d_slots), jnp.int32),
-                (dense8, cell_changed),
+        with jax.named_scope("fleet.gather"):
+            sl = lambda a: lax.dynamic_slice_in_dim(
+                a, i * chunk, chunk, axis=0
             )
-        else:
-            deltas = jnp.zeros((chunk, 0), jnp.int32)
+            cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
+            repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
+            psc, pcc = sl(ps), sl(pc)
+            rc = sl(r)
+            repsc, stc, frc, vc = (
+                shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
+                shard(vc, "b"),
+            )
+            cpc, gvc, pfc = (
+                shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
+            )
+            psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
+        with jax.named_scope("fleet.masks"):
+            prev, static_w, feasible = _row_masks(
+                cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
+                pcc, vc, chunk, c,
+            )
+            prev = shard(prev, "b", c_ax)
+            feasible = shard(feasible, "b", c_ax)
+        with jax.named_scope("fleet.estimate"):
+            general = prof_table[pfc]
+            avail = shard(merge_estimates(repsc, (general,)), "b", c_ax)
+        with jax.named_scope("fleet.divide"):
+            assignment, unsched = _divide_batch(
+                stc, repsc, feasible, static_w, avail, prev, frc,
+                has_aggregated, wide, fast,
+            )
+            # Duplicated rows ride the feasibility bitset; their dense rows
+            # are zero so the resident diff ignores them (meta carries their
+            # state)
+            assignment = shard(
+                jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
+                "b", c_ax,
+            )
+            # counts <= MAX_REPLICAS_FAST
+            dense8 = assignment.astype(jnp.uint8)
+            n_placed = (assignment > 0).sum(axis=1).astype(jnp.int32)
+            has_cand = feasible.any(axis=1)
+            meta = (
+                n_placed
+                | (unsched.astype(jnp.int32) << 8)
+                | (has_cand.astype(jnp.int32) << 9)
+            )
+        with jax.named_scope("fleet.diff"):
+            # diff + in-place resident update. all_rows reads/writes
+            # contiguous slices; partial batches use row gather/scatter (few
+            # rows: the per-row scatter overhead is what made this form wrong
+            # for the 100k storm, which is exactly the all_rows case)
+            if all_rows:
+                # int32 shard-safe offsets (see _fleet_solve: the
+                # partitioner rejects s64 starts on the row-sharded residents)
+                off = (i * chunk).astype(jnp.int32)
+                z32 = jnp.int32(0)
+                old_d = lax.dynamic_slice(rd, (off, z32), (chunk, c))
+                old_m = lax.dynamic_slice_in_dim(rm, off, chunk, 0)
+                rd = lax.dynamic_update_slice(rd, dense8, (off, z32))
+                rm = lax.dynamic_update_slice_in_dim(rm, meta, off, 0)
+            else:
+                old_d = rd[rc]
+                old_m = rm[rc]
+                safe_r = jnp.where(vc, rc, cap)
+                rd = rd.at[safe_r].set(dense8, mode="drop")
+                rm = rm.at[safe_r].set(meta, mode="drop")
+            cell_changed = (dense8 != old_d) & vc[:, None]
+            dcount = cell_changed.sum(axis=1).astype(jnp.int32)
+            changed = (cell_changed.any(axis=1) | (meta != old_m)) & vc
+        with jax.named_scope("fleet.deltas"):
+            if d_cap:
+                # per-row delta compaction via sort, skipped entirely on
+                # steady chunks (the sort over [chunk, C] is the only
+                # non-trivial cost and a steady pass has no changed cells)
+                idxs32 = jnp.arange(c, dtype=jnp.int32)[None, :]
+
+                def _deltas(op):
+                    d8, chm = op
+                    dp = jnp.where(
+                        chm,
+                        (idxs32 << 9) | (d8.astype(jnp.int32) + 1),
+                        jnp.int32(2**31 - 1),
+                    )
+                    srt = lax.sort(dp, is_stable=False)[:, :d_slots]
+                    return jnp.where(srt == 2**31 - 1, 0, srt)
+
+                deltas = lax.cond(
+                    cell_changed.any(),
+                    _deltas,
+                    lambda op: jnp.zeros((chunk, d_slots), jnp.int32),
+                    (dense8, cell_changed),
+                )
+            else:
+                deltas = jnp.zeros((chunk, 0), jnp.int32)
         return (rd, rm), (changed, meta, dcount, deltas)
 
     (res_dense, res_meta), outs = lax.scan(
         body, (res_dense, res_meta), jnp.arange(n_chunks)
     )
-    # pin the updated residents to their allocation layout (row-sharded
-    # under a mesh): matching in/out shardings keep the donation aliased,
-    # so the dense grid never double-buffers across passes
-    res_dense = shard(res_dense, "b", c_ax)
-    res_meta = shard(res_meta, "b")
-    # the wire build below is GLOBAL prefix-scan + scatter compaction:
-    # replicate its inputs explicitly so the residents' row sharding
-    # cannot back-propagate into the cumsums (the CPU SPMD partitioner
-    # emits corrupt streams for sharded global scans — see _fleet_solve)
-    changed = shard(outs[0].reshape(-1), None)  # bool[n_pad]
-    meta = shard(outs[1].reshape(-1), None)
-    dcounts = shard(outs[2].reshape(-1), None)
+    with jax.named_scope("fleet.wire"):
+        # pin the updated residents to their allocation layout (row-sharded
+        # under a mesh): matching in/out shardings keep the donation
+        # aliased, so the dense grid never double-buffers across passes
+        res_dense = shard(res_dense, "b", c_ax)
+        res_meta = shard(res_meta, "b")
+        # the wire build below is GLOBAL prefix-scan + scatter compaction:
+        # replicate its inputs explicitly so the residents' row sharding
+        # cannot back-propagate into the cumsums (the CPU SPMD partitioner
+        # emits corrupt streams for sharded global scans: see _fleet_solve)
+        changed = shard(outs[0].reshape(-1), None)  # bool[n_pad]
+        meta = shard(outs[1].reshape(-1), None)
+        dcounts = shard(outs[2].reshape(-1), None)
 
-    # wire: [4B total][bitmask n_pad/8 B][m_cap x 2B changed metas in row
-    # order][4B dtotal][d_cap x 3B cell deltas] (delta section only when
-    # d_cap > 0). n_pad is a multiple of 256, so the bitmask packs evenly.
-    # The wire meta word carries state (n_placed | flags, 10 bits) plus
-    # min(dcount, 63) in the 6 spare bits; res_meta stores STATE ONLY —
-    # dcount is pass-relative and must not trip the next pass's meta diff.
-    wire_meta = meta | (jnp.minimum(dcounts, 63) << 10)
-    cnt = jnp.cumsum(changed.astype(jnp.int32)) - changed
-    total = cnt[-1] + changed[-1].astype(jnp.int32)
-    write = jnp.where(changed & (cnt < m_cap), cnt, m_cap)
-    mbuf = jnp.zeros((m_cap + 1,), jnp.int32).at[write].set(wire_meta)
-    mstream = mbuf[:m_cap]
-    # changed TABLE rows, compacted in the same bitmask order — stays on
-    # device so a speculative phase B can consume it without waiting for
-    # the host to decode the bitmask (saves one host<->device round-trip
-    # per churn pass)
-    rowbuf = (
-        jnp.full((m_cap + 1,), -1, jnp.int32).at[write].set(r)[:m_cap]
-    )
-    w32 = changed.reshape(-1, 32).astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
-    words = (w32 << shifts).sum(axis=-1, dtype=jnp.uint32)
-    mask_u8 = jnp.stack(
-        [(words >> s) & 0xFF for s in (0, 8, 16, 24)], axis=-1
-    ).astype(jnp.uint8).reshape(-1)
-    total_u8 = jnp.stack(
-        [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
-    ).astype(jnp.uint8)
-    meta_u8 = jnp.stack(
-        [mstream & 0xFF, (mstream >> 8) & 0xFF], axis=-1
-    ).astype(jnp.uint8).reshape(-1)
-    parts = [total_u8, mask_u8, meta_u8]
-    if d_cap:
-        # cell-delta stream: deltas of changed rows whose dcount fits the
-        # meta field (<= 62), compacted in bitmask row order; overflow
-        # rows (sentinel 63) ship via phase B instead
-        deltas_all = shard(
-            outs[3].reshape(changed.shape[0], -1), None, None
+        # wire: [4B total][bitmask n_pad/8 B][m_cap x 2B changed metas in
+        # row order][4B dtotal][d_cap x 3B cell deltas] (delta section only
+        # when d_cap > 0). n_pad is a multiple of 256, so the bitmask packs
+        # evenly. The wire meta word carries state (n_placed | flags, 10
+        # bits) plus min(dcount, 63) in the 6 spare bits; res_meta stores
+        # STATE ONLY — dcount is pass-relative and must not trip the next
+        # pass's meta diff.
+        wire_meta = meta | (jnp.minimum(dcounts, 63) << 10)
+        cnt = jnp.cumsum(changed.astype(jnp.int32)) - changed
+        total = cnt[-1] + changed[-1].astype(jnp.int32)
+        write = jnp.where(changed & (cnt < m_cap), cnt, m_cap)
+        mbuf = jnp.zeros((m_cap + 1,), jnp.int32).at[write].set(wire_meta)
+        mstream = mbuf[:m_cap]
+        # changed TABLE rows, compacted in the same bitmask order — stays
+        # on device so a speculative phase B can consume it without waiting
+        # for the host to decode the bitmask (saves one host<->device
+        # round-trip per churn pass)
+        rowbuf = (
+            jnp.full((m_cap + 1,), -1, jnp.int32).at[write].set(r)[:m_cap]
         )
-        contrib = changed & (dcounts <= 62)
-        rowv = jnp.where(contrib[:, None], deltas_all, 0).reshape(-1)
-        validv = rowv != 0
-        doffs = jnp.cumsum(validv.astype(jnp.int32)) - validv
-        dtotal = doffs[-1] + validv[-1].astype(jnp.int32)
-        dwrite = jnp.where(validv & (doffs < d_cap), doffs, d_cap)
-        dbuf = jnp.zeros((d_cap + 1,), jnp.int32).at[dwrite].set(rowv)
-        dstream = dbuf[:d_cap]
-        dtotal_u8 = jnp.stack(
-            [(dtotal >> s) & 0xFF for s in (0, 8, 16, 24)]
-        ).astype(jnp.uint8)
-        d_u8 = jnp.stack(
-            [dstream & 0xFF, (dstream >> 8) & 0xFF, (dstream >> 16) & 0xFF],
-            axis=-1,
+        w32 = changed.reshape(-1, 32).astype(jnp.uint32)
+        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
+        words = (w32 << shifts).sum(axis=-1, dtype=jnp.uint32)
+        mask_u8 = jnp.stack(
+            [(words >> s) & 0xFF for s in (0, 8, 16, 24)], axis=-1
         ).astype(jnp.uint8).reshape(-1)
-        parts += [dtotal_u8, d_u8]
-    flat = jnp.concatenate(parts)
+        total_u8 = jnp.stack(
+            [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
+        ).astype(jnp.uint8)
+        meta_u8 = jnp.stack(
+            [mstream & 0xFF, (mstream >> 8) & 0xFF], axis=-1
+        ).astype(jnp.uint8).reshape(-1)
+        parts = [total_u8, mask_u8, meta_u8]
+        if d_cap:
+            # cell-delta stream: deltas of changed rows whose dcount fits
+            # the meta field (<= 62), compacted in bitmask row order;
+            # overflow rows (sentinel 63) ship via phase B instead
+            deltas_all = shard(
+                outs[3].reshape(changed.shape[0], -1), None, None
+            )
+            contrib = changed & (dcounts <= 62)
+            rowv = jnp.where(contrib[:, None], deltas_all, 0).reshape(-1)
+            validv = rowv != 0
+            doffs = jnp.cumsum(validv.astype(jnp.int32)) - validv
+            dtotal = doffs[-1] + validv[-1].astype(jnp.int32)
+            dwrite = jnp.where(validv & (doffs < d_cap), doffs, d_cap)
+            dbuf = jnp.zeros((d_cap + 1,), jnp.int32).at[dwrite].set(rowv)
+            dstream = dbuf[:d_cap]
+            dtotal_u8 = jnp.stack(
+                [(dtotal >> s) & 0xFF for s in (0, 8, 16, 24)]
+            ).astype(jnp.uint8)
+            d_u8 = jnp.stack(
+                [dstream & 0xFF, (dstream >> 8) & 0xFF,
+                 (dstream >> 16) & 0xFF],
+                axis=-1,
+            ).astype(jnp.uint8).reshape(-1)
+            parts += [dtotal_u8, d_u8]
+        flat = jnp.concatenate(parts)
     return flat, rowbuf, res_dense, res_meta
 
 
@@ -736,39 +765,42 @@ def _fleet_entries(
     idxs = jnp.arange(c, dtype=jnp.int32)[None, :]
 
     def body(carry, i):
-        rc = lax.dynamic_slice_in_dim(rows, i * chunk, chunk, 0)
-        vc = rc >= 0
-        dense = res_dense[jnp.maximum(rc, 0)].astype(jnp.int32)
-        dense = jnp.where(vc[:, None], dense, 0)
-        packed_full = jnp.where(
-            dense > 0, (idxs << 8) | dense, jnp.int32(2**31 - 1)
-        )
-        srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
+        with jax.named_scope("fleet.gather"):
+            rc = lax.dynamic_slice_in_dim(rows, i * chunk, chunk, 0)
+            vc = rc >= 0
+            dense = res_dense[jnp.maximum(rc, 0)].astype(jnp.int32)
+            dense = jnp.where(vc[:, None], dense, 0)
+        with jax.named_scope("fleet.compact"):
+            packed_full = jnp.where(
+                dense > 0, (idxs << 8) | dense, jnp.int32(2**31 - 1)
+            )
+            srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
         return carry, jnp.where(srt == 2**31 - 1, 0, srt)
 
     _, ents = lax.scan(body, 0, jnp.arange(n_chunks))
-    # replicate before the global compaction scan: the dense resident
-    # input is row-sharded on mesh engines, and a sharded cumsum is
-    # exactly the CPU-SPMD corruption _fleet_solve guards against
-    if mesh is not None:
-        ents = lax.with_sharding_constraint(
-            ents, NamedSharding(mesh, P())
-        )
-    entries = ents.reshape(-1, k_out)  # [m_pad, k_out]
-    valid_e = (entries > 0).reshape(-1)
-    offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
-    total = offs[-1] + valid_e[-1].astype(jnp.int32)
-    packed = entries.reshape(-1)
-    write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
-    buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
-    stream = buf[:e_cap]
-    if byte_wire:
-        total_u8 = jnp.stack(
-            [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
-        ).astype(jnp.uint8)
-        e_u8 = _entry_wire(stream, e_cap, pack21)
-        return jnp.concatenate([total_u8, e_u8])
-    return jnp.concatenate([total[None], stream])
+    with jax.named_scope("fleet.wire"):
+        # replicate before the global compaction scan: the dense resident
+        # input is row-sharded on mesh engines, and a sharded cumsum is
+        # exactly the CPU-SPMD corruption _fleet_solve guards against
+        if mesh is not None:
+            ents = lax.with_sharding_constraint(
+                ents, NamedSharding(mesh, P())
+            )
+        entries = ents.reshape(-1, k_out)  # [m_pad, k_out]
+        valid_e = (entries > 0).reshape(-1)
+        offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
+        total = offs[-1] + valid_e[-1].astype(jnp.int32)
+        packed = entries.reshape(-1)
+        write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
+        buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
+        stream = buf[:e_cap]
+        if byte_wire:
+            total_u8 = jnp.stack(
+                [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
+            ).astype(jnp.uint8)
+            e_u8 = _entry_wire(stream, e_cap, pack21)
+            return jnp.concatenate([total_u8, e_u8])
+        return jnp.concatenate([total[None], stream])
 
 
 def _decode_entry_wire(raw2, cap_used: int, byte_wire: bool, pack21: bool):
@@ -1254,6 +1286,10 @@ class FleetTable:
         self._result_gen = 0
         # per-phase wall times of the last pass (bench breakdown surface)
         self.last_breakdown: dict[str, float] = {}
+        # (breakdown key, start, end) perf_counter stamps of the current
+        # pass's timed phases, in order: what the phase spans are placed
+        # from (_phase / _emit_phase_spans)
+        self._phase_marks: list[tuple] = []
         # rows (re)packed by the current pass (_pack_row increments):
         # the packed-vs-replayed split the history ring records per wave
         self._packed_this_pass = 0
@@ -1918,6 +1954,7 @@ class FleetTable:
         from ..utils.tracing import tracer
 
         with tracer.span("scheduler.solve") as sp:
+            self._phase_marks = []
             res = self._schedule_pass(problems, compiled, delta)
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
@@ -1988,67 +2025,78 @@ class FleetTable:
     #: breakdown keys that are pure host work outside the dispatch/fetch
     #: windows (pack, delta scatter, result decode)
     _HOST_PHASE_KEYS = ("upsert", "sync", "prep", "post")
+    #: breakdown key of a timed phase -> the span it is recorded as
+    _PHASE_SPANS = {
+        **dict.fromkeys(_HOST_PHASE_KEYS, "kernel.host"),
+        "dispatch": "kernel.dispatch",
+        "device": "kernel.device",
+        "fetch": "kernel.fetch",
+    }
+
+    def _phase(self, tmr: dict, key: str, t0: float) -> float:
+        """Close the pass phase ``key`` that began at ``t0``: its seconds
+        go into the breakdown (summed where a phase has two stretches, as
+        a delta pass's ``post``) and its interval is kept for the phase
+        spans. Returns the closing stamp — the next phase's start, so the
+        phases of a pass tile it without gaps."""
+        now = time.perf_counter()
+        tmr[key] = tmr.get(key, 0.0) + (now - t0)
+        self._phase_marks.append((key, t0, now))
+        return now
 
     def _emit_phase_spans(self) -> None:
         """Kernel phase spans + karmada_tpu_kernel_phase_seconds from the
-        last pass's breakdown. Components are DISJOINT: ``fetch`` is the
-        whole post-device window (wire transfer + decode + entry folds —
-        its internal dispatch_b/fetch_b/delta_fold live inside it), and
-        the fenced ``device`` window carries the compile attribution flag
-        when this pass minted a fresh XLA trace."""
+        pass's phase stamps, each span at its TRUE interval: one
+        ``kernel.host`` per host stretch (``phase`` = upsert | sync | prep
+        before the dispatch, post after the fetch), then
+        ``kernel.dispatch``, ``kernel.device``, ``kernel.fetch``. Children
+        of one ``scheduler.solve`` are therefore disjoint and ordered, and
+        a device-idle gap is charged to the phase that really held the
+        host (ISSUE 25). Components are DISJOINT: ``fetch`` is the whole
+        post-device window (wire transfer + decode + entry folds — its
+        internal dispatch_b/fetch_b/delta_fold live inside it), and the
+        fenced ``device`` window carries the compile attribution flag when
+        this pass minted a fresh XLA trace. The histogram is observed once
+        a phase a pass (``host`` with the stretches' sum)."""
         from ..utils.metrics import kernel_phase_seconds
         from ..utils.tracing import tracer
 
         tmr = self.last_breakdown
-        host = sum(tmr.get(k, 0.0) for k in self._HOST_PHASE_KEYS)
         # compile attribution: the compile of a fresh trace runs inside
         # the dispatch call or surfaces at the device fence — on a
         # fresh-trace pass both windows carry the flag, so the summary's
         # compile_s covers either
         fresh = bool(self.new_trace_last_pass)
-        phases = [
-            (
-                "kernel.host",
-                host,
-                "host",
-                # the pass's host->device bytes ride the host span so the
-                # history sampler (and a dumped wave) can read transfer
-                # volume without reaching into the engine
-                {"upload_mb": tmr.get("upload_mb", 0.0)},
-            ),
-            (
-                "kernel.dispatch",
-                tmr.get("dispatch", 0.0),
-                "host",
-                {"compile": fresh} if fresh else {},
-            ),
-            (
-                "kernel.device",
-                tmr.get("device", 0.0),
-                "device",
-                {"compile": fresh},
-            ),
-            (
-                "kernel.fetch",
-                tmr.get("fetch", 0.0),
-                "host",
-                {
-                    "fetch_mb": tmr.get("fetch_mb", 0.0),
-                    "changed_rows": tmr.get("changed_rows", 0.0),
-                },
-            ),
-        ]
-        for name, seconds, kind, attrs in phases:
-            if seconds <= 0.0:
+        attrs = {
+            # the pass's host->device bytes ride the stretch that uploads,
+            # so the history sampler (and a dumped wave) can read transfer
+            # volume without reaching into the engine
+            "sync": {"upload_mb": tmr.get("upload_mb", 0.0)},
+            "dispatch": {"compile": fresh} if fresh else {},
+            "device": {"compile": fresh},
+            "fetch": {
+                "fetch_mb": tmr.get("fetch_mb", 0.0),
+                "changed_rows": tmr.get("changed_rows", 0.0),
+            },
+        }
+        seconds: dict[str, float] = {}
+        for key, t0, t1 in self._phase_marks:
+            if t1 <= t0:
                 continue
-            tracer.record(name, seconds, kind=kind, **attrs)
-            kernel_phase_seconds.observe(seconds, phase=name.split(".")[1])
+            name = self._PHASE_SPANS[key]
+            tracer.record(
+                name, t1 - t0, start=t0,
+                kind="device" if key == "device" else "host",
+                **({"phase": key} if name == "kernel.host" else {}),
+                **attrs.get(key, {}),
+            )
+            seconds[name] = seconds.get(name, 0.0) + (t1 - t0)
+        for name, total in seconds.items():
+            kernel_phase_seconds.observe(total, phase=name.split(".")[1])
 
     def _schedule_pass(
         self, problems: Sequence, compiled: Sequence, delta=None
     ) -> list:
-        import time as _time
-
         if delta is not None:
             res = self._schedule_delta(problems, compiled, delta)
             if res is not None:
@@ -2057,7 +2105,7 @@ class FleetTable:
             # fall through to the full pass below
 
         tmr: dict[str, float] = {}
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         self._pass += 1
         self.new_trace_last_pass = False
         self._packed_this_pass = 0
@@ -2088,17 +2136,15 @@ class FleetTable:
             )
             self._reuse = (problems, compiled, rows_np)
             self._reuse_pass = self._pass
-        tmr["upsert"] = _time.perf_counter() - t0
+        t0 = self._phase(tmr, "upsert", t0)
         # packed-vs-replayed split of THIS pass: a replayed row rode its
         # fingerprint (or the batch-identity fast path) without re-packing
         tmr["rows_packed"] = self._packed_this_pass
         tmr["rows_replayed"] = max(
             len(problems) - self._packed_this_pass, 0
         )
-        t0 = _time.perf_counter()
         self._sync_device()
-        tmr["sync"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
+        t0 = self._phase(tmr, "sync", t0)
         n = len(rows_np)
         # adaptive chunk: a straggler batch of a few hundred rows should
         # not execute a full 4096-row chunk (pow2 snapping keeps the trace
@@ -2244,8 +2290,6 @@ class FleetTable:
         the covering pass), a moved snapshot generation, an uncertified
         kernel set, or a majority-dirty batch where the full pass is
         simply cheaper — and the caller runs the full pass."""
-        import time as _time
-
         ru = self._reuse
         n = len(problems)
         if (
@@ -2264,7 +2308,7 @@ class FleetTable:
             return None
         if idx.size * 2 > n:
             return None  # majority dirty: the full pass wins
-        t_all = _time.perf_counter()
+        t_all = time.perf_counter()
         rows_full = ru[2]
         n_sub = int(idx.size)
         if n_sub == 0:
@@ -2281,7 +2325,7 @@ class FleetTable:
                 "dirty_rows": 0.0,
             }
             res = self._replay_result(problems, rows_full, tmr)
-            tmr["post"] = _time.perf_counter() - t_all
+            self._phase(tmr, "post", t_all)
             self.last_breakdown = tmr
             return res
         sub_p = [problems[int(i)] for i in idx]
@@ -2327,9 +2371,9 @@ class FleetTable:
         tmr["dirty_rows"] = float(n_sub)
         self._reuse = (problems, compiled, rows_new)
         self._reuse_pass = self._pass
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         res = self._replay_result(problems, rows_new, tmr)
-        tmr["post"] = tmr.get("post", 0.0) + (_time.perf_counter() - t0)
+        self._phase(tmr, "post", t0)
         return res
 
     def _replay_result(self, problems, rows_full, tmr):
@@ -2436,8 +2480,6 @@ class FleetTable:
         dense mirror would exceed the HBM budget (multi-million-row
         fleets). Everything ships per pass: full meta + tuned entry
         stream."""
-        import time as _time
-
         cap_round = _cap_round
         # delta base: device-resident per-row entry vectors + the matching
         # host mirror, k_res wide (grow-only running max of k_out so a
@@ -2555,24 +2597,21 @@ class FleetTable:
                 return total, meta, stream
             return int(arr[0]), arr[1 : 1 + n_pad], arr[1 + n_pad :]
 
-        tmr["prep"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
+        t0 = self._phase(tmr, "prep", t0)
         # the resident base is DONATED into the dispatch: detach the
         # attribute first so a pass that dies mid-solve leaves no
         # deleted-buffer reference behind (the next pass re-seeds the
         # delta base instead of crashing on a consumed array)
         res_in, self._resident_entries = self._resident_entries, None
         flat, resident = solve(rows_dev, e_cap, res_in)
-        tmr["dispatch"] = _time.perf_counter() - t0
+        t0 = self._phase(tmr, "dispatch", t0)
         # device fence at the span boundary: block_until_ready splits the
         # on-device execute (plus compile, when this pass minted a fresh
         # trace) from the host-side transfer+decode that follows — the
         # fetch would block on the same event anyway, so the fence costs
         # nothing and buys the device/host attribution
-        t0 = _time.perf_counter()
         flat.block_until_ready()
-        tmr["device"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
+        t0 = self._phase(tmr, "device", t0)
         raw = np.asarray(flat)
         fetched_bytes = raw.nbytes
         total, meta, stream = decode(raw, e_cap)
@@ -2593,9 +2632,8 @@ class FleetTable:
             total, meta, stream = decode(raw, cap_round(safe))
         assert total <= len(stream), (total, e_cap)
         self._resident_entries = resident
-        tmr["fetch"] = _time.perf_counter() - t0
+        t0 = self._phase(tmr, "fetch", t0)
         tmr["fetch_mb"] = fetched_bytes / 1e6
-        t0 = _time.perf_counter()
         self._last_total = total
         n_placed = (meta & 0xFF).astype(np.int64)
         unsched = (meta >> 8) & 1
@@ -2626,7 +2664,7 @@ class FleetTable:
             )
         ]
         terms = [self._terms[r] for r in rows_np]
-        tmr["post"] = _time.perf_counter() - t0
+        self._phase(tmr, "post", t0)
         self.last_breakdown = tmr
         return _FleetResultList(
             problems, terms, batches, n_pad, n_placed, unsched,
@@ -2679,15 +2717,13 @@ class FleetTable:
         and fold the full runs into the host mirror. The entry cap is
         host-summed from ``counts`` so overflow is structurally
         impossible. Returns the fetched byte count."""
-        import time as _time
-
         e_want = int(counts.sum())
         m_pad_b = max(2048, _pow2(len(rows)))
         b_chunk = min(eff_chunk, m_pad_b)
         rows_b = np.full(m_pad_b, -1, np.int32)
         rows_b[: len(rows)] = rows
         e_cap = _cap_round(max(e_want, 1))
-        t_b = _time.perf_counter()
+        t_b = time.perf_counter()
         rows_b_dev = jnp.asarray(rows_b)
         self._mark_entries_trace(
             rows_b_dev, chunk=b_chunk, n_chunks=m_pad_b // b_chunk,
@@ -2705,10 +2741,10 @@ class FleetTable:
             pack21=pack21 and byte_wire,
             mesh=self._entries_mesh,
         )
-        tmr["dispatch_b"] = _time.perf_counter() - t_b
-        t_b = _time.perf_counter()
+        tmr["dispatch_b"] = time.perf_counter() - t_b
+        t_b = time.perf_counter()
         raw2 = np.asarray(flat2)
-        tmr["fetch_b"] = _time.perf_counter() - t_b
+        tmr["fetch_b"] = time.perf_counter() - t_b
         total2, stream = _decode_entry_wire(raw2, e_cap, byte_wire, pack21)
         assert total2 == e_want, (total2, e_want)
         from .. import native
@@ -2727,8 +2763,6 @@ class FleetTable:
         on a steady pass) and, only when rows changed, _fleet_entries over
         exactly those rows with an exactly-sized entry buffer (no
         overflow rerun by construction)."""
-        import time as _time
-
         if (
             self._res_dense is None
             or self._res_dense.shape != (self.cap, c)
@@ -2840,8 +2874,7 @@ class FleetTable:
         self._d_cap_cur = d_cap if d_on else None
 
         cap_round = _cap_round
-        tmr["prep"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
+        t0 = self._phase(tmr, "prep", t0)
         if self._mark_trace(*a_key(m_cap, d_cap)):
             self._record_trace(
                 "fleet_pass", a_key(m_cap, d_cap),
@@ -2916,21 +2949,19 @@ class FleetTable:
                 pack21=pack21 and byte_wire,
                 mesh=self._entries_mesh,
             )
-        tmr["dispatch"] = _time.perf_counter() - t0
+        t0 = self._phase(tmr, "dispatch", t0)
         # device fence (see _solve_legacy): splits phase A's on-device
         # execute (+compile on a fresh trace) from the wire/decode window.
         # The speculative B keeps running behind it — the fence waits on
         # A's output only, so the B-overlaps-A's-decode flow is preserved.
-        t0 = _time.perf_counter()
         flat.block_until_ready()
-        tmr["device"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
+        t0 = self._phase(tmr, "device", t0)
         # A's wire and the speculative B's are fetched separately, so B's
         # transfer overlaps A's fetch+decode. Whether one fused fetch would
         # win on a chip local to the process is not measured on this
         # machine (ROADMAP Design 3).
         raw = np.asarray(flat)
-        tmr["fetch_a"] = _time.perf_counter() - t0
+        tmr["fetch_a"] = time.perf_counter() - t0
         fetched_bytes = raw.nbytes
         from .. import native
 
@@ -2974,7 +3005,7 @@ class FleetTable:
                 d_cap and have_dcounts and dtotal <= d_cap
             )
             if use_delta:
-                t_b = _time.perf_counter()
+                t_b = time.perf_counter()
                 dch = metas >> 10  # min(changed cells, 63) per changed row
                 norm = dch <= 62
                 nd_norm = dch[norm].astype(np.int64)
@@ -2990,7 +3021,7 @@ class FleetTable:
                     )
                 # decode+merge time only; an overflow-row fetch below
                 # reports its own dispatch_b/fetch_b
-                tmr["delta_fold"] = _time.perf_counter() - t_b
+                tmr["delta_fold"] = time.perf_counter() - t_b
                 tmr["delta_rows"] = float(int(norm.sum()))
                 rows_over = ch_rows[~norm]
                 if rows_over.size:
@@ -3014,10 +3045,10 @@ class FleetTable:
                 ):
                     # the speculative B covers exactly the changed rows
                     spec_used = True
-                    t_b = _time.perf_counter()
+                    t_b = time.perf_counter()
                     raw2 = np.asarray(spec_flat)
                     fetched_bytes += raw2.nbytes
-                    tmr["fetch_b"] = _time.perf_counter() - t_b
+                    tmr["fetch_b"] = time.perf_counter() - t_b
                     total2, stream = _decode_entry_wire(
                         raw2, spec_cap, byte_wire, pack21
                     )
@@ -3039,16 +3070,15 @@ class FleetTable:
             # speculation mispredicted (the pass folded another way): block
             # it out NOW and account the cost in this pass — an unfetched
             # dispatch would otherwise drain into the next pass's fetch
-            t_b = _time.perf_counter()
+            t_b = time.perf_counter()
             spec_flat.block_until_ready()
-            tmr["spec_drain"] = _time.perf_counter() - t_b
+            tmr["spec_drain"] = time.perf_counter() - t_b
         self._delta_live = use_delta
         if d_cap:
             self._last_dtotal = int(dtotal)
-        tmr["fetch"] = _time.perf_counter() - t0
+        t0 = self._phase(tmr, "fetch", t0)
         tmr["fetch_mb"] = fetched_bytes / 1e6
         tmr["changed_rows"] = float(total)
-        t0 = _time.perf_counter()
 
         meta_sel = self._host_meta[rows_np]
         n_placed = (meta_sel & 0xFF).astype(np.int64)
@@ -3063,7 +3093,7 @@ class FleetTable:
             )
         ]
         terms = [self._terms[r] for r in rows_np]
-        tmr["post"] = _time.perf_counter() - t0
+        self._phase(tmr, "post", t0)
         self.last_breakdown = tmr
         return _FleetResultList(
             problems, terms, batches, n_pad, n_placed, unsched,

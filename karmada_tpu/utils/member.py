@@ -23,6 +23,7 @@ from typing import Any, Callable, Iterable, Optional
 from ..api.core import Resource
 from ..estimator.accurate import NodeState
 from .clone import clone_resource
+from .worker import WriteCount
 
 
 class UnreachableError(Exception):
@@ -68,6 +69,9 @@ class MemberCluster:
         self._resources: dict[tuple[str, str, str], Resource] = {}
         self._watchers: list[Callable[[MemberEvent], None]] = []
         self._lock = threading.RLock()
+        # objects applied + deleted; the registry this member joins swaps
+        # in the count its plane shares (utils.worker.WriteCount)
+        self._write_count = WriteCount()
         # workload-key -> unschedulable replica count (descheduler input;
         # ref: estimator server/replica/replica.go)
         self.unschedulable_replicas: dict[str, int] = {}
@@ -115,6 +119,7 @@ class MemberCluster:
             existed = key in self._resources
             obj.meta.resource_version += 1
             self._resources[key] = obj
+            self._write_count.n += 1
         self._notify(
             MemberEvent(
                 "Modified" if existed else "Added",
@@ -132,6 +137,8 @@ class MemberCluster:
         self._check()
         with self._lock:
             obj = self._resources.pop((gvk, namespace, name), None)
+            if obj is not None:
+                self._write_count.n += 1
         if obj is not None:
             self._notify(MemberEvent("Deleted", self.name, gvk, namespace, name, obj))
         return obj
@@ -400,11 +407,15 @@ class SubprocessExecRuntime:
 
 
 class MemberClientRegistry:
-    def __init__(self) -> None:
+    def __init__(self, write_count: Optional[WriteCount] = None) -> None:
         self._clients: dict[str, MemberCluster] = {}
+        #: bumped by every object a registered member applies or deletes
+        #: (a plane passes its store's, so one count covers all its state)
+        self.write_count = write_count or WriteCount()
 
     def register(self, member: MemberCluster) -> None:
         self._clients[member.name] = member
+        member._write_count = self.write_count
 
     def deregister(self, name: str) -> None:
         self._clients.pop(name, None)

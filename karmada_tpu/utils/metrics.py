@@ -73,6 +73,8 @@ def _help_line(name: str, help_: str) -> str:
 
 
 class Counter:
+    kind = "counter"
+
     def __init__(self, name: str, help_: str = ""):
         self.name = name
         self.help = help_
@@ -101,17 +103,38 @@ class Counter:
         if self.help:
             yield _help_line(self.name, self.help)
         yield f"# TYPE {self.name} counter"
-        with self._lock:
-            items = sorted(self._values.items())
-        for key, v in items:
+        for key, v in sorted(self.samples().items()):
             label_s = _label_str(key)
             yield f"{self.name}{{{label_s}}} {v}" if label_s else f"{self.name} {v}"
+
+
+class SampledCounter(Counter):
+    """A counter the registry only READS: its owner counts in plain
+    numbers and ``read()`` answers ``{label-set: value}`` when asked. For a
+    source that may take no lock where it counts — the collector's callback
+    (utils.tracing.GcWatch) fires at any allocation, also one made under
+    this registry's own locks."""
+
+    def __init__(self, name: str, help_: str, read):
+        super().__init__(name, help_)
+        self._read = read
+
+    def inc(self, amount: float = 1.0, **labels) -> None:
+        raise TypeError(f"{self.name} is read from its source, not incremented")
+
+    def value(self, **labels) -> float:
+        return self.samples().get(_label_key(labels), 0.0)
+
+    def samples(self) -> dict[tuple, float]:
+        return dict(self._read())
 
 
 class Gauge:
     """A settable sample (queue depth, subscriber count). Same lock
     contract as Counter: set/add mutate and every read snapshots under the
     lock."""
+
+    kind = "gauge"
 
     def __init__(self, name: str, help_: str = ""):
         self.name = name
@@ -163,6 +186,8 @@ class Gauge:
 
 
 class Histogram:
+    kind = "histogram"
+
     def __init__(self, name: str, help_: str = "", buckets=_DEFAULT_BUCKETS):
         self.name = name
         self.help = help_
@@ -285,8 +310,11 @@ class Registry:
     def __init__(self) -> None:
         self._metrics: list = []
 
-    def counter(self, name: str, help_: str = "") -> Counter:
-        c = Counter(name, help_)
+    def counter(self, name: str, help_: str = "", *, read=None) -> Counter:
+        """``read``: the counter's owner keeps the numbers (SampledCounter)."""
+        c = Counter(name, help_) if read is None else SampledCounter(
+            name, help_, read
+        )
         self._metrics.append(c)
         return c
 
@@ -309,9 +337,7 @@ class Registry:
     def families(self) -> list:
         """(name, type, help) per registered metric — the docs metric
         table and its drift guard (tools/docs_from_bench.py) read this."""
-        return [
-            (m.name, type(m).__name__.lower(), m.help) for m in self._metrics
-        ]
+        return [(m.name, m.kind, m.help) for m in self._metrics]
 
     def render(self) -> str:
         lines: list[str] = []
@@ -433,6 +459,13 @@ worker_reconciles = registry.counter(
     "karmada_tpu_worker_reconciles_total",
     "reconciles drained, by worker queue",
 )
+worker_noop_reconciles = registry.counter(
+    "karmada_tpu_worker_noop_reconciles_total",
+    "keys whose reconcile finished and changed nothing (returned DONE with "
+    "no store write, or turned away by a batch reconciler's own gate), by "
+    "worker queue; beside karmada_tpu_worker_reconciles_total it is the "
+    "useful-to-attempted ratio of a controller (counted once a drain)",
+)
 worker_queue_depth = registry.gauge(
     "karmada_tpu_worker_queue_depth",
     "keys still queued per worker after its last drain",
@@ -507,6 +540,33 @@ trace_spans_dropped = registry.counter(
     "wave-trace spans evicted off the tracer ring (one inc per "
     "overwrite) — nonzero means wave_summary coverage is undercounting; "
     "raise KARMADA_TPU_TRACE_CAPACITY for 1M-tier storms",
+)
+
+
+def _gc_samples(field: str):
+    """Reader over the collector watch of utils.tracing — sys.modules-gated:
+    a process that never made a tracer has no watch, and a scrape must not
+    install one."""
+    import sys
+
+    def read() -> dict:
+        tracing = sys.modules.get("karmada_tpu.utils.tracing")
+        return tracing.gc_watch.samples(field) if tracing is not None else {}
+
+    return read
+
+
+gc_collections = registry.counter(
+    "karmada_tpu_gc_collections_total",
+    "collections of the CPython heap, by generation (the program's own "
+    "gc.callbacks entry; go_gc_duration_seconds_count in the reference)",
+    read=_gc_samples("runs"),
+)
+gc_pause_seconds = registry.counter(
+    "karmada_tpu_gc_pause_seconds_total",
+    "seconds the collector held the interpreter, by generation; a full "
+    "(generation-2) collection is also a runtime.gc span in the wave trace",
+    read=_gc_samples("seconds"),
 )
 device_bytes = registry.gauge(
     "karmada_tpu_device_bytes",

@@ -17,6 +17,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Iterable, Optional
 
 from ..api.core import ObjectMeta, new_uid
+from .worker import WriteCount
 
 ADDED = "Added"
 MODIFIED = "Modified"
@@ -67,6 +68,10 @@ class Store:
         self._watchers: dict[str, list[WatchHandler]] = {}
         self._all_watchers: list[WatchHandler] = []
         self._rv = 0
+        #: every mutation bumps it (a hard delete too, which moves no
+        #: resource version); the plane shares it with its member clients
+        #: and its reconcile runtime reads it (utils.worker.WriteCount)
+        self.write_count = WriteCount()
         # admission(kind, obj) raises to reject an apply (webhook seam);
         # delete_admission likewise guards Delete operations
         self._admission = admission
@@ -119,6 +124,7 @@ class Store:
                         f"{current_rv}, precondition {expected_rv}"
                     )
             self._rv += 1
+            self.write_count.n += 1
             obj.meta.resource_version = self._rv
             if not obj.meta.uid:
                 obj.meta.uid = existing.meta.uid if existing else new_uid()
@@ -166,6 +172,7 @@ class Store:
             keyed.append((kind, key, obj))
         events = []
         with self._lock:
+            self.write_count.n += len(keyed)
             for kind, key, obj in keyed:
                 bucket = self._buckets.setdefault(kind, {})
                 existing = bucket.get(key)
@@ -210,12 +217,14 @@ class Store:
                 if obj.meta.deletion_timestamp is None:
                     obj.meta.deletion_timestamp = time.time()
                     self._rv += 1
+                    self.write_count.n += 1
                     obj.meta.resource_version = self._rv
                     event = Event(MODIFIED, kind, key, obj)
                 else:
                     return obj
             else:
                 del bucket[key]
+                self.write_count.n += 1
                 event = Event(DELETED, kind, key, obj)
         self._deliver(event)
         return obj

@@ -1,12 +1,11 @@
-"""Slow-operation tracing, wave-scoped span tracing, event recording.
+"""Wave-scoped span tracing, event recording.
 
-Ref: k8s.io/utils/trace usage (estimator server/estimate.go:37-54 logs
-"Estimating" traces over 100ms) and the EventRecorder pattern
-(scheduler.go:921-967 — events recorded on both binding and template).
+Ref: the EventRecorder pattern (scheduler.go:921-967 — events recorded on
+both binding and template).
 
-The wave tracer (ISSUE 6 tentpole) is the plane-wide form of utiltrace:
-a monotonic WAVE id is stamped when new work enters the plane (the
-detector's template events, or any settle that finds work queued), and
+The wave tracer (ISSUE 6 tentpole): a monotonic WAVE id is stamped when
+new work enters the plane (the detector's template events, or any settle
+that finds work queued), and
 every instrumented region — controller drains, scheduler passes, fleet
 kernel phases, estimator refreshes — records a ``Span`` carrying that
 wave id plus a parent span id, so one storm wave reconstructs as a single
@@ -43,10 +42,20 @@ mutate/read under one lock; the OPEN-span parent chain is thread-local
 (each thread nests its own spans — a span never migrates threads), and an
 *ambient* thread-local context carries the wave/trace/parent triple onto
 executor threads (fan-out pools) and into server handlers.
+
+The collector from inside (ISSUE 25): one ``gc.callbacks`` entry, installed
+beside the process-wide tracer, counts every collection and its pause by
+generation (``karmada_tpu_gc_*``) and records each FULL collection as a
+``runtime.gc`` span under whatever span its thread had open — so a ~2 s
+generation-2 pause is a child of the drain it interrupted, not that
+drain's self time. The callback can fire at any allocation, under any lock
+its thread holds (this tracer's own included), so it takes none: it keeps
+plain numbers and parks the span, and the next ring access files it.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import logging
@@ -98,7 +107,10 @@ SPAN_NAMES: dict[str, str] = {
         "armed-only preemption round of a pass: plane-wide victim "
         "selection + the boosted same-pass re-solve (ISSUE 14)"
     ),
-    "kernel.host": "kernel host phases: pack/upsert/sync/decode",
+    "kernel.host": (
+        "one host stretch of a fleet pass, at its true interval: "
+        "phase=upsert|sync|prep before the dispatch, post after the fetch"
+    ),
     "kernel.dispatch": (
         "kernel dispatch window (sync backends execute inside it; "
         "compile=true on a fresh-trace pass)"
@@ -150,6 +162,11 @@ SPAN_NAMES: dict[str, str] = {
     "channel.breaker": (
         "a circuit-breaker state transition (zero-duration marker span)"
     ),
+    "runtime.gc": (
+        "one FULL (generation-2) collection of the CPython heap, at its "
+        "true interval under the span its thread had open (generation / "
+        "collected attrs); younger generations are counted, not spanned"
+    ),
 }
 
 
@@ -177,42 +194,6 @@ def render_span_table() -> str:
     for name in sorted(SPAN_NAMES):
         lines.append(f"| `{name}` | {SPAN_NAMES[name]} |")
     return "\n".join(lines)
-
-
-@dataclass
-class Step:
-    name: str
-    at: float
-
-
-class Trace:
-    """utiltrace.Trace: named steps, logged when total exceeds threshold."""
-
-    def __init__(self, name: str, **fields):
-        self.name = name
-        self.fields = fields
-        self.start = time.perf_counter()
-        self.steps: list[Step] = []
-
-    def step(self, name: str) -> None:
-        self.steps.append(Step(name, time.perf_counter()))
-
-    def log_if_long(self, threshold_seconds: float = 0.1) -> Optional[str]:
-        total = time.perf_counter() - self.start
-        if total < threshold_seconds:
-            return None
-        parts = [f'"{self.name}" total={total * 1e3:.1f}ms']
-        last = self.start
-        for s in self.steps:
-            parts.append(f"{s.name}={(s.at - last) * 1e3:.1f}ms")
-            last = s.at
-        msg = " ".join(parts) + (
-            " " + " ".join(f"{k}={v}" for k, v in self.fields.items())
-            if self.fields
-            else ""
-        )
-        log.info(msg)
-        return msg
 
 
 # --------------------------------------------------------------------------
@@ -354,6 +335,9 @@ class WaveTracer:
         self.capacity = _env_capacity() if capacity is None else capacity
         self._lock = threading.Lock()
         self._spans: deque[Span] = deque()
+        # spans completed where no lock may be taken (record_late), filed
+        # into the ring by the next access that holds the lock
+        self._late: list[Span] = []
         self._wave_seq = itertools.count(1)
         self._span_seq = itertools.count(1)
         self._local = threading.local()
@@ -507,11 +491,14 @@ class WaveTracer:
             self._local.stack = stack
         return stack
 
-    def _open_ctx(self) -> tuple[int, str, Optional[int]]:
+    def _open_ctx(
+        self, *, lock_free: bool = False
+    ) -> tuple[int, str, Optional[int]]:
         """(wave, trace_id, parent span id) for a span opening NOW on this
         thread: innermost open span wins, then the thread's ambient
         context (executor tasks / server handlers), then the process-wide
-        current wave — read under the lock, stamped exactly once."""
+        current wave — read under the lock, stamped exactly once
+        (``lock_free``: read as it stands, for ``record_late``)."""
         stack = self._stack()
         if stack:
             top = stack[-1]
@@ -519,6 +506,9 @@ class WaveTracer:
         amb = getattr(self._local, "ambient", None)
         if amb is not None:
             return amb.wave, amb.trace_id, amb.span_id
+        if lock_free:
+            wave = self.current_wave  # graftlint: disable=GL011
+            return wave, self._trace_ids.get(wave, ""), None  # graftlint: disable=GL011
         with self._lock:
             return (
                 self.current_wave,
@@ -554,19 +544,35 @@ class WaveTracer:
         finally:
             self._local.ambient = prev
 
+    # called-with-lock-held helper (the *_locked naming convention)
+    def _push_locked(self, sp: Span) -> int:  # graftlint: disable=GL004,GL011
+        """Ring push with counted eviction; returns the evictions (0/1)."""
+        dropped = 0
+        if len(self._spans) >= self.capacity:
+            old = self._spans.popleft()
+            dropped = 1
+            self._dropped_total += 1
+            self._dropped_by_wave[old.wave] = (
+                self._dropped_by_wave.get(old.wave, 0) + 1
+            )
+        self._spans.append(sp)
+        return dropped
+
+    def _file_late_locked(self) -> int:  # graftlint: disable=GL004,GL011
+        """File the parked ``record_late`` spans into the ring. pop(0) and
+        the callback's append are each atomic, so a collection that lands
+        in here only lengthens the list being drained."""
+        dropped = 0
+        while self._late:
+            dropped += self._push_locked(self._late.pop(0))
+        return dropped
+
     def _append(self, sp: Span) -> None:
         """Ring append with counted eviction (called with the lock NOT
         held)."""
-        dropped: Optional[Span] = None
         with self._lock:
-            if len(self._spans) >= self.capacity:
-                dropped = self._spans.popleft()
-                self._dropped_total += 1
-                self._dropped_by_wave[dropped.wave] = (
-                    self._dropped_by_wave.get(dropped.wave, 0) + 1
-                )
-            self._spans.append(sp)
-        if dropped is not None:
+            dropped = self._file_late_locked() + self._push_locked(sp)
+        if dropped:
             counter = self._dropped_counter
             if counter is None:
                 # lazy: utils.metrics is stdlib-only but the tracer must
@@ -574,7 +580,7 @@ class WaveTracer:
                 from .metrics import trace_spans_dropped as counter
 
                 self._dropped_counter = counter
-            counter.inc()
+            counter.inc(dropped)
 
     def _new_span(
         self,
@@ -651,19 +657,40 @@ class WaveTracer:
             if not sp.attrs.pop("_discard", False):
                 self._append(sp)
 
-    def record(self, name: str, duration: float, **attrs) -> Span:
-        """Append an already-measured region as a COMPLETED span (ending
-        now), nested under this thread's innermost open span — for code
-        that times its phases with perf_counter deltas (the fleet pass
-        breakdown) rather than nesting context managers."""
+    def record(
+        self, name: str, duration: float, *,
+        start: Optional[float] = None, **attrs
+    ) -> Span:
+        """Append an already-measured region as a COMPLETED span, nested
+        under this thread's innermost open span — for code that times its
+        phases with perf_counter stamps (the fleet pass breakdown) rather
+        than nesting context managers. With ``start`` (a perf_counter
+        stamp) the span lies at its true interval ``[start, start +
+        duration]``; without, it ends now."""
         wave, trace_id, parent = self._open_ctx()
-        now = time.perf_counter()
+        if start is None:
+            start = time.perf_counter() - duration
         sp = self._new_span(
             name, wave, trace_id, parent, dict(attrs),
-            start=now - duration, end=now,
+            start=start, end=start + duration,
         )
         self._append(sp)
         return sp
+
+    def record_late(
+        self, name: str, duration: float, *, start: float, **attrs
+    ) -> None:
+        """``record`` for a caller that may hold ANY lock, this tracer's
+        included (the collector's callback runs wherever an allocation
+        lands): takes none. The span is parked and reaches the ring with
+        the next access under the lock."""
+        # list.append is atomic; the drain pops under the lock
+        self._late.append(  # graftlint: disable=GL004
+            self._new_span(
+                name, *self._open_ctx(lock_free=True), dict(attrs),
+                start=start, end=start + duration,
+            )
+        )
 
     def open_manual(
         self, name: str, ctx: Optional[TraceContext] = None, **attrs
@@ -704,6 +731,7 @@ class WaveTracer:
 
     def dump(self, wave: Optional[int] = None) -> list[dict]:
         with self._lock:
+            self._file_late_locked()
             spans = list(self._spans)
         if wave is not None:
             spans = [s for s in spans if s.wave == wave]
@@ -713,6 +741,7 @@ class WaveTracer:
         """Completed spans of one wave (ring snapshot, no JSON) — the
         history sampler aggregates engine pass stats off their attrs."""
         with self._lock:
+            self._file_late_locked()
             return [
                 s for s in self._spans
                 if s.wave == wave and s.end is not None
@@ -720,6 +749,7 @@ class WaveTracer:
 
     def waves(self) -> list[int]:
         with self._lock:
+            self._file_late_locked()
             return sorted({s.wave for s in self._spans})
 
     @property
@@ -729,6 +759,7 @@ class WaveTracer:
 
     def clear(self) -> None:
         with self._lock:
+            del self._late[:]
             self._spans.clear()
             self._wave_open = False
             self._dropped_total = 0
@@ -768,6 +799,7 @@ class WaveTracer:
                 return self.wave_summary(wave)
             return waves[-1]
         with self._lock:
+            self._file_late_locked()
             spans = list(self._spans)
             dropped_by_wave = dict(self._dropped_by_wave)
             trace_ids = dict(self._trace_ids)
@@ -832,6 +864,56 @@ class WaveTracer:
 #: the process-wide tracer (one ring per process, like the metrics
 #: registry; MetricsServer and the CLI dump read THIS instance)
 tracer = WaveTracer()
+
+
+class GcWatch:
+    """The program's own view of the CPython collector — the analogue of
+    the Go reference's ``go_gc_duration_seconds``. One ``gc.callbacks``
+    entry: every collection adds to ``runs`` / ``seconds`` by generation
+    (the registry's ``karmada_tpu_gc_collections_total`` and
+    ``karmada_tpu_gc_pause_seconds_total`` read them when scraped), and a
+    generation-2 collection also becomes a ``runtime.gc`` span at its true
+    interval. Only those: a busy plane runs thousands of young collections
+    a minute, which would flood the ring, and the few full ones are nearly
+    all of the pause time.
+
+    Lock-free by necessity (see the module docstring). One start stamp is
+    enough: the interpreter runs one collection at a time."""
+
+    def __init__(self, tracer_obj: WaveTracer):
+        self.tracer = tracer_obj
+        self.runs = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self._started: Optional[float] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._started = time.perf_counter()
+            return
+        t0, self._started = self._started, None
+        if t0 is None:
+            return  # installed while a collection was running
+        pause = time.perf_counter() - t0
+        gen = info["generation"]
+        self.runs[gen] += 1
+        self.seconds[gen] += pause
+        if gen == 2:
+            self.tracer.record_late(
+                "runtime.gc", pause, start=t0,
+                generation=gen, collected=info["collected"],
+            )
+
+    def samples(self, field: str) -> dict:
+        """``runs`` or ``seconds`` as registry samples, by generation."""
+        return {
+            (("generation", str(g)),): float(v)
+            for g, v in enumerate(getattr(self, field))
+        }
+
+
+#: installed once, with the tracer its spans go to
+gc_watch = GcWatch(tracer)
+gc.callbacks.append(gc_watch)
 
 
 class ContextPropagatingExecutor:

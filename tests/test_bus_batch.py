@@ -343,8 +343,14 @@ class TestWatchBatchParity:
                     is not None,
                     timeout=10.0,
                 )
-                # the reconnected stream re-negotiated batched
-                assert replica._watch_supports_batch is True
+                # the reconnected stream re-negotiated batched (waited
+                # for: g1 can still arrive over the dying old stream, whose
+                # failure then clears the pin until the new one's first
+                # frame)
+                assert _wait(
+                    lambda: replica._watch_supports_batch is True,
+                    timeout=10.0,
+                )
             finally:
                 server2.stop()
         finally:

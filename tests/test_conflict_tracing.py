@@ -11,7 +11,7 @@ from karmada_tpu.utils.builders import (
     new_cluster,
     new_deployment,
 )
-from karmada_tpu.utils.tracing import EventRecorder, Trace
+from karmada_tpu.utils.tracing import EventRecorder
 
 
 def make_plane(n=1, **kw):
@@ -62,15 +62,6 @@ class TestConflictResolution:
 
 
 class TestTracing:
-    def test_trace_logs_only_slow_ops(self, caplog):
-        t = Trace("fast-op")
-        t.step("a")
-        assert t.log_if_long(10.0) is None
-        t2 = Trace("slow-op", binding="default/x")
-        t2.step("estimate")
-        msg = t2.log_if_long(0.0)
-        assert "slow-op" in msg and "estimate=" in msg and "binding=default/x" in msg
-
     def test_event_recorder_ring(self):
         rec = EventRecorder(capacity=2)
         for i in range(4):

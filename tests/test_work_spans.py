@@ -1,0 +1,422 @@
+"""ISSUE 25: spans where the work happens.
+
+- fleet and engine phases lie at their true intervals: the children of one
+  ``scheduler.solve`` are disjoint, ordered and inside it, and each name's
+  sum is the pass's ``last_breakdown`` entry (full pass and delta pass);
+- ``record`` without ``start`` still ends now;
+- every drain carries its work counts: a Cluster status event over a
+  settled plane is a no-op storm in ``controller.scheduler``, a rebalancer
+  wave is not;
+- the collector from inside: counters for every collection, a
+  ``runtime.gc`` span for full ones only, no lock taken in the callback;
+- ``jax.named_scope`` names on the stages of the fleet kernels;
+- new-trace flags are per pass (PERF.md section 7, fault 2).
+"""
+
+from __future__ import annotations
+
+import gc
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from karmada_tpu.api import PropagationPolicy, PropagationSpec, ResourceSelector
+from karmada_tpu.api.core import ObjectMeta
+from karmada_tpu.controllers import (
+    ObjectReferenceSelector,
+    WorkloadRebalancer,
+    WorkloadRebalancerSpec,
+)
+from karmada_tpu.controlplane import ControlPlane
+from karmada_tpu.scheduler import ClusterSnapshot, TensorScheduler
+from karmada_tpu.utils import metrics
+from karmada_tpu.utils.builders import (
+    dynamic_weight_placement,
+    new_cluster,
+    new_deployment,
+    synthetic_fleet,
+)
+from karmada_tpu.utils.tracing import GcWatch, WaveTracer, gc_watch, tracer
+from karmada_tpu.utils.worker import DONE, REQUEUE, Runtime, WriteCount
+
+from test_delta_solve import build_problems, churned
+
+HOST_KEYS = ("upsert", "sync", "prep", "post")
+
+
+# --------------------------------------------------------------------------
+# A: phases at their true intervals
+# --------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def engine():
+    snap = ClusterSnapshot(synthetic_fleet(48, seed=7, taint_fraction=0.08))
+    eng = TensorScheduler(snap, trace_manifest="")
+    eng.fleet_threshold = 1
+    problems = build_problems(snap, 600)
+    eng.schedule(problems)
+    eng.schedule(problems)  # arm the batch-identity path
+    return eng, problems
+
+
+def _solve_tree(spans: list) -> tuple:
+    """(the one scheduler.solve span, its kernel.* children by start)."""
+    [solve] = [s for s in spans if s["name"] == "scheduler.solve"]
+    kids = sorted(
+        (s for s in spans if s["parent_id"] == solve["span_id"]
+         and s["name"].startswith("kernel.")),
+        key=lambda s: s["start"],
+    )
+    return solve, kids
+
+
+def _check_tree(solve, kids, breakdown) -> None:
+    eps = 2e-6  # dump() rounds start and duration to the microsecond
+    assert kids, "the pass recorded no kernel phase"
+    for a, b in zip(kids, kids[1:]):
+        assert a["start"] + a["duration_s"] <= b["start"] + eps, (a, b)
+    assert kids[0]["start"] >= solve["start"] - eps
+    last = kids[-1]
+    assert (last["start"] + last["duration_s"]
+            <= solve["start"] + solve["duration_s"] + eps)
+    sums: dict = {}
+    for s in kids:
+        sums[s["name"]] = sums.get(s["name"], 0.0) + s["duration_s"]
+    want = {
+        "kernel.host": sum(breakdown.get(k, 0.0) for k in HOST_KEYS),
+        "kernel.dispatch": breakdown.get("dispatch", 0.0),
+        "kernel.device": breakdown.get("device", 0.0),
+        "kernel.fetch": breakdown.get("fetch", 0.0),
+    }
+    for name, seconds in want.items():
+        assert sums.get(name, 0.0) == pytest.approx(
+            seconds, abs=len(kids) * 1e-6
+        ), name
+
+
+class TestPhaseIntervals:
+    def test_full_pass_children_disjoint_ordered_inside(self, engine):
+        eng, problems = engine
+        tracer.clear()
+        eng.schedule(problems)
+        solve, kids = _solve_tree(tracer.dump())
+        _check_tree(solve, kids, eng._fleet.last_breakdown)
+        # host -> dispatch -> device -> fetch -> host, one host span a
+        # stretch and the phase named on each
+        assert [s["name"].split(".")[1] for s in kids] == [
+            "host", "host", "host", "dispatch", "device", "fetch", "host",
+        ]
+        assert [s["attrs"].get("phase") for s in kids
+                if s["name"] == "kernel.host"] == list(HOST_KEYS)
+        uploads = [s for s in kids if "upload_mb" in s["attrs"]]
+        assert [s["attrs"]["phase"] for s in uploads] == ["sync"]
+
+    def test_delta_pass_children_disjoint_ordered_inside(self, engine):
+        eng, problems = engine
+        changed, _ = churned(problems, np.random.default_rng(1), 30)
+        eng.schedule(changed)  # certifies and compiles the sub-batch trace
+        changed2, _ = churned(changed, np.random.default_rng(2), 30)
+        tracer.clear()
+        eng.schedule(changed2)
+        bd = eng._fleet.last_breakdown
+        assert bd["dirty_rows"] == 30
+        solve, kids = _solve_tree(tracer.dump())
+        _check_tree(solve, kids, bd)
+        # the replay of the untouched rows is a second post stretch
+        assert [s["attrs"]["phase"] for s in kids
+                if s["name"] == "kernel.host"][-2:] == ["post", "post"]
+
+    def test_phase_histogram_observed_once_a_phase_a_pass(self, engine):
+        eng, problems = engine
+        h = metrics.kernel_phase_seconds
+        before = h.snapshot()
+        eng.schedule(problems)
+        after = h.snapshot()
+        for phase in ("host", "dispatch", "device", "fetch"):
+            key = f'phase="{phase}"'
+            assert after[key]["count"] - before[key]["count"] == 1, phase
+
+    def test_engine_spans_start_where_the_work_started(self, engine):
+        eng, problems = engine
+        fresh = [p for p in build_problems(eng.snapshot, 600, seed=11)]
+        tracer.clear()
+        t0 = time.perf_counter()
+        eng.schedule(fresh)  # new objects: the full prologue runs
+        [pack] = [s for s in tracer.dump() if s["name"] == "scheduler.pack"]
+        [solve] = [s for s in tracer.dump() if s["name"] == "scheduler.solve"]
+        assert t0 <= pack["start"]
+        assert pack["start"] + pack["duration_s"] <= solve["start"] + 2e-6
+
+
+class TestRecord:
+    def test_record_without_start_ends_now(self):
+        tr = WaveTracer()
+        before = time.perf_counter()
+        sp = tr.record("kernel.device", 0.25, kind="device")
+        after = time.perf_counter()
+        assert before <= sp.end <= after
+        assert sp.end - sp.start == pytest.approx(0.25)
+
+    def test_record_with_start_lies_at_its_interval(self):
+        tr = WaveTracer()
+        with tr.span("scheduler.solve") as parent:
+            sp = tr.record("kernel.fetch", 0.5, start=100.0, fetch_mb=1.0)
+        assert (sp.start, sp.end) == (100.0, 100.5)
+        assert sp.parent_id == parent.span_id
+        assert sp.wave == parent.wave
+
+
+# --------------------------------------------------------------------------
+# B: work counts on every drain
+# --------------------------------------------------------------------------
+
+
+def _policy():
+    return PropagationPolicy(
+        meta=ObjectMeta(name="p", namespace="default"),
+        spec=PropagationSpec(
+            resource_selectors=[
+                ResourceSelector(api_version="apps/v1", kind="Deployment")
+            ],
+            placement=dynamic_weight_placement(),
+        ),
+    )
+
+
+def _drains(spans: list, worker: str) -> list:
+    return [s["attrs"] for s in spans if s["name"] == f"controller.{worker}"]
+
+
+class TestWorkCounts:
+    N = 12
+
+    @pytest.fixture()
+    def plane(self):
+        clock = [5000.0]
+        cp = ControlPlane(clock=lambda: clock[0])
+        for i in (1, 2, 3):
+            cp.join_cluster(
+                new_cluster(f"member{i}", cpu="100", memory="200Gi")
+            )
+        for i in range(self.N):
+            cp.store.apply(new_deployment(f"app{i}", replicas=4))
+        cp.store.apply(_policy())
+        cp.settle()
+        cp.settle()
+        return cp, clock
+
+    def test_cluster_event_is_a_noop_storm_in_the_scheduler(self, plane):
+        cp, _ = plane
+        cluster = cp.store.get("Cluster", "member1")
+        cluster.meta.labels["touched"] = "1"
+        tracer.clear()
+        cp.store.apply(cluster)  # one Cluster event: every binding queued
+        cp.settle()
+        drains = _drains(tracer.dump(), "scheduler")
+        assert drains, "the scheduler never drained"
+        keys = sum(d["keys"] for d in drains)
+        assert keys == self.N
+        assert sum(d["noop"] for d in drains) == keys
+        assert sum(d["enqueued"] for d in drains) >= keys
+        assert all(d["writes"] == 0 for d in drains)
+
+    def test_rebalancer_wave_does_work(self, plane):
+        cp, clock = plane
+        clock[0] += 10
+        before = metrics.worker_noop_reconciles.value(worker="scheduler")
+        tracer.clear()
+        cp.store.apply(
+            WorkloadRebalancer(
+                meta=ObjectMeta(name="rb1"),
+                spec=WorkloadRebalancerSpec(workloads=[
+                    ObjectReferenceSelector(kind="Deployment", name="app0")
+                ]),
+            )
+        )
+        cp.settle()
+        drains = _drains(tracer.dump(), "scheduler")
+        keys = sum(d["keys"] for d in drains)
+        noop = sum(d["noop"] for d in drains)
+        assert keys >= 1 and noop < keys
+        assert sum(d["writes"] for d in drains) >= 1
+        assert (metrics.worker_noop_reconciles.value(worker="scheduler")
+                - before) == noop
+        # every drain of the wave carries its counts beside items
+        for s in tracer.dump():
+            if s["name"].startswith("controller."):
+                assert {"items", "keys", "enqueued",
+                        "writes"} <= set(s["attrs"]), s
+
+    def test_single_key_noop_is_read_from_the_writes(self):
+        rt = Runtime()
+        wrote = rt.write_count = WriteCount()
+
+        seen: list = []
+
+        def reconcile(key):
+            seen.append(key)
+            if key == "w":
+                wrote.n += 1
+            # "r" comes back once and writes nothing: asked-for work
+            return REQUEUE if key == "r" and seen.count("r") == 1 else DONE
+
+        w = rt.new_worker("t", reconcile)
+        for key in ("a", "w", "r", "a"):
+            w.enqueue(key)
+        tracer.clear()
+        rt.run_until_settled()
+        [d] = _drains(tracer.dump(), "t")
+        # a: no-op; w: wrote; r: requeued, then a no-op
+        # enqueued: a, w, r and r again; the second "a" found it queued
+        assert (d["keys"], d["noop"], d["enqueued"], d["writes"]) == (
+            4, 2, 4, 1), d
+
+    def test_a_batch_that_cannot_tell_carries_no_noop(self):
+        rt = Runtime()
+        wrote = rt.write_count = WriteCount()
+
+        def batch(keys):
+            wrote.n += 1  # some key wrote; the reconciler does not say which
+            return {k: DONE for k in keys}
+
+        w = rt.new_worker("t", lambda k: DONE, reconcile_batch=batch)
+        for key in "abc":
+            w.enqueue(key)
+        tracer.clear()
+        rt.run_until_settled()
+        [d] = _drains(tracer.dump(), "t")
+        assert d["keys"] == 3 and "noop" not in d
+
+    def test_reconcile_each_notes_the_keys_that_buffered_nothing(self):
+        rt = Runtime()
+        rt.write_count = WriteCount()
+        pending: list = []
+
+        def reconcile(key):
+            if key in "bd":
+                pending.append(key)
+            return DONE
+
+        def batch(keys):
+            return w.reconcile_each(keys, reconcile, lambda: len(pending))
+
+        w = rt.new_worker("t", reconcile, reconcile_batch=batch)
+        for key in "abcde":
+            w.enqueue(key)
+        tracer.clear()
+        rt.run_until_settled()
+        [d] = _drains(tracer.dump(), "t")
+        assert (d["keys"], d["noop"]) == (5, 3)
+
+
+# --------------------------------------------------------------------------
+# C: the collector, from inside
+# --------------------------------------------------------------------------
+
+
+class TestCollector:
+    def test_full_collection_is_a_child_span_and_counted(self):
+        runs = metrics.gc_collections.value(generation="2")
+        pause = metrics.gc_pause_seconds.value(generation="2")
+        tracer.clear()
+        with tracer.span("controller.t") as parent:
+            gc.collect()
+        mine = [s for s in tracer.dump() if s["name"] == "runtime.gc"
+                and s["parent_id"] == parent.span_id]
+        assert len(mine) == 1
+        [sp] = mine
+        assert sp["attrs"]["generation"] == 2
+        assert "collected" in sp["attrs"]
+        assert parent.start <= sp["start"]
+        assert sp["start"] + sp["duration_s"] <= parent.end + 2e-6
+        assert metrics.gc_collections.value(generation="2") == runs + 1
+        assert metrics.gc_pause_seconds.value(generation="2") > pause
+
+    def test_young_collection_is_counted_and_not_spanned(self):
+        runs = metrics.gc_collections.value(generation="0")
+        tracer.clear()
+        with tracer.span("controller.t"):
+            gc.collect(0)
+        assert metrics.gc_collections.value(generation="0") >= runs + 1
+        assert not [s for s in tracer.dump() if s["name"] == "runtime.gc"]
+
+    def test_installed_once_on_the_process_tracer(self):
+        assert gc.callbacks.count(gc_watch) == 1
+        assert gc_watch.tracer is tracer
+        assert "karmada_tpu_gc_collections_total" in metrics.registry.render()
+
+    def test_callback_takes_no_lock(self):
+        """A collection can start under any lock its thread holds, the
+        tracer's own included: the callback has to finish there."""
+        tr = WaveTracer()
+        watch = GcWatch(tr)
+        done = threading.Event()
+
+        def under_the_lock():
+            with tr._lock:
+                watch("start", {"generation": 2})
+                watch("stop", {"generation": 2, "collected": 7})
+            done.set()
+
+        t = threading.Thread(target=under_the_lock, daemon=True)
+        t.start()
+        t.join(5.0)
+        assert done.is_set(), "the gc callback blocked on the tracer's lock"
+        [sp] = [s for s in tr.dump() if s["name"] == "runtime.gc"]
+        assert sp["attrs"] == {"generation": 2, "collected": 7}
+
+
+# --------------------------------------------------------------------------
+# D: scopes inside the kernels
+# --------------------------------------------------------------------------
+
+SCOPES = ("fleet.gather", "fleet.masks", "fleet.estimate", "fleet.divide",
+          "fleet.diff", "fleet.deltas", "fleet.wire")
+
+
+def test_fleet_pass_stages_carry_named_scopes(engine):
+    """Every stage name appears in ``_fleet_pass``'s lowered text with
+    debug info, for the very arguments the engine dispatches."""
+    import jax
+    import jax.numpy as jnp
+
+    import karmada_tpu.scheduler.fleet as fleet_mod
+
+    eng, _ = engine
+    table = eng._fleet
+    n_pad, chunk = 1024, 1024
+    rows = jnp.arange(n_pad, dtype=jnp.int32)
+    lowered = fleet_mod._fleet_pass.lower(
+        *table._dev_tables, rows, *table._dev_state,
+        jax.ShapeDtypeStruct(table._res_dense.shape, jnp.uint8),
+        jax.ShapeDtypeStruct(table._res_meta.shape, jnp.int32),
+        chunk=chunk, n_chunks=n_pad // chunk, wide=False, fast=None,
+        has_aggregated=False, all_rows=False, m_cap=4096, d_cap=8192,
+    )
+    text = lowered.as_text(debug_info=True)
+    for scope in SCOPES:
+        assert scope in text, scope
+
+
+# --------------------------------------------------------------------------
+# fault 2: new-trace flags are per pass
+# --------------------------------------------------------------------------
+
+
+def test_host_path_pass_does_not_report_an_earlier_compile():
+    snap = ClusterSnapshot(synthetic_fleet(48, seed=7))
+    eng = TensorScheduler(snap, trace_manifest="")
+    eng.fleet_threshold = 64
+    problems = build_problems(snap, 256, prefix="h")
+    eng.schedule(problems)  # cold fleet pass: compiles
+    assert eng.last_pass_new_trace is True
+    tracer.clear()
+    eng.schedule(problems[:8])  # under the threshold: the host path
+    assert eng.last_pass_new_trace is False
+    assert not [s for s in tracer.dump() if s["attrs"].get("compile")]
+    eng.schedule(problems)
+    eng.schedule(problems)
+    assert eng.last_pass_new_trace is False
