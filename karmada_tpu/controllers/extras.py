@@ -38,17 +38,24 @@ class NamespaceSyncController:
     def __init__(self, store: Store, runtime: Runtime) -> None:
         self.store = store
         self.worker = runtime.new_worker("namespace-sync", self._reconcile)
+        # the Namespace templates' names, in the order they came in: what a
+        # Cluster event re-enqueues, without a walk of every Resource
+        self._namespaces: dict[str, None] = {}
         store.watch("Resource", self._on_resource_event)
         store.watch("Cluster", self._on_cluster_event)
 
     def _on_resource_event(self, event) -> None:
         if event.obj.kind == "Namespace":
-            self.worker.enqueue(event.obj.meta.name)
+            name = event.obj.meta.name
+            if event.type == "Deleted":
+                self._namespaces.pop(name, None)
+            else:
+                self._namespaces[name] = None
+            self.worker.enqueue(name)
 
     def _on_cluster_event(self, event) -> None:
-        for res in self.store.list("Resource"):
-            if res.kind == "Namespace":
-                self.worker.enqueue(res.meta.name)
+        for name in list(self._namespaces):
+            self.worker.enqueue(name)
 
     def _should_sync(self, ns: Resource) -> bool:
         name = ns.meta.name

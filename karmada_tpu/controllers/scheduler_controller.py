@@ -19,6 +19,7 @@ from ..api.core import Condition, set_condition
 from ..api.work import SCHEDULED, ResourceBinding, TargetCluster
 from ..scheduler import BindingProblem, ClusterSnapshot, TensorScheduler
 from ..utils import DONE, Runtime, Store
+from ..utils.metrics import cluster_fanout_keys
 
 DEFAULT_SCHEDULER = "default-scheduler"
 
@@ -127,6 +128,14 @@ class SchedulerController:
         from ..utils.reasons import TransitionDedup
 
         self._reason_dedup = TransitionDedup()
+        # the (kind, key)s a Cluster event can move: every binding of this
+        # scheduler EXCEPT those the gate turned away as they stand now
+        # (settled Divided bindings). A superset of the keys for which
+        # _needs_scheduling or the quota gate would say yes — the gate
+        # reads only the binding, so a key it turned away stays turned
+        # away until a binding event puts it back. A dict for its order:
+        # a Cluster event enqueues in the order the keys came in.
+        self._cluster_movable: dict[tuple, None] = {}
         self.worker = runtime.new_worker(
             "scheduler", self._reconcile,
             reconcile_batch=self._reconcile_batch, batch_size=131072,
@@ -139,14 +148,19 @@ class SchedulerController:
     # -- events ------------------------------------------------------------
 
     def _on_binding_event(self, event) -> None:
-        if event.type == "Deleted":
-            return
         rb = event.obj
-        if rb.spec.scheduler_name != self.scheduler_name:
-            return  # scheduler-name filter (event_handler.go:93-113)
         if id(rb) in self._pending_writeback:
             return  # our own writeback echo
-        self.worker.enqueue((event.kind, event.key))
+        kind_key = (event.kind, event.key)
+        if (
+            event.type == "Deleted"
+            or rb.spec.scheduler_name != self.scheduler_name
+        ):
+            # gone, or another scheduler's (event_handler.go:93-113)
+            self._cluster_movable.pop(kind_key, None)
+            return
+        self._cluster_movable[kind_key] = None
+        self.worker.enqueue(kind_key)
 
     def _on_quota_event(self, event) -> None:
         self._quota_gen += 1
@@ -166,10 +180,11 @@ class SchedulerController:
             # member state moved: memoized accurate estimates are stale
             # (EstimatorRegistry.invalidate staleness contract)
             self.estimator_registry.invalidate()
-        for kind in ("ResourceBinding", "ClusterResourceBinding"):
-            for rb in self.store.list(kind):
-                if rb.spec.scheduler_name == self.scheduler_name:
-                    self.worker.enqueue((kind, rb.meta.namespaced_name))
+        # only the bindings member state can move: settled ones left the
+        # set at the gate and come back with their next binding event
+        cluster_fanout_keys.inc(len(self._cluster_movable))
+        for kind_key in list(self._cluster_movable):
+            self.worker.enqueue(kind_key)
 
     # -- engine ------------------------------------------------------------
 
@@ -354,10 +369,11 @@ class SchedulerController:
             return True, False  # never attempted
         if not sched.status:
             # unschedulable bindings retry on every re-enqueue (the
-            # reference's unschedulable-queue semantics): cluster events
-            # re-enqueue the whole plane, so freed capacity — a
-            # completed preemption eviction, a scale-down, a node join —
-            # re-places a parked victim without any spec change. Quota
+            # reference's unschedulable-queue semantics): a key this gate
+            # passes stays in _cluster_movable, which every cluster event
+            # re-enqueues, so freed capacity — a completed preemption
+            # eviction, a scale-down, a node join — re-places a parked
+            # victim without any spec change. Quota
             # denials are intercepted BEFORE this gate by the
             # generation-gated _quota_denied park, so a denied binding
             # still retries only on quota movement.
@@ -401,6 +417,7 @@ class SchedulerController:
             rb = self.store.get(kind, key)
             if rb is None:
                 self._quota_denied.pop(kind_key, None)
+                self._cluster_movable.pop(kind_key, None)
                 # deleted binding: drop its cached problem so the key's
                 # identity cannot alias a later re-creation
                 self._problem_cache.pop(key, None)
@@ -430,12 +447,16 @@ class SchedulerController:
                     continue
                 should = True  # quota or the binding moved: retry now
             if not should:
+                # settled as it stands: no Cluster event can move it (a
+                # quota-parked key never reaches here — it waits above)
+                self._cluster_movable.pop(kind_key, None)
                 out[kind_key] = DONE
                 continue
             todo.append((kind_key, rb, self._problem_for(key, rb, fresh), fresh))
         # the keys the gate turned away before the engine: this drain's
-        # no-ops (a Cluster status event queues every binding, and all but
-        # the ones a rebalancer named stop here)
+        # no-ops (a binding written again after it was scheduled, by
+        # binding-status's aggregate or the next Cluster event, comes by
+        # once more and leaves _cluster_movable here)
         self.worker.note_noops(len(kind_keys) - len(todo))
         if not todo:
             return out

@@ -466,6 +466,14 @@ worker_noop_reconciles = registry.counter(
     "worker queue; beside karmada_tpu_worker_reconciles_total it is the "
     "useful-to-attempted ratio of a controller (counted once a drain)",
 )
+cluster_fanout_keys = registry.counter(
+    "karmada_tpu_scheduler_cluster_fanout_keys_total",
+    "binding keys the scheduler enqueued in answer to Cluster events: the "
+    "bindings a member's change can move (unschedulable, quota-parked, "
+    "Duplicated / non-workload, scheduled since the last Cluster event), "
+    "never the settled Divided ones (added once an event, by the size of "
+    "the set)",
+)
 worker_queue_depth = registry.gauge(
     "karmada_tpu_worker_queue_depth",
     "keys still queued per worker after its last drain",

@@ -4,8 +4,8 @@
   ``scheduler.solve`` are disjoint, ordered and inside it, and each name's
   sum is the pass's ``last_breakdown`` entry (full pass and delta pass);
 - ``record`` without ``start`` still ends now;
-- every drain carries its work counts: a Cluster status event over a
-  settled plane is a no-op storm in ``controller.scheduler``, a rebalancer
+- every drain carries its work counts: settled bindings written again
+  unchanged are a no-op storm in ``controller.scheduler``, a rebalancer
   wave is not;
 - the collector from inside: counters for every collection, a
   ``runtime.gc`` span for full ones only, no lock taken in the callback;
@@ -208,12 +208,11 @@ class TestWorkCounts:
         cp.settle()
         return cp, clock
 
-    def test_cluster_event_is_a_noop_storm_in_the_scheduler(self, plane):
+    def test_regated_bindings_are_a_noop_storm_in_the_scheduler(self, plane):
         cp, _ = plane
-        cluster = cp.store.get("Cluster", "member1")
-        cluster.meta.labels["touched"] = "1"
         tracer.clear()
-        cp.store.apply(cluster)  # one Cluster event: every binding queued
+        for rb in cp.store.list("ResourceBinding"):
+            cp.store.apply(rb)  # written again unchanged: every one queued
         cp.settle()
         drains = _drains(tracer.dump(), "scheduler")
         assert drains, "the scheduler never drained"
@@ -222,6 +221,14 @@ class TestWorkCounts:
         assert sum(d["noop"] for d in drains) == keys
         assert sum(d["enqueued"] for d in drains) >= keys
         assert all(d["writes"] == 0 for d in drains)
+        # and a Cluster event over the settled plane queues none of them
+        # (ISSUE 26: the gate turned each away, so no member can move it)
+        cluster = cp.store.get("Cluster", "member1")
+        cluster.meta.labels["touched"] = "1"
+        tracer.clear()
+        cp.store.apply(cluster)
+        cp.settle()
+        assert not _drains(tracer.dump(), "scheduler")
 
     def test_rebalancer_wave_does_work(self, plane):
         cp, clock = plane
