@@ -86,14 +86,14 @@ class SchedulerController:
         # which other controllers stamp from the plane clock — both sides
         # must share one time base or Fresh triggers silently degrade
         self.clock = clock or time.time
-        self.extra_estimators = list(extra_estimators)
+        self._engine: Optional[TensorScheduler] = None
+        self.extra_estimators = extra_estimators
         # --plugins enable/disable list + out-of-tree filter registry
         # (scheduler.go:243-247, framework/runtime/registry.go); both reach
         # the engine on every (re)build so flags apply live
         self.disabled_plugins = tuple(disabled_plugins)
         self.custom_filters = list(custom_filters)
         self._snapshot: Optional[ClusterSnapshot] = None
-        self._engine: Optional[TensorScheduler] = None
         # id()s of binding objects whose writeback WE are applying right
         # now: the in-proc store delivers the echo synchronously with the
         # very same object, so identity marks it (one re-gate queue wave
@@ -296,6 +296,19 @@ class SchedulerController:
                 self._victim_kinds[key] = kind
                 out.append(self._problem_for(key, rb, False))
         return out
+
+    @property
+    def extra_estimators(self) -> list:
+        return self._extra_estimators
+
+    @extra_estimators.setter
+    def extra_estimators(self, estimators) -> None:
+        """Re-pointing the estimators (an addon toggled, a member joined)
+        reaches the live engine too: it outlives every same-member-set
+        snapshot swap, and would keep the list it was built with."""
+        self._extra_estimators = list(estimators)
+        if self._engine is not None:
+            self._engine.extra_estimators = list(self._extra_estimators)
 
     def _ensure_engine_quota(self, engine) -> None:
         """Hand the engine a current QuotaSnapshot (None = no FRQs or

@@ -286,6 +286,7 @@ class ControlPlane:
         self.work_status_controller.watch_member(member)
         if self._accurate_enabled:
             self._register_estimator(cluster.name, member)
+            self._point_scheduler_at_estimators()
         self.store.apply(cluster)
         return member
 
@@ -300,10 +301,7 @@ class ControlPlane:
         # members — a stale batch estimator keeps the old cluster-column
         # layout and breaks the min-merge shape on the next reconcile
         if self._accurate_enabled:
-            names = sorted(self.members.names())
-            self.scheduler.extra_estimators = (
-                [self.estimators.make_batch_estimator(names)] if names else []
-            )
+            self._point_scheduler_at_estimators()
 
     # -- optional components (karmadactl addons analogue) ------------------
 
@@ -311,8 +309,15 @@ class ControlPlane:
         snap_dims = ["cpu", "memory", "pods", "ephemeral-storage"]
         est = AccurateEstimator(cluster_name, NodeSnapshot(member.nodes, snap_dims))
         self.estimators.register(est)
+
+    def _point_scheduler_at_estimators(self) -> None:
+        """One batch estimator over the current member set: its cluster
+        columns are positional, so it is rebuilt whenever the set changes,
+        once a change and not once a member."""
         names = sorted(self.members.names())
-        self.scheduler.extra_estimators = [self.estimators.make_batch_estimator(names)]
+        self.scheduler.extra_estimators = (
+            [self.estimators.make_batch_estimator(names)] if names else []
+        )
 
     def enable_accurate_estimators(self) -> None:
         """addons enable karmada-scheduler-estimator: deploy one estimator
@@ -322,6 +327,7 @@ class ControlPlane:
         self._accurate_enabled = True
         for name in sorted(self.members.names()):
             self._register_estimator(name, self.members.get(name))
+        self._point_scheduler_at_estimators()
 
     def disable_accurate_estimators(self) -> None:
         if not self._accurate_enabled:

@@ -16,6 +16,7 @@ karmada_tpu.estimator.service).
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass, field
 from typing import Mapping, Optional, Sequence
@@ -110,23 +111,47 @@ class NodeSnapshot:
     NodeInfo snapshot, pkg/util/lifted/scheduler/cache)."""
 
     def __init__(self, nodes: Sequence[NodeState], dims: Sequence[str]):
-        self.nodes = list(nodes)
-        self.dims = list(dims)
-        self.generation = next(_SNAPSHOT_GEN)
-        n, r = len(nodes), len(dims)
-        self.available = np.zeros((n, r), np.int64)
-        pods_dim = self.dims.index("pods") if "pods" in self.dims else None
+        nodes, dims = list(nodes), list(dims)
+        available = np.zeros((len(nodes), len(dims)), np.int64)
+        pods_dim = dims.index("pods") if "pods" in dims else None
         for i, node in enumerate(nodes):
-            for j, d in enumerate(self.dims):
-                self.available[i, j] = node.allocatable.get(d, 0) - node.requested.get(
+            for j, d in enumerate(dims):
+                available[i, j] = node.allocatable.get(d, 0) - node.requested.get(
                     d, 0
                 )
             if pods_dim is not None:
                 # allowed pods = allocatable pods - running pods
                 # (server/estimate.go:104-112)
-                self.available[i, pods_dim] = max(
+                available[i, pods_dim] = max(
                     node.allocatable.get("pods", 0) - node.num_pods, 0
                 )
+        self._adopt(available, dims, nodes)
+
+    @classmethod
+    def from_arrays(
+        cls, available: np.ndarray, dims: Sequence[str]
+    ) -> "NodeSnapshot":
+        """A snapshot from the packed ``int64[N, R]`` free-resource array an
+        estimator would ship (``dims`` names its columns; the pods column
+        holds allowed pods). The array is adopted, not copied: the caller
+        hands it over. Nothing but each node's free resources is known, so
+        the nodes read as ``NodeCache`` holes: a ``node_claim`` prefilter
+        passes none of them, a claim-free estimate sums them all."""
+        available = np.asarray(available, np.int64)
+        if available.ndim != 2 or available.shape[1] != len(dims):
+            raise ValueError(
+                f"available {available.shape} does not fit dims {list(dims)}"
+            )
+        self = cls.__new__(cls)
+        self._adopt(available, list(dims), [None] * len(available))
+        return self
+
+    def _adopt(self, available: np.ndarray, dims: list, nodes: list) -> None:
+        """The one constructor body: a fresh generation for every instance."""
+        self.nodes = nodes
+        self.dims = dims
+        self.available = available
+        self.generation = next(_SNAPSHOT_GEN)
 
 
 class NodeCache:
@@ -259,6 +284,180 @@ def _node_sum_estimate(node_avail, node_ok, requests):
 #: below this B x N footprint the numpy mirror beats the jit kernel's
 #: dispatch overhead (same crossover idea as the engine's host_small path)
 _NP_ESTIMATE_CELLS = 1 << 14
+
+
+@jax.jit
+def node_sum_table(node_table, node_counts, requests):
+    """``_node_sum_kernel`` over the member axis: ONE dispatch answers every
+    in-process member. ``node_table`` int64[C, N, R] (the members' node
+    arrays, ragged members padded), ``node_counts`` int32[C] (a member's
+    nodes are its first ``node_counts[c]`` rows; the pad rows pass no
+    prefilter), ``requests`` int64[P, R]. Returns int32[P, C]."""
+    with jax.named_scope("estimator.node_sum"):
+        node_ok = (
+            jnp.arange(node_table.shape[1], dtype=jnp.int32)[None, :]
+            < node_counts[:, None]
+        )
+        per_member = jax.vmap(
+            lambda avail, ok: _node_sum_kernel(jnp, avail, ok[None, :], requests)
+        )(node_table, node_ok)
+        return per_member.T
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter_members(node_table, slots, rows):
+    return node_table.at[slots].set(rows)
+
+
+@jax.jit
+def _place_columns(answers, col_slot, host_cols):
+    """int32[P, C] of a batch estimator's columns: the node table's answer
+    where ``col_slot`` names a table slot, the host-built column (memo or
+    -1) elsewhere."""
+    picked = answers[:, jnp.maximum(col_slot, 0)]
+    return jnp.where(col_slot[None, :] >= 0, picked, host_cols)
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def _node_cap(n: int) -> int:
+    """Node-axis capacity of the table for a largest member of ``n`` nodes:
+    a power of two up to 1024, a multiple of 1024 above, so a member that
+    gains a node does not mint a new trace."""
+    return max(8, _pow2(n)) if n <= 1024 else -(-n // 1024) * 1024
+
+
+class NodeTable:
+    """The in-process members' node arrays as ONE device-resident
+    ``int64[C, N_cap, R]`` table (ragged members zero-padded, their true
+    lengths in ``int32[C]``). ``sync`` re-uploads exactly the members whose
+    snapshot generation moved since their slice was uploaded; ``estimate``
+    is one ``node_sum_table`` dispatch whose ``int32[P, C]`` answer stays on
+    the device."""
+
+    #: (C, N_cap, R, P) signatures dispatched so far in this process: a
+    #: fresh one compiles, and counts as a serving-path compile
+    _traces: set = set()
+
+    def __init__(self) -> None:
+        self.names: tuple = ()
+        self.slot: dict[str, int] = {}
+        self.gens: list = []
+        self._counts = np.zeros(0, np.int32)
+        #: per member, per dim: sum over nodes of max(free, 0); bounds its
+        #: answers from above without a device fetch (``bound``)
+        self._colsum = np.zeros((0, 0), np.int64)
+        self.dev = None
+        self.counts_dev = None
+
+    @staticmethod
+    def _read(est) -> tuple:
+        """(generation, nodes, array): the generation is read BEFORE the
+        array, so a concurrent member event makes the slice look stale."""
+        snap = est.snapshot
+        gen = int(getattr(snap, "generation", 0))
+        n = len(snap.nodes)
+        return gen, n, np.asarray(snap.available)[:n]
+
+    def sync(self, members: Sequence[tuple]) -> Optional[dict]:
+        """Bring the table up to ``members`` ([(name, estimator)], the slot
+        order). Returns {"members", "nodes", "bytes"} of what was uploaded
+        (zeros where nothing moved), or None where the members' arrays do
+        not stack (no members, or differing dims): the caller then keeps
+        the per-member path."""
+        reads = [self._read(est) for _name, est in members]
+        if not reads or len({a.shape[1] for _g, _n, a in reads}) != 1:
+            return None
+        names = tuple(name for name, _est in members)
+        r = reads[0][2].shape[1]
+        cap = _node_cap(max(n for _g, n, _a in reads))
+        full = (
+            names != self.names
+            or self.dev is None
+            or self.dev.shape[1:] != (cap, r)
+        )
+        moved = [
+            i for i, (gen, _n, _a) in enumerate(reads)
+            if full or self.gens[i] != gen
+        ]
+        if not moved:
+            return {"members": 0, "nodes": 0, "bytes": 0}
+        if full:
+            self.names = names
+            self.slot = {name: i for i, name in enumerate(names)}
+            self.gens = [None] * len(names)
+            self._counts = np.zeros(len(names), np.int32)
+            self._colsum = np.zeros((len(names), r), np.int64)
+        k = len(moved)
+        whole = full or 2 * k > len(names)
+        # the slots to write: all of them, or the moved ones padded to a
+        # power of two by repeating the first (identical duplicate writes)
+        slots = (
+            list(range(len(names))) if whole
+            else moved + [moved[0]] * (_pow2(k) - k)
+        )
+        # a fresh staging array each time: it is handed to the device whole
+        # and never written again (a CPU device may alias it)
+        stage = np.zeros((len(slots), cap, r), np.int64)
+        for row, i in enumerate(slots):
+            gen, n, arr = reads[i]
+            stage[row, :n] = arr
+            self.gens[i], self._counts[i] = gen, n
+            self._colsum[i] = np.maximum(arr, 0).sum(axis=0)
+        if whole:
+            self.dev = jnp.asarray(stage)
+        else:
+            self.dev = _scatter_members(
+                self.dev, jnp.asarray(np.asarray(slots, np.int32)),
+                jnp.asarray(stage),
+            )
+        self.counts_dev = jnp.asarray(self._counts.copy())
+        uploaded = slots if whole else moved
+        return {
+            "members": len(uploaded),
+            "nodes": int(sum(reads[i][1] for i in uploaded)),
+            "bytes": int(stage.nbytes),
+        }
+
+    def _known_dims(self, requests: np.ndarray) -> tuple:
+        """(the request columns the node arrays carry, bool[P] rows that
+        ask for a dim beyond them). The table's dims are a prefix of the
+        caller's: a dim no node carries is one no node offers, so such a
+        row fits nowhere."""
+        req = np.asarray(requests, np.int64)
+        r = self._colsum.shape[1]
+        return req[:, :r], (req[:, r:] > 0).any(axis=1)
+
+    def estimate(self, requests: np.ndarray):
+        """int32[P, C] on the device, one dispatch."""
+        from ..utils.metrics import estimator_nodes_estimated, kernel_compiles
+
+        requests, unmet = self._known_dims(requests)
+        key = self.dev.shape + (len(requests),)
+        if key not in NodeTable._traces:
+            NodeTable._traces.add(key)
+            kernel_compiles.inc(
+                kernel="node_sum_table", bucket="x".join(map(str, key))
+            )
+        estimator_nodes_estimated.inc(int(self._counts.sum()))
+        out = node_sum_table(self.dev, self.counts_dev, jnp.asarray(requests))
+        return jnp.where(jnp.asarray(unmet)[:, None], 0, out) if unmet.any() else out
+
+    def bound(self, requests: np.ndarray) -> int:
+        """An upper bound of every answer ``estimate(requests)`` gives: a
+        sum of per-node floors never exceeds the floor of the sums, nor a
+        sum of minima the minimum of sums. Host arithmetic only."""
+        req, _unmet = self._known_dims(requests)
+        if not len(req) or not len(self._colsum):
+            return 0
+        best = np.full((len(req), len(self._colsum)), 2**62, np.int64)
+        for d in range(req.shape[1]):
+            ratio = self._colsum[None, :, d] // np.maximum(req[:, d], 1)[:, None]
+            best = np.where((req[:, d] > 0)[:, None], np.minimum(best, ratio), best)
+        best = np.where(best >= 2**62, 0, best)
+        return int(min(best.max(), 2**31 - 1))
 
 
 class ResourceQuotaPlugin:
@@ -432,6 +631,8 @@ class EstimatorRegistry:
         # batch-identity fast path compares — equal tokens prove the
         # estimator contribution to a replayed batch is unchanged
         self._epoch = 0
+        # the in-process members' node arrays, device-resident (NodeTable)
+        self._node_table = NodeTable()
 
     def _count_rpc(self, kind: str, n: int = 1) -> None:
         """One choke point for wire accounting: the per-registry
@@ -519,6 +720,39 @@ class EstimatorRegistry:
         # transient -1 forever while a real pass would answer from memo
         unanswered: set = set()
 
+        def memo_columns(prof_keys, skip=()) -> np.ndarray:
+            """int32[U, C] of the memoized answers after a refresh, -1
+            where a cluster gives none; columns in ``skip`` are left at -1
+            for the caller to fill. Notes every registered cluster that
+            answered -1 transiently in ``unanswered``."""
+            table = np.full((len(prof_keys), len(names)), UNAUTHENTIC, np.int32)
+            memo = self._memo
+            unanswered.clear()
+            for ci, name in enumerate(names):
+                if name in skip:
+                    continue
+                # clusters with no registered estimator answer -1
+                # STRUCTURALLY (deterministic); unconfirmed clusters answer
+                # -1 for this pass only
+                if name not in self._confirmed:
+                    if name in self._by_cluster:
+                        unanswered.add(name)
+                    continue
+                for u, key in enumerate(prof_keys):
+                    val = memo.get((name, key))
+                    if val is not None:
+                        table[u, ci] = val
+                    else:
+                        unanswered.add(name)
+            if unanswered:
+                # degraded pass: at least one registered cluster answered
+                # -1 transiently. Observable (the counter) and never
+                # replayable (refresh_token below answers None).
+                from ..utils.metrics import degraded_passes
+
+                degraded_passes.inc(channel="estimator")
+            return table
+
         def estimate(requests: np.ndarray, replicas: np.ndarray) -> np.ndarray:
             reqs = np.asarray(requests)
             reps = np.asarray(replicas)
@@ -533,32 +767,51 @@ class EstimatorRegistry:
             uniq, inv = np.unique(reqs[live], axis=0, return_inverse=True)
             prof_keys = [row.tobytes() for row in uniq]
             self._refresh(names, uniq, prof_keys, max_workers, timeout_seconds)
-            table = np.full((len(uniq), len(names)), UNAUTHENTIC, np.int32)
-            memo = self._memo
-            unanswered.clear()
-            for ci, name in enumerate(names):
-                # clusters with no registered estimator answer -1
-                # STRUCTURALLY (deterministic); unconfirmed clusters answer
-                # -1 for this pass only
-                if name not in self._confirmed:
-                    if name in self._by_cluster:
-                        unanswered.add(name)
-                    continue
-                for u, key in enumerate(prof_keys):
-                    val = memo.get((name, key))
-                    if val is not None:
-                        table[u, ci] = val
-                    else:
-                        unanswered.add(name)
-            out[live] = table[inv]
-            if unanswered:
-                # degraded pass: at least one registered cluster answered
-                # -1 transiently. Observable (the counter) and never
-                # replayable (refresh_token below answers None).
-                from ..utils.metrics import degraded_passes
-
-                degraded_passes.inc(channel="estimator")
+            out[live] = memo_columns(prof_keys)[inv]
             return out
+
+        def profile_table(profiles: np.ndarray, live: Optional[int] = None):
+            """The answers BY PROFILE, for the fleet table's fold:
+            int32[P, C] for the request vectors ``profiles`` int64[P, R]
+            (what ``estimate`` answers a row of that profile with one
+            replica or more), -1 = no answer. Rows from ``live`` on are
+            padding: they cost no wire and answer -1 from every member off
+            the node table. The in-process members' columns come from ONE
+            ``node_sum_table`` dispatch and the result stays on the device;
+            every other member's column is its memo, as in ``estimate``."""
+            profs = np.asarray(profiles, np.int64)
+            n_live = len(profs) if live is None else int(live)
+            prof_keys = [row.tobytes() for row in profs[:n_live]]
+            answers = self._refresh(
+                names, profs[:n_live], prof_keys, max_workers,
+                timeout_seconds, on_device=profs,
+            )
+            slot = self._node_table.slot if answers is not None else {}
+            host = np.full((len(profs), len(names)), UNAUTHENTIC, np.int32)
+            host[:n_live] = memo_columns(prof_keys, skip=slot)
+            if answers is None:
+                return jnp.asarray(host)
+            col_slot = np.asarray(
+                [slot.get(name, -1) for name in names], np.int32
+            )
+            return _place_columns(
+                answers, jnp.asarray(col_slot), jnp.asarray(host)
+            )
+
+        def profile_bound(profiles: np.ndarray) -> int:
+            """An upper bound of what ``profile_table(profiles)`` just
+            answered, from host state only (the memo's values; the node
+            table's column sums)."""
+            keys = {row.tobytes() for row in np.asarray(profiles, np.int64)}
+            slot = self._node_table.slot
+            held = [
+                v for (name, key), v in self._memo.items()
+                if key in keys and name not in slot
+            ]
+            return max(
+                max(held, default=0),
+                self._node_table.bound(profiles) if slot else 0,
+            )
 
         def refresh_token():
             # the scheduler's batch-identity fast path probes this before
@@ -575,6 +828,8 @@ class EstimatorRegistry:
             return token
 
         estimate.refresh_token = refresh_token
+        estimate.profile_table = profile_table
+        estimate.profile_bound = profile_bound
         return estimate
 
     # -- live refresh machinery (ping + grouped fan-out) -------------------
@@ -586,10 +841,19 @@ class EstimatorRegistry:
         prof_keys: Sequence[bytes],
         max_workers: int,
         timeout_seconds: Optional[float],
-    ) -> None:
+        on_device: Optional[np.ndarray] = None,
+    ):
         """Bring every (cluster, profile) memo cell either up to date or
         provably unanswerable for this pass. Mutates memo/generation state
-        only on the calling thread — pool tasks just return data."""
+        only on the calling thread — pool tasks just return data.
+
+        The in-process members answer from the device-resident node table
+        (``NodeTable``): with ``on_device`` (the request vectors of the
+        fleet's fold, padding included) their answer is returned as it
+        lies on the device, int32[P, members of the table], and their memo
+        cells are left alone; without it the answer is fetched and
+        memoized like any other member's, unless the refresh is so small
+        (``_NP_ESTIMATE_CELLS``) that the per-member numpy path wins."""
         import time as _time
 
         from ..utils.metrics import (
@@ -608,6 +872,7 @@ class EstimatorRegistry:
                 return None
             return max(deadline - _time.perf_counter(), 0.0)
 
+        answers = None
         with tracer.span("estimator.refresh") as sp:
             # steps A+B: confirm generations (local reads + one ping per
             # server connection)
@@ -627,18 +892,79 @@ class EstimatorRegistry:
                 ):
                     continue
                 fetch.append((name, est, getattr(est, "conn", None)))
-            sp.attrs["requeried_clusters"] = len(fetch)
-            if fetch:
+            moved = 0
+            local = [f for f in fetch if f[2] is None]
+            if on_device is not None or (
+                len(uniq) * sum(len(est.snapshot.nodes) for _n, est, _c in local)
+                > _NP_ESTIMATE_CELLS
+            ):
+                synced = self._sync_node_table()
+                if synced is not None:
+                    moved = synced["members"]
+                    fetch = [f for f in fetch if f[0] not in self._node_table.slot]
+                    if on_device is not None:
+                        answers = self._dispatch_node_table(on_device)
+                    elif local:
+                        self._memoize_node_table(
+                            [f[0] for f in local], uniq, prof_keys
+                        )
+            sp.attrs["requeried_clusters"] = len(fetch) + moved
+            if fetch or moved:
                 touched_wire = True
                 # the delta half of the generation-gated refresh: only
                 # clusters whose generation moved (or never fetched)
                 # re-pay the fan-out — this counter is that cardinality
-                estimator_delta_requeries.inc(len(fetch))
+                estimator_delta_requeries.inc(len(fetch) + moved)
+            if fetch:
                 self._fetch(fetch, uniq, prof_keys, max_workers, remaining)
         if touched_wire:
             elapsed = _time.perf_counter() - t0
             self.fanout_seconds_total += elapsed
             estimator_refresh_seconds.observe(elapsed)
+        return answers
+
+    def _sync_node_table(self) -> Optional[dict]:
+        """Upload the moved in-process members' node arrays (span
+        ``estimator.sync``). None where the registry holds no in-process
+        member or their arrays do not stack."""
+        from ..utils.metrics import estimator_upload_bytes
+        from ..utils.tracing import tracer
+
+        members = [
+            (name, est) for name, est in self._by_cluster.items()
+            if getattr(est, "conn", None) is None
+        ]
+        with tracer.span("estimator.sync") as sp:
+            synced = self._node_table.sync(members)
+            if synced is None:
+                return None
+            sp.attrs["members"] = synced["members"]
+            sp.attrs["nodes"] = synced["nodes"]
+            sp.attrs["upload_mb"] = synced["bytes"] / 1e6
+        estimator_upload_bytes.inc(synced["bytes"])
+        return synced
+
+    def _dispatch_node_table(self, requests: np.ndarray):
+        from ..utils.tracing import tracer
+
+        with tracer.span(
+            "estimator.dispatch",
+            profiles=len(requests), members=len(self._node_table.names),
+        ):
+            return self._node_table.estimate(requests)
+
+    def _memoize_node_table(self, wanted, uniq, prof_keys) -> None:
+        """The host path's form of the node table's answer: one dispatch
+        (rows padded to a power of two, zero-request pad rows), one fetch,
+        and the ``wanted`` members' cells memoized at the generation their
+        slice was uploaded at."""
+        table = self._node_table
+        padded = np.zeros((max(4, _pow2(len(uniq))), uniq.shape[1]), np.int64)
+        padded[: len(uniq)] = uniq
+        out = np.asarray(self._dispatch_node_table(padded))
+        for name in wanted:
+            i = table.slot[name]
+            self._memoize(name, prof_keys, out[: len(uniq), i], table.gens[i])
 
     def _confirm_generations(
         self,

@@ -311,11 +311,6 @@ class TensorScheduler:
         # key -> position map of the armed batch (lazily built, only when
         # dirty keys need resolving against a large wave)
         self._key_pos: Optional[dict] = None
-        # estimator-backed batch-identity fast path (see schedule()):
-        # (ids, snapshot gen, estimator ids, confirm tokens, results +
-        # pinned problems) of the last host-path batch whose estimators
-        # could all prove their memo content via refresh_token
-        self._est_batch: Optional[tuple] = None
         # binding key -> (row fingerprint, derived cp | None): skips the
         # packing+selection stage for unchanged spread rows in steady storms
         self._derived_rows: dict = {}
@@ -481,7 +476,6 @@ class TensorScheduler:
             self._batch_ids = None
             self._batch_cache = None
             self._batch_problems = None
-            self._est_batch = None
             self._quota_cache = None
             self._caps_dev = None
             self._caps_dev_token = None
@@ -1664,37 +1658,6 @@ class TensorScheduler:
     ) -> list[ScheduleResult]:
         import time as _time
 
-        # estimator-backed batch-identity fast path: extra estimators force
-        # the host path (no fleet table), but a storm re-scheduling the
-        # SAME problem objects against the SAME snapshot generation is pure
-        # in (problems, snapshot, estimator answers) — and a registry-backed
-        # estimator can PROVE its answers unchanged via refresh_token
-        # (generation confirmation: O(servers) pings, zero wire when
-        # already confirmed). A no-member-movement refresh pass collapses
-        # to the ping + an id() sweep instead of a full re-solve; any
-        # unprovable estimator (no token, unconfirmed cluster, memo drop)
-        # falls through to the full path, which retries it.
-        if (
-            self._est_batch is not None
-            and self.extra_estimators
-            and not self.custom_filters
-        ):
-            ids0, gen0, est_ids0, tokens0, results0, _pinned = self._est_batch
-            if (
-                gen0 == self._snapshot_gen
-                and len(problems) == len(results0)
-                and est_ids0 == tuple(map(id, self.extra_estimators))
-            ):
-                t0 = _time.perf_counter()
-                ids = np.fromiter(map(id, problems), np.int64, len(problems))
-                if np.array_equal(ids, ids0):
-                    tokens = self._est_tokens()
-                    if None not in tokens and tokens == tokens0:
-                        self.last_breakdown = {
-                            "compile": _time.perf_counter() - t0
-                        }
-                        return list(results0)
-
         # batch-identity fast path: a storm re-scheduling the SAME problem
         # objects against the SAME snapshot generation is pure in those
         # inputs — compilation, spread selection, and the eligibility
@@ -1719,7 +1682,7 @@ class TensorScheduler:
             )
             and not (
                 self.custom_filters
-                or self.extra_estimators
+                or self._host_only_estimators()
                 or self.disabled_plugins
             )
             and len(problems) == len(self._batch_ids)
@@ -1749,7 +1712,9 @@ class TensorScheduler:
         # engine-level features that the device-resident path does not
         # model force the general host path for the whole batch
         if not (
-            self.custom_filters or self.extra_estimators or self.disabled_plugins
+            self.custom_filters
+            or self._host_only_estimators()
+            or self.disabled_plugins
         ):
             t0 = _time.perf_counter()
             from ..ops.divide import DUPLICATED as _DUP
@@ -1759,8 +1724,12 @@ class TensorScheduler:
             # group selection collapses to a per-row candidate mask, which
             # is interned as a DERIVED placement (terms = the selection)
             # so the device-resident path divides over exactly the selected
-            # set — SelectClusters becomes part of placement compilation
-            compiled = self._derive_spread_selections(problems, compiled)
+            # set — SelectClusters becomes part of placement compilation.
+            # Not with extra estimators on: the selection ranks groups on
+            # the general estimate alone, so those rows keep the host
+            # path, where the Select stage sees the merged availability
+            if not self.extra_estimators:
+                compiled = self._derive_spread_selections(problems, compiled)
             self.last_breakdown["select"] = _time.perf_counter() - t0
 
             t0 = _time.perf_counter()
@@ -1842,38 +1811,27 @@ class TensorScheduler:
                     for i, res in zip(slow_idx, slow_res):
                         results[i] = res
                 return results
-        res = self._schedule_host(problems, compiled)
-        self._arm_est_batch(problems, res)
-        return res
+        return self._schedule_host(problems, compiled)
+
+    def _host_only_estimators(self) -> bool:
+        """Whether an extra estimator keeps the whole batch on the host
+        path: one that cannot be asked BY PROFILE (a bare callable, whose
+        answer may follow a row's replicas) has no place in the fleet
+        table's fold. A registry's batch estimator can
+        (``profile_table``), and its batches ride the fleet."""
+        return any(
+            not hasattr(est, "profile_table") for est in self.extra_estimators
+        )
 
     def _est_tokens(self) -> tuple:
         """One refresh_token probe per extra estimator (None for
-        estimators without the protocol)."""
+        estimators without the protocol): what the fleet table compares
+        before it trusts the answers it folded."""
         tokens = []
         for est in self.extra_estimators:
             probe = getattr(est, "refresh_token", None)
             tokens.append(probe() if probe is not None else None)
         return tuple(tokens)
-
-    def _arm_est_batch(self, problems, res) -> None:
-        """Arm the estimator-backed batch-identity fast path after a full
-        host-path pass: cache the results keyed by problem ids, snapshot
-        generation, and each estimator's confirm token. The problems list
-        is pinned so a recycled id() cannot alias a stale batch."""
-        if not self.extra_estimators or self.custom_filters:
-            return
-        tokens = self._est_tokens()
-        if None in tokens:
-            self._est_batch = None
-            return
-        self._est_batch = (
-            np.fromiter(map(id, problems), np.int64, len(problems)),
-            self._snapshot_gen,
-            tuple(map(id, self.extra_estimators)),
-            tokens,
-            list(res),
-            list(problems),
-        )
 
     #: cap on interned selection variants; selection outcomes are memoized
     #: by row content so real fleets produce few — the cap only bounds

@@ -87,6 +87,7 @@ _TRACE_KERNELS = {
     "B": "fleet_bits",
     "S": "state_scatter",
     "G": "meta_gather",
+    "F": "estimate_fold",
 }
 
 K_PREV = 32  # max previous-assignment sites on the fast path (small fleets
@@ -1160,6 +1161,20 @@ def _scatter_rows(state, rows, vals):
 _scatter_rows.row_coupled = True
 
 
+@jax.jit
+def _fold_estimates(table, answers, n_live):
+    """Min-merge the estimators' by-profile answers into the profile table,
+    cell by cell with merge_estimates' -1 rule: an answer of -1 keeps the
+    table's cell, a table cell of -1 takes the answer, else the minimum.
+    Rows from ``n_live`` on (the pow2 padding) stay as they are."""
+    live = jnp.arange(table.shape[0], dtype=jnp.int32)[:, None] < n_live
+    out = table
+    for est in answers:
+        merged = jnp.where(out < 0, est, jnp.minimum(out, est))
+        out = jnp.where((est < 0) | ~live, out, merged)
+    return out
+
+
 class FleetTable:
     """Device-resident binding table bound to one TensorScheduler."""
 
@@ -1227,6 +1242,13 @@ class FleetTable:
         self._dirty: set[int] = set()
         self._tables_dirty = True
         self._avail_max = 0
+        # extra-estimator fold (_fold_extra_estimates): the table it starts
+        # from, the estimators' refresh tokens at the last fold, and the
+        # fold's stretch inside the current pass
+        self._prof_base: Optional[tuple] = None
+        self._folded_ests: tuple = ()
+        self._folded_tokens: Optional[tuple] = None
+        self._est_window: Optional[tuple] = None
         self._static_max = 0
         self._snapshot_gen = getattr(engine, "_snapshot_gen", 0)
         # last observed entry total: tunes the fetched buffer well below the
@@ -1848,6 +1870,11 @@ class FleetTable:
         # rebuild runs EVERY churn pass (snapshot gen bumps per drift)
         self._avail_max = self._host_avail_max(profs)
         _mark("avail_max")
+        # what the estimator fold (_fold_extra_estimates) starts from: the
+        # table before any extra estimator's answer, its request vectors
+        # (padding included) and its bound
+        self._prof_base = (prof_table, profs_dev, len(profs), self._avail_max)
+        self._folded_ests, self._folded_tokens = (), None
         # under a mesh the slot tables replicate explicitly (empty-spec
         # NamedSharding): they are gathered per row by slot index inside
         # the sharded solve, and a one-time replicated upload beats a
@@ -1877,6 +1904,55 @@ class FleetTable:
         valid = table != mi
         return int(table[valid].max()) if valid.any() else 0
 
+    def _estimates_moved(self) -> bool:
+        """Whether the resident profile table no longer holds what the
+        engine's extra estimators answer: the estimators themselves were
+        re-pointed, an estimator's ``refresh_token`` moved since the fold,
+        or it gives none (a degraded pass: a registered member answered -1
+        transiently, so nothing folded from it may be trusted, replayed or
+        kept)."""
+        ests = tuple(self.engine.extra_estimators)
+        if ests != self._folded_ests:
+            return True
+        if not ests:
+            return False
+        tokens = self.engine._est_tokens()
+        return None in tokens or tokens != self._folded_tokens
+
+    def _fold_extra_estimates(self) -> None:
+        """Min-merge every extra estimator's by-profile answer into the
+        resident profile table (``_fold_estimates``), one more merge input
+        exactly as the quota caps are. Re-asks the estimators and re-runs
+        the fold, nothing else: no row is packed again, no mask table
+        touched. The stretch is kept in ``_est_window`` so the pass's
+        ``sync`` phase can leave it to the ``estimator.*`` spans."""
+        from ..utils.tracing import tracer
+
+        t_a = time.perf_counter()
+        base, profs_pad, n_live, base_max = self._prof_base
+        ests = self.engine.extra_estimators
+        answers = tuple(est.profile_table(profs_pad, n_live) for est in ests)
+        c = self.engine.snapshot.num_clusters
+        with tracer.span("estimator.fold", profiles=n_live, clusters=c):
+            self._mark_trace(
+                "F", len(profs_pad), c, len(answers), self._mesh is not None
+            )
+            folded = _fold_estimates(base, answers, np.int32(n_live))
+            if self._mesh is not None:
+                folded = jax.device_put(folded, NamedSharding(self._mesh, P()))
+            t = self._dev_tables
+            self._dev_tables = (t[0], t[1], t[2], folded, t[4])
+            # a stated upper bound, not the fold's own max: a cell the
+            # general estimator left unanswered takes the estimator's value
+            self._avail_max = max(
+                [base_max] + [est.profile_bound(profs_pad[:n_live]) for est in ests]
+            )
+        # whose answers were just folded (the objects are pinned: an id()
+        # could be recycled), and their tokens (None after a degraded one)
+        self._folded_ests = tuple(ests)
+        self._folded_tokens = self.engine._est_tokens()
+        self._est_window = (t_a, time.perf_counter())
+
     def _upload_state(self) -> tuple:
         """Full packed-state upload. Under a mesh the state replicates
         EXPLICITLY across every device (NamedSharding with an empty spec):
@@ -1900,6 +1976,8 @@ class FleetTable:
             getattr(self.engine, "_snapshot_gen", 0) != self._snapshot_gen
         ):
             self._rebuild_tables()
+        if self._estimates_moved():
+            self._fold_extra_estimates()
         if self._dev_state is None:
             self._dev_state = self._upload_state()
             self._dirty.clear()
@@ -2033,13 +2111,15 @@ class FleetTable:
         "fetch": "kernel.fetch",
     }
 
-    def _phase(self, tmr: dict, key: str, t0: float) -> float:
+    def _phase(
+        self, tmr: dict, key: str, t0: float, end: Optional[float] = None
+    ) -> float:
         """Close the pass phase ``key`` that began at ``t0``: its seconds
         go into the breakdown (summed where a phase has two stretches, as
         a delta pass's ``post``) and its interval is kept for the phase
         spans. Returns the closing stamp — the next phase's start, so the
         phases of a pass tile it without gaps."""
-        now = time.perf_counter()
+        now = time.perf_counter() if end is None else end
         tmr[key] = tmr.get(key, 0.0) + (now - t0)
         self._phase_marks.append((key, t0, now))
         return now
@@ -2143,7 +2223,15 @@ class FleetTable:
         tmr["rows_replayed"] = max(
             len(problems) - self._packed_this_pass, 0
         )
+        self._est_window = None
         self._sync_device()
+        if self._est_window is not None:
+            # the estimator stretch is the estimator.* spans' own: the
+            # sync phase is what lies before and after it
+            t_a, t0_after = self._est_window
+            self._phase(tmr, "sync", t0, t_a)
+            tmr["estimate"] = t0_after - t_a
+            t0 = t0_after
         t0 = self._phase(tmr, "sync", t0)
         n = len(rows_np)
         # adaptive chunk: a straggler batch of a few hundred rows should
@@ -2301,6 +2389,7 @@ class FleetTable:
             or getattr(self.engine, "_snapshot_gen", 0) != self._snapshot_gen
             or self._reuse_epoch != self._mirror_epoch
             or not delta_certified()
+            or self._estimates_moved()
         ):
             return None
         idx = np.unique(np.asarray(list(delta), np.int64))

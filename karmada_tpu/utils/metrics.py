@@ -416,6 +416,16 @@ estimator_refresh_seconds = registry.histogram(
     "karmada_tpu_estimator_refresh_seconds",
     "wall time of one registry refresh (pings + grouped fan-out)",
 )
+estimator_nodes_estimated = registry.counter(
+    "karmada_tpu_estimator_nodes_estimated_total",
+    "member nodes summed by node_sum_table dispatches (every in-process "
+    "member's nodes, once a dispatch)",
+)
+estimator_upload_bytes = registry.counter(
+    "karmada_tpu_estimator_upload_bytes_total",
+    "bytes of node state uploaded to the device-resident node table "
+    "(the slices of the members whose generation moved)",
+)
 estimator_server_requests = registry.counter(
     "karmada_tpu_estimator_server_requests_total",
     "estimator-server RPCs served, by method",

@@ -124,6 +124,19 @@ SPAN_NAMES: dict[str, str] = {
         "one estimator-registry refresh: generation pings + grouped "
         "profile fan-out"
     ),
+    "estimator.sync": (
+        "under estimator.refresh: which in-process members moved, their "
+        "node arrays stacked and uploaded to the device-resident node "
+        "table (members / nodes / upload_mb attrs)"
+    ),
+    "estimator.dispatch": (
+        "under estimator.refresh: the one node_sum_table dispatch that "
+        "answers every in-process member"
+    ),
+    "estimator.fold": (
+        "the fleet table's min-merge of the estimators' [P, C] answers "
+        "into its resident profile table (profiles / clusters attrs)"
+    ),
     "estimator.rpc": (
         "client side of one estimator-channel RPC (remote=true; "
         "peer/method attrs)"
