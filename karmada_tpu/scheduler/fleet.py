@@ -36,11 +36,17 @@ a trace decides them (ROADMAP Design 3):
   device but fetches ~0.2 MB; a full availability-drift churn pass ships
   only the ~half of rows whose placements actually moved.
 - per-row entry vectors are compacted from the dense assignment by ONE
-  ascending single-operand sort (the packed word orders by site), chosen
-  over gather-based position search and scatter compaction; the dispense
-  itself finds its largest-remainder bonus threshold by binary search
-  instead of top_k (timings behind these choices predate this machine:
-  not measured here);
+  ascending single-operand sort (the packed word orders by site); the
+  dispense finds its largest-remainder bonus threshold by binary search
+  instead of top_k (neither choice has been timed on this machine);
+- no per-element scatter or gather where the data is already ordered.
+  Measured on the v5e (PERF.md section 6, PR 28): an element scatter
+  costs 4.6-8 ns an update and an element gather 9 ns, whatever the
+  bandwidth. So the previous-assignment grid is a compare-and-sum
+  (_row_masks: 0.6 ms of a 100k x 100 pass, the scatter-add 26.8), and
+  the rows' valid prefixes are joined into the wire's stream by log-step
+  shifts (_compact_rows: 1.7 ms for 6.55M slots, the scatter 30.2, a
+  gather by row offsets 20-21 alone);
 - feasible bitsets ride a second, lazily-fetched output only when the
   batch contains Duplicated or zero-replica bindings.
 
@@ -170,6 +176,41 @@ def _entry_wire(stream, e_cap: int, pack21: bool):
     ).astype(jnp.uint8).reshape(-1)
 
 
+def _compact_rows(slots, counts, cap: int):
+    """Row-major compaction without a scatter. ``slots`` int32[n, w]: row
+    r's first ``counts[r]`` (<= w) slots are live, in order. Returns
+    (int32[cap]: the live slots of all rows back to back, zero-padded and
+    cut at ``cap``; their total).
+
+    Every live slot of row r has to move left by r*w - offs[r], a shift
+    that never falls from one row to the next (counts <= w). Such a move
+    needs no scatter: for each bit of the shift, low to high, the slots
+    with that bit set move left by 2^bit, one select over the flat array.
+    Two slots never meet: at equal low bits the later one lies at least as
+    far right as it will end up ahead. On the v5e, 102,400 x 64 slots into
+    2.4M, alone: 4.5 ms for the 23 selects (1.7 ms inside the pass)
+    against 33.0 ms for the scatter of 6.55M updates (4.6 ns each) and
+    20-21 ms for a gather of the 2.4M (9 ns each): PERF.md section 6,
+    PR 28."""
+    n, w = slots.shape
+    counts = counts.astype(jnp.int32)
+    offs = jnp.cumsum(counts, dtype=jnp.int32) - counts
+    total = offs[-1] + counts[-1]
+    live = jnp.arange(w, dtype=jnp.int32)[None, :] < counts[:, None]
+    left = jnp.arange(n, dtype=jnp.int32) * w - offs
+    vals = jnp.where(live, slots, 0).reshape(-1)
+    shift = jnp.where(live, left[:, None], 0).reshape(-1)
+    for b in range((n * w - 1).bit_length()):
+        k = 1 << b
+        v_in = jnp.pad(vals[k:], (0, k))
+        s_in = jnp.pad(shift[k:], (0, k))
+        arrives = ((s_in >> b) & 1) == 1
+        leaves = ((shift >> b) & 1) == 1
+        vals = jnp.where(arrives, v_in, jnp.where(leaves, 0, vals))
+        shift = jnp.where(arrives, s_in, jnp.where(leaves, 0, shift))
+    return jnp.pad(vals, (0, max(cap - n * w, 0)))[:cap], total
+
+
 # --------------------------------------------------------------------------
 # fused solve
 # --------------------------------------------------------------------------
@@ -187,7 +228,7 @@ def _unpack_bits(bits_u8, c: int):
 
 def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
                pcc, vc, chunk: int, c: int):
-    """Per-chunk previous-assignment scatter + THE feasibility algebra,
+    """Per-chunk previous-assignment grid + THE feasibility algebra,
     shared by every kernel that needs it (_fleet_solve, _fleet_pass,
     _fleet_bits) so the mask expression cannot drift between the solve
     and the lazily-computed feasibility bitsets. Returns (prev, static_w,
@@ -198,11 +239,18 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
     steady pass (60 KB/row as int32 planes -> 21 KB packed+static,
     measured 0.57 s -> ~0.2 s over 245 chunks), and the slot table's HBM
     footprint drops ~3x with it."""
-    prev = (
-        jnp.zeros((chunk, c), jnp.int32)
-        .at[jnp.arange(chunk)[:, None], psc]
-        .add(pcc)
-    )
+    with jax.named_scope("fleet.prev"):
+        # compare-and-sum, not a scatter-add: the K_PREV (site, count)
+        # pairs of a row against the cluster iota, summed over the pairs.
+        # Integer adds commute, so duplicate sites and the (0, 0) padding
+        # give what a scatter-add gives; it fuses into the reduction
+        # ([chunk, K, C] is never materialised) and is 0.6 ms of a
+        # 100k x 100 pass where the scatter-add was 26.8 (PERF.md
+        # section 6, PR 28)
+        iota_c = jnp.arange(c, dtype=jnp.int32)
+        prev = jnp.where(
+            psc[:, :, None] == iota_c, pcc[:, :, None], 0
+        ).sum(axis=1, dtype=jnp.int32)
     prev_mask = prev > 0
     # plain [B]-index row gathers: re-probed on the current backend at
     # U in {2..3500} x W in {5k, 15k} — compiles fine and runs at
@@ -720,13 +768,9 @@ def _fleet_pass(
                 outs[3].reshape(changed.shape[0], -1), None, None
             )
             contrib = changed & (dcounts <= 62)
-            rowv = jnp.where(contrib[:, None], deltas_all, 0).reshape(-1)
-            validv = rowv != 0
-            doffs = jnp.cumsum(validv.astype(jnp.int32)) - validv
-            dtotal = doffs[-1] + validv[-1].astype(jnp.int32)
-            dwrite = jnp.where(validv & (doffs < d_cap), doffs, d_cap)
-            dbuf = jnp.zeros((d_cap + 1,), jnp.int32).at[dwrite].set(rowv)
-            dstream = dbuf[:d_cap]
+            dstream, dtotal = _compact_rows(
+                deltas_all, jnp.where(contrib, dcounts, 0), d_cap
+            )
             dtotal_u8 = jnp.stack(
                 [(dtotal >> s) & 0xFF for s in (0, 8, 16, 24)]
             ).astype(jnp.uint8)
@@ -788,13 +832,10 @@ def _fleet_entries(
                 ents, NamedSharding(mesh, P())
             )
         entries = ents.reshape(-1, k_out)  # [m_pad, k_out]
-        valid_e = (entries > 0).reshape(-1)
-        offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
-        total = offs[-1] + valid_e[-1].astype(jnp.int32)
-        packed = entries.reshape(-1)
-        write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
-        buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
-        stream = buf[:e_cap]
+        # the sort left each row's placed sites first
+        stream, total = _compact_rows(
+            entries, (entries > 0).sum(axis=1), e_cap
+        )
         if byte_wire:
             total_u8 = jnp.stack(
                 [(total >> s) & 0xFF for s in (0, 8, 16, 24)]

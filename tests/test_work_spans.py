@@ -380,8 +380,8 @@ class TestCollector:
 # D: scopes inside the kernels
 # --------------------------------------------------------------------------
 
-SCOPES = ("fleet.gather", "fleet.masks", "fleet.estimate", "fleet.divide",
-          "fleet.diff", "fleet.deltas", "fleet.wire")
+SCOPES = ("fleet.gather", "fleet.masks", "fleet.prev", "fleet.estimate",
+          "fleet.divide", "fleet.diff", "fleet.deltas", "fleet.wire")
 
 
 def test_fleet_pass_stages_carry_named_scopes(engine):
