@@ -225,9 +225,9 @@ def build_parser():
     p.add_argument(
         "--scale", action="store_true",
         help="force the scale-1M tier (1M bindings x 5k clusters: steady, "
-        "availability-drift churn, the row-churn delta tiers at "
-        "0.1%%/1%%/10%% churn with the full-solve bit-identity oracle, and "
-        "the legacy-path run) even when --bindings/--no-verify would "
+        "availability-drift churn, and the row-churn delta tiers at "
+        "0.1%%/1%%/10%% churn with the full-solve bit-identity oracle) "
+        "even when --bindings/--no-verify would "
         "otherwise skip it; the default 100k run includes it already",
     )
     p.add_argument(
@@ -1226,9 +1226,9 @@ def run_engine_north_star(args) -> dict:
 
     # ---- 1M x 5k scale tier (first-class, VERDICT r3 item 9) --------------
     # Ten times the headline bindings through the same engine: steady +
-    # full-drift churn p50s with sampled oracle verification. The dense
-    # resident would exceed its HBM budget at this cap, so this tier also
-    # keeps the legacy entry-resident path honest.
+    # full-drift churn p50s with sampled oracle verification. Its dense
+    # resident (1,048,576 x 5,000 = 5.2 GB) is the largest the 6 GiB
+    # bound admits at this cluster count.
     def _scale1m_tier() -> tuple:
         b_m = 1_000_000
         rng_m = np.random.default_rng(1234)
@@ -1271,8 +1271,8 @@ def run_engine_north_star(args) -> dict:
         # path serves. Cost must track churn size, not plane size; the
         # per-pass breakdown must prove the sub dispatch packed exactly
         # the dirty set, and placements must stay bit-identical to the
-        # full-solve oracle (verified after the legacy tier below, once
-        # the resident memory is free for a second 1M engine).
+        # full-solve oracle (verified below, once the resident memory is
+        # free for a second 1M engine).
         def _digest_rows(res, n):
             out = np.empty(n, np.uint64)
             for i in range(n):
@@ -1389,43 +1389,7 @@ def run_engine_north_star(args) -> dict:
         if m_bad:
             print(f"# WARNING: 1M mismatches: {m_bad}", file=sys.stderr)
             tier_status["scale-1M"] = f"error: {m_bad} mismatches"
-        # keep the legacy entry-resident path honest at scale too: with
-        # the 6 GiB dense budget the 1M tier rides the dense path, so pin
-        # the budget to 0 and post a steady p50 through the legacy solve
-        # (the path any table beyond the budget runs on)
         del m_engine, m_res
-        gc.collect()
-        import karmada_tpu.scheduler.fleet as _fleet_mod
-
-        saved_budget = _fleet_mod.DENSE_RESIDENT_MAX_BYTES
-        _fleet_mod.DENSE_RESIDENT_MAX_BYTES = 0
-        try:
-            l_engine = TensorScheduler(snap, chunk_size=args.chunk)
-            t0 = time.perf_counter()
-            l_engine.schedule(m_problems)
-            print(f"# 1M legacy warm pass: {time.perf_counter() - t0:.1f}s",
-                  file=sys.stderr)
-            # adaptive settle: the legacy e_cap's sustained-shrink window
-            # is longer than three fixed passes — breaking early parked
-            # its one allowed recompile inside the timed window (14.6s
-            # recorded where the clean pass runs ~4s)
-            settle_engine(
-                l_engine, lambda i: l_engine.schedule(m_problems),
-                floor=3, cap=12, label="1M legacy settle",
-            )
-            l_times = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                l_engine.schedule(m_problems)
-                l_times.append(time.perf_counter() - t0)
-            m1_legacy = float(np.median(l_times))
-            print(
-                f"# 1M legacy steady p50: {m1_legacy:.3f}s",
-                file=sys.stderr,
-            )
-            del l_engine
-        finally:
-            _fleet_mod.DENSE_RESIDENT_MAX_BYTES = saved_budget
         gc.collect()
         # bit-identity oracle for the row-churn tiers: a fresh engine with
         # the delta path killed (KARMADA_TPU_DELTA_SOLVE=0) full-solves
@@ -1461,7 +1425,6 @@ def run_engine_north_star(args) -> dict:
             "steady": m1_steady,
             "churn": m1_churn,
             "churn_max": m1_churn_max,
-            "legacy": m1_legacy,
             **m_churn_tiers,
         }
 
@@ -1634,7 +1597,6 @@ def run_engine_north_star(args) -> dict:
         out["scale1m_steady_p50"] = _r(m1d.get("steady"))
         out["scale1m_churn_p50"] = _r(m1d.get("churn"))
         out["scale1m_churn_max"] = _r(m1d.get("churn_max"))
-        out["scale1m_legacy_p50"] = _r(m1d.get("legacy"))
         out["scale1m_churn0p1pct_p50"] = _r(m1d.get("churn0p1pct"))
         out["scale1m_churn1pct_p50"] = _r(m1d.get("churn1pct"))
         out["scale1m_churn10pct_p50"] = _r(m1d.get("churn10pct"))
@@ -4247,11 +4209,7 @@ def run_multichip(args) -> dict:
         # donation probe: the resident the table holds NOW must be
         # consumed (aliased, not copied) by the next pass's solve
         fleet = engine._fleet
-        resident = (
-            fleet._res_dense
-            if fleet._res_dense is not None
-            else fleet._resident_entries
-        )
+        resident = fleet._res_dense
         engine.schedule(problems)
         donated[key] = bool(resident.is_deleted())
         times = []

@@ -985,7 +985,7 @@ def stage_mesh(
         ("meta", fleet._res_meta, True),
         ("state", fleet._dev_state[0], False),
     ):
-        _check(arr is not None, f"no {name} resident (legacy layout?)")
+        _check(arr is not None, f"no {name} resident")
         per_dev = [s.data.nbytes for s in arr.addressable_shards]
         _check(len(arr.sharding.device_set) == n_devices,
                f"{name} lives on {len(arr.sharding.device_set)} devices")

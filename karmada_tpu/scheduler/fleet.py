@@ -4,7 +4,8 @@ Ref: pkg/scheduler/cache/cache.go:42-62 — the reference keeps a cluster
 cache fed by informers so each scheduling attempt touches only deltas.
 This module is that idea taken device-side: per-binding state (placement
 slot, request profile slot, previous assignment sites, replicas, flags)
-lives in HBM between scheduling passes, and each pass is
+and every row's last answer live in HBM between scheduling passes, and
+each pass is
 
     host delta scatter  ->  ONE fused XLA dispatch  ->  ONE compact fetch.
 
@@ -14,11 +15,16 @@ device syncs), which capped the engine at ~4k bindings/s while the kernel
 alone did 100k x 5k in 0.74 s. The fleet table removes all per-pass O(B)
 host packing for unchanged bindings and all but one device round-trip.
 
+There is ONE resident layout: the dense assignment, uint8[cap, C], with
+one meta word a row, donated into every pass and updated in place. A table
+whose cap x C would pass DENSE_RESIDENT_MAX_BYTES raises FleetTableTooLarge
+where it grows: there is no second layout to fall back to.
+
 What the layout minimises: bytes moved between host and device per pass,
-and blocking host<->device round-trips per pass. What either costs on a
-chip local to the process is not measured on this machine; the wire and
-cap mechanisms below were sized on an earlier, slower link and stay until
-a trace decides them (ROADMAP Design 3):
+and blocking host<->device round-trips per pass. Where a pass's time goes
+on the v5e is measured cell by cell in PERF.md sections 5 and 6 (at
+100k x 100, all rows moving: the kernel 24 ms, the 7.5 MB fetch 11 ms, the
+decode 9 ms):
 
 - all per-row state is gathered ON DEVICE from resident arrays (`rows` is
   the only per-pass index upload, and the all-rows storm case keeps even
@@ -29,16 +35,18 @@ a trace decides them (ROADMAP Design 3):
   bandwidth; the historical one-hot-matmul workaround for a scan-gather
   compile hang remains in ops.estimate.gather_profile_rows for other
   callers);
-- DELTA FETCH: the device keeps every row's previous (site << 8 | count)
-  entry vector resident; a pass ships home only the rows whose vector
-  CHANGED (plus one meta word per row), against a host-side mirror of the
-  entry table. A steady rebalance storm re-divides all 100k bindings on
-  device but fetches ~0.2 MB; a full availability-drift churn pass ships
-  only the ~half of rows whose placements actually moved.
-- per-row entry vectors are compacted from the dense assignment by ONE
-  ascending single-operand sort (the packed word orders by site); the
+- DELTA FETCH: _fleet_pass diffs each row's new dense vector against the
+  resident and ships home a changed-row bitmask, the changed rows' meta
+  words and their changed CELLS (site << 9 | count + 1), folded into a
+  host-side mirror of every row's (site << 8 | count) entry vector. A
+  steady rebalance storm re-divides all 100k bindings on device and
+  fetches a few tens of KB; a churn pass ships the cells that moved;
+- rows with more than 62 changed cells, and passes whose deltas overflow
+  their buffer, take phase B: _fleet_entries gathers exactly those rows
+  from the dense resident and compacts each into its entry vector by ONE
+  ascending single-operand sort (the packed word orders by site). The
   dispense finds its largest-remainder bonus threshold by binary search
-  instead of top_k (neither choice has been timed on this machine);
+  instead of top_k;
 - no per-element scatter or gather where the data is already ordered.
   Measured on the v5e (PERF.md section 6, PR 28): an element scatter
   costs 4.6-8 ns an update and an element gather 9 ns, whatever the
@@ -47,8 +55,8 @@ a trace decides them (ROADMAP Design 3):
   the rows' valid prefixes are joined into the wire's stream by log-step
   shifts (_compact_rows: 1.7 ms for 6.55M slots, the scatter 30.2, a
   gather by row offsets 20-21 alone);
-- feasible bitsets ride a second, lazily-fetched output only when the
-  batch contains Duplicated or zero-replica bindings.
+- feasible bitsets ride a second, lazily-dispatched kernel (_fleet_bits)
+  only when the batch contains Duplicated or zero-replica bindings.
 
 Eligibility: a binding rides the fleet path when its placement has a single
 affinity term, no spread-constraint selection (or the static-weight ignore
@@ -87,7 +95,6 @@ log = logging.getLogger("karmada_tpu")
 #: trace-key prefix -> kernel family, for the per-bucket compile counter
 #: (karmada_tpu_kernel_compiles_total) every _mark_trace feeds
 _TRACE_KERNELS = {
-    "L": "fleet_solve",
     "A": "fleet_pass",
     "E": "fleet_entries",
     "B": "fleet_bits",
@@ -163,9 +170,9 @@ def _pack21(stream, e_cap: int):
 
 
 def _entry_wire(stream, e_cap: int, pack21: bool):
-    """The entry stream's byte-wire serialization (shared by both solve
-    kernels so the format cannot drift): 21-bit packed (+3 pad bytes for
-    the host's 4-byte-window decoder) or plain 3-byte entries."""
+    """The entry stream's byte-wire serialization (_decode_entry_wire is
+    its inverse): 21-bit packed (+3 pad bytes for the host's
+    4-byte-window decoder) or plain 3-byte entries."""
     if pack21:
         return jnp.concatenate(
             [_pack21(stream, e_cap), jnp.zeros((3,), jnp.uint8)]
@@ -229,9 +236,9 @@ def _unpack_bits(bits_u8, c: int):
 def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
                pcc, vc, chunk: int, c: int):
     """Per-chunk previous-assignment grid + THE feasibility algebra,
-    shared by every kernel that needs it (_fleet_solve, _fleet_pass,
-    _fleet_bits) so the mask expression cannot drift between the solve
-    and the lazily-computed feasibility bitsets. Returns (prev, static_w,
+    shared by every kernel that needs it (_fleet_pass, _fleet_bits) so
+    the mask expression cannot drift between the solve and the
+    lazily-computed feasibility bitsets. Returns (prev, static_w,
     feasible); callers apply their own sharding constraints.
 
     The affinity and taint planes ship BITPACKED (uint8, 8 clusters per
@@ -272,269 +279,42 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
     return prev, static_w, feasible
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "chunk", "n_chunks", "k_out", "k_res", "e_cap", "wide", "fast",
-        "has_aggregated", "all_rows", "mesh", "shard_c",
-        "pack21",
-    ),
-    donate_argnames=("prev_entries",),
-)
-def _fleet_solve(
-    cp_bits,  # uint8[U, 2*W8]: bitpacked [aff&spread_field | taint]
-    cp_static,  # int32[U, C]: static weights
-    gvk_bits,  # uint8[G, W8] bitpacked enablement masks
-    prof_table,  # int32[P, C] general availability (-1 = no answer)
-    incomplete_en,  # bool[C] — ~CompleteAPIEnablements
-    rows,  # int32[n_pad] table rows (-1 = padding)
-    cp_idx, gvk_idx, prof_idx,  # int32[cap]
-    replicas, strategy,  # int32[cap]
-    fresh,  # bool[cap]
-    prev_sites, prev_counts,  # int32[cap, K_PREV]
-    prev_entries,  # int32[cap, k_res] — last pass's entry rows (delta
-    # base). DONATED: the updated resident aliases this buffer, so the
-    # persistent entry base never double-buffers in HBM and a settle
-    # drain re-uses the same device allocation pass after pass.
-    *,
-    chunk: int,
-    n_chunks: int,
-    k_out: int,
-    k_res: int,  # resident entry width >= k_out (stable across batches)
-    e_cap: int,
-    wide: bool,
-    fast: Optional[tuple],
-    has_aggregated: bool,
-    all_rows: bool,
-    mesh=None,  # jax.sharding.Mesh with axes ("b", "c") — None = single-device
-    shard_c: bool = False,  # also shard the cluster axis over mesh axis "c"
-    pack21: bool = False,  # 21-bit entry packing (site < 2^13)
-):
-    c = cp_static.shape[1]
-    c_ax = "c" if (mesh is not None and shard_c) else None
-
-    def shard(a, *axes):
-        # sharding constraints on the per-chunk working set: GSPMD
-        # partitions the row (and optionally cluster) axis across the mesh;
-        # the dispense sorts along a sharded cluster axis induce c-axis
-        # all-gathers — the same collective structure as
-        # parallel.solver.make_sharded_step, proven placement-identical by
-        # tests/test_parallel_graft.py
-        if mesh is None:
-            return a
-        return lax.with_sharding_constraint(
-            a, NamedSharding(mesh, P(*axes))
-        )
-
-    with jax.named_scope("fleet.gather"):
-        valid = rows >= 0
-        r = jnp.maximum(rows, 0)
-        # compact per-pass state ([n_pad]), gathered outside the scan
-        cp = cp_idx[r]
-        gv = gvk_idx[r]
-        pf = prof_idx[r]
-        reps = jnp.where(valid, replicas[r], 0)
-        st = strategy[r]
-        fr = fresh[r] & valid
-        ps = prev_sites[r]
-        pc = jnp.where(valid[:, None], prev_counts[r], 0)
-
-    def body(carry, i):
-        with jax.named_scope("fleet.gather"):
-            sl = lambda a: lax.dynamic_slice_in_dim(
-                a, i * chunk, chunk, axis=0
-            )
-            cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
-            repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-            psc, pcc = sl(ps), sl(pc)
-            repsc, stc, frc, vc = (
-                shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
-                shard(vc, "b"),
-            )
-            cpc, gvc, pfc = (
-                shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
-            )
-            psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
-        # mask composition — same algebra as TensorScheduler._pack_chunk,
-        # via the shared helper every feasibility consumer uses
-        with jax.named_scope("fleet.masks"):
-            prev, static_w, feasible = _row_masks(
-                cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, vc, chunk, c,
-            )
-            prev = shard(prev, "b", c_ax)
-            feasible = shard(feasible, "b", c_ax)
-        with jax.named_scope("fleet.estimate"):
-            general = prof_table[pfc]
-            avail = shard(merge_estimates(repsc, (general,)), "b", c_ax)
-        with jax.named_scope("fleet.divide"):
-            assignment, unsched = _divide_batch(
-                stc, repsc, feasible, static_w, avail, prev, frc,
-                has_aggregated, wide, fast,
-            )
-            # Duplicated rows are represented by the feasible bitset (their
-            # count is just `replicas` everywhere feasible); zero their dense
-            # rows so the entry stream carries only Divided placements
-            assignment = shard(
-                jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
-                "b", c_ax,
-            )
-        # compact each row's placed sites (<= k_out of them: every placed
-        # site holds >= 1 of <= max-replicas <= k_out replicas): the packed
-        # (site << 8 | count) word sorts by site, so one ascending
-        # single-operand sort + a static prefix slice IS the per-row entry
-        # vector. Measured on the v5e at C=5k: sort 0.29s vs 1.8s for
-        # binary-search position extraction (batched gathers) and 2.5s for
-        # scatter compaction.
-        with jax.named_scope("fleet.compact"):
-            selected = assignment > 0
-            n_placed = selected.sum(axis=1).astype(jnp.int32)
-            idxs = jnp.arange(c, dtype=jnp.int32)[None, :]
-            packed_full = jnp.where(
-                selected, (idxs << 8) | assignment, jnp.int32(2**31 - 1)
-            )
-            srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
-            entries = shard(jnp.where(srt == 2**31 - 1, 0, srt), "b", None)
-            has_cand = feasible.any(axis=1)
-        return carry, (entries, n_placed.astype(jnp.int32), unsched, has_cand)
-
-    _, outs = lax.scan(body, 0, jnp.arange(n_chunks))
-    entries = outs[0].reshape(-1, k_out)  # [n_pad, k_out]
-    n_placed = outs[1].reshape(-1)
-    unsched = outs[2].reshape(-1)
-    has_cand = outs[3].reshape(-1)
-
-    # delta detection: a row whose entry vector is identical to last pass's
-    # ships nothing — the host already holds its entries. Steady storms
-    # fetch ~zero bytes; the changed bit rides the meta word. The all-rows
-    # storm (rows == iota) reads and writes the resident base as contiguous
-    # slices — the general row gather/scatter costs ~0.17s/pass at 100k.
-    # The resident base is k_res wide (grow-only across batches) so a
-    # straggler batch with a smaller per-batch k_out neither wipes the base
-    # nor leaves stale columns: its vectors are zero-padded to k_res.
-    with jax.named_scope("fleet.diff"):
-        if k_res > k_out:
-            entries = jnp.pad(entries, ((0, 0), (0, k_res - k_out)))
-        if all_rows:
-            # int32 offsets: the SPMD partitioner mixes the shard-offset
-            # arithmetic (s32) with the slice start, and an x64-default s64
-            # start fails HLO verification on the row-sharded resident
-            z32 = jnp.int32(0)
-            pe = lax.dynamic_slice_in_dim(
-                prev_entries, z32, entries.shape[0], 0
-            )
-            changed = (entries != pe).any(axis=1) & valid
-            new_resident = lax.dynamic_update_slice_in_dim(
-                prev_entries, entries, z32, 0
-            )
-        else:
-            changed = (entries != prev_entries[r]).any(axis=1) & valid
-            new_resident = prev_entries.at[
-                jnp.where(valid, r, prev_entries.shape[0])
-            ].set(entries, mode="drop")
-        # pin the updated resident to the layout it was allocated with
-        # (row-sharded under a mesh): donation aliases input->output only
-        # when the shardings agree, so the constraint is what keeps the
-        # persistent base buffer-stable across passes
-        new_resident = shard(new_resident, "b", None)
-
-    # compact changed rows' (site, count) pairs into one row-major entry
-    # stream; zero entries are the padding the per-row vectors carry.
-    # The compaction is a GLOBAL prefix scan — replicate its inputs
-    # explicitly: without the constraint, the resident's row sharding
-    # back-propagates into the cumsum/scatter and the partitioned scan
-    # emits a corrupt stream (observed on the CPU SPMD partitioner:
-    # changed-entry totals beyond the theoretical bound)
-    with jax.named_scope("fleet.wire"):
-        entries_w = shard(entries, None, None)
-        changed_w = shard(changed, None)
-        valid_e = ((entries_w > 0) & changed_w[:, None]).reshape(-1)
-        offs = jnp.cumsum(valid_e.astype(jnp.int32)) - valid_e
-        total = offs[-1] + valid_e[-1].astype(jnp.int32)
-        packed = entries_w.reshape(-1)
-        write = jnp.where(valid_e & (offs < e_cap), offs, e_cap)
-        buf = jnp.zeros((e_cap + 1,), jnp.int32).at[write].set(packed)
-        stream = buf[:e_cap]
-
-        # one metadata word per row:
-        # n_placed | unsched<<8 | has_cand<<9 | changed<<10
-        meta = (
-            n_placed
-            | (unsched.astype(jnp.int32) << 8)
-            | (has_cand.astype(jnp.int32) << 9)
-            | (changed_w.astype(jnp.int32) << 10)
-        )
-        c_total = cp_static.shape[1]
-        if c_total <= 0xFFFF:
-            # byte wire: transfer bytes are the pass's budget, and a packed
-            # entry fits 3 bytes when the site index fits 16 bits (counts
-            # are <= MAX_REPLICAS_FAST < 256, meta words < 2^11). Bytes are
-            # decomposed with shifts, not bitcasts, so the layout is
-            # endianness-independent.
-            total_u8 = jnp.stack(
-                [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
-            ).astype(jnp.uint8)
-            meta_u8 = jnp.stack(
-                [meta & 0xFF, (meta >> 8) & 0xFF], axis=-1
-            ).astype(jnp.uint8).reshape(-1)
-            e_u8 = _entry_wire(stream, e_cap, pack21)
-            flat = jnp.concatenate([total_u8, meta_u8, e_u8])
-        else:
-            flat = jnp.concatenate([total[None], meta, stream])
-    return flat, new_resident
-
-
 # --------------------------------------------------------------------------
 # two-phase solve: pass kernel (A) + changed-rows entry kernel (B)
 # --------------------------------------------------------------------------
 #
-# The single-dispatch _fleet_solve above compacts EVERY row's entry vector
-# with a full-width [chunk, C] sort each pass — measured ~0.29s of the
-# ~0.41s kernel at 100k x 5k, paid even when a steady pass changes nothing.
-# The two-phase form keeps the DENSE assignment resident (uint8[cap, C])
-# and splits the pass:
+# The table keeps the DENSE assignment resident (uint8[cap, C], with one
+# meta word a row beside it) and a pass is split in two:
 #
-#   A: solve + diff against the dense resident + update it; wire home is
-#      4B changed-count + a changed-row BITMASK (n/8 bytes) + the changed
-#      rows' meta words (tuned cap). No sort, no entry stream: a steady
-#      100k pass ships ~13 KB and runs no compaction at all.
-#   B: only when rows changed — gather exactly the changed rows from the
-#      dense resident and sort-compact THEM into the entry stream. The
-#      entry cap is sized EXACTLY from the changed metas the host already
-#      holds (sum of n_placed), so the overflow->rerun double dispatch of
-#      the tuned single-phase path is structurally impossible here.
-#
-# The legacy single-dispatch path remains for tables whose dense mirror
-# would not fit the HBM budget (cap x C bytes), e.g. the 1M-binding tier.
+#   A (_fleet_pass): divide every row, diff against the dense resident and
+#      update it in place; the wire home is 4B changed-count + a
+#      changed-row BITMASK (n/8 bytes) + the changed rows' meta words
+#      (tuned cap m_cap) + the changed CELLS of rows with <= 62 of them
+#      (tuned cap d_cap). No sort of unchanged rows, no entry stream: a
+#      steady 100k pass ships ~13 KB plus the delta floor.
+#   B (_fleet_entries): only for changed rows the cell deltas could not
+#      carry — gather exactly those rows from the dense resident and
+#      sort-compact THEM into the (site << 8 | count) entry stream. Every
+#      placed site holds >= 1 replica, so a row has <= k_out entries and
+#      the packed word sorts by site: one ascending single-operand sort
+#      and a static prefix slice IS the row's entry vector (on the v5e at
+#      C=5k: 0.29 s against 1.8 s for binary-search position extraction
+#      and 2.5 s for scatter compaction). The entry cap is the sum of the
+#      changed rows' n_placed, which the host already holds from A's
+#      metas, so the buffer cannot overflow.
 
-#: dense-resident budget: above this, FleetTable uses the legacy
-#: entry-resident single-dispatch path (a 1M x 5k table's 5.2 GB mirror
-#: plus the solve working set over-commits a 16 GB part in practice —
-#: measured RESOURCE_EXHAUSTED on the v5e). Override via
-#: KARMADA_TPU_DENSE_BUDGET (bytes) on larger parts.
-def _dense_budget() -> int:
-    import os
-
-    raw = os.environ.get("KARMADA_TPU_DENSE_BUDGET", "")
-    try:
-        # 6 GiB default: a v5e chip carries 16 GB HBM and the dense
-        # resident is the only O(rows x clusters) tenant — at 6 GiB the
-        # 1M x 5k tier rides the dense+delta path (steady 4.5s -> 2.3s,
-        # churn 15s -> 12s measured) and tables beyond it (>1.2M rows at
-        # 5k clusters) fall back to the entry-resident legacy path.
-        return int(raw) if raw else 6 << 30
-    except ValueError:
-        import sys
-
-        print(
-            f"# KARMADA_TPU_DENSE_BUDGET={raw!r} is not an integer byte "
-            "count; using the 6 GiB default",
-            file=sys.stderr,
-        )
-        return 6 << 30
+#: the most bytes the dense resident (cap x C, one uint8 a cell: the one
+#: O(rows x clusters) tenant of the device) may take; FleetTable._grow
+#: refuses a table past it. A 1M x 5k table's 5.2 GB resident plus the
+#: solve's working set was measured RESOURCE_EXHAUSTED on a 16 GB v5e;
+#: 6 GiB is 1.28M rows at 5,000 clusters and 64M rows at 100.
+DENSE_RESIDENT_MAX_BYTES = 6 << 30
 
 
-DENSE_RESIDENT_MAX_BYTES = _dense_budget()
+class FleetTableTooLarge(ValueError):
+    """The fleet table would outgrow DENSE_RESIDENT_MAX_BYTES."""
+
+
 M_ROUND = 1 << 15  # changed-meta buffer quantum (bounds trace churn)
 D_ROUND = 1 << 16  # cell-delta buffer quantum (bounds trace churn)
 D_FLOOR = 8192  # cell-delta floor: 24 KB of wire on every steady pass
@@ -666,8 +446,9 @@ def _fleet_pass(
             # rows: the per-row scatter overhead is what made this form wrong
             # for the 100k storm, which is exactly the all_rows case)
             if all_rows:
-                # int32 shard-safe offsets (see _fleet_solve: the
-                # partitioner rejects s64 starts on the row-sharded residents)
+                # int32 offsets: the SPMD partitioner mixes its s32
+                # shard-offset arithmetic with the slice start, and an s64
+                # start fails HLO verification on the row-sharded residents
                 off = (i * chunk).astype(jnp.int32)
                 z32 = jnp.int32(0)
                 old_d = lax.dynamic_slice(rd, (off, z32), (chunk, c))
@@ -722,7 +503,8 @@ def _fleet_pass(
         # the wire build below is GLOBAL prefix-scan + scatter compaction:
         # replicate its inputs explicitly so the residents' row sharding
         # cannot back-propagate into the cumsums (the CPU SPMD partitioner
-        # emits corrupt streams for sharded global scans: see _fleet_solve)
+        # emits corrupt streams for sharded global scans: changed totals
+        # beyond the theoretical bound were observed)
         changed = shard(outs[0].reshape(-1), None)  # bool[n_pad]
         meta = shard(outs[1].reshape(-1), None)
         dcounts = shard(outs[2].reshape(-1), None)
@@ -826,7 +608,7 @@ def _fleet_entries(
     with jax.named_scope("fleet.wire"):
         # replicate before the global compaction scan: the dense resident
         # input is row-sharded on mesh engines, and a sharded cumsum is
-        # exactly the CPU-SPMD corruption _fleet_solve guards against
+        # exactly the CPU-SPMD corruption _fleet_pass's wire guards against
         if mesh is not None:
             ents = lax.with_sharding_constraint(
                 ents, NamedSharding(mesh, P())
@@ -912,10 +694,9 @@ def _gather_meta(res_meta, rows):
 
 # row_coupled: the graftlint-dep delta-safety declarations (IR006-
 # checked against the traced jaxprs, see tools/graftlint/dep.py). The
-# solve/pass/entries kernels compact globally across the resident cap
+# pass/entries kernels compact globally across the resident cap
 # axis (coupled); bits/meta are per-row — bits' scan windowing keeps the
 # analyzer's verdict 'unproven', so neither is delta_safe yet.
-_fleet_solve.row_coupled = True
 _fleet_pass.row_coupled = True
 _fleet_entries.row_coupled = True
 _fleet_bits.row_coupled = False
@@ -930,7 +711,6 @@ _gather_meta.row_coupled = False
 #: jax-free load-time filter) mirrors these names and is asserted against
 #: this dict at replay time; graftlint IR004 fails on any drift.
 FLEET_KERNELS = {
-    "fleet_solve": _fleet_solve,
     "fleet_pass": _fleet_pass,
     "fleet_entries": _fleet_entries,
     "fleet_bits": _fleet_bits,
@@ -1292,15 +1072,12 @@ class FleetTable:
         self._est_window: Optional[tuple] = None
         self._static_max = 0
         self._snapshot_gen = getattr(engine, "_snapshot_gen", 0)
-        # last observed entry total: tunes the fetched buffer well below the
-        # worst-case sum(replicas) bound (mean placed clusters per binding is
-        # far under max replicas); overflow falls back to the safe bound
+        # the last pass's changed-entry total: sizes the speculative phase
+        # B's entry buffer (a miss falls back to the exact fetch)
         self._last_total: Optional[int] = None  # None = no pass observed yet
-        self._e_cap_cur: Optional[int] = None
-        # delta-fetch base: device-resident [cap, k_out] per-row entry
-        # vectors from the last pass + the host mirror results read from.
-        # None = next pass reports every row changed and refills both.
-        self._resident_entries = None
+        # host mirror of every row's entry vector ([cap, k_res]): what
+        # results read from, folded from each pass's changed rows. None =
+        # next pass reports every row changed and refills it.
         self._host_entries: Optional[np.ndarray] = None
         self._k_res = 1  # running max entry width (grow-only)
         # mesh layout (canonical shape tuple) the residents were born on:
@@ -1323,9 +1100,8 @@ class FleetTable:
         self._last_dtotal: Optional[int] = None
         self._delta_live = False
         # (target, consecutive passes desired) for a frozen shrink — see
-        # the shrink-to-seen-only block in _solve_dense / _solve_legacy
+        # the cap tuning in _solve_dense
         self._shrink_desire: tuple = (None, 0)
-        self._e_shrink_desire: tuple = (None, 0)
         # O(1) batch reuse: (problems_list, compiled_list, rows) of the
         # last scheduled batch — the engine's batch-identity fast path
         # re-passes the SAME list objects, so identity means the row
@@ -1393,7 +1169,7 @@ class FleetTable:
         loops poll this alongside ``new_trace_last_pass`` — breaking warmup
         while a desire is pending parks the compile inside the timed
         window (an 18s dispatch stall on the 1M tier)."""
-        return bool(self._shrink_desire[1] or self._e_shrink_desire[1])
+        return bool(self._shrink_desire[1])
 
     def exhaustion_summary(self) -> str:
         """One line of WHY this table reports slots_exhausted — printed by
@@ -1491,23 +1267,22 @@ class FleetTable:
         self._all_rows_n = -1
         # row ids were remapped: the delta base is meaningless now, and so
         # is any result view still pointing at the old row layout
-        self._resident_entries = None
         self._reset_dense()
         self._reuse = None  # row ids remapped
         self._result_gen += 1
         return True
 
     def _reset_dense(self) -> None:
-        """Invalidate the dense-path residents (row remap / growth / path
-        switch). The next dense pass reallocates zeroed residents and a
-        zeroed host meta mirror — a consistent pair, so every row whose
-        current result is nonzero re-reports as changed and refills the
-        mirrors. The host ENTRY mirror must reset with them: after a row
-        remap its runs belong to other bindings, and the cell-delta fold
-        MERGES into existing runs (a full-row phase-B fold rewrites rows
-        wholesale and would mask the staleness, but a delta-carried pass
-        diffing against zeroed residents emits insert-only deltas — merged
-        into a stale run, stale sites would survive)."""
+        """Invalidate the residents (row remap / growth). The next pass
+        reallocates zeroed residents and a zeroed host meta mirror — a
+        consistent pair, so every row whose current result is nonzero
+        re-reports as changed and refills the mirrors. The host ENTRY
+        mirror must reset with them: after a row remap its runs belong to
+        other bindings, and the cell-delta fold MERGES into existing runs
+        (a full-row phase-B fold rewrites rows wholesale and would mask
+        the staleness, but a delta-carried pass diffing against zeroed
+        residents emits insert-only deltas — merged into a stale run,
+        stale sites would survive)."""
         self._res_dense = None
         self._res_meta = None
         self._host_meta = None
@@ -1516,6 +1291,15 @@ class FleetTable:
 
     def _grow(self, need: int) -> None:
         new_cap = max(self.chunk, _pow2(need))
+        # an input check, not a fallback: refused before anything is
+        # allocated at the new cap (the table keeps its rows and its cap)
+        c = self.engine.snapshot.num_clusters
+        if new_cap * c > DENSE_RESIDENT_MAX_BYTES:
+            raise FleetTableTooLarge(
+                f"fleet table of {new_cap} rows x {c} clusters needs a "
+                f"dense resident of {new_cap * c} bytes; the bound is "
+                f"{DENSE_RESIDENT_MAX_BYTES} (DENSE_RESIDENT_MAX_BYTES)"
+            )
         st = {
             "cp_idx": np.zeros(new_cap, np.int32),
             "gvk_idx": np.zeros(new_cap, np.int32),
@@ -2088,7 +1872,7 @@ class FleetTable:
         """Resident device bytes by ledger kind — the EXACT ``nbytes`` of
         the arrays this table holds right now (ISSUE 12 b): the packed
         state grid, the interned slot tables, the donated result
-        residents (legacy entry vectors or dense pair), and the cached
+        residents (the dense pair), and the cached
         all-rows index. The accounting the 1M-on-16GB-HBM question needs
         before anyone puts the resident grid on a real part."""
 
@@ -2102,11 +1886,7 @@ class FleetTable:
         return {
             "packed_grid": nb(self._dev_state),
             "slot_tables": nb(self._dev_tables),
-            "donated_residents": (
-                nb(self._resident_entries)
-                + nb(self._res_dense)
-                + nb(self._res_meta)
-            ),
+            "donated_residents": nb(self._res_dense) + nb(self._res_meta),
             "rows_index": nb(self._all_rows_dev),
         }
 
@@ -2114,8 +1894,7 @@ class FleetTable:
         """Platform of the buffers the ledger counts (PR 9's honesty
         rule carried to the gauge: forced-host bytes must never read as
         HBM — the label says whose memory it is)."""
-        for x in (self._dev_state, self._dev_tables, self._res_dense,
-                  self._resident_entries):
+        for x in (self._dev_state, self._dev_tables, self._res_dense):
             arr = x[0] if isinstance(x, tuple) and x else x
             try:
                 if arr is not None:
@@ -2357,9 +2136,6 @@ class FleetTable:
                     *_tables, _rows, *_state, chunk=_chunk,
                     n_chunks=_n_chunks,
                 )
-        safe = int(
-            np.minimum(np.where(is_dup, 0, reps_sel), k_out).sum()
-        )
         # table-validated mesh (see __init__): the row axis shards over
         # "b" on every pass — batches are padded to the pow2 chunk, so
         # the mesh-divisible bucket holds by construction. The cluster
@@ -2379,25 +2155,21 @@ class FleetTable:
                 and c % c_sz == 0
             )
         mesh_el = _mesh_shape(mesh)
-        shared = dict(
+        # host->device transfer of THIS pass so far (state scatter/upload
+        # + row indices): the multichip bench's steady-pass bound — a
+        # steady storm must ship changed rows' bytes, never the grid
+        tmr["upload_mb"] = self._last_upload_bytes / 1e6
+        res = self._solve_dense(
             problems=problems, rows_np=rows_np, rows_dev=rows_dev, tmr=tmr,
             n=n, n_pad=n_pad, eff_chunk=eff_chunk, n_chunks=n_chunks,
             is_all=is_all, c=c, k_out=k_out, wide=wide, fast=fast,
-            has_agg=has_agg, bits_src=bits_src, is_dup=is_dup, safe=safe,
+            has_agg=has_agg, bits_src=bits_src, is_dup=is_dup,
             mesh=mesh, mesh_el=mesh_el, shard_c=shard_c,
             byte_wire=c <= 0xFFFF,
             # 21-bit entry packing: 2.625 B/entry when the site id fits
             # 13 bits
             pack21=c <= (1 << 13), t0=t0,
         )
-        # host->device transfer of THIS pass so far (state scatter/upload
-        # + row indices): the multichip bench's steady-pass bound — a
-        # steady storm must ship changed rows' bytes, never the grid
-        tmr["upload_mb"] = self._last_upload_bytes / 1e6
-        if self.cap * c <= DENSE_RESIDENT_MAX_BYTES:
-            res = self._solve_dense(**shared)
-        else:
-            res = self._solve_legacy(**shared)
         # this pass dispatched every reuse row, so the mirrors now cover
         # them at the current epoch — the delta-eligibility fence
         self._reuse_epoch = self._mirror_epoch
@@ -2407,9 +2179,8 @@ class FleetTable:
     #: a few-thousand-row delta must never shrink the caps the next full
     #: storm dispatches at (every distinct cap pair is an XLA trace)
     _TUNE_ATTRS = (
-        "_last_total", "_e_cap_cur", "_e_shrink_desire", "_m_cap_cur",
-        "_shrink_desire", "_d_cap_cur", "_last_changed", "_last_dtotal",
-        "_delta_live",
+        "_last_total", "_m_cap_cur", "_shrink_desire", "_d_cap_cur",
+        "_last_changed", "_last_dtotal", "_delta_live",
     )
 
     def _schedule_delta(self, problems, compiled, delta):
@@ -2464,13 +2235,10 @@ class FleetTable:
         cap_before = self.cap
         tune = tuple(getattr(self, a) for a in self._TUNE_ATTRS)
         # virgin tuning state for the sub dispatch: demand-sized caps
-        # (the safe bounds for a sub batch — no overflow rerun possible)
         # keyed per pow2 sub-size bucket, so a settle train of equal-size
         # deltas converges to one trace instead of thrashing the tuned
         # full-pass caps
         self._last_total = None
-        self._e_cap_cur = None
-        self._e_shrink_desire = (None, 0)
         self._m_cap_cur = None
         self._shrink_desire = (None, 0)
         self._d_cap_cur = None
@@ -2590,217 +2358,6 @@ class FleetTable:
             shape, dtype, device=NamedSharding(mesh, P(*axes))
         )
 
-    def _upload_resident(self, host, mesh, *, c_axis=False):
-        """Host mirror -> device resident on the same layout rule as
-        ``_alloc_resident`` (the donation-overflow re-upload path)."""
-        arr = jnp.asarray(host)
-        if mesh is None:
-            return arr
-        axes = ["b"] + [None] * (arr.ndim - 1)
-        if c_axis and arr.ndim > 1:
-            axes[1] = "c"
-        return jax.device_put(arr, NamedSharding(mesh, P(*axes)))
-
-    def _solve_legacy(
-        self, *, problems, rows_np, rows_dev, tmr, n, n_pad, eff_chunk,
-        n_chunks, is_all, c, k_out, wide, fast, has_agg, bits_src, is_dup,
-        safe, mesh, mesh_el, shard_c, byte_wire, pack21, t0,
-    ) -> "_FleetResultList":
-        """Single-dispatch entry-resident solve — the path for tables whose
-        dense mirror would exceed the HBM budget (multi-million-row
-        fleets). Everything ships per pass: full meta + tuned entry
-        stream."""
-        cap_round = _cap_round
-        # delta base: device-resident per-row entry vectors + the matching
-        # host mirror, k_res wide (grow-only running max of k_out so a
-        # straggler batch with smaller replicas doesn't wipe the base).
-        # Table growth, a k_res increase, or a mesh-layout change resets
-        # both — the next pass reports every row changed and refills them.
-        k_res = max(self._k_res, k_out)
-        if (
-            self._resident_entries is None
-            or self._resident_entries.shape != (self.cap, k_res)
-            or self._resident_mesh != mesh_el
-        ):
-            self._resident_entries = self._alloc_resident(
-                (self.cap, k_res), jnp.int32, mesh
-            )
-            self._host_entries = np.zeros((self.cap, k_res), np.int32)
-            self._resident_mesh = mesh_el
-            self._mirror_epoch += 1
-        if self._host_meta is None or self._host_meta.shape[0] != self.cap:
-            # legacy meta mirror: the wire ships full meta every pass, so
-            # the mirror is pure bookkeeping here — but it is what lets a
-            # delta pass replay untouched rows' n_placed/unsched/has_cand
-            # without re-dispatching them
-            self._host_meta = np.zeros(self.cap, np.int32)
-            self._mirror_epoch += 1
-        self._k_res = k_res
-
-        # fetched bytes scale with e_cap, so tune it to ~1.25x the last
-        # observed total; the safe bound can never overflow and is the
-        # first-pass / fallback trace. Hysteresis: grow immediately, shrink
-        # only after two consecutive lower demands — every distinct e_cap is
-        # a fresh XLA trace, and a demand oscillating across a quantum
-        # boundary was recompiling the solve once per storm wave
-        # _last_total tracks the last pass's CHANGED-entry total — under
-        # delta fetch a steady storm's demand is ~zero, so the tuned cap
-        # (and with it the fetched buffer) collapses to the floor quantum;
-        # a churn burst overflows once, reruns at the safe bound, and the
-        # cap follows it back up
-        def l_key(cap: int) -> tuple:
-            # mesh_el (the canonical mesh SHAPE, not a bool): partitioned
-            # executables are distinct per mesh shape, and the manifest
-            # key must never let a mesh=1 record seed a mesh=8 boot
-            return (
-                "L", self.cap, c, self._dev_tables[0].shape, eff_chunk,
-                n_chunks, k_out, k_res, cap, wide, fast, has_agg, is_all,
-                mesh_el, shard_c, pack21 and byte_wire,
-            )
-
-        prev_e = self._e_cap_cur
-        needed = cap_round(safe)
-        if self._last_total is not None and self._last_total * 5 // 4 < safe:
-            needed = min(needed, cap_round(self._last_total * 5 // 4))
-        # demand-based grow-immediately / shrink-on-sustained-desire (same
-        # policy as the dense pair: 2 passes to switch to an already-
-        # compiled trace, SHRINK_SUSTAIN to compile a smaller one)
-        if prev_e is None or needed >= prev_e:
-            e_cap = needed
-            self._e_shrink_desire = (None, 0)
-        else:
-            e_cap = prev_e
-            tgt, cnt = self._e_shrink_desire
-            cnt = cnt + 1 if tgt == needed else 1
-            self._e_shrink_desire = (needed, cnt)
-            sustain = (
-                2 if l_key(needed) in self._seen_traces else SHRINK_SUSTAIN
-            )
-            if cnt >= sustain:
-                e_cap = needed
-                self._e_shrink_desire = (None, 0)
-        self._e_cap_cur = e_cap
-
-        def solve(rows_slice, cap, resident):
-            if self._mark_trace(*l_key(cap)):
-                self._record_trace(
-                    "fleet_solve", l_key(cap),
-                    (*self._dev_tables, rows_slice, *self._dev_state,
-                     resident),
-                    chunk=eff_chunk, n_chunks=n_chunks, k_out=k_out,
-                    k_res=k_res, e_cap=cap, wide=wide, fast=fast,
-                    has_aggregated=has_agg, all_rows=is_all, mesh=mesh,
-                    shard_c=shard_c, pack21=pack21 and byte_wire,
-                )
-            return _fleet_solve(
-                *self._dev_tables,
-                rows_slice,
-                *self._dev_state,
-                resident,
-                chunk=eff_chunk,
-                n_chunks=n_chunks,
-                k_out=k_out,
-                k_res=k_res,
-                e_cap=cap,
-                wide=wide,
-                fast=fast,
-                has_aggregated=has_agg,
-                all_rows=is_all,
-                mesh=mesh,
-                shard_c=shard_c,
-                pack21=pack21 and byte_wire,
-            )
-
-        def decode(arr, cap):
-            """(total, meta int32[n_pad], stream int32[*])"""
-            if byte_wire:
-                from .. import native
-
-                total = native.le32(arr)
-                meta = native.decode2(arr[4 : 4 + 2 * n_pad])
-                tail = arr[4 + 2 * n_pad :]
-                stream = (
-                    native.decode21(tail, cap)
-                    if pack21
-                    else native.decode3(tail)
-                )
-                return total, meta, stream
-            return int(arr[0]), arr[1 : 1 + n_pad], arr[1 + n_pad :]
-
-        t0 = self._phase(tmr, "prep", t0)
-        # the resident base is DONATED into the dispatch: detach the
-        # attribute first so a pass that dies mid-solve leaves no
-        # deleted-buffer reference behind (the next pass re-seeds the
-        # delta base instead of crashing on a consumed array)
-        res_in, self._resident_entries = self._resident_entries, None
-        flat, resident = solve(rows_dev, e_cap, res_in)
-        t0 = self._phase(tmr, "dispatch", t0)
-        # device fence at the span boundary: block_until_ready splits the
-        # on-device execute (plus compile, when this pass minted a fresh
-        # trace) from the host-side transfer+decode that follows — the
-        # fetch would block on the same event anyway, so the fence costs
-        # nothing and buys the device/host attribution
-        flat.block_until_ready()
-        t0 = self._phase(tmr, "device", t0)
-        raw = np.asarray(flat)
-        fetched_bytes = raw.nbytes
-        total, meta, stream = decode(raw, e_cap)
-        if total > e_cap:
-            # overflow: rerun at the safe bound. The first dispatch
-            # DONATED the pre-pass resident, so the rerun diffs against a
-            # re-upload of the host mirror — identical content by
-            # construction (the fold below has not run yet). One extra
-            # upload on the rare overflow pass buys alias-in-place on
-            # every steady pass.
-            res_in = self._upload_resident(self._host_entries, mesh)
-            tmr["upload_mb"] = (
-                tmr.get("upload_mb", 0.0) + self._host_entries.nbytes / 1e6
-            )
-            flat, resident = solve(rows_dev, cap_round(safe), res_in)
-            raw = np.asarray(flat)
-            fetched_bytes += raw.nbytes
-            total, meta, stream = decode(raw, cap_round(safe))
-        assert total <= len(stream), (total, e_cap)
-        self._resident_entries = resident
-        t0 = self._phase(tmr, "fetch", t0)
-        tmr["fetch_mb"] = fetched_bytes / 1e6
-        self._last_total = total
-        n_placed = (meta & 0xFF).astype(np.int64)
-        unsched = (meta >> 8) & 1
-        has_cand = (meta >> 9) & 1
-        changed = ((meta >> 10) & 1).astype(bool)
-        # meta mirror covers every dispatched row (state bits only — the
-        # changed flag is a per-pass wire artifact, not row state)
-        self._host_meta[rows_np] = (
-            np.asarray(meta[:n]) & 0x3FF
-        ).astype(np.int32)
-        # fold the changed rows' entry runs into the persistent host mirror
-        ch_pos = np.flatnonzero(changed[:n])
-        if len(ch_pos):
-            from .. import native
-
-            native.fold_entries(
-                self._host_entries, rows_np[ch_pos], n_placed[ch_pos],
-                np.asarray(stream, np.int32),
-            )
-        tmr["changed_rows"] = float(len(ch_pos))
-        self._result_gen += 1
-
-        names = self.engine.snapshot.names
-        batches = [
-            _FleetBatch(
-                names, self._host_entries, rows_np, bits_src,
-                self, self._result_gen,
-            )
-        ]
-        terms = [self._terms[r] for r in rows_np]
-        self._phase(tmr, "post", t0)
-        self.last_breakdown = tmr
-        return _FleetResultList(
-            problems, terms, batches, n_pad, n_placed, unsched,
-            has_cand, is_dup,
-        )
-
     def _e_key(
         self, chunk: int, n_chunks: int, k_out: int, e_cap: int,
         byte_wire: bool, pack21: bool,
@@ -2887,7 +2444,7 @@ class FleetTable:
     def _solve_dense(
         self, *, problems, rows_np, rows_dev, tmr, n, n_pad, eff_chunk,
         n_chunks, is_all, c, k_out, wide, fast, has_agg, bits_src, is_dup,
-        safe, mesh, mesh_el, shard_c, byte_wire, pack21, t0,
+        mesh, mesh_el, shard_c, byte_wire, pack21, t0,
     ) -> "_FleetResultList":
         """Two-phase solve: _fleet_pass (divide + dense diff, ~13 KB wire
         on a steady pass) and, only when rows changed, _fleet_entries over
@@ -2921,16 +2478,17 @@ class FleetTable:
             )
         self._k_res = k_res
 
-        # changed-meta buffer: tuned like the legacy e_cap but overflow
-        # costs one cheap _gather_meta round-trip, not a solve rerun
+        # changed-meta buffer, tuned to the last pass's changed-row count;
+        # an overflow costs one cheap _gather_meta round-trip
         def m_round(v: int) -> int:
             v = max(v, 1)
             q = -(-v // M_ROUND) * M_ROUND if v > 4096 else 4096
             return min(q, n_pad)
 
         def a_key(m: int, d: int) -> tuple:
-            # mesh_el: canonical mesh shape (see l_key) — partitioned
-            # executables and their manifest records are per-shape
+            # mesh_el (the canonical mesh SHAPE, not a bool): partitioned
+            # executables are distinct per mesh shape, and the manifest
+            # key must never let a mesh=1 record seed a mesh=8 boot
             return (
                 "A", self.cap, c, self._dev_tables[0].shape, eff_chunk,
                 n_chunks, wide, fast, has_agg, is_all, m, d,
@@ -3003,7 +2561,6 @@ class FleetTable:
         self._m_cap_cur = m_cap
         self._d_cap_cur = d_cap if d_on else None
 
-        cap_round = _cap_round
         t0 = self._phase(tmr, "prep", t0)
         if self._mark_trace(*a_key(m_cap, d_cap)):
             self._record_trace(
@@ -3061,7 +2618,7 @@ class FleetTable:
             self._last_changed and self._last_total
             and not self._delta_live and not delta_expected
         ):
-            spec_cap = cap_round(self._last_total * 9 // 8)
+            spec_cap = _cap_round(self._last_total * 9 // 8)
             b_chunk = min(eff_chunk, m_cap)
             self._mark_entries_trace(
                 rowbuf, chunk=b_chunk, n_chunks=m_cap // b_chunk,
@@ -3080,8 +2637,11 @@ class FleetTable:
                 mesh=self._entries_mesh,
             )
         t0 = self._phase(tmr, "dispatch", t0)
-        # device fence (see _solve_legacy): splits phase A's on-device
-        # execute (+compile on a fresh trace) from the wire/decode window.
+        # device fence at the span boundary: block_until_ready splits
+        # phase A's on-device execute (+compile on a fresh trace) from the
+        # wire/decode window — the fetch would block on the same event
+        # anyway, so the fence costs nothing and buys the device/host
+        # attribution.
         # The speculative B keeps running behind it — the fence waits on
         # A's output only, so the B-overlaps-A's-decode flow is preserved.
         flat.block_until_ready()

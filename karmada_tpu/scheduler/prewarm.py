@@ -85,7 +85,6 @@ _SCHEMA_VERSION = 1
 #: ``row_coupled`` attribute, checked for agreement (and proven against
 #: the traced jaxprs) by graftlint IR006.
 _KERNELS = {
-    "fleet_solve": True,
     "fleet_pass": True,
     "fleet_entries": True,
     "fleet_bits": False,
@@ -291,12 +290,11 @@ def _cap_prev(cap: int) -> Optional[int]:
 
 
 #: kernel -> {static name: index of that cap in the record's ledger key}
-#: (fleet.l_key / _e_key / a_key layouts). Shrink-bucket derivation
+#: (fleet._e_key / a_key layouts). Shrink-bucket derivation
 #: substitutes the cap element of an OBSERVED key; the sanity check in
 #: expand_records (key[idx] == statics[cap]) keeps a layout drift from
 #: ever seeding a wrong key.
 _KEY_CAP_INDEX = {
-    "fleet_solve": {"e_cap": 8},
     "fleet_entries": {"e_cap": 6},
     "fleet_pass": {"m_cap": 10, "d_cap": 11},
 }
@@ -347,7 +345,7 @@ def expand_records(records: list[dict]) -> list[dict]:
         statics = dict(r["statics"])
         grown: list[dict] = []
         shrunk: list[dict] = []
-        if r["kernel"] in ("fleet_solve", "fleet_entries"):
+        if r["kernel"] == "fleet_entries":
             e_cap = statics.get("e_cap")
             if isinstance(e_cap, int):
                 grown.append({**statics, "e_cap": _cap_round(e_cap + 1)})

@@ -69,12 +69,6 @@ ENV_FLAGS: dict[str, EnvFlag] = {
             "rebuilt table's upcoming shapes off the serving path.",
         ),
         EnvFlag(
-            "KARMADA_TPU_DENSE_BUDGET", str(6 << 30),
-            "HBM byte budget for the dense-resident fleet table; tables "
-            "whose dense mirror exceeds it fall back to the "
-            "entry-resident legacy path. Raise on parts with more HBM.",
-        ),
-        EnvFlag(
             "KARMADA_TPU_NO_NATIVE", "0",
             "Set to 1 to skip building/loading the ctypes native decode "
             "helpers and always use the numpy fallback path.",

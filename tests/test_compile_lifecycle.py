@@ -100,6 +100,31 @@ class TestTraceManifest:
         stats = prewarm.replay(m)
         assert stats["specs"] == 0 and stats["failed"] == 0
 
+    def test_record_of_a_removed_kernel_dropped_on_load(self, tmp_path):
+        """A manifest written by a build that still had the
+        entry-resident layout holds ``fleet_solve`` records. The kernel is
+        gone: the loader drops them as it drops any unknown kernel, and
+        replay compiles the rest without reporting a failure."""
+        path = tmp_path / "manifest.json"
+        seed_manifest(path)
+        data = json.loads(path.read_text())
+        n_live = len(data["records"])
+        data["records"].insert(0, {
+            "kernel": "fleet_solve",
+            "key": ["L", 4096, C, [16, 14], 512, 1, 64, 64, 4096, True,
+                    None, False, True, None, False, True],
+            "in_shapes": [[[4096, 64], "int32"]],
+            "statics": {"chunk": 512, "n_chunks": 1, "k_out": 64,
+                        "k_res": 64, "e_cap": 4096},
+        })
+        path.write_text(json.dumps(data))
+        m = prewarm.TraceManifest(str(path))
+        assert len(m.records) == n_live
+        assert all(r["kernel"] != "fleet_solve" for r in m.records)
+        stats = prewarm.replay(m, expand=False)
+        assert stats["failed"] == 0 and stats["compiled"] == n_live
+        assert all(k[0] != "L" for k in m.warmed_keys())
+
     def test_ir_retrace_round_trip(self, tmp_path):
         """A recorded manifest entry re-traced by the graftlint IR tier
         yields a byte-identical shape/static signature across a
@@ -131,13 +156,13 @@ class TestTraceManifest:
     def test_expand_records_next_bucket(self):
         from karmada_tpu.scheduler.fleet import M_ROUND, _cap_round
 
-        solve = {
-            "kernel": "fleet_solve",
-            "key": ["L", 1024],
+        entries = {
+            "kernel": "fleet_entries",
+            "key": ["E", 1024],
             "in_shapes": [[[64, 4], "int64"]],
             "statics": {"e_cap": 1024, "chunk": 256},
         }
-        grown = prewarm.expand_records([solve])
+        grown = prewarm.expand_records([entries])
         assert len(grown) == 1
         # expanded specs are honest: no ledger key (never dispatched),
         # and the e_cap landed on the NEXT quantized bucket
@@ -212,8 +237,8 @@ class TestRestoreContract:
         m = prewarm.TraceManifest(str(path))
         good_keys = m.keys()
         bogus = {
-            "kernel": "fleet_solve",
-            "key": ["L", "bogus", 999],
+            "kernel": "fleet_entries",
+            "key": ["E", "bogus", 999],
             "in_shapes": [[[3, 3], "int64"]],
             "statics": {"e_cap": -1, "chunk": 0},
         }
@@ -222,7 +247,7 @@ class TestRestoreContract:
         stats = prewarm.replay(m, expand=False)
         assert stats["failed"] >= 1 and stats["compiled"] >= 1
         warmed = m.warmed_keys()
-        assert ("L", "bogus", 999) not in warmed
+        assert ("E", "bogus", 999) not in warmed
         assert warmed == good_keys
 
     def test_explicit_opt_out_beats_env(self, tmp_path, monkeypatch):
@@ -295,17 +320,22 @@ class TestRestoreContract:
         self, tmp_path, monkeypatch
     ):
         """The BENCH_r05 mid-settle compile, at toy scale: a manifest that
-        only observed CHURN passes misses the shrink-bucket solve family
-        (a settle train's entry demand collapses to the cap floor, and
-        the sustained-shrink retune mints a fresh trace mid-settle). The
+        only observed CHURN passes at a table shape misses the
+        shrink-bucket solve family (a settle train's cell-delta demand
+        collapses to the floor, and the sustained-shrink retune of
+        ``fleet_pass``'s ``d_cap`` mints a fresh trace mid-settle). The
         shrink expansion must cover it: an engine restored from the
-        churn-only manifest reports new_trace=False across a FULL settle
-        train. Legacy path (the tier that regressed), full passes only
-        (the delta path freezes cap tuning, so shrink dynamics live on
-        the full-pass side)."""
+        churn-only manifest lives the recorded life over again AND a full
+        settle train beyond it with no fresh solve trace. The fleet
+        reaches its 300 rows only in the storm, so the recorded cold
+        start (256 rows) ran the floor bucket at another shape. Full
+        passes only (the delta path freezes cap tuning, so shrink
+        dynamics live on the full-pass side); the delta quanta are cut to
+        toy size so 300 rows x 50 clusters move them."""
         import karmada_tpu.scheduler.fleet as fleet_mod
 
-        monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 0)
+        monkeypatch.setattr(fleet_mod, "D_FLOOR", 64)
+        monkeypatch.setattr(fleet_mod, "D_ROUND", 256)
         monkeypatch.setenv("KARMADA_TPU_DELTA_SOLVE", "0")
 
         def churned(problems, seed):
@@ -335,46 +365,60 @@ class TestRestoreContract:
                 )
             return out
 
-        path = tmp_path / "churn.json"
-        snap = ClusterSnapshot(synthetic_fleet(C, seed=7))
-        eng = TensorScheduler(snap, trace_manifest=str(path))
-        problems = toy_problems()
-        eng.schedule(problems)
-        for s in range(1, 4):  # the churn storm: caps grow and stay up
-            problems = churned(problems, s)
-            eng.schedule(problems)
-        # one light pass: the small-scatter upload shapes are part of any
-        # real churn history; what the manifest must NOT have observed is
-        # the settle train's shrink retune
-        problems = settled(problems, 5)
-        eng.schedule(problems)
-        churn_records = path.read_bytes()
-        settle_start = problems
+        def recorded_life():
+            """Cold start at 256 rows, then the churn storm over the
+            grown fleet (caps grow and stay up), then one light pass:
+            the small-scatter upload shapes are part of any real churn
+            history; what the manifest must NOT have observed is the
+            settle train's shrink retune."""
+            problems = toy_problems()
+            yield problems[:256]
+            for s in range(1, 4):
+                problems = churned(problems, s)
+                yield problems
+            yield settled(problems, 5)
+
+        def settle_train(problems):
+            for s in range(10, 20):
+                problems = settled(problems, s)
+                yield problems
+
         # the manifest-persisted solve families (fleet.py ledger-key
         # prefixes): the multi-second compiles the warmup contract
         # covers. Tiny ledger-only utility kernels (the "S" row scatter)
         # stay out of the manifest by design — their first-dispatch
         # compiles are sub-millisecond and allowed.
-        solve_fams = ("L", "A", "E", "B")
+        solve_fams = ("A", "E", "B")
 
-        def fresh_solve_keys(fleet, before):
+        def fresh_solve_keys(eng, problems):
+            # a table not built yet starts from what the replay warmed
+            before = (
+                set(eng._fleet._seen_traces) if eng._fleet is not None
+                else eng.trace_manifest.warmed_keys()
+            )
+            eng.schedule(problems)
             return [
-                k for k in fleet._seen_traces - before
+                k for k in eng._fleet._seen_traces - before
                 if k[0] in solve_fams
             ]
 
+        path = tmp_path / "churn.json"
+        snap = ClusterSnapshot(synthetic_fleet(C, seed=7))
+        eng = TensorScheduler(snap, trace_manifest=str(path))
+        for problems in recorded_life():
+            eng.schedule(problems)
+        churn_records = path.read_bytes()
+        settle_start = problems
         # the repro: keep settling THIS engine (light churn, demand near
         # zero) — the cap shrink retunes mid-train and mints a fresh
         # SOLVE trace the churn records never covered
         saw_fresh = []
-        for s in range(10, 20):
-            problems = settled(problems, s)
-            before = set(eng._fleet._seen_traces)
-            eng.schedule(problems)
-            saw_fresh += fresh_solve_keys(eng._fleet, before)
-        assert saw_fresh, (
-            "settle train minted no fresh solve trace — shrink dynamics "
-            "moved; re-point this regression at the new retune path"
+        for problems in settle_train(settle_start):
+            saw_fresh += fresh_solve_keys(eng, problems)
+        assert [k[0] for k in saw_fresh] == ["A"], (
+            "the settle train did not mint exactly one fresh fleet_pass "
+            "trace — shrink dynamics moved; re-point this regression at "
+            f"the new retune path: {saw_fresh}"
         )
         # restore from the CHURN-ONLY record set: shrink expansion must
         # prepay (and honestly seed) the settle train's buckets
@@ -383,15 +427,16 @@ class TestRestoreContract:
         stats = prewarm.warmup(str(path2))
         assert stats["failed"] == 0 and stats["compiled"] > 0
         eng2 = TensorScheduler(snap, trace_manifest=str(path2))
-        problems = settle_start
-        eng2.schedule(problems)
-        assert eng2.last_pass_new_trace is False
-        for s in range(10, 20):
-            problems = settled(problems, s)
-            before = set(eng2._fleet._seen_traces)
-            eng2.schedule(problems)
-            assert not fresh_solve_keys(eng2._fleet, before), (
-                f"settle pass {s - 9} compiled a solve trace on the "
+        for i, problems in enumerate(recorded_life()):
+            assert not fresh_solve_keys(eng2, problems), (
+                f"recorded pass {i} compiled a solve trace on the "
+                "restored engine"
+            )
+            if i == 0:
+                assert eng2.last_pass_new_trace is False
+        for i, problems in enumerate(settle_train(settle_start)):
+            assert not fresh_solve_keys(eng2, problems), (
+                f"settle pass {i + 1} compiled a solve trace on the "
                 "restored engine"
             )
 

@@ -29,7 +29,6 @@ import os
 import numpy as np
 import pytest
 
-import karmada_tpu.scheduler.fleet as fleet_mod
 from karmada_tpu import cli as _cli
 from karmada_tpu.api import (
     PropagationPolicy,
@@ -191,18 +190,15 @@ class TestDeltaVsFullIdentity:
         if devices > 1:
             assert delta_eng._fleet._mesh is mesh
 
-    @pytest.mark.parametrize("legacy", (False, True), ids=("dense", "legacy"))
-    def test_identity_on_both_resident_paths(self, snap, legacy, monkeypatch):
-        """Single-device, both resident layouts: the legacy
-        entry-resident path maintains the same host mirrors the replay
-        reads, so the delta contract is layout-independent."""
-        if legacy:
-            monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 0)
+    def test_identity_over_three_churn_rounds(self, snap):
+        """Single-device, a smaller batch and three rounds: the replay
+        reads the host mirrors the dense pass maintains, round after
+        round."""
         delta_eng = TensorScheduler(snap, trace_manifest="")
         full_eng = TensorScheduler(snap, trace_manifest="")
         delta_eng.fleet_threshold = 1
         full_eng.fleet_threshold = 1
-        problems = build_problems(snap, 300, prefix=f"r{int(legacy)}_")
+        problems = build_problems(snap, 300, prefix="r0_")
         delta_eng.schedule(problems)
         full_solve(full_eng, problems)
         rng = np.random.default_rng(7)
@@ -210,7 +206,7 @@ class TestDeltaVsFullIdentity:
             problems, idx = churned(problems, rng, 9)
             ref = decoded(full_solve(full_eng, problems))
             got = decoded(delta_eng.schedule(problems))
-            assert got == ref, f"legacy={legacy} round={rnd}"
+            assert got == ref, f"round={rnd}"
             assert dirty_dispatched(delta_eng) == len(idx)
 
 

@@ -317,17 +317,13 @@ def test_fleet_lazy_results_expose_schedule_result_surface():
     assert len(res[1].feasible) > 0
 
 
-@pytest.mark.parametrize("path", ["dense", "legacy"])
-def test_delta_fetch_sequence_fuzz(path, monkeypatch):
+def test_delta_fetch_sequence_fuzz():
     """Multi-pass mutation fuzz for the delta-fetch machinery: random
     per-pass mutations (replica bumps, prev rewrites, fresh flips, NEW
     bindings, availability-only snapshot swaps, partial batches) must keep
     the fleet path identical to a fresh host-path run on EVERY pass — the
     resident entry base / host mirror / changed-bit protocol can never
-    serve a stale placement. Runs against BOTH solve paths (the legacy
-    entry-resident path serves tables past the dense HBM budget)."""
-    if path == "legacy":
-        monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 0)
+    serve a stale placement."""
     rng = np.random.default_rng(123)
     clusters = synthetic_fleet(40, seed=21)
     snap = ClusterSnapshot(clusters)

@@ -180,7 +180,7 @@ def test_entries_for_changed_follows_spec_deps():
     # feeds both); the dispense/divide/masks kernels never read it
     assert {"quota_admit", "quota_cluster_caps"} <= set(scoped)
     assert "preempt_select" in scoped
-    assert "fleet_solve" in scoped
+    assert "fleet_pass" in scoped
     assert "divide_replicas" not in scoped
     assert "masks.contains_all" not in scoped
 

@@ -8,6 +8,10 @@ multi-stage lifecycle (churn/growth/compaction at 4k rows) lives in
 ``__graft_entry__.dryrun_multichip`` and ``bench.py --multichip``.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -160,20 +164,15 @@ class TestMeshConstruction:
 
 
 class TestShardedPlacementIdentity:
-    """Sharded-vs-single identity across the bucket grid (both resident
-    paths), including B not divisible by the device count and batches
+    """Sharded-vs-single identity across the bucket grid, including B not
+    divisible by the device count and batches
     small enough that padding dominates whole chunks."""
 
     # (rows, note) — 512 aligns with the 256-chunk; 300/31 leave padding
     # rows in the tail chunk (31 pads a whole sub-chunk at eff_chunk 256)
     BATCHES = ((512, "aligned"), (300, "padded-tail"), (31, "tiny"))
 
-    @pytest.mark.parametrize("legacy", (False, True), ids=("dense", "legacy"))
-    def test_mesh2_identity_across_batch_shapes(
-        self, snap, legacy, monkeypatch
-    ):
-        if legacy:
-            monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 0)
+    def test_mesh2_identity_across_batch_shapes(self, snap):
         mesh = scheduling_mesh(2)
         for n, note in self.BATCHES:
             problems = build_problems(snap, n, prefix=f"s{n}_")
@@ -277,34 +276,24 @@ class TestDonatedResidents:
     """The persistent packed state is donated into the next solve: the
     pre-pass buffers are CONSUMED (aliased in place), not copied."""
 
-    def test_dense_residents_donated(self, snap):
+    @pytest.mark.parametrize("meshed", (False, True), ids=("single", "mesh2"))
+    def test_dense_residents_donated(self, snap, meshed):
         problems = build_problems(snap, 512, with_dup=False)
         eng = TensorScheduler(
-            snap, mesh=scheduling_mesh(2), trace_manifest=""
+            snap,
+            mesh=scheduling_mesh(2) if meshed else False,
+            trace_manifest="",
         )
         eng.schedule(problems)
         old_dense = eng._fleet._res_dense
         old_meta = eng._fleet._res_meta
         eng.schedule(problems)
         assert old_dense.is_deleted() and old_meta.is_deleted()
-        # and the new residents keep the row-sharded layout (the alias
-        # only holds when in/out shardings agree)
-        spec = eng._fleet._res_dense.sharding.spec
-        assert tuple(spec)[:1] == ("b",)
-
-    @pytest.mark.parametrize("meshed", (False, True), ids=("single", "mesh2"))
-    def test_legacy_resident_donated(self, snap, monkeypatch, meshed):
-        monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 0)
-        eng = TensorScheduler(
-            snap,
-            mesh=scheduling_mesh(2) if meshed else False,
-            trace_manifest="",
-        )
-        problems = build_problems(snap, 512, with_dup=False)
-        eng.schedule(problems)
-        old = eng._fleet._resident_entries
-        eng.schedule(problems)
-        assert old.is_deleted()
+        if meshed:
+            # and the new residents keep the row-sharded layout (the
+            # alias only holds when in/out shardings agree)
+            spec = eng._fleet._res_dense.sharding.spec
+            assert tuple(spec)[:1] == ("b",)
 
     def test_steady_upload_bounded(self, snap):
         # a steady storm must not re-upload the packed grid: after the
@@ -318,6 +307,71 @@ class TestDonatedResidents:
         steady = eng._fleet.last_breakdown["upload_mb"]
         assert first > 0.1  # the initial packed-state upload
         assert steady == 0.0  # all-rows index cached on device
+
+
+class TestDenseResidentBound:
+    """The dense resident is the one layout: a table whose cap x C would
+    pass DENSE_RESIDENT_MAX_BYTES is refused where it grows, before
+    anything is allocated for it — an input check, not a fallback."""
+
+    @pytest.mark.parametrize("meshed", (False, True), ids=("single", "mesh2"))
+    def test_table_past_the_bound_raises_and_allocates_nothing(
+        self, snap, monkeypatch, meshed
+    ):
+        # 512 rows x 48 clusters sits exactly on the bound and is
+        # admitted; the 513th row asks for a cap of 1024
+        monkeypatch.setattr(fleet_mod, "DENSE_RESIDENT_MAX_BYTES", 512 * C)
+        eng = TensorScheduler(
+            snap,
+            chunk_size=256,
+            mesh=scheduling_mesh(2) if meshed else False,
+            trace_manifest="",
+        )
+        too_many = build_problems(snap, 600, with_dup=False, prefix="big_")
+        with pytest.raises(fleet_mod.FleetTableTooLarge) as exc:
+            eng.schedule(too_many)
+        msg = str(exc.value)
+        for fact in ("1024 rows", f"{C} clusters", str(1024 * C),
+                     str(512 * C)):
+            assert fact in msg, (fact, msg)
+        table = eng._fleet
+        assert table.cap == 512 and table.n_rows == 512
+        assert table._st["cp_idx"].shape == (512,)
+        assert table._dev_state is None and table._dev_tables is None
+        assert table._res_dense is None and table._res_meta is None
+        assert not any(table.device_bytes().values())
+        # no side path took the batch, and the engine still serves a
+        # table that fits, with the host path's placements
+        fits = too_many[:400]
+        got = decoded(eng.schedule(fits))
+        assert eng._fleet is table and table._res_dense.shape == (512, C)
+        if meshed:
+            assert tuple(table._res_dense.sharding.spec)[:1] == ("b",)
+        host = TensorScheduler(snap, trace_manifest="")
+        want = decoded(host._schedule_host(
+            fits, [host._compiled(p.placement) for p in fits]
+        ))
+        assert got == want
+
+    def test_budget_env_var_is_gone(self):
+        """KARMADA_TPU_DENSE_BUDGET selected between two layouts; with one
+        layout it is not a knob: unregistered, and a process that sets it
+        reads the same bound."""
+        from karmada_tpu.utils.flags import ENV_FLAGS
+
+        assert "KARMADA_TPU_DENSE_BUDGET" not in ENV_FLAGS
+        env = dict(os.environ, KARMADA_TPU_DENSE_BUDGET="1",
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import karmada_tpu.scheduler.fleet as f; "
+             "print(f.DENSE_RESIDENT_MAX_BYTES)"],
+            env=env, capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert int(out.stdout.split()[-1]) == 6 << 30
+        assert fleet_mod.DENSE_RESIDENT_MAX_BYTES == 6 << 30
 
 
 class TestMeshTraceIdentity:

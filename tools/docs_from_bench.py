@@ -64,8 +64,6 @@ def block(d: dict) -> str:
         f"| 1M×5k full-drift churn p50 / max | "
         f"{fmt(d.get('scale1m_churn_p50'))} / "
         f"{fmt(d.get('scale1m_churn_max'))} |",
-        f"| 1M×5k legacy entry-resident steady p50 | "
-        f"{fmt(d.get('scale1m_legacy_p50'))} |",
     ]
     wp = d.get("whole_plane_bindings_s")
     if wp is not None:

@@ -325,41 +325,6 @@ def _fleet_state(d: dict) -> list:
     )
 
 
-def _specs_fleet_solve() -> list:
-    from karmada_tpu.scheduler.fleet import _cap_round
-
-    d = _fleet_dims()
-    fast_idx, _ = _fast_tuples(d["c"])
-    k_out = k_res = 8
-    e_cap = _cap_round(1)
-
-    def spec(variant, **statics):
-        base = dict(
-            chunk=d["chunk"], n_chunks=1, k_out=k_out, k_res=k_res,
-            e_cap=e_cap, wide=True, fast=None, has_aggregated=True,
-            all_rows=True, mesh=None, shard_c=False, pack21=True,
-        )
-        base.update(statics)
-        shapes = tuple(
-            _fleet_tables(d) + [((d["n_pad"],), "int32")] + _fleet_state(d)
-            + [((d["cap"], base["k_res"]), "int32")]
-        )
-        return KernelSpec(variant, shapes, base)
-
-    return [
-        spec("wide-allrows"),
-        spec("narrow-fast-partial", wide=False, fast=fast_idx,
-             all_rows=False, pack21=False),
-        spec("next-e-bucket", e_cap=_cap_round(e_cap + 1)),
-        # sharded grid: the same program under a 2-device ("b") mesh —
-        # trace_spec materializes the shape into a live Mesh, so IR001-
-        # IR005 (incl. the donation audit over the row-sharded resident)
-        # run over the PARTITIONED executable's jaxpr, not just the
-        # single-device form
-        spec("sharded-b2", mesh=_MESH2),
-    ]
-
-
 #: canonical 2-device mesh shape for the sharded spec variants (the
 #: serialized form the trace manifest also records; trace_spec builds the
 #: live mesh over the forced host devices at trace time)
@@ -389,8 +354,11 @@ def _specs_fleet_pass() -> list:
         spec("wide-allrows"),
         spec("narrow-fast-delta", wide=False, fast=fast_idx,
              d_cap=D_FLOOR, all_rows=False),
-        # sharded grid under a 2-device mesh (see _specs_fleet_solve):
-        # proves the donated dense residents still alias when partitioned
+        # sharded grid: the same program under a 2-device ("b") mesh —
+        # trace_spec materializes the shape into a live Mesh, so IR001-
+        # IR005 run over the PARTITIONED executable's jaxpr, and the
+        # donation audit proves the dense residents still alias when
+        # row-sharded
         spec("sharded-b2", mesh=_MESH2),
     ]
 
@@ -568,16 +536,10 @@ ENTRY_POINTS: dict = {
                row_args=(0, 1, 3, 4, 5, 6), plane_args=(2,)),
         # scheduler fleet kernels (manifest-recorded solve family + the
         # ledger-only utility kernels). The row space is the resident
-        # cap axis; the solve/pass/entries kernels compact globally
+        # cap axis; the pass/entries kernels compact globally
         # (declared coupled), bits/meta are per-row but scan-windowed,
         # so the analyzer returns 'unproven' — declared honestly, not
         # delta_safe (see DEVELOPMENT.md, delta-safe kernel contract).
-        _entry("fleet_solve", "scheduler", "karmada_tpu.scheduler.fleet",
-               "_fleet_solve", "karmada_tpu/scheduler/fleet.py",
-               _specs_fleet_solve, manifest="fleet_solve",
-               row_coupled=True,
-               row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14),
-               spec_deps=_FLEET_DEPS),
         _entry("fleet_pass", "scheduler", "karmada_tpu.scheduler.fleet",
                "_fleet_pass", "karmada_tpu/scheduler/fleet.py",
                _specs_fleet_pass, manifest="fleet_pass",
