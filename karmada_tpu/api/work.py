@@ -241,6 +241,10 @@ class WorkStatus:
 @dataclass
 class Work:
     KIND = "Work"
+    #: meta.generation is the store's (utils.store.Store.apply): it moves
+    #: on every write of a Work but a status-only one, so its watchers can
+    #: tell a spec change from a status write
+    STORE_GENERATION = True
 
     meta: ObjectMeta = field(default_factory=ObjectMeta)
     spec: WorkSpec = field(default_factory=WorkSpec)

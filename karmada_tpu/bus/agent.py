@@ -53,14 +53,18 @@ class ReplicaStoreFacade:
 
     # -- writes (primary, over the bus) ------------------------------------
 
-    def apply(self, obj, *, expected_rv=None):
+    def apply(self, obj, *, expected_rv=None, status_only=False):
+        """``status_only`` is taken and not carried: the wire has no word
+        for it, so the primary reads every write as a spec write and moves
+        a Work's generation (Store.apply) — the echo wakes every Work
+        watcher, as it always did over the bus."""
         return self._replica.apply(obj, expected_rv=expected_rv)
 
-    def apply_many(self, objs):
+    def apply_many(self, objs, *, status_only=False):
         """Batched write-through (Store.apply_many contract): one
         ApplyBatch RPC per KARMADA_TPU_BUS_BATCH ops instead of one
         round-trip per object — the controllers' per-drain write sets
-        ride this over the bus."""
+        ride this over the bus. ``status_only``: as in ``apply``."""
         return self._replica.apply_many(objs)
 
     def delete(self, kind: str, key: str, force: bool = False):

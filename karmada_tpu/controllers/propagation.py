@@ -39,7 +39,11 @@ from ..api.policy import DIVIDED
 from ..interpreter import ResourceInterpreter
 from ..utils import DONE, REQUEUE, Runtime, Store
 from ..utils.codec import from_jsonable, to_jsonable
-from ..utils.metrics import works_rendered
+from ..utils.metrics import (
+    work_manifest_renders,
+    work_status_events_skipped,
+    works_rendered,
+)
 from ..utils.member import (
     ConflictError,
     MemberClientRegistry,
@@ -114,26 +118,30 @@ def _work_signature(work: Work):
 class TemplateRehydrator:
     """Consumer-side template-delta cache: decodes each WorkloadTemplate
     manifest ONCE (content-addressed — a digest's body never changes) and
-    renders each Work's manifest as clone(base) + patch, memoized per
-    Work so repeated reconciles hand back the SAME object (the member
-    ObjectWatcher's no-op cache pins on manifest identity). Returns None
-    when the template has not been mirrored yet — callers REQUEUE and the
-    WorkloadTemplate watch unparks them."""
+    renders a Work's manifest as clone(base) + patch. ``manifests``
+    memoizes the render per Work so repeated reconciles hand back the SAME
+    object (the member ObjectWatcher's no-op cache pins on manifest
+    identity); ``render`` keeps nothing, for a consumer that uses the
+    manifest once. Both return None when the template has not been
+    mirrored yet — callers REQUEUE and the WorkloadTemplate watch unparks
+    them. A long-lived ``consumer`` names itself and its renders count
+    in karmada_tpu_work_manifest_renders_total; a one-shot reader
+    (``work_manifests``) does not."""
 
-    def __init__(self, store) -> None:
+    def __init__(self, store, consumer: Optional[str] = None) -> None:
         self.store = store
+        self._renders = (
+            work_manifest_renders.labels(consumer=consumer)
+            if consumer else None
+        )
         self._base: dict[str, Resource] = {}
         # work key -> (digest, patch key, rendered list)
         self._rendered: dict[str, tuple] = {}
 
-    def manifests(self, work: Work) -> Optional[list]:
+    def render(self, work: Work) -> Optional[list]:
         ref = work.spec.workload_template
         if ref is None or not ref.digest:
             return work.spec.workload
-        pkey = _patch_key(ref.patch)
-        hit = self._rendered.get(work.meta.namespaced_name)
-        if hit is not None and hit[0] == ref.digest and hit[1] == pkey:
-            return hit[2]
         base = self._base.get(ref.digest)
         if base is None:
             tpl = self.store.get("WorkloadTemplate", ref.digest)
@@ -144,10 +152,23 @@ class TemplateRehydrator:
         out = clone_resource(base)
         if ref.patch:
             out.spec.update(ref.patch)
-        rendered = [out]
-        self._rendered[work.meta.namespaced_name] = (
-            ref.digest, pkey, rendered
-        )
+        if self._renders is not None:
+            self._renders.inc()
+        return [out]
+
+    def manifests(self, work: Work) -> Optional[list]:
+        ref = work.spec.workload_template
+        if ref is None or not ref.digest:
+            return work.spec.workload
+        pkey = _patch_key(ref.patch)
+        hit = self._rendered.get(work.meta.namespaced_name)
+        if hit is not None and hit[0] == ref.digest and hit[1] == pkey:
+            return hit[2]
+        rendered = self.render(work)
+        if rendered is not None:
+            self._rendered[work.meta.namespaced_name] = (
+                ref.digest, pkey, rendered
+            )
         return rendered
 
     def forget_digest(self, digest: str) -> None:
@@ -171,14 +192,24 @@ class WorkIndex:
     the binding/status controllers would otherwise pay per reconcile:
     - by binding label (orphan cleanup, status aggregation)
     - by propagated target (cluster, gvk, namespace, name) for member-event
-      routing in the work-status controller."""
+      routing in the work-status controller.
+
+    Every entry is a function of a Work's binding label, template digest
+    and targets, and the store moves a Work's ``meta.generation`` on every
+    write that may have changed one of them (``Store.apply``: all but the
+    status-only writes of execution and work-status). The index reads it:
+    a Modified event at the generation it indexed leaves the entries as
+    they are."""
 
     def __init__(self, store: Store) -> None:
         self.store = store
         self._by_binding: dict[str, set[str]] = {}
         self._by_target: dict[tuple, str] = {}
-        # work key -> (ref, targets, template digest)
+        # work key -> (generation indexed, ref, targets, template digest)
         self._work_meta: dict[str, tuple] = {}
+        self._skipped = work_status_events_skipped.labels(
+            consumer="work-index"
+        )
         # template digest -> referencing work keys (the template GC's
         # refcount surface: a digest nobody references is collectable)
         self._by_digest: dict[str, set[str]] = {}
@@ -188,9 +219,18 @@ class WorkIndex:
 
     def _on_event(self, event) -> None:
         key = event.key
-        old_ref, old_targets, old_digest = self._work_meta.pop(
-            key, (None, (), None)
-        )
+        indexed = self._work_meta.get(key)
+        if indexed is None:
+            old_ref, old_targets, old_digest = None, (), None
+        elif (
+            event.type == "Modified"
+            and indexed[0] == event.obj.meta.generation
+        ):
+            self._skipped.inc()
+            return
+        else:
+            del self._work_meta[key]
+            _, old_ref, old_targets, old_digest = indexed
         if old_ref is not None:
             self._by_binding.get(old_ref, set()).discard(key)
         if old_digest is not None:
@@ -230,7 +270,7 @@ class WorkIndex:
             self._by_digest.setdefault(digest, set()).add(key)
         for t in targets:
             self._by_target[t] = key
-        self._work_meta[key] = (ref, targets, digest)
+        self._work_meta[key] = (work.meta.generation, ref, targets, digest)
 
     def digest_refcount(self, digest: str) -> int:
         return len(self._by_digest.get(digest, ()))
@@ -836,7 +876,18 @@ class BindingController:
 
 
 class ExecutionController:
-    """Work -> member cluster apply/delete (pkg/controllers/execution/)."""
+    """Work -> member cluster apply/delete (pkg/controllers/execution/).
+
+    Its Work watch is upstream's ``GenerationChangedPredicate``: the store
+    moves a Work's ``meta.generation`` on every write but a status-only one
+    (``Store.apply``; this controller's own condition writes and
+    work-status's manifest statuses are the status-only ones), and a
+    Modified event at the generation the last apply reconcile read wakes
+    nothing. What re-runs a Work for another reason enqueues it itself:
+    REQUEUE (unreachable member, template not mirrored), the template
+    watch's un-parking, a rejected status write in ``_flush``, and
+    ``member_object_moved`` (work-status saw the member's object change
+    under a Work whose apply failed, or away from what was applied)."""
 
     def __init__(
         self,
@@ -848,7 +899,12 @@ class ExecutionController:
         self.store = store
         self.members = members
         self.watcher = ObjectWatcher(members, interpreter)
-        self.rehydrator = TemplateRehydrator(store)
+        self.rehydrator = TemplateRehydrator(store, "execution")
+        # work key -> meta.generation the last apply reconcile read
+        self._acted: dict[str, int] = {}
+        self._skipped = work_status_events_skipped.labels(
+            consumer="execution"
+        )
         # deletes parked while a cluster is unreachable; retried when the
         # cluster comes back (the asynchronous-retry analogue — burning
         # requeue budget against a dead cluster helps nobody)
@@ -889,6 +945,7 @@ class ExecutionController:
             # execution_controller.go:229-257)
             work: Work = event.obj
             self.rehydrator.forget_work(event.key)
+            self._acted.pop(event.key, None)
             # a Work deleted while parked on a never-arriving template
             # must not leak its parked entry
             for parked in self._awaiting_template.values():
@@ -910,8 +967,27 @@ class ExecutionController:
                     for w in work.spec.workload
                 )
             self.worker.enqueue(("delete", cluster, targets))
+        elif (
+            event.type == "Modified"
+            and self._acted.get(event.key) == event.obj.meta.generation
+        ):
+            self._skipped.inc()  # a status write: the spec is the one applied
         else:
             self.worker.enqueue(("apply", event.key, None))
+
+    def member_object_moved(self, work: Work, target: tuple, observed) -> None:
+        """Work-status reconciled a member event for ``target`` (cluster,
+        gvk, namespace, name) of ``work`` and found ``observed`` there.
+        Its status write wakes nothing here any more, so the two things
+        that write used to set off are asked for by name: the retry of an
+        apply that failed (a conflict is permanent only "until the member
+        object changes"), and the re-apply over an object that is no
+        longer at the version this controller wrote (member drift)."""
+        if self.watcher.drifted(*target, observed) or any(
+            c.type == WORK_APPLIED and not c.status
+            for c in work.status.conditions
+        ):
+            self.worker.enqueue(("apply", work.meta.namespaced_name, None))
 
     def _reconcile_batch(self, items) -> dict:
         self._buffering = True
@@ -928,7 +1004,7 @@ class ExecutionController:
         if self._buffering:
             self._pending_applies.append(work)
         else:
-            self.store.apply(work)
+            self.store.apply(work, status_only=True)
 
     def _flush(self) -> None:
         applies, self._pending_applies = self._pending_applies, []
@@ -936,7 +1012,7 @@ class ExecutionController:
             return
         apply_many = getattr(self.store, "apply_many", None)
         if apply_many is not None:
-            for work, _err in apply_many(applies):
+            for work, _err in apply_many(applies, status_only=True):
                 # rejected status write: retry the Work (the unbatched
                 # path raised and the worker requeued)
                 self.worker.enqueue(
@@ -944,7 +1020,7 @@ class ExecutionController:
                 )
         else:
             for work in applies:
-                self.store.apply(work)
+                self.store.apply(work, status_only=True)
 
     def _reconcile(self, item) -> Optional[str]:
         action, key_or_cluster, targets = item
@@ -962,6 +1038,9 @@ class ExecutionController:
         cluster = cluster_of_execution_namespace(key.split("/", 1)[0])
         if work is None or cluster is None:
             return DONE
+        # read before anything acts on the spec: a writer that moves it
+        # meanwhile delivers an event at a later generation
+        self._acted[key] = work.meta.generation
         cluster_obj = self.store.get("Cluster", cluster)
         if cluster_obj is not None and cluster_obj.spec.sync_mode == "Pull":
             return DONE  # the in-cluster agent applies Pull-mode works
@@ -1024,21 +1103,27 @@ class WorkStatusController:
         members: MemberClientRegistry,
         interpreter: ResourceInterpreter,
         work_index: Optional[WorkIndex] = None,
+        on_member_object=None,
     ) -> None:
         self.store = store
         self.members = members
         self.interpreter = interpreter
         self.work_index = work_index or WorkIndex(store)
-        self.rehydrator = TemplateRehydrator(store)
+        # (work, target, observed) of every member event reconciled with
+        # the object present: the plane hands in
+        # ExecutionController.member_object_moved, since this controller's
+        # status write no longer wakes execution
+        self.on_member_object = on_member_object
+        # renders only to recreate, and keeps no render
+        self.rehydrator = TemplateRehydrator(store, "work-status")
         # member-event keys parked on a template that has not mirrored
         # yet (the recreate path needs the rehydrated manifest); the
         # WorkloadTemplate watch unparks them — REQUEUE alone drops the
         # key after MAX_RETRIES in cooperative mode
         self._awaiting_template: dict[str, set] = {}
         self.worker = runtime.new_worker("work-status", self._reconcile)
-        # rehydrator eviction: without these the decode/render caches
-        # grow with ALL-TIME work/template churn
-        store.watch("Work", self._on_work_event, replay=False)
+        # rehydrator eviction: without it the decode cache grows with
+        # ALL-TIME template churn
         store.watch(
             "WorkloadTemplate", self._on_template_event, replay=False
         )
@@ -1055,36 +1140,41 @@ class WorkStatusController:
             (event.cluster, event.gvk, event.namespace, event.name, event.type)
         )
 
-    def _find_work(self, cluster: str, gvk: str, namespace: str, name: str):
-        """(work, desired manifest | None) for a member target. For
-        template-delta works the identity check rides the ref and the
-        manifest rehydrates lazily; a missing template answers (work,
-        None) so the recreate path can REQUEUE instead of dropping."""
+    def _find_work(
+        self, cluster: str, gvk: str, namespace: str, name: str
+    ) -> Optional[Work]:
+        """The Work that propagates a member target: the index's answer,
+        held to the identity the Work itself states (a template-delta
+        Work carries it on the ref, so no manifest is rendered to ask)."""
         work = self.work_index.work_for_target(cluster, gvk, namespace, name)
         if work is None:
-            return None, None
+            return None
         tref = work.spec.workload_template
         if tref is not None and tref.digest:
-            if (
-                f"{tref.api_version}/{tref.kind}" == gvk
-                and tref.namespace == namespace
-                and tref.name == name
-            ):
-                manifests = self.rehydrator.manifests(work)
-                return work, manifests[0] if manifests else None
-            return None, None
-        for workload in work.spec.workload:
-            if (
-                f"{workload.api_version}/{workload.kind}" == gvk
-                and workload.meta.namespace == namespace
-                and workload.meta.name == name
-            ):
-                return work, workload
-        return None, None
+            stated = (
+                (f"{tref.api_version}/{tref.kind}", tref.namespace, tref.name),
+            )
+        else:
+            stated = (
+                (f"{w.api_version}/{w.kind}", w.meta.namespace, w.meta.name)
+                for w in work.spec.workload
+            )
+        return work if (gvk, namespace, name) in stated else None
 
-    def _on_work_event(self, event) -> None:
-        if event.type == "Deleted":
-            self.rehydrator.forget_work(event.key)
+    def _desired(
+        self, work: Work, gvk: str, namespace: str, name: str
+    ) -> Optional[Resource]:
+        """The manifest ``work`` wants at a target ``_find_work`` matched,
+        rendered now for a template-delta Work: None where its template
+        has not been mirrored yet."""
+        for manifest in self.rehydrator.render(work) or ():
+            if (
+                f"{manifest.api_version}/{manifest.kind}" == gvk
+                and manifest.meta.namespace == namespace
+                and manifest.meta.name == name
+            ):
+                return manifest
+        return None
 
     def _on_template_event(self, event) -> None:
         if event.type == "Deleted":
@@ -1097,7 +1187,7 @@ class WorkStatusController:
 
     def _reconcile(self, key) -> Optional[str]:
         cluster, gvk, namespace, name, event_type = key
-        work, desired = self._find_work(cluster, gvk, namespace, name)
+        work = self._find_work(cluster, gvk, namespace, name)
         if work is None:
             return DONE
         member = self.members.get(cluster)
@@ -1110,6 +1200,7 @@ class WorkStatusController:
         if observed is None:
             # recreate deleted-but-desired (work_status_controller.go:311)
             if not work.spec.preserve_resources_on_deletion:
+                desired = self._desired(work, gvk, namespace, name)
                 if desired is None:
                     # template not mirrored yet: park on the digest (the
                     # watch unparks) AND requeue as a belt-and-braces
@@ -1124,6 +1215,10 @@ class WorkStatusController:
                 except UnreachableError:
                     return REQUEUE
             return DONE
+        if self.on_member_object is not None:
+            self.on_member_object(
+                work, (cluster, gvk, namespace, name), observed
+            )
         status = self.interpreter.reflect_status(observed)
         # health is Unknown until the member reports any status — a fresh
         # object is not "Unhealthy" (failover must not fire on it)
@@ -1151,7 +1246,7 @@ class WorkStatusController:
             )
             updated = True
         if updated:
-            self.store.apply(work)
+            self.store.apply(work, status_only=True)
         return DONE
 
 

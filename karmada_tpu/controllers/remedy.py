@@ -202,7 +202,7 @@ class KarmadaAgent:
         # template-delta rehydration (ISSUE 11): Works arriving over the
         # bus may carry (digest, patch) instead of a full manifest; the
         # agent renders them against the mirrored WorkloadTemplate
-        self.rehydrator = TemplateRehydrator(store)
+        self.rehydrator = TemplateRehydrator(store, "agent")
         self._awaiting_template: dict[str, set] = {}
         # per-drain write set: status reflections flush as one batched
         # write-through (one ApplyBatch RPC over the bus facade)

@@ -121,6 +121,7 @@ class ControlPlane:
         self.work_status_controller = WorkStatusController(
             self.store, self.runtime, self.members, self.interpreter,
             work_index=self.work_index,
+            on_member_object=self.execution_controller.member_object_moved,
         )
         self.binding_status_controller = BindingStatusController(
             self.store, self.runtime, self.detector,

@@ -436,13 +436,16 @@ class ObjectWatcher:
     def __init__(self, members: MemberClientRegistry, interpreter) -> None:
         self.members = members
         self.interpreter = interpreter
-        self._versions: dict[tuple[str, str, str, str], int] = {}
         # (cluster, gvk, ns, name) -> (desired manifest pin, applied rv,
         # conflict_resolution): re-applying the SAME manifest object onto an
-        # un-drifted member is a no-op, and the execution controller echoes
-        # one such apply per Work condition update — the pin (a strong ref,
-        # so the id cannot be recycled) collapses that loop. Any member
-        # drift changes the observed resource_version and misses the cache.
+        # un-drifted member is a no-op. The execution controller no longer
+        # comes back once per Work condition update (it drops status
+        # writes by the Work's generation), but a key it re-enqueues
+        # itself (REQUEUE, a rejected status write, a restart's replay of
+        # Works already applied) presents the manifest it applied again,
+        # and the pin (a strong ref, so the id cannot be recycled) makes
+        # that free. Any member drift changes the observed
+        # resource_version and misses the cache.
         self._applied: dict[tuple[str, str, str, str], tuple] = {}
 
     def create_or_update(
@@ -488,7 +491,6 @@ class ObjectWatcher:
             to_apply = clone_resource(desired)
             to_apply.meta.annotations[MANAGED_ANNOTATION] = "true"
         applied = member.apply(to_apply)
-        self._versions[vkey] = applied.meta.resource_version
         self._applied[vkey] = (
             desired, applied.meta.resource_version, conflict_resolution,
         )
@@ -499,13 +501,14 @@ class ObjectWatcher:
         if member is None:
             return
         member.delete(gvk, namespace, name)
-        self._versions.pop((cluster, gvk, namespace, name), None)
         self._applied.pop((cluster, gvk, namespace, name), None)
 
-    def needs_update(self, cluster: str, desired: Resource) -> bool:
-        gvk = f"{desired.api_version}/{desired.kind}"
-        member = self.members.get(cluster)
-        if member is None:
-            return True
-        observed = member.get(gvk, desired.meta.namespace, desired.meta.name)
-        return observed is None
+    def drifted(
+        self, cluster: str, gvk: str, namespace: str, name: str,
+        observed: Resource,
+    ) -> bool:
+        """Whether ``observed``, read from the member, is an object this
+        watcher wrote and no longer at the version it wrote (objectwatcher
+        NeedsUpdate): the member, or someone on it, has written since."""
+        pin = self._applied.get((cluster, gvk, namespace, name))
+        return pin is not None and pin[1] != observed.meta.resource_version

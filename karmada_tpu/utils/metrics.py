@@ -72,6 +72,23 @@ def _help_line(name: str, help_: str) -> str:
     return f"# HELP {name} {escaped}"
 
 
+class Tally:
+    """One label set of a Counter, bound once (``Counter.labels``) by an
+    owner that counts on a hot path: ``inc`` is a plain add, with no lock
+    and no label sort (a watch handler that turns away thousands of events
+    a wave pays for nothing else). Exact under the single-threaded
+    reconcile runtime; writers racing on threads may lose an increment, as
+    with the workers' own counts (utils.worker)."""
+
+    __slots__ = ("n",)
+
+    def __init__(self) -> None:
+        self.n = 0.0
+
+    def inc(self, amount: float = 1.0) -> None:
+        self.n += amount
+
+
 class Counter:
     kind = "counter"
 
@@ -79,20 +96,36 @@ class Counter:
         self.name = name
         self.help = help_
         self._values: dict[tuple, float] = defaultdict(float)
+        self._tallies: dict[tuple, Tally] = {}
         self._lock = threading.Lock()
 
     def inc(self, amount: float = 1.0, **labels) -> None:
         with self._lock:
             self._values[_label_key(labels)] += amount
 
-    def value(self, **labels) -> float:
+    def labels(self, **labels) -> Tally:
+        """The Tally of this label set (one a label set, shared by every
+        caller): its count is added to what ``inc`` counted there."""
+        key = _label_key(labels)
         with self._lock:
-            return self._values.get(_label_key(labels), 0.0)
+            tally = self._tallies.get(key)
+            if tally is None:
+                tally = self._tallies[key] = Tally()
+            return tally
+
+    def value(self, **labels) -> float:
+        key = _label_key(labels)
+        with self._lock:
+            tally = self._tallies.get(key)
+            return self._values.get(key, 0.0) + (tally.n if tally else 0.0)
 
     def samples(self) -> dict[tuple, float]:
         """Label-set -> value snapshot (bench records enumerate these)."""
         with self._lock:
-            return dict(self._values)
+            out = dict(self._values)
+            for key, tally in self._tallies.items():
+                out[key] = out.get(key, 0.0) + tally.n
+            return out
 
     def snapshot(self) -> dict[str, float]:
         """JSON-stable samples (label string -> value) — the flight
@@ -120,6 +153,9 @@ class SampledCounter(Counter):
         self._read = read
 
     def inc(self, amount: float = 1.0, **labels) -> None:
+        raise TypeError(f"{self.name} is read from its source, not incremented")
+
+    def labels(self, **labels) -> Tally:
         raise TypeError(f"{self.name} is read from its source, not incremented")
 
     def value(self, **labels) -> float:
@@ -464,6 +500,21 @@ works_rendered = registry.counter(
     "karmada_tpu_controller_works_rendered_total",
     "Work objects created or updated by the binding controller (the "
     "work-render throughput ROADMAP item 3 optimizes)",
+)
+work_status_events_skipped = registry.counter(
+    "karmada_tpu_work_status_events_skipped_total",
+    "Work Modified events a consumer of Work specs turned away because the "
+    "Work's meta.generation was the one it had already acted on (a status "
+    "or condition write: the store moves a Work's generation on every "
+    "other write), by consumer: execution (no apply reconcile enqueued), "
+    "work-index (entries left as they are)",
+)
+work_manifest_renders = registry.counter(
+    "karmada_tpu_work_manifest_renders_total",
+    "template-delta Work manifests rendered (clone of the decoded "
+    "WorkloadTemplate + the Work's patch), by consumer: execution (once a "
+    "Work generation), work-status (only to recreate an object deleted on "
+    "its member), agent (Pull mode)",
 )
 worker_reconciles = registry.counter(
     "karmada_tpu_worker_reconciles_total",

@@ -50,6 +50,17 @@ def obj_kind(obj: Any) -> str:
     return type(obj).KIND if hasattr(type(obj), "KIND") else type(obj).__name__
 
 
+def _move_generation(obj: Any, existing: Any, status_only: bool) -> None:
+    """A write over ``existing`` of a kind whose generation the store owns
+    (``STORE_GENERATION`` on the class: Work). Only a write that says it is
+    status-only AND hands in the stored object itself leaves the generation
+    where it is: another object under the key may carry any spec."""
+    if getattr(type(obj), "STORE_GENERATION", False) and not (
+        status_only and existing is obj
+    ):
+        obj.meta.generation = existing.meta.generation + 1
+
+
 class Store:
     """Typed object store. Mutations are thread-safe; watch handlers run
     synchronously on the mutating thread, outside the lock (so handlers may
@@ -95,10 +106,27 @@ class Store:
         with self._lock:
             self._rv = max(self._rv, rv - 1)
 
-    def apply(self, obj: Any, *, expected_rv: Optional[int] = None) -> Any:
-        """Create-or-update. Bumps resource_version; bumps generation when a
-        spec is present and changed is not detectable (callers that mutate
-        spec in place should bump generation themselves via ``bump_generation``).
+    def apply(
+        self,
+        obj: Any,
+        *,
+        expected_rv: Optional[int] = None,
+        status_only: bool = False,
+    ) -> Any:
+        """Create-or-update. Bumps resource_version.
+
+        ``meta.generation`` belongs to the writers (the detector, the
+        rebalancer, the CLI move it with the spec; ``bump_generation``), but
+        for a kind that sets ``STORE_GENERATION`` (Work) it belongs to the
+        store, as it does to the apiserver upstream: every write over an
+        existing object moves it, but a write that passes ``status_only``
+        (the status-subresource analogue: execution's conditions,
+        work-status's manifest statuses) with the stored object itself.
+        Any writer that does not say so — a fresh object applied over the
+        key, a replica's replay, a facade that cannot carry the word —
+        reads as a spec write. The readers are the Work watchers that do
+        spec-derived work (ExecutionController, WorkIndex): a Modified
+        event at the generation they acted on is a status write.
 
         ``expected_rv`` is the apiserver's optimistic-concurrency
         precondition: the write succeeds only if the CURRENT object's
@@ -126,6 +154,8 @@ class Store:
             self._rv += 1
             self.write_count.n += 1
             obj.meta.resource_version = self._rv
+            if existing is not None:
+                _move_generation(obj, existing, status_only)
             if not obj.meta.uid:
                 obj.meta.uid = existing.meta.uid if existing else new_uid()
             if existing is None and not obj.meta.creation_timestamp:
@@ -137,7 +167,7 @@ class Store:
         self._deliver(event)
         return obj
 
-    def apply_many(self, objs: list) -> list:
+    def apply_many(self, objs: list, *, status_only: bool = False) -> list:
         """Batched create-or-update for INDEPENDENT objects: admission runs
         per object (against pre-batch state — use only for sweeps whose
         objects don't admit against each other, like a storm writeback
@@ -153,7 +183,7 @@ class Store:
         wave). Rejected objects are skipped (no rv bump, no event) and
         returned as ``[(obj, exception), ...]`` for the caller to surface.
         No ``expected_rv`` support: CAS writers want the single-object
-        path."""
+        path. ``status_only`` speaks for the whole batch, as in ``apply``."""
         import time as _time
 
         if not objs:
@@ -178,6 +208,8 @@ class Store:
                 existing = bucket.get(key)
                 self._rv += 1
                 obj.meta.resource_version = self._rv
+                if existing is not None:
+                    _move_generation(obj, existing, status_only)
                 if not obj.meta.uid:
                     obj.meta.uid = existing.meta.uid if existing else new_uid()
                 if existing is None and not obj.meta.creation_timestamp:
@@ -219,6 +251,7 @@ class Store:
                     self._rv += 1
                     self.write_count.n += 1
                     obj.meta.resource_version = self._rv
+                    _move_generation(obj, obj, False)
                     event = Event(MODIFIED, kind, key, obj)
                 else:
                     return obj
