@@ -57,7 +57,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 #: chips). The sidecar stage's own process is the CPU-pinned plane; the
 #: solver it spawns is the one that owns the chip.
 STAGES = (
-    ("engine", True, 500),
+    ("engine", True, 700),
     ("kernels", True, 700),
     ("plane", True, 400),
     ("sidecar", False, 300),
@@ -122,10 +122,40 @@ def _placements(results) -> list:
     return [(r.success, sorted(r.clusters.items())) for r in results]
 
 
+def _select_np(snap, problems, feasible, strategy, reps, avail, prev):
+    """``feasible`` narrowed, row by row, to what refimpl.spread's
+    SelectClusters oracle selects for the rows whose placement carries
+    spread constraints (none selected = FitError). Static weights ignore
+    them (select_clusters.go:63-78); Duplicated ignores availability."""
+    import numpy as np
+
+    from karmada_tpu.refimpl.divider import DUPLICATED, STATIC_WEIGHT
+    from karmada_tpu.refimpl.spread import select_spread_clusters
+
+    region_of = {j: cl.spec.region for j, cl in enumerate(snap.clusters)}
+    out = feasible.copy()
+    for k, p in enumerate(problems):
+        scs = p.placement.spread_constraints if p.placement else ()
+        if not scs or strategy[k] == STATIC_WEIGHT:
+            continue
+        sel = select_spread_clusters(
+            np.flatnonzero(feasible[k]).tolist(), region_of,
+            {int(j): 100 for j in np.flatnonzero(prev[k] > 0)},
+            {j: int(avail[k, j]) + int(prev[k, j])
+             for j in range(feasible.shape[1])},
+            {sc.spread_by_field: (sc.min_groups, sc.max_groups) for sc in scs},
+            int(reps[k]), strategy[k] == DUPLICATED,
+        )
+        out[k] = False
+        out[k, sel or []] = True
+    return out
+
+
 def _numpy_mismatches(snap, problems, results, host_eng, idx) -> int:
     """Rows of ``idx`` whose engine placement differs from the vectorized
     numpy divider (refimpl.divider_np) over the general estimator's
-    availability — the bench's full-set check, on a sample."""
+    availability and, for spread-constrained rows, refimpl.spread's
+    selection — the bench's full-set check, on a sample."""
     import numpy as np
 
     import bench
@@ -145,6 +175,11 @@ def _numpy_mismatches(snap, problems, results, host_eng, idx) -> int:
         avail = np.minimum(
             np.where(avail == 2**31 - 1, reps[:, None], avail), 2**31 - 1
         ).astype(np.int32)
+        # a binding without replicas asks the estimators nothing
+        avail = np.where(reps[:, None] == 0, 0, avail)
+        feasible = _select_np(
+            snap, sub, feasible, strategy, reps, avail, prev
+        )
         got, unsched = assign_batch_np(
             strategy, reps, feasible, static_w, avail, prev, fresh
         )
@@ -159,6 +194,82 @@ def _numpy_mismatches(snap, problems, results, host_eng, idx) -> int:
                 good = res.success and dict(res.clusters) == want
             bad += not good
     return bad
+
+
+def _drift(clusters, seed: int):
+    """Every cluster's allocation moves by -3..3 x 0.5% of its allocatable
+    a dim, in place; returns the snapshot of the moved fleet."""
+    import numpy as np
+
+    from karmada_tpu.scheduler import ClusterSnapshot
+
+    rng = np.random.default_rng(seed)
+    for cl in clusters:
+        rs = cl.status.resource_summary
+        for dim, q in list(rs.allocated.items()):
+            alloc = rs.allocatable.get(dim, 0)
+            step = int(rng.integers(-3, 4)) * max(1, alloc // 200)
+            rs.allocated[dim] = int(min(max(0, q + step), alloc))
+    return ClusterSnapshot(clusters)
+
+
+#: placements of the engine stage's mixed-policy batch (_policy_problems)
+N_POLICIES = 6
+
+
+def _policy_problems(clusters, n: int) -> list:
+    """``n`` bindings under the documented policy kinds side by side:
+    Duplicated under a label selector, dynamic and static weights,
+    Aggregated, and dynamic weight / Aggregated under spread constraints."""
+    import numpy as np
+
+    from karmada_tpu.api.policy import (
+        ClusterAffinity,
+        LabelSelector,
+        SpreadConstraint,
+    )
+    from karmada_tpu.scheduler import BindingProblem
+    from karmada_tpu.utils import builders
+
+    rng = np.random.default_rng(31)
+    names = [cl.name for cl in clusters]
+    weighted = rng.choice(len(names), min(15, len(names)), replace=False)
+
+    def sc(field, lo, hi):
+        return SpreadConstraint(
+            spread_by_field=field, min_groups=lo, max_groups=hi
+        )
+
+    policies = [
+        builders.duplicated_placement(cluster_affinity=ClusterAffinity(
+            label_selector=LabelSelector(match_labels={"env": "prod"}))),
+        builders.dynamic_weight_placement(),
+        builders.static_weight_placement(
+            {names[j]: 1 + int(j) % 4 for j in weighted}),
+        builders.aggregated_placement(),
+        builders.dynamic_weight_placement(spread_constraints=[
+            sc("region", 2, 3), sc("cluster", 3, 6)]),
+        builders.aggregated_placement(spread_constraints=[
+            sc("cluster", 2, 4)]),
+    ]
+    assert len(policies) == N_POLICIES
+    kinds = rng.choice(N_POLICIES, n, p=[0.3, 0.25, 0.15, 0.2, 0.05, 0.05])
+    problems = []
+    for i in range(n):
+        size = 1 + i % 8
+        held = (
+            rng.choice(len(names), int(rng.integers(1, 5)), replace=False)
+            if rng.random() < 0.7 else ()
+        )
+        problems.append(BindingProblem(
+            key=f"policy-{i}", placement=policies[kinds[i]],
+            replicas=int(rng.integers(1, 40)),
+            requests={"cpu": 250 * size, "memory": (512 << 20) * size},
+            gvk="apps/v1/Deployment",
+            prev={names[j]: int(rng.integers(1, 9)) for j in held},
+            fresh=bool(rng.random() < 0.05),
+        ))
+    return problems
 
 
 # --------------------------------------------------------------------------
@@ -184,11 +295,7 @@ def stage_engine(
 
     import bench
     from karmada_tpu import native
-    from karmada_tpu.scheduler import (
-        BindingProblem,
-        ClusterSnapshot,
-        TensorScheduler,
-    )
+    from karmada_tpu.scheduler import BindingProblem, TensorScheduler
 
     timings: dict = {}
     t0 = time.perf_counter()
@@ -301,14 +408,7 @@ def stage_engine(
 
     # ---- churn pass: every cluster's allocation drifts, the snapshot
     # swaps in place (update_snapshot), every row re-divides
-    rng_c = np.random.default_rng(99)
-    for cl in w.clusters:
-        rs = cl.status.resource_summary
-        for dim, q in list(rs.allocated.items()):
-            alloc = rs.allocatable.get(dim, 0)
-            step = int(rng_c.integers(-3, 4)) * max(1, alloc // 200)
-            rs.allocated[dim] = int(min(max(0, q + step), alloc))
-    drifted = ClusterSnapshot(w.clusters)
+    drifted = _drift(w.clusters, 99)
     t0 = time.perf_counter()
     _check(engine.update_snapshot(drifted), "update_snapshot refused an "
            "availability-only drift")
@@ -324,6 +424,37 @@ def stage_engine(
     _check(churn_bad == 0, f"{churn_bad}/{len(churn_idx)} churn rows "
            "differ from the numpy divider")
 
+    # ---- mixed-policy batch: the documented policy kinds side by side,
+    # two of them under spread constraints, over two snapshot generations.
+    # A spread selection is row state of the fleet table: the placement
+    # table keeps one slot a placement however the selections move.
+    pol_problems = _policy_problems(w.clusters, min(b, 2048))
+    pol_eng = TensorScheduler(
+        drifted, chunk_size=4096, trace_manifest=_manifest_path(), mesh=mesh
+    )
+    pol_idx = _spread(len(pol_problems), 512)
+    t0 = time.perf_counter()
+    pol_bad, pol_slots = 0, []
+    for gen_snap in (drifted, _drift(w.clusters, 7)):
+        _check(pol_eng.update_snapshot(gen_snap), "update_snapshot refused "
+               "an availability-only drift")
+        pol_res = pol_eng.schedule(pol_problems)
+        _check(pol_eng._fleet is not None, "the mixed-policy batch never "
+               "engaged the fleet table")
+        pol_slots.append(len(pol_eng._fleet._cp_pl))
+        pol_bad += _numpy_mismatches(
+            gen_snap, pol_problems, pol_res,
+            TensorScheduler(gen_snap, mesh=False), pol_idx,
+        )
+    timings["policy_passes_s"] = time.perf_counter() - t0
+    _check(pol_bad == 0, f"{pol_bad}/{2 * len(pol_idx)} mixed-policy rows "
+           "differ from refimpl (divider_np + spread)")
+    _check(
+        pol_slots[0] == pol_slots[1] <= N_POLICIES,
+        f"the placement table went {pol_slots} slots over two snapshot "
+        f"generations of {N_POLICIES} placements",
+    )
+
     lib = native.get()
     no_native = os.environ.get("KARMADA_TPU_NO_NATIVE") == "1"
     _check(lib is not None or no_native, "the native fold (karmada_tpu/"
@@ -335,6 +466,8 @@ def stage_engine(
         "buffer_platform": fleet._buffer_platform(),
         "settle_passes": settle,
         "numpy_checked": len(np_idx) + len(dirty) + len(churn_idx),
+        "policy_rows_checked": 2 * len(pol_idx),
+        "policy_slots": pol_slots[1],
         "oracle_checked": len(oracle_idx),
         "mismatches": 0,
         "delta_rows_packed": packed,

@@ -290,16 +290,14 @@ class TensorScheduler:
         # table detect in-place snapshot swaps (update_snapshot)
         self._fleet = None
         self._snapshot_gen = 0
-        # (id(base compiled), selection bytes) -> (derived cp, pinned base)
-        self._selection_cache: dict = {}
         # batch-identity fast path (see schedule()): id() array of the last
-        # all-fleet batch + the derived lists; _batch_problems pins the
+        # all-fleet batch + the compiled lists; _batch_problems pins the
         # problem objects so a recycled id() cannot alias a stale batch
         self._batch_ids: Optional[np.ndarray] = None
         self._batch_gen = -1
         self._batch_cache: Optional[tuple] = None
         self._batch_problems: Optional[list] = None
-        self._batch_spread = True  # batch holds derived spread selections
+        self._batch_spread = True  # batch holds spread-selected rows
         self._batch_token = None  # snapshot.mask_token at cache time
         # per-pass dirty-key set (ISSUE 20): the controller's invalidation
         # sources (watch bus, quota bumps, estimator movement, evictions)
@@ -311,9 +309,12 @@ class TensorScheduler:
         # key -> position map of the armed batch (lazily built, only when
         # dirty keys need resolving against a large wave)
         self._key_pos: Optional[dict] = None
-        # binding key -> (row fingerprint, derived cp | None): skips the
-        # packing+selection stage for unchanged spread rows in steady storms
-        self._derived_rows: dict = {}
+        # binding key -> (row fingerprint, pinned placement, selection
+        # bits | None): a spread row's last SelectClusters result, packed
+        # as the fleet's row state takes it (None = FitError). Skips the
+        # packing+selection stage for unchanged rows at one snapshot
+        # generation; across generations it is what ``moved`` is told by
+        self._row_selections: dict = {}
         # batched solves dispatched (host chunks + fleet passes): the
         # chaos bench reads this to prove a failover wave reschedules its
         # displaced bindings in O(chunks) solves, not O(bindings)
@@ -420,9 +421,9 @@ class TensorScheduler:
         # recompiling thousands of selectors (~0.5s/pass at 3.5k placements)
         if snapshot.mask_token != self.snapshot.mask_token:
             self._placement_cache.clear()
-            self._selection_cache.clear()
-        self._derived_rows.clear()  # selections depend on capacities
         self.snapshot = snapshot
+        # spread selections depend on capacities: their row fingerprints
+        # carry this generation, so none outlives the swap
         self._snapshot_gen += 1
         return True
 
@@ -479,9 +480,9 @@ class TensorScheduler:
             self._quota_cache = None
             self._caps_dev = None
             self._caps_dev_token = None
-            # derived spread selections rank groups on cap-folded
-            # availability: cap content changes invalidate them
-            self._derived_rows.clear()
+            # spread selections rank groups on cap-folded availability:
+            # cap content changes invalidate them
+            self._row_selections.clear()
 
     # -- quota admission ---------------------------------------------------
 
@@ -1330,7 +1331,7 @@ class TensorScheduler:
         """Build one chunk's ExplainCapture. Stage masks carry the
         solve's exact leniency rules (already-placed taint/API leniency,
         evictions folded into the taint/NoExecute stage, the spread
-        selection where a derived row exists) so a bit here means "this
+        selection where the row has one) so a bit here means "this
         stage excluded this cluster in THIS pass". Out-of-tree custom
         filters are engine-level host hooks with no stage identity and
         are not attributed."""
@@ -1428,17 +1429,22 @@ class TensorScheduler:
         if "SpreadConstraint" in disabled:
             spread_ok = np.ones((b, c), bool)
         else:
-            # spread rows with a derived selection: the Select stage's
-            # surviving set IS the selection mask (id-pinned row cache)
+            # spread rows with a selection: the Select stage's surviving
+            # set IS the selection mask (placement-pinned row cache)
             for i, (p, cp) in enumerate(zip(problems, compiled)):
                 if len(cp.terms) == 1 and not cp.fleet_single_term:
-                    hit = self._derived_rows.get(p.key)
+                    hit = self._row_selections.get(p.key)
                     if (
                         hit is not None
+                        and hit[0][0] == self._snapshot_gen
                         and hit[1] is p.placement
                         and hit[2] is not None
                     ):
-                        spread_ok[i] = spread_ok[i] & hit[2].terms[0][1]
+                        sel = np.unpackbits(
+                            np.frombuffer(hit[2], np.uint8),
+                            bitorder="little",
+                        )[:c].astype(bool)
+                        spread_ok[i] = spread_ok[i] & sel
 
         # pre-cap merged availability: the host mirror when exact, the
         # device merge (without the cap estimator — the cap is its own
@@ -1647,7 +1653,8 @@ class TensorScheduler:
         self.last_breakdown.update(self._fleet.last_breakdown)
         # re-arm the identity token on the swapped lists (gen and mask
         # token are unchanged by construction; _batch_spread likewise —
-        # swapped-in rows are never derived selections)
+        # swapped-in rows are never spread-constrained, and the rows that
+        # are keep the selection the fleet's row state holds)
         self._batch_problems = fp2
         self._batch_ids = ids
         self._batch_cache = (fp2, fc2)
@@ -1672,9 +1679,9 @@ class TensorScheduler:
                 self._batch_gen == self._snapshot_gen
                 # availability-only drift keeps every compiled mask and the
                 # eligibility partition valid (placements key on filter
-                # fields = mask_token); only derived SPREAD selections
-                # depend on capacities, so spread-free batches reuse across
-                # the swap — churn passes skip the prologue too
+                # fields = mask_token); only SPREAD selections depend on
+                # capacities, so spread-free batches reuse across the swap
+                # — churn passes skip the prologue too
                 or (
                     not self._batch_spread
                     and self._batch_token == self.snapshot.mask_token
@@ -1706,68 +1713,75 @@ class TensorScheduler:
             if res is not None:
                 return res
 
-        t0 = t_pack = _time.perf_counter()
-        compiled = [self._compiled(p.placement) for p in problems]
-        self.last_breakdown = {"compile": _time.perf_counter() - t0}
         # engine-level features that the device-resident path does not
         # model force the general host path for the whole batch
-        if not (
+        fleet_ok = not (
             self.custom_filters
             or self._host_only_estimators()
             or self.disabled_plugins
+        )
+        from contextlib import nullcontext
+
+        from ..utils.tracing import tracer as _tracer
+
+        # the host prologue (placement compile + spread selection +
+        # eligibility partition) is the wave tree's "pack" phase: one
+        # span, with the Select stage as its child, so a storm's pass
+        # decomposes into pack / solve(dispatch/device/fetch) under
+        # scheduler.pass
+        with (
+            _tracer.span("scheduler.pack", rows=len(problems))
+            if fleet_ok
+            else nullcontext()
         ):
             t0 = _time.perf_counter()
-            from ..ops.divide import DUPLICATED as _DUP
-            from .fleet import K_PREV as _KP, MAX_REPLICAS_FAST as _MRF
+            compiled = [self._compiled(p.placement) for p in problems]
+            self.last_breakdown = {"compile": _time.perf_counter() - t0}
+            if fleet_ok:
+                t0 = _time.perf_counter()
+                from ..ops.divide import DUPLICATED as _DUP
+                from .fleet import K_PREV as _KP, MAX_REPLICAS_FAST as _MRF
 
-            # spread-constraint rows ride the fleet too: their host-side
-            # group selection collapses to a per-row candidate mask, which
-            # is interned as a DERIVED placement (terms = the selection)
-            # so the device-resident path divides over exactly the selected
-            # set — SelectClusters becomes part of placement compilation.
-            # Not with extra estimators on: the selection ranks groups on
-            # the general estimate alone, so those rows keep the host
-            # path, where the Select stage sees the merged availability
-            if not self.extra_estimators:
-                compiled = self._derive_spread_selections(problems, compiled)
-            self.last_breakdown["select"] = _time.perf_counter() - t0
+                # spread-constraint rows ride the fleet too: their
+                # host-side group selection collapses to a per-row
+                # candidate mask, which the fleet table keeps as ROW
+                # STATE (one packed mask a row, uploaded when it moves)
+                # and ANDs into the row's feasibility, so the
+                # device-resident path divides over exactly the selected
+                # set
+                sel_idx, sel_bits = self._select_spread_rows(
+                    problems, compiled
+                )
+                selected = frozenset(sel_idx.tolist())
+                self.last_breakdown["select"] = _time.perf_counter() - t0
 
-            t0 = _time.perf_counter()
-            # THE fleet-eligibility predicate (single source of truth):
-            # placement half precomputed as cp.fleet_single_term; the
-            # per-problem half stays a plain inline expression because this
-            # comprehension runs B times per storm pass — a method call per
-            # row costs ~2.4us x 100k = 240ms
-            fast_idx = [
-                i
-                for i, (p, cp) in enumerate(zip(problems, compiled))
-                if cp.fleet_single_term
-                and not p.evict_clusters
-                and len(p.prev) <= _KP
-                and (cp.strategy == _DUP or p.replicas <= _MRF)
-            ]
-            self.last_breakdown["eligible"] = _time.perf_counter() - t0
-            # the host prologue (placement compile + spread selection +
-            # eligibility partition) is the wave tree's "pack" phase —
-            # recorded as one span, from where the prologue began, so a
-            # storm's pass decomposes into pack / solve(dispatch/device/
-            # fetch) under scheduler.pass
-            from ..utils.tracing import tracer as _tracer
-
-            _tracer.record(
-                "scheduler.pack",
-                sum(
-                    self.last_breakdown.get(k, 0.0)
-                    for k in ("compile", "select", "eligible")
-                ),
-                start=t_pack, rows=len(problems),
-            )
+                t0 = _time.perf_counter()
+                # THE fleet-eligibility predicate (single source of
+                # truth): placement half precomputed as
+                # cp.fleet_single_term (a spread-constrained row passes it
+                # through the selection it was given); the per-problem
+                # half stays a plain inline expression because this
+                # comprehension runs B times per storm pass — a method
+                # call per row costs ~2.4us x 100k = 240ms
+                fast_idx = [
+                    i
+                    for i, (p, cp) in enumerate(zip(problems, compiled))
+                    if (cp.fleet_single_term or i in selected)
+                    and not p.evict_clusters
+                    and len(p.prev) <= _KP
+                    and (cp.strategy == _DUP or p.replicas <= _MRF)
+                ]
+                self.last_breakdown["eligible"] = _time.perf_counter() - t0
+        if fleet_ok:
             if len(fast_idx) >= self.fleet_threshold:
                 from .fleet import FleetTable
 
                 if self._fleet is not None and self._fleet.slots_exhausted:
                     import sys as _sys
 
+                    from ..utils.metrics import fleet_table_rebuilds
+
+                    fleet_table_rebuilds.inc()
                     print(
                         "# fleet table rebuild: "
                         + self._fleet.exhaustion_summary(),
@@ -1779,8 +1793,20 @@ class TensorScheduler:
                     self._fleet = FleetTable(self)
                 fp = [problems[i] for i in fast_idx]
                 fc = [compiled[i] for i in fast_idx]
+                selections = None
+                if len(sel_idx):
+                    # the selected rows' positions in the fleet batch (a
+                    # selected row another clause sent to the host path
+                    # takes its selection there itself)
+                    fast_arr = np.asarray(fast_idx, np.int64)
+                    pos = np.searchsorted(fast_arr, sel_idx)
+                    pos = np.minimum(pos, len(fast_arr) - 1)
+                    rides = fast_arr[pos] == sel_idx
+                    selections = (pos[rides], sel_bits[rides])
                 self.solve_batches += 1
-                fast_res = self._fleet.schedule(fp, fc)
+                fast_res = self._fleet.schedule(
+                    fp, fc, selections=selections
+                )
                 self.last_breakdown.update(self._fleet.last_breakdown)
                 if len(fast_idx) == len(problems):
                     # all rows rode the fleet: hand back the lazy
@@ -1794,9 +1820,7 @@ class TensorScheduler:
                     )
                     self._batch_gen = self._snapshot_gen
                     self._batch_cache = (fp, fc)
-                    self._batch_spread = any(
-                        getattr(cp, "derived", False) for cp in fc
-                    )
+                    self._batch_spread = selections is not None
                     self._batch_token = self.snapshot.mask_token
                     return fast_res
                 results: list = [None] * len(problems)
@@ -1833,72 +1857,80 @@ class TensorScheduler:
             tokens.append(probe() if probe is not None else None)
         return tuple(tokens)
 
-    #: cap on interned selection variants; selection outcomes are memoized
-    #: by row content so real fleets produce few — the cap only bounds
-    #: adversarial churn
-    SELECTION_CACHE_CAP = 8192
-
-    def _derive_spread_selections(
+    def _select_spread_rows(
         self,
         problems: Sequence[BindingProblem],
         compiled: list[CompiledPlacement],
-    ) -> list[CompiledPlacement]:
-        """Replace each single-term spread-constraint row's compiled
-        placement with a DERIVED one whose affinity term IS the selected
-        candidate set (select_clusters.go's SelectClusters stage folded
-        into placement compilation). Selection runs on host exactly as the
-        general path's Select stage does (same code, same memoization);
-        the interned result makes the row fleet-eligible, so spread
-        workloads get the device-resident delta-fetch path. Rows the
-        selection REJECTS (FitError) keep their original placement and
-        fall through to the host path, which reports the failure.
+    ) -> tuple:
+        """The Select stage (select_clusters.go's SelectClusters) for the
+        single-term spread-constrained rows of a batch. Returns
+        ``(idx, bits)``: the rows the selection ACCEPTED, ascending, and
+        each one's selected set as a packed mask (uint8[k, ceil(C/8)],
+        little bit order): the fleet table's row state, which it ANDs
+        into the row's feasibility, so those rows are fleet-eligible and
+        get the device-resident delta-fetch path. Selection runs on host
+        exactly as the general path's Select stage does (same code, same
+        memoization). Rows the selection REJECTS (FitError) are left out
+        and fall through to the host path, which reports the failure. So
+        does every such row with extra estimators on: the selection ranks
+        groups on the general estimate alone, and the host path's Select
+        stage sees the merged availability.
 
-        Steady-state cost: selections are pure in (snapshot generation,
-        placement, replicas/requests/prev), so a per-binding-key cache
-        skips the whole packing+selection stage for unchanged rows, and
+        A selection is pure in (snapshot generation, placement,
+        replicas/requests/prev), so a per-binding-key cache answers the
+        unchanged rows of a generation without packing or selecting, and
         availability rows come from a per-profile cache (one device fetch
-        per NEW profile per snapshot generation)."""
+        per NEW profile per snapshot generation). A moved generation
+        re-selects every row: what the selection ranks on moved."""
+        import time as _time
+
+        from ..utils.metrics import spread_selections
+        from ..utils.tracing import tracer
         from .spread import select_clusters_batch
 
         # cheap predicate: fleet_single_term is precomputed per compiled
         # placement; a single-term cp that is NOT fleet-eligible is exactly
         # a spread-constrained one (the ignore rule is folded in)
-        spread_idx = [
+        snap = self.snapshot
+        w8 = (snap.num_clusters + 7) // 8
+        spread_idx = [] if self.extra_estimators else [
             i
             for i, cp in enumerate(compiled)
             if len(cp.terms) == 1 and not cp.fleet_single_term
         ]
         if not spread_idx:
-            return compiled
-        compiled = list(compiled)
-        snap = self.snapshot
+            return np.empty(0, np.int64), np.empty((0, w8), np.uint8)
+        t_start = _time.perf_counter()
         gen = self._snapshot_gen
-        cache = self._selection_cache
-        row_cache = self._derived_rows
-        pending: list[int] = []
+        cache = self._row_selections
+        picked: list[int] = []
+        bits: list[bytes] = []
+        pending: list[tuple] = []
+        hits = fit_errors = failed = moved = 0
         for i in spread_idx:
             p = problems[i]
             fp = (
                 gen, id(p.placement), p.replicas,
                 tuple(p.requests.items()), tuple(p.prev.items()),
             )
-            hit = row_cache.get(p.key)
+            hit = cache.get(p.key)
             # hit[1] pins the Placement whose id() the fingerprint embeds:
             # without it a GC'd placement re-allocated at the same address
-            # would alias a stale derived selection (same hazard the
-            # _selection_cache pins its base against)
+            # would alias a stale selection
             if hit is not None and hit[0] == fp and hit[1] is p.placement:
-                if hit[2] is not None:
-                    compiled[i] = hit[2]
-                continue  # None = cached FitError: stay on the host path
-            pending.append(i)
-        if not pending:
-            return compiled
+                hits += 1
+                if hit[2] is None:
+                    fit_errors += 1  # cached FitError: stays on the host path
+                else:
+                    picked.append(i)
+                    bits.append(hit[2])
+                continue
+            pending.append((i, fp))
 
         for start in range(0, len(pending), self.chunk_size):
-            idx = pending[start : start + self.chunk_size]
-            sub_p = [problems[i] for i in idx]
-            sub_c = [compiled[i] for i in idx]
+            part = pending[start : start + self.chunk_size]
+            sub_p = [problems[i] for i, _ in part]
+            sub_c = [compiled[i] for i, _ in part]
             feasible, _strat, replicas, _sw, requests, prev, _fr = (
                 self._pack_chunk(sub_p, sub_c, 0)
             )
@@ -1915,48 +1947,40 @@ class TensorScheduler:
             candidates = select_clusters_batch(
                 snap, sub_p, sub_c, 0, feasible, avail, prev
             )
-            for k, i in enumerate(idx):
+            packed = np.packbits(candidates, axis=1, bitorder="little")
+            chosen = candidates.any(axis=1)
+            for k, (i, fp) in enumerate(part):
                 p = problems[i]
-                fp = (
-                    gen, id(p.placement), p.replicas,
-                    tuple(p.requests.items()), tuple(p.prev.items()),
-                )
-                sel = candidates[k]
-                if not sel.any():
-                    # FitError: host reports (placement pinned, see lookup)
-                    row_cache[p.key] = (fp, p.placement, None)
-                    continue
-                base = compiled[i]
-                key = (id(base), sel.tobytes())
-                entry = cache.get(key)
-                if entry is None:
-                    c = snap.num_clusters
-                    derived = CompiledPlacement(
-                        placement=base.placement,
-                        terms=[(base.terms[0][0], sel.copy())],
-                        # selection already ran on the post-filter set;
-                        # all-true here keeps the fleet's leniency
-                        # re-composition idempotent
-                        taint_ok=np.ones(c, bool),
-                        spread_field_ok=np.ones(c, bool),
-                        strategy=base.strategy,
-                        static_weights=base.static_weights,
-                        spread_constraints=[],
-                        fleet_single_term=True,
-                    )
-                    derived.derived = True  # fleet keys rows on id(derived)
-                    if len(cache) >= self.SELECTION_CACHE_CAP:
-                        cache.clear()
-                    # pin base: the key embeds id(base) — a GC'd base whose
-                    # address is recycled must not alias a cache entry
-                    cache[key] = (derived, base)
+                # None = FitError: the host path reports it
+                row_bits = packed[k].tobytes() if chosen[k] else None
+                old = cache.get(p.key)
+                if old is None or old[2] != row_bits:
+                    moved += 1
+                cache[p.key] = (fp, p.placement, row_bits)
+                if row_bits is None:
+                    failed += 1
                 else:
-                    derived = entry[0]
-                compiled[i] = derived
-                row_cache[p.key] = (fp, p.placement, derived)
-        if len(row_cache) > 4 * max(len(problems), 1) + 65536:
-            row_cache.clear()  # key-churn bound; repopulates next pass
-        return compiled
+                    picked.append(i)
+                    bits.append(row_bits)
+        if len(cache) > 4 * max(len(problems), 1) + 65536:
+            cache.clear()  # key-churn bound; repopulates next pass
+
+        if hits:
+            spread_selections.inc(hits, outcome="hit")
+        if len(pending) - failed:
+            spread_selections.inc(len(pending) - failed, outcome="computed")
+        if failed:
+            spread_selections.inc(failed, outcome="fit_error")
+        tracer.record(
+            "scheduler.select", _time.perf_counter() - t_start,
+            start=t_start, rows=len(spread_idx), hits=hits,
+            computed=len(pending), fit_errors=fit_errors + failed,
+            moved=moved,
+        )
+        idx = np.asarray(picked, np.int64)
+        masks = np.frombuffer(b"".join(bits), np.uint8).reshape(-1, w8)
+        order = np.argsort(idx)
+        return idx[order], masks[order]
 
     def _selection_availability(
         self, requests: np.ndarray, replicas: np.ndarray, gen: int

@@ -56,15 +56,20 @@ decode 9 ms):
   shifts (_compact_rows: 1.7 ms for 6.55M slots, the scatter 30.2, a
   gather by row offsets 20-21 alone);
 - feasible bitsets ride a second, lazily-dispatched kernel (_fleet_bits)
-  only when the batch contains Duplicated or zero-replica bindings.
+  only when the batch contains Duplicated or zero-replica bindings;
+- a spread-constrained row's SelectClusters result is ROW STATE: one packed
+  selection mask a row (``sel_bits``, ceil(C/8) bytes, all ones for a row
+  without constraints), uploaded with the rows whose selection moved and
+  ANDed into the candidate mask in _row_masks. The placement table holds
+  the placements users wrote, however long the federation runs.
 
 Eligibility: a binding rides the fleet path when its placement has a single
-affinity term, no spread-constraint selection (or the static-weight ignore
-rule, select_clusters.go:63-78), no eviction tasks, <= K_PREV previous
-sites, and (for Divided strategies) replicas <= MAX_REPLICAS_FAST so the
-per-row entry-vector bound holds. Everything else takes the general host
-path — the two paths are differentially fuzz-tested for identical
-placements.
+affinity term, a spread-constraint selection the engine's Select stage
+accepted (or no constraints, or the static-weight ignore rule,
+select_clusters.go:63-78), no eviction tasks, <= K_PREV previous sites, and
+(for Divided strategies) replicas <= MAX_REPLICAS_FAST so the per-row
+entry-vector bound holds. Everything else takes the general host path — the
+two paths are differentially fuzz-tested for identical placements.
 """
 
 from __future__ import annotations
@@ -113,9 +118,9 @@ MAX_SLOTS = 8192  # unique placements/gvks/profiles FLOOR before slot
 # 21 KB at C=5000; plain row gathers make the per-pass cost independent
 # of U. The EFFECTIVE cap scales with the cluster count up to
 # CP_TABLE_MAX_BYTES (a 5k-cluster fleet carries the MAX_SLOTS_HARD
-# 65536 uniques within ~1.4 GB), and crossing 3/4 of it first evicts
-# slots no live row references — only a fleet whose LIVE rows reference
-# more uniques than the budget allows falls back to a rebuild per call.
+# 65536 uniques within ~1.4 GB), and crossing it first evicts slots no
+# live row references — only a fleet whose LIVE rows reference more
+# uniques than the budget allows falls back to a rebuild per call.
 CP_TABLE_MAX_BYTES = 1536 << 20  # device cp-table budget (HBM)
 MAX_SLOTS_HARD = 65536  # interning-dict / host-staging sanity bound
 E_ROUND = 1 << 18  # entry-buffer quantum (bounds trace churn)
@@ -234,7 +239,7 @@ def _unpack_bits(bits_u8, c: int):
 
 
 def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-               pcc, vc, chunk: int, c: int):
+               pcc, vc, sbc, chunk: int, c: int):
     """Per-chunk previous-assignment grid + THE feasibility algebra,
     shared by every kernel that needs it (_fleet_pass, _fleet_bits) so
     the mask expression cannot drift between the solve and the
@@ -245,7 +250,9 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
     byte): the per-row cp gather was the second-largest term of the 1M
     steady pass (60 KB/row as int32 planes -> 21 KB packed+static,
     measured 0.57 s -> ~0.2 s over 245 chunks), and the slot table's HBM
-    footprint drops ~3x with it."""
+    footprint drops ~3x with it. ``sbc`` uint8[chunk, W8] is each row's
+    own selection mask, packed the same way: the SelectClusters result of
+    a spread-constrained row, all ones for every other row."""
     with jax.named_scope("fleet.prev"):
         # compare-and-sum, not a scatter-add: the K_PREV (site, count)
         # pairs of a row against the cluster iota, summed over the pairs.
@@ -274,6 +281,7 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
         aff_ok
         & (gvk_ok | (prev_mask & incomplete_en[None, :]))
         & (taint_ok | prev_mask)  # taints (leniency)
+        & _unpack_bits(sbc, c)  # the row's spread selection
         & vc[:, None]
     )
     return prev, static_w, feasible
@@ -345,6 +353,7 @@ def _fleet_pass(
     replicas, strategy,  # int32[cap]
     fresh,  # bool[cap]
     prev_sites, prev_counts,  # int32[cap, K_PREV]
+    sel_bits,  # uint8[cap, W8] bitpacked spread selection a row (ones = none)
     res_dense,  # uint8[cap, C] last pass's dense assignment (donated)
     res_meta,  # int32[cap] last pass's meta words (donated)
     *,
@@ -390,6 +399,10 @@ def _fleet_pass(
         fr = fresh[r] & valid
         ps = prev_sites[r]
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
+        # an all-rows pass reads row i at position i (the padding past the
+        # last row is masked by ``valid``), so the selection masks are
+        # sliced from the resident as they lie: no gather
+        sb = sel_bits if all_rows else sel_bits[r]
 
     def body(carry, i):
         rd, rm = carry
@@ -399,7 +412,7 @@ def _fleet_pass(
             )
             cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
             repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-            psc, pcc = sl(ps), sl(pc)
+            psc, pcc, sbc = sl(ps), sl(pc), sl(sb)
             rc = sl(r)
             repsc, stc, frc, vc = (
                 shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
@@ -409,10 +422,11 @@ def _fleet_pass(
                 shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
             )
             psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
+            sbc = shard(sbc, "b", None)
         with jax.named_scope("fleet.masks"):
             prev, static_w, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, vc, chunk, c,
+                pcc, vc, sbc, chunk, c,
             )
             prev = shard(prev, "b", c_ax)
             feasible = shard(feasible, "b", c_ax)
@@ -646,7 +660,7 @@ def _decode_entry_wire(raw2, cap_used: int, byte_wire: bool, pack21: bool):
 def _fleet_bits(
     cp_bits, cp_static, gvk_bits, prof_table, incomplete_en, rows,
     cp_idx, gvk_idx, prof_idx, replicas, strategy, fresh,
-    prev_sites, prev_counts, *, chunk: int, n_chunks: int,
+    prev_sites, prev_counts, sel_bits, *, chunk: int, n_chunks: int,
 ):
     """Feasibility bitsets as their own lazily-DISPATCHED kernel: only
     Duplicated / zero-replica rows ever read them (their result IS the
@@ -657,29 +671,33 @@ def _fleet_bits(
     arrays are immutable, so a batch holding these refs stays consistent
     even after later passes rebuild the live tables)."""
     c = cp_static.shape[1]
-    valid = rows >= 0
-    r = jnp.maximum(rows, 0)
-    cp = cp_idx[r]
-    gv = gvk_idx[r]
-    ps = prev_sites[r]
-    pc = jnp.where(valid[:, None], prev_counts[r], 0)
+    with jax.named_scope("fleet.bits"):
+        valid = rows >= 0
+        r = jnp.maximum(rows, 0)
+        cp = cp_idx[r]
+        gv = gvk_idx[r]
+        ps = prev_sites[r]
+        pc = jnp.where(valid[:, None], prev_counts[r], 0)
+        sb = sel_bits[r]
 
-    def body(carry, i):
-        sl = lambda a: lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=0)
-        cpc, gvc, vc = sl(cp), sl(gv), sl(valid)
-        psc, pcc = sl(ps), sl(pc)
-        _, _, feasible = _row_masks(
-            cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc, pcc, vc,
-            chunk, c,
-        )
-        pad = (-c) % 32
-        f = jnp.pad(feasible, ((0, 0), (0, pad)))
-        w32 = f.reshape(chunk, -1, 32).astype(jnp.uint32)
-        shifts = jnp.arange(32, dtype=jnp.uint32)[None, None, :]
-        return carry, (w32 << shifts).sum(axis=-1, dtype=jnp.uint32)
+        def body(carry, i):
+            sl = lambda a: lax.dynamic_slice_in_dim(
+                a, i * chunk, chunk, axis=0
+            )
+            cpc, gvc, vc = sl(cp), sl(gv), sl(valid)
+            psc, pcc, sbc = sl(ps), sl(pc), sl(sb)
+            _, _, feasible = _row_masks(
+                cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
+                pcc, vc, sbc, chunk, c,
+            )
+            pad = (-c) % 32
+            f = jnp.pad(feasible, ((0, 0), (0, pad)))
+            w32 = f.reshape(chunk, -1, 32).astype(jnp.uint32)
+            shifts = jnp.arange(32, dtype=jnp.uint32)[None, None, :]
+            return carry, (w32 << shifts).sum(axis=-1, dtype=jnp.uint32)
 
-    _, out = lax.scan(body, 0, jnp.arange(n_chunks))
-    return out.reshape(-1, out.shape[-1])
+        _, out = lax.scan(body, 0, jnp.arange(n_chunks))
+        return out.reshape(-1, out.shape[-1])
 
 
 @jax.jit
@@ -815,18 +833,39 @@ class _FleetBatch:
             )
         return self.host_entries[self.rows[pos]]
 
+    def _fetch_bits(self) -> None:
+        """The lazy feasibility-bitset pass, as a phase of its own: the
+        ``_fleet_bits`` dispatch over this pass's captured inputs, the
+        fence, the fetch. One ``kernel.bits`` span at its true interval
+        (it lies outside ``scheduler.solve``: the first reader of a
+        Duplicated or zero-replica row pays it, once a batch)."""
+        from ..utils.metrics import kernel_phase_seconds
+        from ..utils.tracing import tracer
+
+        t0 = time.perf_counter()
+        bits_dev = (
+            self._bits_dev() if callable(self._bits_dev) else self._bits_dev
+        )
+        t1 = time.perf_counter()
+        bits_dev.block_until_ready()
+        t2 = time.perf_counter()
+        # force little-endian word layout before the byte view so the
+        # bit positions are host-endianness-independent (the entry
+        # stream is decoded with shifts for the same reason)
+        self._bits_np = np.ascontiguousarray(
+            np.asarray(bits_dev).astype("<u4", copy=False)
+        )
+        dur = time.perf_counter() - t0
+        tracer.record(
+            "kernel.bits", dur, start=t0, rows=len(self.rows),
+            fetch_mb=self._bits_np.nbytes / 1e6,
+            dispatch_s=t1 - t0, device_s=t2 - t1,
+        )
+        kernel_phase_seconds.observe(dur, phase="bits")
+
     def feasible_names(self, pos: int) -> tuple:
         if self._bits_np is None:
-            bits_dev = (
-                self._bits_dev() if callable(self._bits_dev)
-                else self._bits_dev
-            )
-            # force little-endian word layout before the byte view so the
-            # bit positions are host-endianness-independent (the entry
-            # stream is decoded with shifts for the same reason)
-            self._bits_np = np.ascontiguousarray(
-                np.asarray(bits_dev).astype("<u4", copy=False)
-            )
+            self._fetch_bits()
         row = self._bits_np[pos]
         idx = np.nonzero(
             np.unpackbits(row.view(np.uint8), bitorder="little")
@@ -968,7 +1007,7 @@ class _FleetResultList:
 
 _STATE_FIELDS = (
     "cp_idx", "gvk_idx", "prof_idx", "replicas", "strategy", "fresh",
-    "prev_sites", "prev_counts",
+    "prev_sites", "prev_counts", "sel_bits",
 )
 
 
@@ -1132,6 +1171,9 @@ class FleetTable:
         # rows (re)packed by the current pass (_pack_row increments):
         # the packed-vs-replayed split the history ring records per wave
         self._packed_this_pass = 0
+        # placement slots added since the last publish (_pack_row
+        # increments; schedule() counts them and stamps its span)
+        self._slots_minted_this_pass = 0
         # host->device bytes of the current pass (state upload/scatter +
         # row indices), reset by _sync_device; surfaces as upload_mb
         self._last_upload_bytes = 0
@@ -1309,6 +1351,9 @@ class FleetTable:
             "fresh": np.zeros(new_cap, bool),
             "prev_sites": np.zeros((new_cap, K_PREV), np.int32),
             "prev_counts": np.zeros((new_cap, K_PREV), np.int32),
+            # a row's spread selection, bitpacked as the cp planes are;
+            # all ones = no selection narrows this row
+            "sel_bits": np.full((new_cap, (c + 7) // 8), 0xFF, np.uint8),
         }
         for k, a in self._st.items():
             st[k][: self.cap] = a
@@ -1319,17 +1364,13 @@ class FleetTable:
         self._reuse = None
 
     @staticmethod
-    def _fingerprint(p, compiled) -> tuple:
-        # DERIVED placements (spread selections interned by core.schedule)
-        # carry their candidate set in the compiled object, so the row must
-        # re-pack whenever the derived object changes — its identity IS the
-        # selection content (interned per (base, mask)). Plain placements
-        # key on the Placement object: their compiled masks recompile IN
-        # PLACE at the same slot on snapshot swaps.
+    def _fingerprint(p) -> tuple:
+        # rows key on the Placement object: its compiled masks recompile IN
+        # PLACE at the same slot on snapshot swaps. A spread selection is
+        # not part of the fingerprint: it is row state of its own
+        # (_apply_selections), uploaded when it moves.
         return (
-            id(p.placement),
-            id(compiled) if getattr(compiled, "derived", False) else None,
-            p.replicas, p.gvk, p.fresh,
+            id(p.placement), p.replicas, p.gvk, p.fresh,
             tuple(p.requests.items()), tuple(p.prev.items()),
         )
 
@@ -1337,14 +1378,10 @@ class FleetTable:
         row = self._key_row.get(problem.key)
         if row is not None:
             self._row_last_used[row] = self._pass
-            # O(1) fast path: same problem object AND same compiled
-            # identity class (the stored fingerprint's derived-id element
-            # pins derived selections; None for plain placements)
-            if self._problems[row] is problem and self._fps[row][1] == (
-                id(compiled) if getattr(compiled, "derived", False) else None
-            ):
+            # O(1) fast path: the same problem object
+            if self._problems[row] is problem:
                 return row
-            fp = self._fingerprint(problem, compiled)
+            fp = self._fingerprint(problem)
             if fp == self._fps[row]:
                 self._problems[row] = problem
                 return row
@@ -1371,6 +1408,7 @@ class FleetTable:
             slot = len(self._cp_pl)
             self._cp_slot[id(compiled)] = slot
             self._cp_pl.append((problem.placement, compiled))
+            self._slots_minted_this_pass += 1
             self._static_max = max(
                 self._static_max, int(compiled.static_weights.max(initial=0))
             )
@@ -1433,28 +1471,24 @@ class FleetTable:
                 k += 1
         st["prev_sites"][row] = sites
         st["prev_counts"][row] = cnts
-        self._fps[row] = self._fingerprint(problem, compiled)
+        # a (re)packed row starts unselected; the pass's selections, if
+        # the row has one, land after the upserts (_apply_selections)
+        st["sel_bits"][row] = 0xFF
+        self._fps[row] = self._fingerprint(problem)
         self._terms[row] = compiled.terms[0][0]
         self._dirty.add(row)
 
-    def _compact_slots(self, aggressive: bool = False) -> None:
-        """Drop placement slots no live row references. The cheap sweep
-        drops DERIVED slots only (selection drift interns new variants
-        every availability change); ``aggressive`` (under cap pressure)
-        also drops unreferenced PLAIN slots — create/delete churn over a
-        heterogeneous fleet retires placements whose rows compaction
-        already reclaimed, and re-interning a returning placement is one
-        cached compile + one slot append. Triggers a full table rebuild +
-        state re-upload, so it runs only under pressure."""
+    def _compact_slots(self) -> None:
+        """Drop placement slots no live row references: create/delete
+        churn over a heterogeneous fleet retires placements whose rows
+        compaction already reclaimed, and re-interning a returning
+        placement is one cached compile + one slot append. Triggers a full
+        table rebuild + state re-upload, so it runs only under cap
+        pressure (slots_exhausted)."""
         used = set(
             int(s) for s in np.unique(self._st["cp_idx"][: self.n_rows])
         )
-        keep = [
-            i
-            for i, (pl, cp) in enumerate(self._cp_pl)
-            if i in used
-            or (not aggressive and not getattr(cp, "derived", False))
-        ]
+        keep = [i for i in range(len(self._cp_pl)) if i in used]
         if len(keep) == len(self._cp_pl):
             return
         remap = np.full(len(self._cp_pl), -1, np.int32)
@@ -1497,15 +1531,13 @@ class FleetTable:
     @property
     def slots_exhausted(self) -> bool:
         mx = self._max_slots()
-        if len(self._cp_pl) > mx * 3 // 4:
-            self._compact_slots()
         if len(self._cp_pl) > mx:
             # retired placements stay pinned by their AGED rows: reclaim
             # idle rows first, then sweep every unreferenced slot — a
             # generational churn workload (new unique placements per wave)
             # keeps one table alive instead of rebuilding per call
             self._compact()
-            self._compact_slots(aggressive=True)
+            self._compact_slots()
         return (
             len(self._cp_pl) > mx
             or len(self._gvk_list) > mx
@@ -1541,20 +1573,12 @@ class FleetTable:
         elif gen != self._snapshot_gen:
             # snapshot swapped in place (same cluster set): recompile each
             # slot's placement against the new snapshot, order-preserving so
-            # row cp_idx values stay valid. DERIVED slots (interned spread
-            # selections) are NOT recompiled — their mask IS the selection
-            # content owned by core's selection cache; re-derivation happens
-            # upstream per pass, landing changed selections in NEW slots via
-            # the id(derived)-keyed row fingerprints. Recompiling them here
-            # would overwrite the selection with the base affinity mask.
+            # row cp_idx values stay valid
             self._snapshot_gen = gen
             self._cp_slot.clear()
             self._static_max = 0
-            for i, (pl, cp_old) in enumerate(self._cp_pl):
-                if getattr(cp_old, "derived", False):
-                    cp = cp_old
-                else:
-                    cp = self.engine._compiled(pl)
+            for i, (pl, _) in enumerate(self._cp_pl):
+                cp = self.engine._compiled(pl)
                 self._cp_pl[i] = (pl, cp)
                 self._cp_slot[id(cp)] = i
                 self._static_max = max(
@@ -1835,7 +1859,8 @@ class FleetTable:
     # -- scheduling --------------------------------------------------------
 
     def schedule(
-        self, problems: Sequence, compiled: Sequence, delta=None
+        self, problems: Sequence, compiled: Sequence, delta=None,
+        selections=None,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -1853,20 +1878,52 @@ class FleetTable:
         table can prove its resident mirrors still cover the untouched
         rows, only the delta positions are packed and dispatched and the
         rest replay from the mirrors; otherwise the pass silently runs
-        full."""
+        full.
+
+        ``selections`` (optional) is ``(positions, bits)``: the spread-
+        constrained rows of ``problems`` and each one's SelectClusters
+        result under the current snapshot, bitpacked (uint8[k, W8], little
+        bit order). A selection is row state: it stays with the row until
+        a later pass brings another, so a pass over the same batch at the
+        same snapshot generation need not bring any."""
+        from ..utils.metrics import fleet_placement_slots, fleet_slots_minted
         from ..utils.tracing import tracer
 
         with tracer.span("scheduler.solve") as sp:
             self._phase_marks = []
-            res = self._schedule_pass(problems, compiled, delta)
+            res = self._schedule_pass(problems, compiled, delta, selections)
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
             sp.attrs["rows_packed"] = int(tmr.get("rows_packed", 0))
             sp.attrs["rows_replayed"] = int(tmr.get("rows_replayed", 0))
             sp.attrs["dirty_rows"] = int(tmr.get("dirty_rows", 0))
+            minted, self._slots_minted_this_pass = (
+                self._slots_minted_this_pass, 0
+            )
+            sp.attrs["slots"] = len(self._cp_pl)
+            sp.attrs["slots_minted"] = minted
             self._emit_phase_spans()
+        if minted:
+            fleet_slots_minted.inc(minted)
+        fleet_placement_slots.set(len(self._cp_pl))
         self._publish_device_bytes()
         return res
+
+    def _apply_selections(self, rows_np: np.ndarray, selections) -> int:
+        """Write the pass's spread selections into the row state and mark
+        the rows whose selection moved for upload. Returns how many moved.
+        Rows the pass brings no selection for keep theirs (all ones since
+        they were packed, for a row without constraints)."""
+        pos, bits = selections
+        if not len(pos):
+            return 0
+        rows = rows_np[pos]
+        st = self._st["sel_bits"]
+        moved = (st[rows] != bits).any(axis=1)
+        if moved.any():
+            st[rows[moved]] = bits[moved]
+            self._dirty.update(rows[moved].tolist())
+        return int(moved.sum())
 
     def device_bytes(self) -> dict[str, int]:
         """Resident device bytes by ledger kind — the EXACT ``nbytes`` of
@@ -1995,7 +2052,8 @@ class FleetTable:
             kernel_phase_seconds.observe(total, phase=name.split(".")[1])
 
     def _schedule_pass(
-        self, problems: Sequence, compiled: Sequence, delta=None
+        self, problems: Sequence, compiled: Sequence, delta=None,
+        selections=None,
     ) -> list:
         if delta is not None:
             res = self._schedule_delta(problems, compiled, delta)
@@ -2036,6 +2094,8 @@ class FleetTable:
             )
             self._reuse = (problems, compiled, rows_np)
             self._reuse_pass = self._pass
+        if selections is not None:
+            tmr["sel_moved"] = self._apply_selections(rows_np, selections)
         t0 = self._phase(tmr, "upsert", t0)
         # packed-vs-replayed split of THIS pass: a replayed row rode its
         # fingerprint (or the batch-identity fast path) without re-packing
@@ -2096,46 +2156,7 @@ class FleetTable:
         need_bits = bool(is_dup.any() or (reps_sel == 0).any())
         bits_src = None
         if need_bits:
-            # lazy feasibility bitsets: capture the PASS-TIME device
-            # arrays (immutable) so a consumer decoding a Duplicated
-            # result later gets this pass's sets even if the live tables
-            # have since been rebuilt. Dispatched at most once per batch,
-            # on first feasible/cluster access.
-            _tables = self._dev_tables
-            _state = self._dev_state
-            _rows = rows_dev
-            _chunk, _n_chunks = eff_chunk, n_chunks
-
-            def bits_src():
-                # the signature must carry every shape the trace closes
-                # over: the cp-table capacity (slot growth re-traces), the
-                # rows-buffer length, and the state cap — the old
-                # (chunk, n_chunks)-only key let a slot-table growth mint
-                # a new XLA trace that new_trace_last_pass never reported
-                from ..parallel.mesh import mesh_shape as _bits_mesh_shape
-
-                key = (
-                    "B", _chunk, _n_chunks, _tables[0].shape,
-                    int(_rows.shape[0]), int(_state[0].shape[0]),
-                    # canonical mesh shape: the bits inputs commit to the
-                    # mesh (replicated), so each shape is a distinct
-                    # executable — a bool here let a mesh=2 manifest
-                    # fake-warm a mesh=8 boot
-                    _bits_mesh_shape(self._mesh),
-                )
-                if self._mark_trace(*key) and self._mesh is None:
-                    # meshed dispatches stay manifest-UNRECORDED: the
-                    # kernel has no mesh static, so a replay could only
-                    # compile the single-device form and would seed this
-                    # key as falsely warmed (see _quota_admission)
-                    self._record_trace(
-                        "fleet_bits", key, (*_tables, _rows, *_state),
-                        chunk=_chunk, n_chunks=_n_chunks,
-                    )
-                return _fleet_bits(
-                    *_tables, _rows, *_state, chunk=_chunk,
-                    n_chunks=_n_chunks,
-                )
+            bits_src = self._bits_src(lambda: rows_dev, eff_chunk, n_chunks)
         # table-validated mesh (see __init__): the row axis shards over
         # "b" on every pass — batches are padded to the pow2 chunk, so
         # the mesh-divisible bucket holds by construction. The cluster
@@ -2294,7 +2315,16 @@ class FleetTable:
         n_pad = max(eff_chunk, -(-n // eff_chunk) * eff_chunk)
         bits_src = None
         if need_bits:
-            bits_src = self._bits_full_src(rows_full, n, n_pad, eff_chunk)
+            # over the FULL reuse rows (a replayed Duplicated row's consumer
+            # needs the whole batch's bitsets, not the dirty sub-batch's);
+            # the row-index upload waits for the first access: most delta
+            # batches never decode a Duplicated row
+            def rows_dev():
+                ar = np.full(n_pad, -1, np.int32)
+                ar[:n] = rows_full
+                return jnp.asarray(ar)
+
+            bits_src = self._bits_src(rows_dev, eff_chunk, n_pad // eff_chunk)
         self._result_gen += 1
         names = self.engine.snapshot.names
         batches = [
@@ -2309,36 +2339,43 @@ class FleetTable:
             has_cand, is_dup,
         )
 
-    def _bits_full_src(self, rows_full, n, n_pad, eff_chunk):
-        """Lazy feasibility-bitset thunk over the FULL reuse rows — the
-        delta-pass counterpart of the inline bits closure in
-        _schedule_pass (a replayed Duplicated row's consumer needs the
-        whole batch's bitsets, not just the dirty sub-batch's). Dispatch
-        + row-index upload are deferred to first access: most delta
-        batches never decode a Duplicated row."""
+    def _bits_src(self, rows_dev, chunk: int, n_chunks: int):
+        """Lazy feasibility-bitset thunk of a batch: it captures the
+        PASS-TIME device arrays (immutable), so a consumer decoding a
+        Duplicated result later gets this pass's sets even if the live
+        tables have since been rebuilt. ``rows_dev`` is itself a thunk
+        (the row indices on the device). Dispatched at most once per
+        batch, on the first feasible/cluster access (_FleetBatch)."""
         _tables = self._dev_tables
         _state = self._dev_state
-        n_chunks = n_pad // eff_chunk
 
         def bits_src():
             from ..parallel.mesh import mesh_shape as _bits_mesh_shape
 
-            ar = np.full(n_pad, -1, np.int32)
-            ar[:n] = rows_full
-            rows_dev = jnp.asarray(ar)
+            rows = rows_dev()
+            # the signature must carry every shape the trace closes over:
+            # the cp-table capacity (slot growth re-traces), the
+            # rows-buffer length, and the state cap
             key = (
-                "B", eff_chunk, n_chunks, _tables[0].shape,
-                int(rows_dev.shape[0]), int(_state[0].shape[0]),
+                "B", chunk, n_chunks, _tables[0].shape,
+                int(rows.shape[0]), int(_state[0].shape[0]),
+                # canonical mesh shape: the bits inputs commit to the
+                # mesh (replicated), so each shape is a distinct
+                # executable — a bool here let a mesh=2 manifest
+                # fake-warm a mesh=8 boot
                 _bits_mesh_shape(self._mesh),
             )
             if self._mark_trace(*key) and self._mesh is None:
+                # meshed dispatches stay manifest-UNRECORDED: the kernel
+                # has no mesh static, so a replay could only compile the
+                # single-device form and would seed this key as falsely
+                # warmed (see _quota_admission)
                 self._record_trace(
-                    "fleet_bits", key, (*_tables, rows_dev, *_state),
-                    chunk=eff_chunk, n_chunks=n_chunks,
+                    "fleet_bits", key, (*_tables, rows, *_state),
+                    chunk=chunk, n_chunks=n_chunks,
                 )
             return _fleet_bits(
-                *_tables, rows_dev, *_state, chunk=eff_chunk,
-                n_chunks=n_chunks,
+                *_tables, rows, *_state, chunk=chunk, n_chunks=n_chunks,
             )
 
         return bits_src

@@ -438,6 +438,31 @@ kernel_phase_seconds = registry.histogram(
     "sync/decode), dispatch, device (fenced execute, compile included "
     "when the pass minted a fresh trace), fetch",
 )
+spread_selections = registry.counter(
+    "karmada_tpu_spread_selections_total",
+    "spread-constrained rows seen by the engine's Select stage, by "
+    "outcome: hit (answered from the row cache: same row content, same "
+    "snapshot generation), computed (SelectClusters ran on the host), "
+    "fit_error (the constraints cannot be met; the row leaves the fleet "
+    "path and the host path reports it)",
+)
+fleet_placement_slots = registry.gauge(
+    "karmada_tpu_fleet_placement_slots",
+    "placement slots the fleet table holds (one a user placement; a "
+    "spread selection is row state and takes none), set after every pass",
+)
+fleet_slots_minted = registry.counter(
+    "karmada_tpu_fleet_slots_minted_total",
+    "placement slots added to a fleet table (a placement's first row, or "
+    "every live placement again after a table rebuild); flat once each "
+    "user placement has its slot",
+)
+fleet_table_rebuilds = registry.counter(
+    "karmada_tpu_fleet_table_rebuilds_total",
+    "fleet tables dropped and rebuilt because their LIVE rows reference "
+    "more placements, GVKs or request profiles than the slot budget "
+    "holds (a full repack and re-upload each)",
+)
 estimator_rpcs = registry.counter(
     "karmada_tpu_estimator_rpcs_total",
     "scheduler-side estimator wire traffic by kind (batch matrix RPCs, "

@@ -97,6 +97,11 @@ SPAN_NAMES: dict[str, str] = {
         "host prologue of a pass: placement compile + spread selection + "
         "eligibility partition"
     ),
+    "scheduler.select": (
+        "under scheduler.pack, only when the batch holds spread-constrained "
+        "rows: the Select stage (SelectClusters on the host; rows / hits / "
+        "computed / fit_errors / moved attrs)"
+    ),
     "scheduler.host": "host-path (non-fleet) scheduling of a batch",
     "scheduler.solve": "one fleet-table solve pass",
     "scheduler.explain": (
@@ -120,6 +125,11 @@ SPAN_NAMES: dict[str, str] = {
         "minted a fresh XLA trace)"
     ),
     "kernel.fetch": "post-device wire transfer + decode + entry folds",
+    "kernel.bits": (
+        "the lazy feasibility-bitset pass of a batch (_fleet_bits), when "
+        "the first Duplicated or zero-replica result is read: dispatch + "
+        "fence + fetch (rows / fetch_mb / dispatch_s / device_s attrs)"
+    ),
     "estimator.refresh": (
         "one estimator-registry refresh: generation pings + grouped "
         "profile fan-out"

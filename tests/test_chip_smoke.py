@@ -113,6 +113,10 @@ def test_engine_stage_tiny():
     assert facts["delta_rows_packed"] == b // 100
     assert facts["delta_rows_replayed"] == b - b // 100
     assert facts["native_fold"] == "loaded"
+    # the mixed-policy batch: 2 generations x 512 rows against refimpl
+    # (divider_np + spread), one slot a placement on both
+    assert facts["policy_rows_checked"] == 1024
+    assert facts["policy_slots"] == chip_smoke.N_POLICIES
 
 
 def test_kernels_stage_tiny():

@@ -59,6 +59,7 @@ def _row_masks_args(chunk: int, c: int, sites, counts):
         jnp.asarray(sites),
         jnp.asarray(counts),
         jnp.ones((chunk,), bool),  # vc
+        jnp.full((chunk, w8), 0xFF, jnp.uint8),  # sbc: no row selected down
     )
 
 
@@ -80,7 +81,7 @@ def test_row_masks_prev_equals_host_scatter_add(chunk, c, duplicates):
 def test_row_masks_lowers_to_no_scatter():
     chunk, c = 256, 100
     sites, counts = _prev_pairs(chunk, c, True, seed=1)
-    lowered = jax.jit(fleet_mod._row_masks, static_argnums=(9, 10)).lower(
+    lowered = jax.jit(fleet_mod._row_masks, static_argnums=(10, 11)).lower(
         *_row_masks_args(chunk, c, sites, counts), chunk, c
     )
     # without debug info: the locations carry this test's own name

@@ -322,6 +322,7 @@ def _fleet_state(d: dict) -> list:
         [((cap,), "int32")] * 5  # cp_idx gvk_idx prof_idx replicas strategy
         + [((cap,), "bool")]  # fresh
         + [((cap, d["k_prev"]), "int32")] * 2  # prev_sites prev_counts
+        + [((cap, d["w8"]), "uint8")]  # sel_bits
     )
 
 
@@ -400,7 +401,7 @@ def _specs_gather_meta() -> list:
 
 
 def _group_scatter(structs):
-    return tuple(structs[0:8]), structs[8], tuple(structs[9:17])
+    return tuple(structs[0:9]), structs[9], tuple(structs[10:19])
 
 
 def _specs_scatter_rows() -> list:
