@@ -447,6 +447,15 @@ def stage_engine(
             TensorScheduler(gen_snap, mesh=False), pol_idx,
         )
     timings["policy_passes_s"] = time.perf_counter() - t0
+    # the spread rows' Select stage ran on the device (the fleet table's
+    # own kernel) unless the federation holds more regions than its table
+    pol_select = pol_eng._fleet._select_cache
+    pol_device_rows = pol_select.n if pol_select is not None else 0
+    _check(
+        pol_device_rows > 0 or pol_eng._fleet._dev_spread is None,
+        "the mixed-policy batch's spread rows were not selected on the "
+        "device though the snapshot's regions fit the kernel's table",
+    )
     _check(pol_bad == 0, f"{pol_bad}/{2 * len(pol_idx)} mixed-policy rows "
            "differ from refimpl (divider_np + spread)")
     _check(
@@ -468,6 +477,7 @@ def stage_engine(
         "numpy_checked": len(np_idx) + len(dirty) + len(churn_idx),
         "policy_rows_checked": 2 * len(pol_idx),
         "policy_slots": pol_slots[1],
+        "policy_rows_device_selected": pol_device_rows,
         "oracle_checked": len(oracle_idx),
         "mismatches": 0,
         "delta_rows_packed": packed,

@@ -297,8 +297,12 @@ class TensorScheduler:
         self._batch_gen = -1
         self._batch_cache: Optional[tuple] = None
         self._batch_problems: Optional[list] = None
-        self._batch_spread = True  # batch holds spread-selected rows
-        self._batch_token = None  # snapshot.mask_token at cache time
+        # snapshot.mask_token at cache time; None where the batch holds
+        # rows the HOST selected (their selection follows the capacities,
+        # so such a batch is not reused across a generation move)
+        self._batch_token = None
+        # the mask_token the host-selection line was last printed for
+        self._host_select_told = None
         # per-pass dirty-key set (ISSUE 20): the controller's invalidation
         # sources (watch bus, quota bumps, estimator movement, evictions)
         # accumulate binding keys whose problems changed since the last
@@ -310,10 +314,13 @@ class TensorScheduler:
         # dirty keys need resolving against a large wave)
         self._key_pos: Optional[dict] = None
         # binding key -> (row fingerprint, pinned placement, selection
-        # bits | None): a spread row's last SelectClusters result, packed
-        # as the fleet's row state takes it (None = FitError). Skips the
-        # packing+selection stage for unchanged rows at one snapshot
-        # generation; across generations it is what ``moved`` is told by
+        # bits | None): the last SelectClusters result of a spread row the
+        # HOST selected (a snapshot with more regions than the device
+        # kernel's table, or a row the explain capture asked about),
+        # packed as the fleet's row state takes it (None = FitError).
+        # Skips the packing+selection stage for unchanged rows at one
+        # snapshot generation; across generations it is what ``moved`` is
+        # told by
         self._row_selections: dict = {}
         # batched solves dispatched (host chunks + fleet passes): the
         # chaos bench reads this to prove a failover wave reschedules its
@@ -384,6 +391,9 @@ class TensorScheduler:
         cp.fleet_single_term = len(cp.terms) == 1 and (
             not cp.spread_constraints
             or should_ignore_spread_constraint(cp.placement or Placement())
+        )
+        cp.spread_single_term = (
+            len(cp.terms) == 1 and not cp.fleet_single_term
         )
         self._placement_cache[key] = (placement, cp)
         # the cap must exceed the fleet table's live-slot budget: a live
@@ -1430,9 +1440,16 @@ class TensorScheduler:
             spread_ok = np.ones((b, c), bool)
         else:
             # spread rows with a selection: the Select stage's surviving
-            # set IS the selection mask (placement-pinned row cache)
+            # set IS the selection mask (placement-pinned row cache). Rows
+            # the device selected have no cached one: the capture computes
+            # the host selection for the rows it is asked about
+            spread_rows = [
+                i for i, cp in enumerate(compiled) if cp.spread_single_term
+            ]
+            if spread_rows and not self.extra_estimators:
+                self._select_spread_rows(problems, compiled, spread_rows)
             for i, (p, cp) in enumerate(zip(problems, compiled)):
-                if len(cp.terms) == 1 and not cp.fleet_single_term:
+                if cp.spread_single_term:
                     hit = self._row_selections.get(p.key)
                     if (
                         hit is not None
@@ -1652,9 +1669,9 @@ class TensorScheduler:
         res = self._fleet.schedule(fp2, fc2, delta=diff)
         self.last_breakdown.update(self._fleet.last_breakdown)
         # re-arm the identity token on the swapped lists (gen and mask
-        # token are unchanged by construction; _batch_spread likewise —
-        # swapped-in rows are never spread-constrained, and the rows that
-        # are keep the selection the fleet's row state holds)
+        # token are unchanged by construction; swapped-in rows are never
+        # spread-constrained, and the rows that are keep the selection
+        # the fleet's row state holds)
         self._batch_problems = fp2
         self._batch_ids = ids
         self._batch_cache = (fp2, fc2)
@@ -1666,10 +1683,11 @@ class TensorScheduler:
         import time as _time
 
         # batch-identity fast path: a storm re-scheduling the SAME problem
-        # objects against the SAME snapshot generation is pure in those
-        # inputs — compilation, spread selection, and the eligibility
-        # partition all key on object identity + snapshot gen, so one id()
-        # sweep (~8ms at 100k) replaces the ~55ms host prologue. This is
+        # objects is pure in those inputs — compilation and the
+        # eligibility partition key on object identity + the snapshot's
+        # filter fields, and the spread selection is the fleet table's own
+        # stage (_fleet_select, in every pass), so one id() sweep (~8ms at
+        # 100k) replaces the ~55ms host prologue. This is
         # the vectorized form of the per-row `is problem` fast path the
         # fleet's upsert already takes; like it, it assumes problem objects
         # are not mutated in place between passes.
@@ -1679,13 +1697,11 @@ class TensorScheduler:
                 self._batch_gen == self._snapshot_gen
                 # availability-only drift keeps every compiled mask and the
                 # eligibility partition valid (placements key on filter
-                # fields = mask_token); only SPREAD selections depend on
-                # capacities, so spread-free batches reuse across the swap
-                # — churn passes skip the prologue too
-                or (
-                    not self._batch_spread
-                    and self._batch_token == self.snapshot.mask_token
-                )
+                # fields = mask_token), and the fleet table re-selects its
+                # spread rows on the device in every pass, so batches reuse
+                # across the swap — churn passes skip the prologue too
+                # (the token is None for a batch with host-selected rows)
+                or self._batch_token == self.snapshot.mask_token
             )
             and not (
                 self.custom_filters
@@ -1743,15 +1759,30 @@ class TensorScheduler:
                 from .fleet import K_PREV as _KP, MAX_REPLICAS_FAST as _MRF
 
                 # spread-constraint rows ride the fleet too: their
-                # host-side group selection collapses to a per-row
-                # candidate mask, which the fleet table keeps as ROW
-                # STATE (one packed mask a row, uploaded when it moves)
-                # and ANDs into the row's feasibility, so the
-                # device-resident path divides over exactly the selected
-                # set
+                # selection is ROW STATE of the fleet table (one packed
+                # mask a row, ANDed into the row's feasibility), computed
+                # by the table's own kernel from its resident state
+                # (_fleet_select) wherever the snapshot's regions fit the
+                # kernel's subset table, FitError rows included; past
+                # that the host selects, and the rows it accepts ride
+                # with their masks uploaded. Batches with extra
+                # estimators keep their spread rows off the fleet
+                from .select import regions_fit
+
+                spread_idx = [] if self.extra_estimators else [
+                    i for i, cp in enumerate(compiled)
+                    if cp.spread_single_term
+                ]
+                on_device = bool(spread_idx) and regions_fit(self.snapshot)
+                if spread_idx:
+                    self._report_host_selected(
+                        0 if on_device else len(spread_idx)
+                    )
                 sel_idx, sel_bits = self._select_spread_rows(
-                    problems, compiled
+                    problems, compiled, [] if on_device else spread_idx
                 )
+                if on_device:
+                    sel_idx = np.asarray(spread_idx, np.int64)
                 selected = frozenset(sel_idx.tolist())
                 self.last_breakdown["select"] = _time.perf_counter() - t0
 
@@ -1793,8 +1824,8 @@ class TensorScheduler:
                     self._fleet = FleetTable(self)
                 fp = [problems[i] for i in fast_idx]
                 fc = [compiled[i] for i in fast_idx]
-                selections = None
-                if len(sel_idx):
+                selections = select = None
+                if selected:
                     # the selected rows' positions in the fleet batch (a
                     # selected row another clause sent to the host path
                     # takes its selection there itself)
@@ -1802,10 +1833,13 @@ class TensorScheduler:
                     pos = np.searchsorted(fast_arr, sel_idx)
                     pos = np.minimum(pos, len(fast_arr) - 1)
                     rides = fast_arr[pos] == sel_idx
-                    selections = (pos[rides], sel_bits[rides])
+                    if on_device:
+                        select = pos[rides]
+                    else:
+                        selections = (pos[rides], sel_bits[rides])
                 self.solve_batches += 1
                 fast_res = self._fleet.schedule(
-                    fp, fc, selections=selections
+                    fp, fc, selections=selections, select=select
                 )
                 self.last_breakdown.update(self._fleet.last_breakdown)
                 if len(fast_idx) == len(problems):
@@ -1820,8 +1854,10 @@ class TensorScheduler:
                     )
                     self._batch_gen = self._snapshot_gen
                     self._batch_cache = (fp, fc)
-                    self._batch_spread = selections is not None
-                    self._batch_token = self.snapshot.mask_token
+                    self._batch_token = (
+                        self.snapshot.mask_token if selections is None
+                        else None
+                    )
                     return fast_res
                 results: list = [None] * len(problems)
                 for i, res in zip(fast_idx, fast_res):
@@ -1857,24 +1893,51 @@ class TensorScheduler:
             tokens.append(probe() if probe is not None else None)
         return tuple(tokens)
 
+    def _report_host_selected(self, rows: int) -> None:
+        """Say how many of the batch's spread rows the HOST selects (the
+        snapshot holds more regions than the device kernel's table): the
+        gauge every batch, a line on stderr once a snapshot layout. Those
+        rows cost ~84 us each, every wave whose generation moved."""
+        from ..utils.metrics import spread_host_selected_rows
+
+        spread_host_selected_rows.set(rows)
+        token = self.snapshot.mask_token
+        if rows and self._host_select_told != token:
+            import sys as _sys
+
+            from .select import R_CAP, region_count
+
+            self._host_select_told = token
+            print(
+                f"# spread selection on the host: {rows} rows, the snapshot "
+                f"holds {region_count(self.snapshot)} regions and the fleet "
+                f"table's select kernel takes {R_CAP}",
+                file=_sys.stderr,
+                flush=True,
+            )
+
     def _select_spread_rows(
         self,
         problems: Sequence[BindingProblem],
         compiled: list[CompiledPlacement],
+        spread_idx: list,
     ) -> tuple:
-        """The Select stage (select_clusters.go's SelectClusters) for the
-        single-term spread-constrained rows of a batch. Returns
+        """The Select stage (select_clusters.go's SelectClusters) ON THE
+        HOST, for the rows ``spread_idx`` of a batch (single-term,
+        spread-constrained). The fleet table selects such rows itself
+        (_fleet_select, from its resident state); this is the path for the
+        rows that kernel does not take (a snapshot with more regions than
+        its subset table holds) and for the explain capture. Returns
         ``(idx, bits)``: the rows the selection ACCEPTED, ascending, and
         each one's selected set as a packed mask (uint8[k, ceil(C/8)],
-        little bit order): the fleet table's row state, which it ANDs
-        into the row's feasibility, so those rows are fleet-eligible and
-        get the device-resident delta-fetch path. Selection runs on host
-        exactly as the general path's Select stage does (same code, same
-        memoization). Rows the selection REJECTS (FitError) are left out
-        and fall through to the host path, which reports the failure. So
-        does every such row with extra estimators on: the selection ranks
-        groups on the general estimate alone, and the host path's Select
-        stage sees the merged availability.
+        little bit order): the fleet table's row state, uploaded when it
+        moves. Selection runs exactly as the general path's Select stage
+        does (same code, same memoization). Rows the selection REJECTS
+        (FitError) are left out and fall through to the host path, which
+        reports the failure. Callers keep rows of a batch with extra
+        estimators out: the selection ranks groups on the general estimate
+        alone, and the host path's Select stage sees the merged
+        availability.
 
         A selection is pure in (snapshot generation, placement,
         replicas/requests/prev), so a per-binding-key cache answers the
@@ -1888,16 +1951,8 @@ class TensorScheduler:
         from ..utils.tracing import tracer
         from .spread import select_clusters_batch
 
-        # cheap predicate: fleet_single_term is precomputed per compiled
-        # placement; a single-term cp that is NOT fleet-eligible is exactly
-        # a spread-constrained one (the ignore rule is folded in)
         snap = self.snapshot
         w8 = (snap.num_clusters + 7) // 8
-        spread_idx = [] if self.extra_estimators else [
-            i
-            for i, cp in enumerate(compiled)
-            if len(cp.terms) == 1 and not cp.fleet_single_term
-        ]
         if not spread_idx:
             return np.empty(0, np.int64), np.empty((0, w8), np.uint8)
         t_start = _time.perf_counter()
@@ -1973,7 +2028,7 @@ class TensorScheduler:
             spread_selections.inc(failed, outcome="fit_error")
         tracer.record(
             "scheduler.select", _time.perf_counter() - t_start,
-            start=t_start, rows=len(spread_idx), hits=hits,
+            start=t_start, rows=len(spread_idx), device=0, hits=hits,
             computed=len(pending), fit_errors=fit_errors + failed,
             moved=moved,
         )

@@ -59,14 +59,21 @@ decode 9 ms):
   only when the batch contains Duplicated or zero-replica bindings;
 - a spread-constrained row's SelectClusters result is ROW STATE: one packed
   selection mask a row (``sel_bits``, ceil(C/8) bytes, all ones for a row
-  without constraints), uploaded with the rows whose selection moved and
-  ANDed into the candidate mask in _row_masks. The placement table holds
-  the placements users wrote, however long the federation runs.
+  without constraints), ANDed into the candidate mask in _row_masks. It is
+  COMPUTED ON THE DEVICE, in every pass that holds such rows, by one
+  batched kernel (_fleet_select: scheduler/select.py's tensor form of
+  spread.py + groups.py) over the same resident state the pass divides on,
+  and written straight into the resident ``sel_bits``: the host neither
+  computes, packs, compares nor uploads it. A snapshot with more regions
+  than the kernel's subset table (R_CAP) keeps the host selection, uploaded
+  with the rows whose selection moved (_apply_selections). The placement
+  table holds the placements users wrote, however long the federation runs.
 
 Eligibility: a binding rides the fleet path when its placement has a single
-affinity term, a spread-constraint selection the engine's Select stage
-accepted (or no constraints, or the static-weight ignore rule,
-select_clusters.go:63-78), no eviction tasks, <= K_PREV previous sites, and
+affinity term (with spread constraints or without: a FitError of the device
+selection is the row's empty candidate set; where the host selects, the
+selection must have accepted the row), no eviction tasks, <= K_PREV
+previous sites, and
 (for Divided strategies) replicas <= MAX_REPLICAS_FAST so the per-row
 entry-vector bound holds. Everything else takes the general host path — the
 two paths are differentially fuzz-tested for identical placements.
@@ -78,7 +85,7 @@ import logging
 import time
 
 from functools import partial
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 import jax
@@ -94,6 +101,15 @@ from ..ops.quota import (
     quota_admit as _quota_admit,
     quota_cluster_caps as _quota_cluster_caps,
 )
+from .select import (
+    MODE_NONE,
+    N_PARAMS,
+    R_CAP,
+    constraint_params,
+    region_table,
+    select_rows,
+    subset_table,
+)
 
 log = logging.getLogger("karmada_tpu")
 
@@ -103,6 +119,7 @@ _TRACE_KERNELS = {
     "A": "fleet_pass",
     "E": "fleet_entries",
     "B": "fleet_bits",
+    "T": "fleet_select",
     "S": "state_scatter",
     "G": "meta_gather",
     "F": "estimate_fold",
@@ -700,6 +717,90 @@ def _fleet_bits(
         return out.reshape(-1, out.shape[-1])
 
 
+#: rows a chunk of _fleet_select: its [chunk, C, R_CAP] temporaries stay
+#: under SELECT_TEMP_BYTES whatever the cluster count
+SELECT_CHUNK = 2048
+SELECT_TEMP_BYTES = 256 << 20
+
+
+def _select_chunk(n: int, c: int) -> int:
+    by_temp = SELECT_TEMP_BYTES // (4 * R_CAP * max(c, 1))
+    by_temp = 1 << max(by_temp, 256).bit_length() - 1
+    return min(SELECT_CHUNK, by_temp, _pow2(max(n, 256)))
+
+
+@partial(jax.jit, static_argnames=("chunk", "n_chunks"))
+def _fleet_select(
+    cp_bits, cp_static, gvk_bits, prof_table, incomplete_en,
+    sp_params,  # int32[U, N_PARAMS] constraint_params a placement slot
+    region_of,  # int32[C] region_table of the snapshot
+    sub_bits, sub_prefix,  # subset_table(R_CAP)
+    rows,  # int32[n_pad] the spread-constrained table rows (-1 = padding)
+    cp_idx, gvk_idx, prof_idx, replicas, prev_sites, prev_counts, sel_bits,
+    *, chunk: int, n_chunks: int,
+):
+    """The Select stage (SelectClusters) of the batch's spread-constrained
+    rows, from the resident row state, written into the resident
+    ``sel_bits`` at those rows. Feasibility and availability are the
+    pass's own expressions (_row_masks with no selection applied,
+    merge_estimates over the resident profile table), so the selection
+    ranks on exactly what _fleet_pass will divide on. An empty selection
+    is a FitError: the row then has no candidate and _fleet_pass clears
+    ``has_cand`` in its meta word. Returns (sel_bits, int32[2]: rows with
+    an empty selection, rows whose selection moved)."""
+    c = cp_static.shape[1]
+    cap, w8 = sel_bits.shape
+    with jax.named_scope("fleet.select"):
+        valid = rows >= 0
+        r = jnp.maximum(rows, 0)
+        cp = cp_idx[r]
+        gv = gvk_idx[r]
+        pf = prof_idx[r]
+        reps = jnp.where(valid, replicas[r], 0)
+        ps = prev_sites[r]
+        pc = jnp.where(valid[:, None], prev_counts[r], 0)
+        old = sel_bits[r]
+        unselected = jnp.full((chunk, w8), 0xFF, jnp.uint8)
+        weights = jnp.int32(1) << jnp.arange(8, dtype=jnp.int32)
+
+        def body(carry, i):
+            sb, fit_errors, moved = carry
+            sl = lambda a: lax.dynamic_slice_in_dim(
+                a, i * chunk, chunk, axis=0
+            )
+            cpc, gvc, pfc, repsc, vc = sl(cp), sl(gv), sl(pf), sl(reps), sl(valid)
+            prev, _, feasible = _row_masks(
+                cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc,
+                sl(ps), sl(pc), vc, unselected, chunk, c,
+            )
+            avail = merge_estimates(repsc, (prof_table[pfc],))
+            params = sp_params[cpc]
+            sel = select_rows(
+                feasible, prev, avail, repsc, params, region_of,
+                sub_bits, sub_prefix,
+            )
+            packed = (
+                jnp.pad(sel, ((0, 0), (0, w8 * 8 - c)))
+                .reshape(chunk, w8, 8)
+                .astype(jnp.int32)
+                * weights
+            ).sum(axis=-1, dtype=jnp.int32).astype(jnp.uint8)
+            sb = sb.at[jnp.where(vc, sl(r), cap)].set(packed, mode="drop")
+            empty = vc & (params[:, 0] != MODE_NONE) & ~sel.any(axis=1)
+            differs = vc & (packed != sl(old)).any(axis=1)
+            return (
+                sb,
+                fit_errors + empty.sum(dtype=jnp.int32),
+                moved + differs.sum(dtype=jnp.int32),
+            ), None
+
+        (sel_bits, fit_errors, moved), _ = lax.scan(
+            body, (sel_bits, jnp.int32(0), jnp.int32(0)),
+            jnp.arange(n_chunks),
+        )
+        return sel_bits, jnp.stack([fit_errors, moved])
+
+
 @jax.jit
 def _gather_meta(res_meta, rows):
     """Changed-meta fallback when phase A's tuned meta buffer overflows:
@@ -718,6 +819,8 @@ def _gather_meta(res_meta, rows):
 _fleet_pass.row_coupled = True
 _fleet_entries.row_coupled = True
 _fleet_bits.row_coupled = False
+# writes land at ``rows`` and the two counts sum over every row
+_fleet_select.row_coupled = True
 _gather_meta.row_coupled = False
 
 
@@ -732,6 +835,7 @@ FLEET_KERNELS = {
     "fleet_pass": _fleet_pass,
     "fleet_entries": _fleet_entries,
     "fleet_bits": _fleet_bits,
+    "fleet_select": _fleet_select,
     # quota plane (ops.quota): dispatched engine-side (TensorScheduler's
     # admission wrapper + cap fold), registered here so prewarm replay and
     # the graftlint IR tier see them like every other solve-family kernel
@@ -1011,6 +1115,14 @@ _STATE_FIELDS = (
 )
 
 
+#: the row state _fleet_select reads (``strategy`` folds into the placement's
+#: constraint parameters, ``fresh`` bears on the division alone)
+_SELECT_STATE = tuple(
+    _STATE_FIELDS.index(k) for k in _STATE_FIELDS
+    if k not in ("strategy", "fresh")
+)
+
+
 @jax.jit
 def _scatter_rows(state, rows, vals):
     return tuple(a.at[rows].set(v) for a, v in zip(state, vals))
@@ -1033,6 +1145,17 @@ def _fold_estimates(table, answers, n_live):
         merged = jnp.where(out < 0, est, jnp.minimum(out, est))
         out = jnp.where((est < 0) | ~live, out, merged)
     return out
+
+
+class _SelectRows(NamedTuple):
+    """The rows of a batch that _fleet_select selects, as dispatched."""
+
+    select: np.ndarray  # positions in the batch
+    rows_np: np.ndarray  # the batch's table rows those positions index
+    rows_dev: jax.Array  # int32[chunk * n_chunks] table rows, -1 padded
+    n: int
+    chunk: int
+    n_chunks: int
 
 
 class FleetTable:
@@ -1097,6 +1220,17 @@ class FleetTable:
         # device
         self._dev_state: Optional[tuple] = None
         self._dev_tables: Optional[tuple] = None
+        # what _fleet_select reads beside the slot tables: (constraint
+        # parameters a placement slot, the snapshot's region table), built
+        # with them; None where the snapshot holds more than R_CAP regions
+        self._dev_spread: Optional[tuple] = None
+        self._dev_subsets: Optional[tuple] = None  # subset_table, uploaded once
+        # the device selection of the current batch, kept while the same
+        # positions of the same rows are asked for again
+        self._select_cache: Optional[_SelectRows] = None
+        # the last select dispatch's (perf_counter start, end, rows,
+        # device counts), until schedule() records its span
+        self._select_mark: Optional[tuple] = None
         self._all_rows_dev = None
         self._all_rows_n = -1
         self._dirty: set[int] = set()
@@ -1141,10 +1275,11 @@ class FleetTable:
         # (target, consecutive passes desired) for a frozen shrink — see
         # the cap tuning in _solve_dense
         self._shrink_desire: tuple = (None, 0)
-        # O(1) batch reuse: (problems_list, compiled_list, rows) of the
-        # last scheduled batch — the engine's batch-identity fast path
-        # re-passes the SAME list objects, so identity means the row
-        # mapping is already current (cleared on growth/compaction).
+        # O(1) batch reuse: (problems_list, compiled_list, rows, select)
+        # of the last scheduled batch — the engine's batch-identity fast
+        # path re-passes the SAME list objects, so identity means the row
+        # mapping is already current (cleared on growth/compaction), and
+        # ``select`` (the positions the device selects, or None) with it.
         # _reuse_pass stands in for the per-row last-used bumps the
         # skipped upserts would have done (consumed by _compact).
         self._reuse: Optional[tuple] = None
@@ -1354,6 +1489,9 @@ class FleetTable:
             # a row's spread selection, bitpacked as the cp planes are;
             # all ones = no selection narrows this row
             "sel_bits": np.full((new_cap, (c + 7) // 8), 0xFF, np.uint8),
+            # rows whose resident sel_bits is _fleet_select's, not this
+            # mirror's (host bookkeeping, never uploaded)
+            "sel_on_dev": np.zeros(new_cap, bool),
         }
         for k, a in self._st.items():
             st[k][: self.cap] = a
@@ -1367,8 +1505,9 @@ class FleetTable:
     def _fingerprint(p) -> tuple:
         # rows key on the Placement object: its compiled masks recompile IN
         # PLACE at the same slot on snapshot swaps. A spread selection is
-        # not part of the fingerprint: it is row state of its own
-        # (_apply_selections), uploaded when it moves.
+        # not part of the fingerprint: it is row state of its own, written
+        # on the device by _fleet_select (or uploaded when it moves, for a
+        # row the host selected: _apply_selections).
         return (
             id(p.placement), p.replicas, p.gvk, p.fresh,
             tuple(p.requests.items()), tuple(p.prev.items()),
@@ -1471,9 +1610,11 @@ class FleetTable:
                 k += 1
         st["prev_sites"][row] = sites
         st["prev_counts"][row] = cnts
-        # a (re)packed row starts unselected; the pass's selections, if
-        # the row has one, land after the upserts (_apply_selections)
+        # a (re)packed row starts unselected; the pass's selection, if the
+        # row has one, lands after the uploads (_fleet_select, or
+        # _apply_selections for a row the host selected)
         st["sel_bits"][row] = 0xFF
+        st["sel_on_dev"][row] = False
         self._fps[row] = self._fingerprint(problem)
         self._terms[row] = compiled.terms[0][0]
         self._dirty.add(row)
@@ -1694,6 +1835,15 @@ class FleetTable:
                 .set(jnp.asarray(gvk_packed))
             )
             inc_dev = jnp.asarray(~snap.complete_enablements)
+            # the Select stage's small tables move with the slot tables:
+            # each slot's constraint parameters (zero rows past the live
+            # slots: no constraint) and the snapshot's region table
+            regions = region_table(snap)
+            self._dev_spread = None
+            if regions is not None:
+                sp = np.zeros((cp_bits_dev.shape[0], N_PARAMS), np.int32)
+                sp[:n_slots] = [constraint_params(cp) for _, cp in self._cp_pl]
+                self._dev_spread = (jnp.asarray(sp), jnp.asarray(regions))
         else:
             _, _, gvk_dev, _, inc_dev = self._dev_tables
         _mark("masks")
@@ -1734,6 +1884,10 @@ class FleetTable:
         if self._mesh is not None:
             repl = NamedSharding(self._mesh, P())
             tables = tuple(jax.device_put(a, repl) for a in tables)
+            if self._dev_spread is not None:
+                self._dev_spread = tuple(
+                    jax.device_put(a, repl) for a in self._dev_spread
+                )
         self._dev_tables = tables
         self._mask_token = token
         self._tables_dirty = False
@@ -1860,7 +2014,7 @@ class FleetTable:
 
     def schedule(
         self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None,
+        selections=None, select=None,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -1880,18 +2034,34 @@ class FleetTable:
         rest replay from the mirrors; otherwise the pass silently runs
         full.
 
-        ``selections`` (optional) is ``(positions, bits)``: the spread-
-        constrained rows of ``problems`` and each one's SelectClusters
-        result under the current snapshot, bitpacked (uint8[k, W8], little
-        bit order). A selection is row state: it stays with the row until
-        a later pass brings another, so a pass over the same batch at the
-        same snapshot generation need not bring any."""
-        from ..utils.metrics import fleet_placement_slots, fleet_slots_minted
+        ``select`` (optional) is the POSITIONS of the spread-constrained
+        rows whose SelectClusters stage runs on the device: _fleet_select
+        computes each one's selection from the resident row state and
+        writes it into the resident ``sel_bits``, in every pass over these
+        rows (the batch-identity fast path brings the same list objects and
+        no ``select``: the table keeps the positions with the batch).
+
+        ``selections`` (optional) is ``(positions, bits)``: rows the HOST
+        selected (a snapshot with more regions than R_CAP) and each one's
+        SelectClusters result under the current snapshot, bitpacked
+        (uint8[k, W8], little bit order). Such a selection is row state: it
+        stays with the row until a later pass brings another, so a pass
+        over the same batch at the same snapshot generation need not bring
+        any. A batch brings one or the other (the snapshot's region count
+        decides for all its spread rows), never both."""
+        from ..utils.metrics import (
+            fleet_placement_slots,
+            fleet_slots_minted,
+            spread_selections,
+        )
         from ..utils.tracing import tracer
 
         with tracer.span("scheduler.solve") as sp:
             self._phase_marks = []
-            res = self._schedule_pass(problems, compiled, delta, selections)
+            self._select_mark = None
+            res = self._schedule_pass(
+                problems, compiled, delta, selections, select
+            )
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
             sp.attrs["rows_packed"] = int(tmr.get("rows_packed", 0))
@@ -1903,6 +2073,22 @@ class FleetTable:
             sp.attrs["slots"] = len(self._cp_pl)
             sp.attrs["slots_minted"] = minted
             self._emit_phase_spans()
+            mark, self._select_mark = self._select_mark, None
+            if mark is not None:
+                # the Select stage's span: the host's share of it (the row
+                # vector and the dispatch), at its interval, with what the
+                # kernel counted (copied to the host since the dispatch and
+                # read after the pass's fence: no device round trip)
+                t_a, t_b, n_sel, counts = mark
+                fit_errors, moved = (int(v) for v in np.asarray(counts))
+                tracer.record(
+                    "scheduler.select", t_b - t_a, start=t_a, rows=n_sel,
+                    device=n_sel, computed=0, hits=0,
+                    fit_errors=fit_errors, moved=moved,
+                )
+                spread_selections.inc(n_sel - fit_errors, outcome="device")
+                if fit_errors:
+                    spread_selections.inc(fit_errors, outcome="fit_error")
         if minted:
             fleet_slots_minted.inc(minted)
         fleet_placement_slots.set(len(self._cp_pl))
@@ -1919,11 +2105,77 @@ class FleetTable:
             return 0
         rows = rows_np[pos]
         st = self._st["sel_bits"]
-        moved = (st[rows] != bits).any(axis=1)
+        # a row the device selected last holds the kernel's bits there,
+        # whatever this mirror says
+        on_dev = self._st["sel_on_dev"]
+        moved = (st[rows] != bits).any(axis=1) | on_dev[rows]
         if moved.any():
             st[rows[moved]] = bits[moved]
+            on_dev[rows[moved]] = False
             self._dirty.update(rows[moved].tolist())
         return int(moved.sum())
+
+    def _select_on_device(self, rows_np: np.ndarray, select) -> None:
+        """Dispatch _fleet_select over the positions ``select`` of the
+        pass's rows (no host wait: the pass's fence is the only fence).
+        The device row vector is padded to whole chunks and kept while the
+        same positions of the same rows come again, so one trace and one
+        upload serve every wave of a batch."""
+        t_a = time.perf_counter()
+        if self._dev_spread is None:
+            raise RuntimeError(
+                f"the snapshot holds more than {R_CAP} regions: its spread "
+                "rows select on the host (TensorScheduler decides by "
+                "scheduler.select.region_table, as this table does)"
+            )
+        c = self.engine.snapshot.num_clusters
+        cache = self._select_cache
+        if not (
+            cache is not None
+            and cache.rows_np is rows_np
+            and np.array_equal(cache.select, select)
+        ):
+            rows = rows_np[select]
+            n = len(rows)
+            chunk = _select_chunk(n, c)
+            n_chunks = -(-n // chunk)
+            ar = np.full(chunk * n_chunks, -1, np.int32)
+            ar[:n] = rows
+            self._st["sel_on_dev"][rows] = True
+            self._last_upload_bytes += ar.nbytes
+            cache = _SelectRows(
+                select, rows_np, jnp.asarray(ar), n, chunk, n_chunks
+            )
+            self._select_cache = cache
+        chunk, n_chunks = cache.chunk, cache.n_chunks
+        if self._dev_subsets is None:
+            self._dev_subsets = tuple(
+                jnp.asarray(a) for a in subset_table(R_CAP)
+            )
+        state = self._dev_state
+        args = (
+            *self._dev_tables, *self._dev_spread, *self._dev_subsets,
+            cache.rows_dev, *(state[k] for k in _SELECT_STATE),
+        )
+        from ..parallel.mesh import mesh_shape as _mesh_shape
+
+        key = (
+            "T", self.cap, c, self._dev_tables[0].shape, chunk, n_chunks,
+            _mesh_shape(self._mesh),
+        )
+        if self._mark_trace(*key) and self._mesh is None:
+            # meshed dispatches stay manifest-unrecorded, as _fleet_bits'
+            self._record_trace(
+                "fleet_select", key, args, chunk=chunk, n_chunks=n_chunks
+            )
+        sel_bits, counts = _fleet_select(
+            *args, chunk=chunk, n_chunks=n_chunks
+        )
+        self._dev_state = (*state[:-1], sel_bits)
+        # the two counts start for the host now and are there by the pass's
+        # fence: reading them for the span waits on nothing
+        counts.copy_to_host_async()
+        self._select_mark = (t_a, time.perf_counter(), cache.n, counts)
 
     def device_bytes(self) -> dict[str, int]:
         """Resident device bytes by ledger kind — the EXACT ``nbytes`` of
@@ -1942,9 +2194,11 @@ class FleetTable:
 
         return {
             "packed_grid": nb(self._dev_state),
-            "slot_tables": nb(self._dev_tables),
+            "slot_tables": nb(self._dev_tables) + nb(self._dev_spread)
+            + nb(self._dev_subsets),
             "donated_residents": nb(self._res_dense) + nb(self._res_meta),
-            "rows_index": nb(self._all_rows_dev),
+            "rows_index": nb(self._all_rows_dev)
+            + (nb(self._select_cache.rows_dev) if self._select_cache else 0),
         }
 
     def _buffer_platform(self) -> str:
@@ -2053,7 +2307,7 @@ class FleetTable:
 
     def _schedule_pass(
         self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None,
+        selections=None, select=None,
     ) -> list:
         if delta is not None:
             res = self._schedule_delta(problems, compiled, delta)
@@ -2075,6 +2329,10 @@ class FleetTable:
             # the skipped upserts would have done; _compact honors it.
             rows_np = ru[2]
             self._reuse_pass = self._pass
+            if select is None and selections is None:
+                select = ru[3]
+            else:
+                self._reuse = (problems, compiled, rows_np, select)
         else:
             # reclaim rows of deleted/idle bindings before the table would
             # grow (compaction reindexes rows, so it must run before any
@@ -2092,7 +2350,7 @@ class FleetTable:
                 np.int32,
                 len(problems),
             )
-            self._reuse = (problems, compiled, rows_np)
+            self._reuse = (problems, compiled, rows_np, select)
             self._reuse_pass = self._pass
         if selections is not None:
             tmr["sel_moved"] = self._apply_selections(rows_np, selections)
@@ -2113,6 +2371,13 @@ class FleetTable:
             tmr["estimate"] = t0_after - t_a
             t0 = t0_after
         t0 = self._phase(tmr, "sync", t0)
+        if select is not None and len(select):
+            # the Select stage on the device, after the pass's uploads and
+            # before the pass; its host stretch is the scheduler.select
+            # span's, so the phases leave it out as they do the estimators'
+            self._select_on_device(rows_np, select)
+            tmr["select_dispatch"] = self._select_mark[1] - t0
+            t0 = self._select_mark[1]
         n = len(rows_np)
         # adaptive chunk: a straggler batch of a few hundred rows should
         # not execute a full 4096-row chunk (pow2 snapping keeps the trace
@@ -2239,7 +2504,7 @@ class FleetTable:
             self._pass += 1
             self.new_trace_last_pass = False
             self._packed_this_pass = 0
-            self._reuse = (problems, compiled, rows_full)
+            self._reuse = (problems, compiled, rows_full, ru[3])
             self._reuse_pass = self._pass
             tmr: dict[str, float] = {
                 "rows_packed": 0.0,
@@ -2288,7 +2553,10 @@ class FleetTable:
         tmr = self.last_breakdown  # the sub pass's phase breakdown
         tmr["rows_replayed"] = float(n - n_sub)
         tmr["dirty_rows"] = float(n_sub)
-        self._reuse = (problems, compiled, rows_new)
+        # the swapped-in rows are never spread-constrained (the engine's
+        # delta pass sends such a batch through the full prologue), so the
+        # batch's device-selected positions stand
+        self._reuse = (problems, compiled, rows_new, ru[3])
         self._reuse_pass = self._pass
         t0 = time.perf_counter()
         res = self._replay_result(problems, rows_new, tmr)

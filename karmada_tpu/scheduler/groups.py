@@ -1,11 +1,12 @@
 """Topology group selection: the region-DFS of spread constraints.
 
 Faithful re-execution of pkg/scheduler/core/spreadconstraint/
-{select_groups.go, select_clusters_by_region.go, group_clusters.go}: feasible
-group combinatorics are small (regions per fleet, not clusters), so this
-bounded search stays on host while scoring inputs (availability, locality
-scores) come from the batched device kernels (SURVEY.md section 7: "keep
-bounded search on host, tensorize scoring only").
+{select_groups.go, select_clusters_by_region.go, group_clusters.go}, one row
+at a time on the host. Feasible group combinatorics are small (regions per
+fleet, not clusters), which is also what lets scheduler/select.py enumerate
+them: the fleet table's rows take that batched device form (the DFS as a
+subset table over at most R_CAP regions), and this module stays as the
+semantics it is tested against and as the search for every other row.
 
 Semantics mirrored:
 - group score (group_clusters.go:138-330): Duplicated counts clusters whose

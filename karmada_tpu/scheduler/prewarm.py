@@ -88,6 +88,7 @@ _KERNELS = {
     "fleet_pass": True,
     "fleet_entries": True,
     "fleet_bits": False,
+    "fleet_select": True,
     "quota_admit": True,
     "quota_cluster_caps": False,
     "explain_pass": False,

@@ -319,6 +319,9 @@ class CompiledPlacement:
     # placement-level half of the fleet fast-path gate, precomputed by
     # TensorScheduler._compiled (the per-problem check is a hot loop)
     fleet_single_term: bool = False
+    # single-affinity-term WITH effective spread constraints: the rows the
+    # Select stage selects (on the device, scheduler.select, or on the host)
+    spread_single_term: bool = False
 
 
 def compile_placement(placement: Optional[Placement], snap: ClusterSnapshot) -> CompiledPlacement:

@@ -1,9 +1,13 @@
 """Spread-constraint selection: narrow feasible clusters before assignment.
 
 Ref: pkg/scheduler/core/spreadconstraint/. The reference groups scored
-clusters by topology and runs a DFS over group combinations; this build keeps
-the batched tensor path for the dominant cases and a bounded host search for
-ragged group combinatorics (SURVEY.md section 7 "hard parts").
+clusters by topology and runs a DFS over group combinations. This module
+and scheduler/groups.py are that selection row by row on the host: the
+SEMANTICS, the oracle tests/test_fleet_select.py holds the device kernel to
+bit for bit, and the path of every row the kernel does not take (the general
+host path's Select stage; a fleet batch on a snapshot with more regions than
+the kernel's subset table). The fleet table's own rows are selected on the
+device by scheduler/select.py's batched form of the same rules.
 
 Implemented here:
 - ignore rules (select_clusters.go:63-86): static-weighted division ignores

@@ -441,10 +441,20 @@ kernel_phase_seconds = registry.histogram(
 spread_selections = registry.counter(
     "karmada_tpu_spread_selections_total",
     "spread-constrained rows seen by the engine's Select stage, by "
-    "outcome: hit (answered from the row cache: same row content, same "
-    "snapshot generation), computed (SelectClusters ran on the host), "
-    "fit_error (the constraints cannot be met; the row leaves the fleet "
-    "path and the host path reports it)",
+    "outcome: device (selected by the fleet table's kernel from its "
+    "resident state), hit (host path, answered from the row cache: same "
+    "row content, same snapshot generation), computed (SelectClusters "
+    "ran on the host), fit_error (the constraints cannot be met: on the "
+    "device the row reports no candidate; a host-selected row leaves the "
+    "fleet path and the host path reports it)",
+)
+spread_host_selected_rows = registry.gauge(
+    "karmada_tpu_spread_host_selected_rows",
+    "spread-constrained rows of the last such batch whose SelectClusters "
+    "stage ran on the host because the snapshot holds more regions than "
+    "the fleet table's select kernel takes (scheduler.select.R_CAP = 8); 0 "
+    "when the kernel took them all. Above 0 each of those rows costs host "
+    "time in every wave whose snapshot generation moved",
 )
 fleet_placement_slots = registry.gauge(
     "karmada_tpu_fleet_placement_slots",

@@ -98,9 +98,12 @@ SPAN_NAMES: dict[str, str] = {
         "eligibility partition"
     ),
     "scheduler.select": (
-        "under scheduler.pack, only when the batch holds spread-constrained "
-        "rows: the Select stage (SelectClusters on the host; rows / hits / "
-        "computed / fit_errors / moved attrs)"
+        "only when the batch holds spread-constrained rows: the host's "
+        "share of the Select stage. Under scheduler.solve: the dispatch of "
+        "the fleet table's select kernel (device = rows it selected); "
+        "under scheduler.pack: SelectClusters on the host for the rows the "
+        "kernel does not take (rows / device / hits / computed / "
+        "fit_errors / moved attrs)"
     ),
     "scheduler.host": "host-path (non-fleet) scheduling of a batch",
     "scheduler.solve": "one fleet-table solve pass",

@@ -117,6 +117,7 @@ def test_engine_stage_tiny():
     # (divider_np + spread), one slot a placement on both
     assert facts["policy_rows_checked"] == 1024
     assert facts["policy_slots"] == chip_smoke.N_POLICIES
+    assert facts["policy_rows_device_selected"] > 0
 
 
 def test_kernels_stage_tiny():

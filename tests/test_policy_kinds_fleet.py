@@ -1,5 +1,8 @@
 """ISSUE 31: the documented policy kinds side by side on the fleet path, and
-a spread selection as ROW STATE of the fleet table.
+a spread selection as ROW STATE of the fleet table. ISSUE 32: that state is
+written on the device by the table's own kernel (``_fleet_select``), so the
+cases that read the host's selection cache read the ``device`` counts and
+the resident ``sel_bits`` instead.
 
 The deployment is the benchmark's own (``fed-100c-policies`` at its
 rehearsal size: 12 members in 3 regions x 2 zones, 600 bindings, the six
@@ -9,7 +12,7 @@ cannot be met. Over three turns of a drifting ring:
 (a) ``schedule()`` == refimpl (divider_np + spread), row by row;
 (b) == benchmark/reference/policies.py, so tier-1 holds the benchmark's
     copy of the semantics;
-(c) every row but the FitError rows is answered by the fleet;
+(c) every row is answered by the fleet, the FitError rows too;
 (d) the placement table's slot count never moves and nothing is rebuilt,
     also with more distinct selections a wave than the table has slots;
 (e) a Duplicated row read after a later pass replaced the tables answers
@@ -40,10 +43,11 @@ SEED = 2147483777
 TURNS = 3
 
 
-def _deployment(seed: int = SEED):
+def _deployment(seed: int = SEED, layout: dict | None = None):
     """(deployment built, first snapshot, ring of snapshots, their allocs)."""
     _, _, cfg, traffic = bench_run.load_cell(CELL, True)
     cfg = copy.deepcopy(cfg)
+    cfg["layout"].update(layout or {})
     cfg["bindings_mix"]["replicas_min"] = 0  # zero-replica rows
     cfg["placements"][0]["share"] = 0.28
     cfg["placements"].append({
@@ -75,7 +79,7 @@ def _counts() -> dict:
         "rebuilds": metrics.fleet_table_rebuilds.value(),
         "minted": metrics.fleet_slots_minted.value(),
         **{o: metrics.spread_selections.value(outcome=o)
-           for o in ("hit", "computed", "fit_error")},
+           for o in ("device", "hit", "computed", "fit_error")},
     }
 
 
@@ -161,23 +165,28 @@ def test_schedule_equals_the_benchmarks_reference(storm, turn):
         assert placed[dep.kind != 6].all()
 
 
-def test_every_row_but_the_fit_errors_rides_the_fleet(storm):
+def test_every_row_rides_the_fleet_the_fit_errors_too(storm):
     host = [s for s in storm.spans if s["name"] == "scheduler.host"]
     solve = [s for s in storm.spans if s["name"] == "scheduler.solve"]
-    assert len(host) == len(solve) == len(storm.waves)
-    assert {s["attrs"]["rows"] for s in host} == {storm.unsat_rows}
-    assert {s["attrs"]["rows"] for s in solve} == {600 - storm.unsat_rows}
-    # one fleet pass and one host chunk a wave
-    assert {w.solves for w in storm.waves} == {2}
+    assert not host and len(solve) == len(storm.waves)
+    assert {s["attrs"]["rows"] for s in solve} == {600}
+    # one fleet pass a wave and nothing else
+    assert {w.solves for w in storm.waves} == {1}
+    # a FitError is the device's empty selection: no candidate, that error
+    for w in storm.waves:
+        unsat = [r for r, k in zip(w.results, storm.dep.kind) if k == 6]
+        assert len(unsat) == storm.unsat_rows
+        assert not any(r.success or r.clusters for r in unsat)
 
 
 def test_the_placement_table_holds_policies_not_selections(storm):
-    # six placements ride the fleet (the seventh's rows are all FitErrors)
-    assert {w.slots for w in storm.waves} == {6}
+    # all seven placements ride the fleet (the seventh's rows are all
+    # FitErrors, which the device selection answers)
+    assert {w.slots for w in storm.waves} == {7}
     assert len({w.table for w in storm.waves}) == 1
     assert storm.delta["rebuilds"] == 0
-    assert storm.delta["minted"] == 0  # all six were interned by pass 0
-    assert storm.slots_gauge == 6
+    assert storm.delta["minted"] == 0  # all seven were interned by pass 0
+    assert storm.slots_gauge == 7
 
 
 def test_more_selections_a_wave_than_slots_rebuilds_nothing(monkeypatch):
@@ -192,15 +201,18 @@ def test_more_selections_a_wave_than_slots_rebuilds_nothing(monkeypatch):
     table = engine._fleet
     assert table._max_slots() == 16
     rebuilds = metrics.fleet_table_rebuilds.value()
+    spread = np.flatnonzero(np.isin(dep.kind, (4, 5)))
+    rows = np.asarray([table._key_row[dep.problems[i].key] for i in spread])
     seen = set()
     for g in range(2 * len(snaps)):
         assert engine.update_snapshot(snaps[g % len(snaps)])
         engine.schedule(dep.problems)
-        distinct = {sel[2] for sel in engine._row_selections.values()
-                    if sel[0][0] == engine._snapshot_gen and sel[2]}
+        # the selections of this wave, as the resident row state holds them
+        resident = np.asarray(table._dev_state[-1])[rows]
+        distinct = {r.tobytes() for r in resident if r.any()}
         assert len(distinct) > 16, len(distinct)
         seen |= distinct
-        assert len(table._cp_pl) == 6 and engine._fleet is table
+        assert len(table._cp_pl) == 7 and engine._fleet is table
     assert len(seen) > 32
     assert metrics.fleet_table_rebuilds.value() == rebuilds
 
@@ -231,17 +243,22 @@ def test_duplicated_rows_answer_with_their_own_passs_tables():
 
 
 def test_a_moved_selection_changes_that_rows_answer_alone():
-    dep, first, _, _ = _deployment(seed=3)
+    """Row state the HOST brings (``selections=``): what a snapshot with
+    more regions than the device kernel's table runs on. The same 12
+    members, each a region of its own."""
+    dep, first, _, _ = _deployment(seed=3, layout={
+        "regions": 12, "zones_per_region": 1, "members_per_zone": 1})
     engine = TensorScheduler(first, chunk_size=256)
     base = _copy_out(engine.schedule(dep.problems))
     table = engine._fleet
-    fp, fc = engine._batch_cache if engine._batch_cache else (None, None)
-    if fp is None:  # FitError rows kept the batch off the identity path
-        rides = [i for i, p in enumerate(dep.problems) if dep.kind[i] != 6]
-        fp = [dep.problems[i] for i in rides]
-        fc = [engine._compiled(p.placement) for p in fp]
-    else:
-        rides = list(range(len(fp)))
+    assert table._dev_spread is None and table._select_cache is None
+    assert metrics.spread_host_selected_rows.value() > 0
+    # every row rides (four regions exist here, so no selection fails),
+    # none of them device-selected
+    fp, fc, _, select = table._reuse
+    assert select is None and len(fp) == len(dep.problems)
+    rides = list(range(len(fp)))
+    resident = np.asarray(table._dev_state[-1]).copy()
     # a dynamic-weight row under spread constraints, narrowed by hand to
     # one of the members it was given
     pos = next(k for k, i in enumerate(rides)
@@ -251,8 +268,15 @@ def test_a_moved_selection_changes_that_rows_answer_alone():
     mask = np.zeros(len(dep.fleet["names"]), bool)
     mask[dep.fleet["names"].index(keep)] = True
     bits = np.packbits(mask, bitorder="little")[None, :]
+    # the moved selection is uploaded and is what the resident state holds,
+    # at that row alone
     res = table.schedule(fp, fc, selections=(np.asarray([pos]), bits))
     assert table.last_breakdown["sel_moved"] == 1
+    after = np.asarray(table._dev_state[-1])
+    trow = table._key_row[fp[pos].key]
+    assert (after[trow] == bits[0]).all()
+    others = np.arange(len(after)) != trow
+    assert (after[others] == resident[others]).all()
     got = _copy_out(res)
     assert set(got[pos].clusters) == {keep}
     assert sum(got[pos].clusters.values()) == dep.bind["replicas"][row]
@@ -266,46 +290,56 @@ def test_a_moved_selection_changes_that_rows_answer_alone():
 
 
 def test_an_unmoved_generation_reselects_nothing():
+    """On the device every pass selects its spread rows again; what an
+    unmoved generation shows is that no selection MOVED, and that the host
+    computed none either way."""
     dep, first, snaps, _ = _deployment(seed=3)
     engine = TensorScheduler(first, chunk_size=256)
     engine.schedule(dep.problems)
-    assert engine.update_snapshot(snaps[0])
+    assert engine.update_snapshot(snaps[1])
     tracer.clear()
     engine.schedule(dep.problems)
     engine.schedule(list(dep.problems))  # another list: the prologue runs
     first_pass, again = [
         s["attrs"] for s in tracer.dump() if s["name"] == "scheduler.select"]
-    assert first_pass["computed"] == first_pass["rows"] > 0
-    assert again["computed"] == 0 and again["moved"] == 0
-    assert again["hits"] == again["rows"] == first_pass["rows"]
-    assert again["fit_errors"] == first_pass["fit_errors"] == 12
+    assert first_pass["device"] == first_pass["rows"] > 0
+    assert first_pass["moved"] > 0  # the generation moved
+    assert again["device"] == again["rows"] == first_pass["rows"]
+    assert again["moved"] == 0
+    for a in (first_pass, again):
+        assert a["computed"] == a["hits"] == 0
+        assert a["fit_errors"] == 12
+    assert not engine._row_selections  # the host cache holds nothing
 
 
 def test_spans_and_counters_carry_the_counts(storm):
     select = [s for s in storm.spans if s["name"] == "scheduler.select"]
-    pack = {s["span_id"]: s for s in storm.spans
-            if s["name"] == "scheduler.pack"}
-    assert len(select) == len(pack) == len(storm.waves)
+    solve = {s["span_id"]: s for s in storm.spans
+             if s["name"] == "scheduler.solve"}
+    assert len(select) == len(solve) == len(storm.waves)
+    # the waves ride the batch-identity fast path: no prologue at all
+    assert not [s for s in storm.spans if s["name"] == "scheduler.pack"]
     n = storm.spread_rows
     for s in select:
-        # every wave moved the generation: every row re-selected
+        # every wave the kernel selects every spread row; the host none
         a = s["attrs"]
-        assert (a["rows"], a["hits"], a["computed"]) == (n, 0, n)
+        assert (a["rows"], a["device"], a["hits"], a["computed"]) == (
+            n, n, 0, 0)
         assert a["fit_errors"] == storm.unsat_rows
         assert 0 <= a["moved"] <= n
-        # the Select stage is the pack span's child, inside its interval
-        parent = pack[s["parent_id"]]
+        # the Select stage's host share is the solve span's child, inside
+        # its interval
+        parent = solve[s["parent_id"]]
         assert parent["start"] <= s["start"]
         assert (s["start"] + s["duration_s"]
                 <= parent["start"] + parent["duration_s"] + 1e-6)
     assert sum(s["attrs"]["moved"] for s in select) > 0
     waves = len(storm.waves)
-    assert storm.delta["hit"] == 0
+    assert storm.delta["hit"] == storm.delta["computed"] == 0
     assert storm.delta["fit_error"] == waves * storm.unsat_rows
-    assert storm.delta["computed"] == waves * (n - storm.unsat_rows)
-    solve = [s["attrs"] for s in storm.spans if s["name"] == "scheduler.solve"]
-    assert {a["slots"] for a in solve} == {6}
-    assert {a["slots_minted"] for a in solve} == {0}
+    assert storm.delta["device"] == waves * (n - storm.unsat_rows)
+    assert {a["attrs"]["slots"] for a in solve.values()} == {7}
+    assert {a["attrs"]["slots_minted"] for a in solve.values()} == {0}
 
 
 def test_the_bits_pass_is_a_phase_of_its_own():
@@ -320,7 +354,7 @@ def test_the_bits_pass_is_a_phase_of_its_own():
     bits = [s for s in tracer.dump() if s["name"] == "kernel.bits"]
     assert len(bits) == 1
     a = bits[0]["attrs"]
-    assert a["rows"] == 588 and a["fetch_mb"] > 0
+    assert a["rows"] == 600 and a["fetch_mb"] > 0
     assert a["dispatch_s"] + a["device_s"] <= bits[0]["duration_s"]
     lowered = fleet_mod._fleet_bits.lower(
         *engine._fleet._dev_tables,

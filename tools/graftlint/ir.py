@@ -393,6 +393,24 @@ def _specs_fleet_bits() -> list:
     return [KernelSpec("base", shapes, {"chunk": d["chunk"], "n_chunks": 1})]
 
 
+def _specs_fleet_select() -> list:
+    from karmada_tpu.scheduler.select import N_PARAMS, R_CAP
+
+    d = _fleet_dims()
+    state = _fleet_state(d)
+    subsets = 1 << R_CAP
+    shapes = tuple(
+        _fleet_tables(d)
+        + [((_U, N_PARAMS), "int32"), ((d["c"],), "int32"),  # sp_params, region_of
+           ((subsets,), "int32"), ((subsets, subsets), "float32")]
+        + [((d["n_pad"],), "int32")]
+        # the state it reads: cp_idx gvk_idx prof_idx replicas, prev_sites
+        # prev_counts, sel_bits (neither strategy nor fresh)
+        + state[:4] + state[6:]
+    )
+    return [KernelSpec("base", shapes, {"chunk": d["chunk"], "n_chunks": 1})]
+
+
 def _specs_gather_meta() -> list:
     d = _fleet_dims()
     return [KernelSpec(
@@ -557,6 +575,15 @@ ENTRY_POINTS: dict = {
                row_coupled=False,
                row_args=(6, 7, 8, 9, 10, 11, 12, 13),
                spec_deps=_FLEET_DEPS),
+        # the Select stage: per-row math, but its writes land at ``rows``
+        # (a scatter into the resident sel_bits) and its two counts sum
+        # over every row: declared coupled
+        _entry("fleet_select", "scheduler", "karmada_tpu.scheduler.fleet",
+               "_fleet_select", "karmada_tpu/scheduler/fleet.py",
+               _specs_fleet_select, manifest="fleet_select",
+               row_coupled=True,
+               row_args=(10, 11, 12, 13, 14, 15, 16),
+               spec_deps=_FLEET_DEPS + ("karmada_tpu/scheduler/select.py",)),
         _entry("gather_meta", "scheduler", "karmada_tpu.scheduler.fleet",
                "_gather_meta", "karmada_tpu/scheduler/fleet.py",
                _specs_gather_meta, row_coupled=False, row_args=(0,),

@@ -10,12 +10,17 @@ scopes existed carries none: run with a fresh ``JAX_COMPILATION_CACHE_DIR``.
 From the repo root, on the chip:
 
     JAX_COMPILATION_CACHE_DIR=/tmp/fresh python3 tools/scope_profile.py \
-        rebalance-100kx100.drift 3300000601 16 [--rehearse] [--skip-hlo]
+        rebalance-100kx100.drift 3300000601 16 [--rehearse] [--skip-hlo] \
+        [--kernel=_fleet_select]
 
-Writes ``chiprun_out/scope_profile.json`` (per-scope and per-op seconds a
-wave), ``chiprun_out/spans_<cell>.json`` (the program's spans of those waves)
-and ``chiprun_out/fleet_pass_hlo.txt``. ``--rehearse`` runs the cell's tiny
-sizes on whatever device is there (no TPU plane: the tables come out empty).
+``--kernel`` names another jitted kernel of ``scheduler/fleet.py`` to join
+scopes for (PR 32: ``_fleet_select``, whose stages are ``select.*`` scopes
+inside ``fleet.select``); instruction names repeat across modules, so read
+the rows of that kernel's module only. Writes ``chiprun_out/scope_profile.json``
+(per-scope and per-op seconds a wave), ``chiprun_out/spans_<cell>.json`` (the
+program's spans of those waves) and ``chiprun_out/<kernel>_hlo.txt``.
+``--rehearse`` runs the cell's tiny sizes on whatever device is there (no TPU
+plane: the tables come out empty).
 """
 
 import collections
@@ -33,7 +38,7 @@ os.environ.setdefault("KARMADA_TPU_CACHE_MIN_COMPILE_SECS", "0")
 
 from benchmark import run  # noqa: E402
 
-SCOPE = re.compile(r"fleet\.[a-z]+")
+SCOPE = re.compile(r"(?:fleet|select)\.[a-z]+")
 INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = ")
 OPNAME = re.compile(r'op_name="([^"]*)"')
 CALLS = re.compile(r"calls=(%[\w.\-]+)")
@@ -96,7 +101,9 @@ def main():
     bench, entry, cfg, traffic = run.load_cell(cell, "--rehearse" in sys.argv)
     import karmada_tpu.scheduler.fleet as fleet_mod
 
-    real_pass = fleet_mod._fleet_pass
+    kernel = next((a.split("=", 1)[1] for a in sys.argv
+                   if a.startswith("--kernel=")), "_fleet_pass")
+    real_pass = getattr(fleet_mod, kernel)
     last_call = {}
 
     def spy(*args, **kw):
@@ -105,7 +112,7 @@ def main():
         last_call["kw"] = kw
         return real_pass(*args, **kw)
 
-    fleet_mod._fleet_pass = spy
+    setattr(fleet_mod, kernel, spy)
     dep, mix = run.build(cfg, traffic, seed, run.log)
     dep.setup()
     mix.build()
@@ -138,7 +145,8 @@ def main():
     if last_call and "--skip-hlo" not in sys.argv:
         hlo = real_pass.lower(*last_call["avals"], **last_call["kw"]
                               ).compile().as_text()
-        with open(os.path.join(ROOT, "chiprun_out", "fleet_pass_hlo.txt"), "w") as f:
+        with open(os.path.join(ROOT, "chiprun_out",
+                               f"{kernel.strip('_')}_hlo.txt"), "w") as f:
             f.write(hlo[:8_000_000])
         smap = scope_map(hlo)
         print("hlo instructions", len(smap), "scoped",
