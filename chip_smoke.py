@@ -160,6 +160,7 @@ def _numpy_mismatches(snap, problems, results, host_eng, idx) -> int:
 
     import bench
     from karmada_tpu.refimpl.divider_np import assign_batch_np
+    from karmada_tpu.refimpl.failover_np import solve_one_ordered
 
     bad = 0
     names = snap.names
@@ -183,9 +184,25 @@ def _numpy_mismatches(snap, problems, results, host_eng, idx) -> int:
         got, unsched = assign_batch_np(
             strategy, reps, feasible, static_w, avail, prev, fresh
         )
+        # rows under ordered affinity terms go through the reference's own
+        # retry loop (try a group, divide, on failure the next)
+        ordered = [k for k, p in enumerate(sub) if p.placement is not None
+                   and len(p.placement.cluster_affinities) > 1]
+        if ordered:
+            compiled = [host_eng._compiled(p.placement) for p in sub]
+            base = host_eng._pack_chunk(sub, compiled, 0, with_affinity=False)[0]
         for k, i in enumerate(part):
             res = results[i]
-            if unsched[k] or not feasible[k].any():
+            if k in ordered:
+                terms = compiled[k].terms
+                row, ti, err = solve_one_ordered(
+                    np.stack([m for _, m in terms]), base[k], strategy[k],
+                    reps[k], static_w[k], avail[k], prev[k], fresh[k])
+                good = res.affinity_name == terms[ti][0] and (
+                    not res.success if err else res.success
+                    and dict(res.clusters) == {
+                        names[j]: int(row[j]) for j in np.flatnonzero(row)})
+            elif unsched[k] or not feasible[k].any():
                 good = not res.success
             else:
                 want = {
@@ -213,19 +230,27 @@ def _drift(clusters, seed: int):
     return ClusterSnapshot(clusters)
 
 
-#: placements of the engine stage's mixed-policy batch (_policy_problems)
-N_POLICIES = 6
+#: placements of the engine stage's mixed-policy batch (_policy_problems);
+#: the last two hold two ordered affinity terms, so the fleet table holds
+#: one slot more for each of them (a slot a (placement, term))
+N_POLICIES = 8
+N_POLICY_SLOTS = N_POLICIES + 2
 
 
 def _policy_problems(clusters, n: int) -> list:
     """``n`` bindings under the documented policy kinds side by side:
     Duplicated under a label selector, dynamic and static weights,
-    Aggregated, and dynamic weight / Aggregated under spread constraints."""
+    Aggregated, dynamic weight / Aggregated under spread constraints, and
+    dynamic weight / Aggregated under two ordered affinity terms (primary: a
+    region, then a backup), a share of whose rows hold eviction tasks."""
     import numpy as np
 
     from karmada_tpu.api.policy import (
         ClusterAffinity,
+        ClusterAffinityTerm,
+        FieldSelector,
         LabelSelector,
+        LabelSelectorRequirement,
         SpreadConstraint,
     )
     from karmada_tpu.scheduler import BindingProblem
@@ -240,6 +265,16 @@ def _policy_problems(clusters, n: int) -> list:
             spread_by_field=field, min_groups=lo, max_groups=hi
         )
 
+    regions = sorted({cl.spec.region for cl in clusters if cl.spec.region})
+
+    def term(name, region=None):
+        return ClusterAffinityTerm(
+            affinity_name=name,
+            field_selector=FieldSelector(match_expressions=[
+                LabelSelectorRequirement(
+                    key="region", operator="In", values=[region])])
+            if region else None)
+
     policies = [
         builders.duplicated_placement(cluster_affinity=ClusterAffinity(
             label_selector=LabelSelector(match_labels={"env": "prod"}))),
@@ -251,22 +286,42 @@ def _policy_problems(clusters, n: int) -> list:
             sc("region", 2, 3), sc("cluster", 3, 6)]),
         builders.aggregated_placement(spread_constraints=[
             sc("cluster", 2, 4)]),
+        builders.dynamic_weight_placement(cluster_affinities=[
+            term("primary", regions[0]), term("backup", regions[-1])]),
+        builders.aggregated_placement(cluster_affinities=[
+            term("primary", regions[-1]), term("backup")]),
     ]
     assert len(policies) == N_POLICIES
-    kinds = rng.choice(N_POLICIES, n, p=[0.3, 0.25, 0.15, 0.2, 0.05, 0.05])
+    kinds = rng.choice(
+        N_POLICIES, n, p=[0.27, 0.22, 0.13, 0.18, 0.05, 0.05, 0.05, 0.05])
+    primary = {
+        6: [j for j, cl in enumerate(clusters) if cl.spec.region == regions[0]],
+        7: [j for j, cl in enumerate(clusters) if cl.spec.region == regions[-1]],
+    }
     problems = []
     for i in range(n):
         size = 1 + i % 8
+        pool = primary.get(kinds[i], range(len(names)))
         held = (
-            rng.choice(len(names), int(rng.integers(1, 5)), replace=False)
+            rng.choice(pool, min(len(pool), int(rng.integers(1, 5))),
+                       replace=False)
             if rng.random() < 0.7 else ()
         )
+        # half the failover rows were evicted from some of what they held,
+        # one in eight from their whole primary region (it falls back)
+        evict = ()
+        if kinds[i] in primary and rng.random() < 0.5:
+            evict = tuple(names[j] for j in held[: 1 + len(held) // 2])
+            held = held[len(evict):]
+            if rng.random() < 0.25:
+                evict = tuple(names[j] for j in primary[kinds[i]][:8])
         problems.append(BindingProblem(
             key=f"policy-{i}", placement=policies[kinds[i]],
             replicas=int(rng.integers(1, 40)),
             requests={"cpu": 250 * size, "memory": (512 << 20) * size},
             gvk="apps/v1/Deployment",
             prev={names[j]: int(rng.integers(1, 9)) for j in held},
+            evict_clusters=evict,
             fresh=bool(rng.random() < 0.05),
         ))
     return problems
@@ -459,9 +514,19 @@ def stage_engine(
     _check(pol_bad == 0, f"{pol_bad}/{2 * len(pol_idx)} mixed-policy rows "
            "differ from refimpl (divider_np + spread)")
     _check(
-        pol_slots[0] == pol_slots[1] <= N_POLICIES,
+        pol_slots[0] == pol_slots[1] <= N_POLICY_SLOTS,
         f"the placement table went {pol_slots} slots over two snapshot "
-        f"generations of {N_POLICIES} placements",
+        f"generations of {N_POLICIES} placements ({N_POLICY_SLOTS} terms)",
+    )
+    # the rows under ordered affinity terms had their term chosen on the
+    # device (the fleet table's term kernel), at this stage's width
+    pol_terms = pol_eng._fleet._term_cache
+    failover_rows = pol_terms.n if pol_terms is not None else 0
+    _check(
+        failover_rows == sum(
+            1 for p in pol_problems if len(p.placement.cluster_affinities) > 1),
+        "the mixed-policy batch's multi-term rows did not all ride the "
+        "fleet table's term kernel",
     )
 
     lib = native.get()
@@ -478,6 +543,7 @@ def stage_engine(
         "policy_rows_checked": 2 * len(pol_idx),
         "policy_slots": pol_slots[1],
         "policy_rows_device_selected": pol_device_rows,
+        "failover_rows_device_chosen": failover_rows,
         "oracle_checked": len(oracle_idx),
         "mismatches": 0,
         "delta_rows_packed": packed,
@@ -972,6 +1038,7 @@ def stage_sidecar(
 
     from karmada_tpu import cli
     from karmada_tpu.localup import (
+        drain_output,
         scrape_line,
         scrape_solver_backend,
         spawn_child,
@@ -999,6 +1066,9 @@ def stage_sidecar(
         scrape_line(proc, r"metrics listening on port (\d+)")
         backend = scrape_solver_backend(proc, solver_platform)
         timings["sidecar_up_s"] = time.perf_counter() - t0
+        # nothing below reads the sidecar's output: keep its pipe empty, or
+        # a chatty sidecar blocks in a write while a solve is in flight
+        drain_output(proc)
 
         solver = RemoteSolver(f"127.0.0.1:{port}", timeout_seconds=600.0)
         passes: list = []

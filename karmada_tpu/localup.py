@@ -138,6 +138,27 @@ def _scrape_port(proc: subprocess.Popen, pattern: str, timeout: float = 240.0) -
     return int(scrape_line(proc, pattern, timeout))
 
 
+def drain_output(proc: subprocess.Popen) -> None:
+    """Keep reading, and dropping, what the child writes from here on, on a
+    daemon thread. ``spawn_child`` hands the child one pipe for stdout and
+    stderr; once the lines a launcher waits for are scraped nobody reads
+    it, and a child that has written it full (64 KiB: XLA's warnings on
+    loading a compile cache alone do that) blocks in its next write, in the
+    middle of whatever it was serving."""
+    import threading
+
+    fd = proc.stdout.fileno()
+
+    def pump() -> None:
+        try:
+            while os.read(fd, 1 << 16):
+                pass
+        except (OSError, ValueError):
+            pass  # the pipe closed under us: the child is gone
+
+    threading.Thread(target=pump, daemon=True).start()
+
+
 def scrape_solver_backend(
     proc: subprocess.Popen, platform: str, timeout: float = 120.0
 ) -> str:

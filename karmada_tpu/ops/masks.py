@@ -141,23 +141,37 @@ def _first_fit_group_kernel(
     xp, cand_tc, term_len, avail, replicas, prev, dynamic, fresh
 ):
     """Backend-generic body of :func:`first_fit_group` (xp is numpy for
-    the snapshot path, jax.numpy under a trace)."""
-    _b, t, _c = cand_tc.shape
-    num = replicas.astype(xp.int64)
-    prev_full_sum = prev.sum(axis=1)
-    cand_any = cand_tc.any(axis=2)
+    the snapshot path, jax.numpy under a trace). ``cand_tc`` is the stacked
+    bool[B, T, C] or a sequence of T bool[B, C] planes.
+
+    The arithmetic keeps the dtype it is given, so the fleet table's term
+    kernel runs it in int32: every sum is compared with the row's replicas
+    or less, so each element is first cut at replicas + 1. A sum that holds
+    a cut element is at least replicas + 1 and answers every comparison as
+    the exact sum would; a sum that holds none is exact. In int32 the
+    caller keeps (replicas + 1) x (C + previous sites) of a dynamic row
+    under 2^31 (scheduler.fleet: MAX_REPLICAS_FAST)."""
+    planes = (
+        cand_tc if isinstance(cand_tc, (list, tuple))
+        else [cand_tc[:, ti, :] for ti in range(cand_tc.shape[1])]
+    )
+    t = len(planes)
+    num = replicas
+    lim = (num + 1)[:, None]
+    avail = xp.minimum(avail, lim)
+    prev = xp.minimum(prev, lim)
+    # sums keep the dtype (jax.numpy would widen an int32 sum under x64)
+    dt = avail.dtype
+    prev_full_sum = prev.sum(axis=1, dtype=dt)
+    cand_any = xp.stack([p.any(axis=1) for p in planes], axis=1)
     # per-term masked sums as a stack over the short static T axis (the
     # same O(B*T*C) adds as the old in-place fill, but expressible on
     # immutable device arrays)
     avail_sum = xp.stack(
-        [xp.where(cand_tc[:, ti, :], avail, 0).sum(axis=1)
-         for ti in range(t)],
-        axis=1,
+        [xp.where(p, avail, 0).sum(axis=1, dtype=dt) for p in planes], axis=1,
     )
     prev_sum = xp.stack(
-        [xp.where(cand_tc[:, ti, :], prev, 0).sum(axis=1)
-         for ti in range(t)],
-        axis=1,
+        [xp.where(p, prev, 0).sum(axis=1, dtype=dt) for p in planes], axis=1,
     )
     dyn = dynamic[:, None]
     fr = fresh[:, None]

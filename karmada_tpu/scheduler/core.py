@@ -303,6 +303,8 @@ class TensorScheduler:
         self._batch_token = None
         # the mask_token the host-selection line was last printed for
         self._host_select_told = None
+        # the by-reason counts the host-path line was last printed for
+        self._host_path_told = None
         # per-pass dirty-key set (ISSUE 20): the controller's invalidation
         # sources (watch bus, quota bumps, estimator movement, evictions)
         # accumulate binding keys whose problems changed since the last
@@ -383,18 +385,21 @@ class TensorScheduler:
             return hit[1]
         cp = compile_placement(placement, self.snapshot)
         # placement-level half of the fleet-eligibility predicate, computed
-        # once per compiled placement: the per-problem check in schedule()
-        # runs 100k times per storm pass and must stay a plain attribute
-        # test, not a function call (measured ~240ms/pass as a method)
+        # once per compiled placement (the per-binding half is
+        # fleet.row_rides; schedule() applies both to every row of a batch
+        # whose identity fast path did not fire)
+        from .fleet import T_CAP
         from .spread import should_ignore_spread_constraint
 
-        cp.fleet_single_term = len(cp.terms) == 1 and (
+        unconstrained = (
             not cp.spread_constraints
             or should_ignore_spread_constraint(cp.placement or Placement())
         )
-        cp.spread_single_term = (
-            len(cp.terms) == 1 and not cp.fleet_single_term
-        )
+        # ordered affinity terms ride as term slots of the fleet's rows, up
+        # to T_CAP of them; several terms TOGETHER with spread constraints
+        # keep the host's per-term round loop
+        cp.fleet_terms = len(cp.terms) <= T_CAP and unconstrained
+        cp.spread_single_term = len(cp.terms) == 1 and not unconstrained
         self._placement_cache[key] = (placement, cp)
         # the cap must exceed the fleet table's live-slot budget: a live
         # placement set larger than the LRU turns a storm's cyclic access
@@ -1643,8 +1648,7 @@ class TensorScheduler:
                 diff = np.union1d(diff, np.asarray(extra, np.int64))
         if diff.size * 2 > n:
             return None
-        from ..ops.divide import DUPLICATED as _DUP
-        from .fleet import K_PREV as _KP, MAX_REPLICAS_FAST as _MRF
+        from .fleet import row_rides
 
         fp, fc = self._batch_cache
         fp2 = list(fp)
@@ -1653,14 +1657,10 @@ class TensorScheduler:
             pos = int(pos)
             p = problems[pos]
             cp = self._compiled(p.placement)
-            if not (
-                cp.fleet_single_term
-                and not p.evict_clusters
-                and len(p.prev) <= _KP
-                and (cp.strategy == _DUP or p.replicas <= _MRF)
-            ):
-                # a changed row left the fleet-eligible set (spread/
-                # multi-term/eviction): the full prologue partitions it
+            if not (cp.fleet_terms and row_rides(p, cp)):
+                # a changed row left the fleet-eligible set (or is
+                # spread-constrained, whose selection the full prologue
+                # arranges): the full prologue partitions it
                 return None
             fp2[pos] = p
             fc2[pos] = cp
@@ -1755,8 +1755,7 @@ class TensorScheduler:
             self.last_breakdown = {"compile": _time.perf_counter() - t0}
             if fleet_ok:
                 t0 = _time.perf_counter()
-                from ..ops.divide import DUPLICATED as _DUP
-                from .fleet import K_PREV as _KP, MAX_REPLICAS_FAST as _MRF
+                from .fleet import row_rides
 
                 # spread-constraint rows ride the fleet too: their
                 # selection is ROW STATE of the fleet table (one packed
@@ -1787,21 +1786,22 @@ class TensorScheduler:
                 self.last_breakdown["select"] = _time.perf_counter() - t0
 
                 t0 = _time.perf_counter()
-                # THE fleet-eligibility predicate (single source of
-                # truth): placement half precomputed as
-                # cp.fleet_single_term (a spread-constrained row passes it
-                # through the selection it was given); the per-problem
-                # half stays a plain inline expression because this
-                # comprehension runs B times per storm pass — a method
-                # call per row costs ~2.4us x 100k = 240ms
+                # THE fleet-eligibility predicate, from what the code
+                # observes: the placement half precomputed as
+                # cp.fleet_terms (at most T_CAP ordered affinity terms, and
+                # no spread constraints beside several of them; a
+                # spread-constrained single-term row passes through the
+                # selection it was given), the per-binding half
+                # fleet.row_rides (at most K_EVICT eviction tasks and
+                # K_PREV previous sites, Divided replicas within the entry
+                # vector): the one expression _delta_pass applies too.
+                # Rows past it take the host path, row by row, below
                 fast_idx = [
                     i
                     for i, (p, cp) in enumerate(zip(problems, compiled))
-                    if (cp.fleet_single_term or i in selected)
-                    and not p.evict_clusters
-                    and len(p.prev) <= _KP
-                    and (cp.strategy == _DUP or p.replicas <= _MRF)
+                    if (cp.fleet_terms or i in selected) and row_rides(p, cp)
                 ]
+                self._report_host_path(problems, compiled, fast_idx)
                 self.last_breakdown["eligible"] = _time.perf_counter() - t0
         if fleet_ok:
             if len(fast_idx) >= self.fleet_threshold:
@@ -1839,7 +1839,8 @@ class TensorScheduler:
                         selections = (pos[rides], sel_bits[rides])
                 self.solve_batches += 1
                 fast_res = self._fleet.schedule(
-                    fp, fc, selections=selections, select=select
+                    fp, fc, selections=selections, select=select,
+                    host_rows=len(problems) - len(fast_idx),
                 )
                 self.last_breakdown.update(self._fleet.last_breakdown)
                 if len(fast_idx) == len(problems):
@@ -1871,6 +1872,11 @@ class TensorScheduler:
                     for i, res in zip(slow_idx, slow_res):
                         results[i] = res
                 return results
+        # no fleet pass: an engine-level feature, or fewer eligible rows
+        # than the threshold, keeps the whole batch on the host path
+        from ..utils.metrics import fleet_host_path_rows
+
+        fleet_host_path_rows.set(len(problems))
         return self._schedule_host(problems, compiled)
 
     def _host_only_estimators(self) -> bool:
@@ -1892,6 +1898,48 @@ class TensorScheduler:
             probe = getattr(est, "refresh_token", None)
             tokens.append(probe() if probe is not None else None)
         return tuple(tokens)
+
+    def _report_host_path(self, problems, compiled, fast_idx) -> None:
+        """Say how many rows of the batch leave the fleet table for the
+        host path and why: the gauge every batch, a line on stderr once a
+        batch layout (the counts by reason). Each of those rows is packed
+        and solved on the host in every wave."""
+        from ..utils.metrics import fleet_host_path_rows
+
+        fleet_host_path_rows.set(len(problems) - len(fast_idx))
+        if len(fast_idx) == len(problems):
+            return
+        from .fleet import K_EVICT, T_CAP
+
+        rides = set(fast_idx)
+        why = {"terms": 0, "tasks": 0, "terms+spread": 0, "other": 0}
+        for i, (p, cp) in enumerate(zip(problems, compiled)):
+            if i in rides:
+                continue
+            if len(cp.terms) > T_CAP:
+                why["terms"] += 1
+            elif len(p.evict_clusters) > K_EVICT:
+                why["tasks"] += 1
+            elif len(cp.terms) > 1 and not cp.fleet_terms:
+                why["terms+spread"] += 1
+            else:
+                why["other"] += 1
+        told = tuple(why.values())
+        if told != self._host_path_told:
+            import sys as _sys
+
+            self._host_path_told = told
+            print(
+                f"# fleet host path: {len(problems) - len(fast_idx)} of "
+                f"{len(problems)} rows: {why['terms']} with more than "
+                f"{T_CAP} affinity terms, {why['tasks']} with more than "
+                f"{K_EVICT} eviction tasks, {why['terms+spread']} with "
+                f"several terms and spread constraints, {why['other']} "
+                "past another cap (previous sites, replicas, a failed "
+                "host selection)",
+                file=_sys.stderr,
+                flush=True,
+            )
 
     def _report_host_selected(self, rows: int) -> None:
         """Say how many of the batch's spread rows the HOST selects (the

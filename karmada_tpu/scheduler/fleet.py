@@ -67,16 +67,32 @@ decode 9 ms):
   computes, packs, compares nor uploads it. A snapshot with more regions
   than the kernel's subset table (R_CAP) keeps the host selection, uploaded
   with the rows whose selection moved (_apply_selections). The placement
-  table holds the placements users wrote, however long the federation runs.
+  table holds the placements users wrote, however long the federation runs;
+- failover is row state too. The placement table interns one slot for each
+  (placement, affinity term): term t's slot carries that term's affinity
+  plane, the placement's taint plane and its static weights. A row holds
+  its ordered term slots (``term_slots``, T_CAP of them, -1 = unused) and
+  its graceful-eviction tasks (``evict_sites``, K_EVICT cluster indices,
+  -1 = unused); ``cp_idx`` is the slot _row_masks gathers: the CHOSEN
+  term's. _row_masks masks the evicted members of every row by one
+  compare-and-reduce (the form ``fleet.prev`` has), and in every pass that
+  holds multi-term rows one batched kernel (_fleet_terms) builds each
+  term's candidate set with _row_masks' own algebra, applies the divider's
+  schedulability predicate (ops.masks._first_fit_group_kernel, in int32)
+  on the availability the pass divides on, and writes the first fitting
+  term's slot into the resident ``cp_idx`` and its index into ``term_sel``
+  (one byte a row: what a result's ``affinity_name`` reads).
 
-Eligibility: a binding rides the fleet path when its placement has a single
-affinity term (with spread constraints or without: a FitError of the device
-selection is the row's empty candidate set; where the host selects, the
-selection must have accepted the row), no eviction tasks, <= K_PREV
-previous sites, and
+Eligibility: a binding rides the fleet path when its placement has at most
+T_CAP affinity terms and, with more than one term, no spread constraints
+(a single-term placement rides with spread constraints or without: a
+FitError of the device selection is the row's empty candidate set; where
+the host selects, the selection must have accepted the row), and the
+binding holds <= K_EVICT eviction tasks, <= K_PREV previous sites, and
 (for Divided strategies) replicas <= MAX_REPLICAS_FAST so the per-row
-entry-vector bound holds. Everything else takes the general host path — the
-two paths are differentially fuzz-tested for identical placements.
+entry-vector bound holds. Everything else takes the general host path, row
+by row in the same batch — the two paths are differentially fuzz-tested
+for identical placements.
 """
 
 from __future__ import annotations
@@ -93,9 +109,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..ops.divide import AGGREGATED, DUPLICATED as S_DUPLICATED, _divide_batch
+from ..ops.divide import (
+    AGGREGATED,
+    DUPLICATED as S_DUPLICATED,
+    DYNAMIC_WEIGHT,
+    _divide_batch,
+)
 from ..ops.estimate import MAX_INT32, merge_estimates
 from ..ops.explain import explain_pass as _explain_pass
+from ..ops.masks import _first_fit_group_kernel
 from ..ops.preempt import preempt_select as _preempt_select
 from ..ops.quota import (
     quota_admit as _quota_admit,
@@ -120,6 +142,7 @@ _TRACE_KERNELS = {
     "E": "fleet_entries",
     "B": "fleet_bits",
     "T": "fleet_select",
+    "R": "fleet_terms",
     "S": "state_scatter",
     "G": "meta_gather",
     "F": "estimate_fold",
@@ -129,6 +152,10 @@ K_PREV = 32  # max previous-assignment sites on the fast path (small fleets
 # legitimately spread one binding over dozens of clusters; rows beyond this
 # take the general host path)
 MAX_REPLICAS_FAST = 128  # divided-strategy replica cap (bounds the entry vector)
+T_CAP = 4  # ordered affinity terms a row holds as term slots (ClusterAffinities
+# is "primary, then backup": placements past this take the general host path)
+K_EVICT = 8  # graceful-eviction tasks a row holds as cluster indices (a task
+# drains within its grace period; rows past this take the general host path)
 MAX_SLOTS = 8192  # unique placements/gvks/profiles FLOOR before slot
 # eviction engages. Sizing (bitpacked layout): a slot costs two packed
 # mask planes (2*ceil(C/8) uint8) + an int32 static-weight row (4C) ~
@@ -141,6 +168,19 @@ MAX_SLOTS = 8192  # unique placements/gvks/profiles FLOOR before slot
 CP_TABLE_MAX_BYTES = 1536 << 20  # device cp-table budget (HBM)
 MAX_SLOTS_HARD = 65536  # interning-dict / host-staging sanity bound
 E_ROUND = 1 << 18  # entry-buffer quantum (bounds trace churn)
+
+
+def row_rides(p, cp) -> bool:
+    """The per-binding half of THE fleet-eligibility predicate (the
+    placement half is CompiledPlacement.fleet_terms, or the selection a
+    spread-constrained row was given): the row state has room for the
+    binding's eviction tasks and previous sites, and a Divided row's
+    replicas bound its entry vector."""
+    return (
+        len(p.evict_clusters) <= K_EVICT
+        and len(p.prev) <= K_PREV
+        and (cp.strategy == S_DUPLICATED or p.replicas <= MAX_REPLICAS_FAST)
+    )
 
 
 def _pow2(n: int) -> int:
@@ -256,7 +296,7 @@ def _unpack_bits(bits_u8, c: int):
 
 
 def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-               pcc, vc, sbc, chunk: int, c: int):
+               pcc, evc, vc, sbc, chunk: int, c: int):
     """Per-chunk previous-assignment grid + THE feasibility algebra,
     shared by every kernel that needs it (_fleet_pass, _fleet_bits) so
     the mask expression cannot drift between the solve and the
@@ -269,7 +309,9 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
     measured 0.57 s -> ~0.2 s over 245 chunks), and the slot table's HBM
     footprint drops ~3x with it. ``sbc`` uint8[chunk, W8] is each row's
     own selection mask, packed the same way: the SelectClusters result of
-    a spread-constrained row, all ones for every other row."""
+    a spread-constrained row, all ones for every other row. ``evc``
+    int32[chunk, K_EVICT] is each row's graceful-eviction tasks as cluster
+    indices (-1 = unused): the ClusterEviction filter, for every row."""
     with jax.named_scope("fleet.prev"):
         # compare-and-sum, not a scatter-add: the K_PREV (site, count)
         # pairs of a row against the cluster iota, summed over the pairs.
@@ -282,6 +324,10 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
         prev = jnp.where(
             psc[:, :, None] == iota_c, pcc[:, :, None], 0
         ).sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("fleet.evict"):
+        # the same compare-and-reduce: a row's K_EVICT task sites against
+        # the cluster iota (-1 meets no cluster)
+        evicted = (evc[:, :, None] == iota_c).any(axis=1)
     prev_mask = prev > 0
     # plain [B]-index row gathers: re-probed on the current backend at
     # U in {2..3500} x W in {5k, 15k} — compiles fine and runs at
@@ -298,6 +344,7 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
         aff_ok
         & (gvk_ok | (prev_mask & incomplete_en[None, :]))
         & (taint_ok | prev_mask)  # taints (leniency)
+        & ~evicted  # graceful-eviction tasks
         & _unpack_bits(sbc, c)  # the row's spread selection
         & vc[:, None]
     )
@@ -370,6 +417,7 @@ def _fleet_pass(
     replicas, strategy,  # int32[cap]
     fresh,  # bool[cap]
     prev_sites, prev_counts,  # int32[cap, K_PREV]
+    evict_sites,  # int32[cap, K_EVICT] eviction-task cluster indices (-1 = none)
     sel_bits,  # uint8[cap, W8] bitpacked spread selection a row (ones = none)
     res_dense,  # uint8[cap, C] last pass's dense assignment (donated)
     res_meta,  # int32[cap] last pass's meta words (donated)
@@ -417,8 +465,10 @@ def _fleet_pass(
         ps = prev_sites[r]
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
         # an all-rows pass reads row i at position i (the padding past the
-        # last row is masked by ``valid``), so the selection masks are
-        # sliced from the resident as they lie: no gather
+        # last row is masked by ``valid``), so the eviction sites and the
+        # selection masks are sliced from the residents as they lie: no
+        # gather
+        ev = evict_sites if all_rows else evict_sites[r]
         sb = sel_bits if all_rows else sel_bits[r]
 
     def body(carry, i):
@@ -429,7 +479,7 @@ def _fleet_pass(
             )
             cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
             repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-            psc, pcc, sbc = sl(ps), sl(pc), sl(sb)
+            psc, pcc, evc, sbc = sl(ps), sl(pc), sl(ev), sl(sb)
             rc = sl(r)
             repsc, stc, frc, vc = (
                 shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
@@ -439,11 +489,11 @@ def _fleet_pass(
                 shard(cpc, "b"), shard(gvc, "b"), shard(pfc, "b")
             )
             psc, pcc = shard(psc, "b", None), shard(pcc, "b", None)
-            sbc = shard(sbc, "b", None)
+            evc, sbc = shard(evc, "b", None), shard(sbc, "b", None)
         with jax.named_scope("fleet.masks"):
             prev, static_w, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, vc, sbc, chunk, c,
+                pcc, evc, vc, sbc, chunk, c,
             )
             prev = shard(prev, "b", c_ax)
             feasible = shard(feasible, "b", c_ax)
@@ -677,7 +727,8 @@ def _decode_entry_wire(raw2, cap_used: int, byte_wire: bool, pack21: bool):
 def _fleet_bits(
     cp_bits, cp_static, gvk_bits, prof_table, incomplete_en, rows,
     cp_idx, gvk_idx, prof_idx, replicas, strategy, fresh,
-    prev_sites, prev_counts, sel_bits, *, chunk: int, n_chunks: int,
+    prev_sites, prev_counts, evict_sites, sel_bits, *, chunk: int,
+    n_chunks: int,
 ):
     """Feasibility bitsets as their own lazily-DISPATCHED kernel: only
     Duplicated / zero-replica rows ever read them (their result IS the
@@ -695,6 +746,7 @@ def _fleet_bits(
         gv = gvk_idx[r]
         ps = prev_sites[r]
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
+        ev = evict_sites[r]
         sb = sel_bits[r]
 
         def body(carry, i):
@@ -705,7 +757,7 @@ def _fleet_bits(
             psc, pcc, sbc = sl(ps), sl(pc), sl(sb)
             _, _, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, vc, sbc, chunk, c,
+                pcc, sl(ev), vc, sbc, chunk, c,
             )
             pad = (-c) % 32
             f = jnp.pad(feasible, ((0, 0), (0, pad)))
@@ -736,8 +788,8 @@ def _fleet_select(
     region_of,  # int32[C] region_table of the snapshot
     sub_bits, sub_prefix,  # subset_table(R_CAP)
     rows,  # int32[n_pad] the spread-constrained table rows (-1 = padding)
-    cp_idx, gvk_idx, prof_idx, replicas, prev_sites, prev_counts, sel_bits,
-    *, chunk: int, n_chunks: int,
+    cp_idx, gvk_idx, prof_idx, replicas, prev_sites, prev_counts,
+    evict_sites, sel_bits, *, chunk: int, n_chunks: int,
 ):
     """The Select stage (SelectClusters) of the batch's spread-constrained
     rows, from the resident row state, written into the resident
@@ -759,6 +811,7 @@ def _fleet_select(
         reps = jnp.where(valid, replicas[r], 0)
         ps = prev_sites[r]
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
+        ev = evict_sites[r]
         old = sel_bits[r]
         unselected = jnp.full((chunk, w8), 0xFF, jnp.uint8)
         weights = jnp.int32(1) << jnp.arange(8, dtype=jnp.int32)
@@ -771,7 +824,7 @@ def _fleet_select(
             cpc, gvc, pfc, repsc, vc = sl(cp), sl(gv), sl(pf), sl(reps), sl(valid)
             prev, _, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc,
-                sl(ps), sl(pc), vc, unselected, chunk, c,
+                sl(ps), sl(pc), sl(ev), vc, unselected, chunk, c,
             )
             avail = merge_estimates(repsc, (prof_table[pfc],))
             params = sp_params[cpc]
@@ -801,6 +854,97 @@ def _fleet_select(
         return sel_bits, jnp.stack([fit_errors, moved])
 
 
+#: bytes the [chunk, C] temporaries of one _fleet_terms chunk may take (a
+#: handful of int32 grids and T_CAP candidate planes)
+TERMS_TEMP_BYTES = 256 << 20
+
+
+def _terms_chunk(n: int, c: int) -> int:
+    by_temp = TERMS_TEMP_BYTES // (4 * (4 + T_CAP) * max(c, 1))
+    by_temp = 1 << max(by_temp, 256).bit_length() - 1
+    return min(4096, by_temp, _pow2(max(n, 256)))
+
+
+@partial(jax.jit, static_argnames=("chunk", "n_chunks"))
+def _fleet_terms(
+    cp_bits, cp_static, gvk_bits, prof_table, incomplete_en,
+    rows,  # int32[n_pad] the multi-term table rows (-1 = padding)
+    term_slots,  # int32[cap, T_CAP] a row's ordered term slots (-1 = unused)
+    term_sel,  # uint8[cap] the index of the term each row was last given
+    cp_idx, gvk_idx, prof_idx, replicas, strategy, fresh,
+    prev_sites, prev_counts, evict_sites,
+    *, chunk: int, n_chunks: int,
+):
+    """Ordered ClusterAffinities on the device: for the batch's multi-term
+    rows, the first affinity term whose candidates the divider can place
+    the row on (scheduler.go scheduleResourceBindingWithClusterAffinities:
+    try a group, on failure the next), from the resident row state. Each
+    term's candidate set is the pass's own expression (_row_masks gathered
+    at that term's slot, with no selection: a multi-term row rides without
+    spread constraints), availability is the pass's (merge_estimates over
+    the resident profile table), and the predicate is the host path's
+    (_first_fit_group_kernel, in int32: the divider's cohort math; a
+    Duplicated or static-weight row fits where a candidate exists). The
+    chosen term's slot goes into the resident ``cp_idx`` at those rows and
+    its index into ``term_sel``; a row no term fits keeps its LAST live
+    term, whose division reports the failure. Returns (cp_idx, term_sel,
+    int32[2]: rows whose chosen term is not the first, rows no term
+    fits)."""
+    c = cp_static.shape[1]
+    cap = cp_idx.shape[0]
+    with jax.named_scope("fleet.terms"):
+        valid = rows >= 0
+        r = jnp.maximum(rows, 0)
+        unselected = jnp.full((chunk, (c + 7) // 8), 0xFF, jnp.uint8)
+
+        def body(carry, i):
+            cp_out, sel_out, fallback, unfit = carry
+            sl = lambda a: lax.dynamic_slice_in_dim(
+                a, i * chunk, chunk, axis=0
+            )
+            rc, vc = sl(r), sl(valid)
+            ts = term_slots[rc]
+            gvc, psc, evc = gvk_idx[rc], prev_sites[rc], evict_sites[rc]
+            pcc = jnp.where(vc[:, None], prev_counts[rc], 0)
+            # a Divided row rides the fleet at replicas <= MAX_REPLICAS_FAST;
+            # the cut keeps the sums of the other rows (whose predicate is
+            # "a candidate exists") inside int32 as well
+            reps = jnp.where(
+                vc, jnp.minimum(replicas[rc], MAX_REPLICAS_FAST), 0
+            )
+            st = strategy[rc]
+            planes = []
+            for t in range(T_CAP):
+                slot = ts[:, t]
+                prev, _, feasible = _row_masks(
+                    cp_bits, cp_static, gvk_bits, incomplete_en,
+                    jnp.maximum(slot, 0), gvc, psc, pcc, evc,
+                    vc & (slot >= 0), unselected, chunk, c,
+                )
+                planes.append(feasible)
+            avail = merge_estimates(reps, (prof_table[prof_idx[rc]],))
+            rank, fit = _first_fit_group_kernel(
+                jnp, planes, (ts >= 0).sum(axis=1, dtype=jnp.int32), avail,
+                reps, prev, (st == DYNAMIC_WEIGHT) | (st == AGGREGATED),
+                fresh[rc] & vc,
+            )
+            chosen = jnp.take_along_axis(ts, rank[:, None], axis=1)[:, 0]
+            at = jnp.where(vc, rc, cap)
+            cp_out = cp_out.at[at].set(chosen, mode="drop")
+            sel_out = sel_out.at[at].set(rank.astype(jnp.uint8), mode="drop")
+            return (
+                cp_out, sel_out,
+                fallback + (vc & fit & (rank > 0)).sum(dtype=jnp.int32),
+                unfit + (vc & ~fit).sum(dtype=jnp.int32),
+            ), None
+
+        (cp_idx, term_sel, fallback, unfit), _ = lax.scan(
+            body, (cp_idx, term_sel, jnp.int32(0), jnp.int32(0)),
+            jnp.arange(n_chunks),
+        )
+        return cp_idx, term_sel, jnp.stack([fallback, unfit])
+
+
 @jax.jit
 def _gather_meta(res_meta, rows):
     """Changed-meta fallback when phase A's tuned meta buffer overflows:
@@ -821,6 +965,8 @@ _fleet_entries.row_coupled = True
 _fleet_bits.row_coupled = False
 # writes land at ``rows`` and the two counts sum over every row
 _fleet_select.row_coupled = True
+# the same: the chosen slots land at ``rows``, the counts sum over the rows
+_fleet_terms.row_coupled = True
 _gather_meta.row_coupled = False
 
 
@@ -836,6 +982,7 @@ FLEET_KERNELS = {
     "fleet_entries": _fleet_entries,
     "fleet_bits": _fleet_bits,
     "fleet_select": _fleet_select,
+    "fleet_terms": _fleet_terms,
     # quota plane (ops.quota): dispatched engine-side (TensorScheduler's
     # admission wrapper + cap fold), registered here so prewarm replay and
     # the graftlint IR tier see them like every other solve-family kernel
@@ -913,10 +1060,11 @@ class _FleetBatch:
 
     __slots__ = (
         "names", "host_entries", "rows", "_bits_dev", "_bits_np",
-        "_table", "_gen",
+        "_table", "_gen", "_term_sel",
     )
 
-    def __init__(self, names, host_entries, rows, bits_dev, table, gen):
+    def __init__(self, names, host_entries, rows, bits_dev, table, gen,
+                 term_sel=None):
         self.names = names
         self.host_entries = host_entries  # int32[cap, k_out] (site<<8|count)
         self.rows = rows  # int32[n] table row per result position
@@ -927,6 +1075,16 @@ class _FleetBatch:
         self._bits_np = None
         self._table = table
         self._gen = gen
+        # the pass-time ``term_sel`` resident (device uint8[cap], immutable;
+        # its copy to the host started with the term kernel's dispatch),
+        # read by the first multi-term result that names its term
+        self._term_sel = term_sel
+
+    def term_of(self, pos: int) -> int:
+        """Index of the affinity term the row at ``pos`` was divided on."""
+        if not isinstance(self._term_sel, np.ndarray):
+            self._term_sel = np.asarray(self._term_sel)
+        return int(self._term_sel[self.rows[pos]])
 
     def entries_for(self, pos: int) -> np.ndarray:
         if self._table is not None and self._table._result_gen != self._gen:
@@ -1083,9 +1241,14 @@ class _FleetResultList:
             if (self._is_dup[i] and p.replicas > 0 and not err)
             else None
         )
+        batch, pos = self._batches[i // self._slice_rows], i % self._slice_rows
+        # a single-term row's name is a constant; a multi-term row holds its
+        # terms' names and reads which one the term kernel chose
+        name = self._terms[i]
+        if name.__class__ is tuple:
+            name = name[batch.term_of(pos)]
         res = FleetResult(
-            p.key, self._terms[i], err,
-            self._batches[i // self._slice_rows], i % self._slice_rows,
+            p.key, name, err, batch, pos,
             int(self._n_placed[i]), dup, p.replicas == 0,
         )
         self._cache[i] = res
@@ -1111,7 +1274,7 @@ class _FleetResultList:
 
 _STATE_FIELDS = (
     "cp_idx", "gvk_idx", "prof_idx", "replicas", "strategy", "fresh",
-    "prev_sites", "prev_counts", "sel_bits",
+    "prev_sites", "prev_counts", "evict_sites", "sel_bits",
 )
 
 
@@ -1145,6 +1308,17 @@ def _fold_estimates(table, answers, n_live):
         merged = jnp.where(out < 0, est, jnp.minimum(out, est))
         out = jnp.where((est < 0) | ~live, out, merged)
     return out
+
+
+class _TermRows(NamedTuple):
+    """The multi-term rows of a batch, as _fleet_terms takes them."""
+
+    rows_np: np.ndarray  # the batch's table rows (identity: whose they are)
+    rows_dev: jax.Array  # int32[chunk * n_chunks] multi-term rows, -1 padded
+    n: int
+    chunk: int
+    n_chunks: int
+    evicted: int  # rows of the batch that hold an eviction task
 
 
 class _SelectRows(NamedTuple):
@@ -1193,9 +1367,13 @@ class FleetTable:
         self._terms: list = []  # affinity term name per row
         self._row_last_used: list[int] = []  # pass counter per row
         self._pass = 0
-        # interning slots
-        self._cp_slot: dict[int, int] = {}
-        self._cp_pl: list = []  # slot -> (placement, compiled) pinned
+        # interning slots: one a (placement, affinity term), keyed by the
+        # Placement object (pinned below, so its id() is never reused) and
+        # the term's index. A compiled placement is a pure function of
+        # (placement, snapshot): the engine may compile the same placement
+        # again (a mask-token move clears its cache) and the slot stands
+        self._cp_slot: dict[tuple, int] = {}
+        self._cp_pl: list = []  # slot -> (placement, compiled, term) pinned
         self._cp_uploaded = 0  # slots currently valid on the device table
         self._cp_remapped = False  # slot ids changed: full upload needed
         self._gvk_slot: dict[str, int] = {}
@@ -1231,6 +1409,15 @@ class FleetTable:
         # the last select dispatch's (perf_counter start, end, rows,
         # device counts), until schedule() records its span
         self._select_mark: Optional[tuple] = None
+        # the rows' ordered term slots and the term each was last given
+        # (device int32[cap, T_CAP] / uint8[cap]); the multi-term rows of
+        # the current batch as _fleet_terms takes them; the last term
+        # dispatch's (start, end, rows, device counts, evicted rows) until
+        # schedule() records its span
+        self._dev_term_slots = None
+        self._dev_term_sel = None
+        self._term_cache: Optional[_TermRows] = None
+        self._terms_mark: Optional[tuple] = None
         self._all_rows_dev = None
         self._all_rows_n = -1
         self._dirty: set[int] = set()
@@ -1441,6 +1628,8 @@ class FleetTable:
         self.n_rows = len(keep)
         self._dirty.clear()
         self._dev_state = None  # full re-upload with the compacted layout
+        self._dev_term_sel = None
+        self._term_cache = None
         self._all_rows_n = -1
         # row ids were remapped: the delta base is meaningless now, and so
         # is any result view still pointing at the old row layout
@@ -1486,6 +1675,11 @@ class FleetTable:
             "fresh": np.zeros(new_cap, bool),
             "prev_sites": np.zeros((new_cap, K_PREV), np.int32),
             "prev_counts": np.zeros((new_cap, K_PREV), np.int32),
+            # a row's graceful-eviction tasks as cluster indices and its
+            # ordered term slots (host staging of the term kernel's input;
+            # uploaded beside the state, never read by the pass)
+            "evict_sites": np.full((new_cap, K_EVICT), -1, np.int32),
+            "term_slots": np.full((new_cap, T_CAP), -1, np.int32),
             # a row's spread selection, bitpacked as the cp planes are;
             # all ones = no selection narrows this row
             "sel_bits": np.full((new_cap, (c + 7) // 8), 0xFF, np.uint8),
@@ -1498,6 +1692,8 @@ class FleetTable:
         self._st = st
         self.cap = new_cap
         self._dev_state = None  # full re-upload
+        self._dev_term_sel = None
+        self._term_cache = None
         self._reset_dense()  # cap changed: residents reallocate zeroed
         self._reuse = None
 
@@ -1511,6 +1707,7 @@ class FleetTable:
         return (
             id(p.placement), p.replicas, p.gvk, p.fresh,
             tuple(p.requests.items()), tuple(p.prev.items()),
+            p.evict_clusters,
         )
 
     def upsert(self, problem, compiled) -> int:
@@ -1537,22 +1734,42 @@ class FleetTable:
         self._pack_row(row, problem, compiled)
         return row
 
+    @staticmethod
+    def _slot_key(placement, term: int) -> tuple:
+        """The interning key of a (placement, affinity term) slot: the
+        Placement OBJECT (its slot pins it) and the term's index."""
+        return (id(placement) if placement is not None else 0, term)
+
     def _pack_row(self, row: int, problem, compiled) -> None:
         self._packed_this_pass += 1
+        # the object the row state is packed from: upsert's identity skip
+        # compares against it (left at the row's first object, a binding
+        # whose first object came back after another would skip its repack)
+        self._problems[row] = problem
         snap = self.engine.snapshot
         st = self._st
-        # placement slot
-        slot = self._cp_slot.get(id(compiled))
-        if slot is None:
-            slot = len(self._cp_pl)
-            self._cp_slot[id(compiled)] = slot
-            self._cp_pl.append((problem.placement, compiled))
-            self._slots_minted_this_pass += 1
-            self._static_max = max(
-                self._static_max, int(compiled.static_weights.max(initial=0))
-            )
-            self._tables_dirty = True
-        st["cp_idx"][row] = slot
+        # placement slots, one a term (the engine sends no placement with
+        # more than T_CAP terms); cp_idx starts at the first term's and is
+        # the term kernel's to rewrite for a multi-term row
+        pl = problem.placement
+        terms = compiled.terms
+        slots = st["term_slots"][row]
+        slots[:] = -1
+        for t in range(len(terms)):
+            slot = self._cp_slot.get(self._slot_key(pl, t))
+            if slot is None:
+                slot = len(self._cp_pl)
+                self._cp_slot[self._slot_key(pl, t)] = slot
+                self._cp_pl.append((pl, compiled, t))
+                self._slots_minted_this_pass += 1
+                self._static_max = max(
+                    self._static_max,
+                    int(compiled.static_weights.max(initial=0)),
+                )
+                self._tables_dirty = True
+            slots[t] = slot
+        st["cp_idx"][row] = slots[0]
+        self._term_cache = None
         # gvk slot
         gslot = self._gvk_slot.get(problem.gvk)
         if gslot is None:
@@ -1610,13 +1827,23 @@ class FleetTable:
                 k += 1
         st["prev_sites"][row] = sites
         st["prev_counts"][row] = cnts
+        evict = st["evict_sites"][row]
+        evict[:] = -1
+        k = 0
+        for name in problem.evict_clusters:
+            j = snap.index.get(name)
+            if j is not None:
+                evict[k] = j
+                k += 1
         # a (re)packed row starts unselected; the pass's selection, if the
         # row has one, lands after the uploads (_fleet_select, or
         # _apply_selections for a row the host selected)
         st["sel_bits"][row] = 0xFF
         st["sel_on_dev"][row] = False
         self._fps[row] = self._fingerprint(problem)
-        self._terms[row] = compiled.terms[0][0]
+        self._terms[row] = (
+            terms[0][0] if len(terms) == 1 else tuple(n for n, _ in terms)
+        )
         self._dirty.add(row)
 
     def _compact_slots(self) -> None:
@@ -1626,9 +1853,8 @@ class FleetTable:
         placement is one cached compile + one slot append. Triggers a full
         table rebuild + state re-upload, so it runs only under cap
         pressure (slots_exhausted)."""
-        used = set(
-            int(s) for s in np.unique(self._st["cp_idx"][: self.n_rows])
-        )
+        ts = self._st["term_slots"][: self.n_rows]
+        used = set(int(s) for s in np.unique(ts[ts >= 0]))
         keep = [i for i in range(len(self._cp_pl)) if i in used]
         if len(keep) == len(self._cp_pl):
             return
@@ -1636,14 +1862,17 @@ class FleetTable:
         for new_i, old_i in enumerate(keep):
             remap[old_i] = new_i
         self._cp_pl = [self._cp_pl[i] for i in keep]
-        self._cp_slot = {id(cp): i for i, (pl, cp) in enumerate(self._cp_pl)}
+        self._cp_slot = {
+            self._slot_key(pl, t): i
+            for i, (pl, _, t) in enumerate(self._cp_pl)
+        }
         self._static_max = max(
-            (int(cp.static_weights.max(initial=0)) for _, cp in self._cp_pl),
+            (int(cp.static_weights.max(initial=0))
+             for _, cp, _ in self._cp_pl),
             default=0,
         )
-        self._st["cp_idx"][: self.n_rows] = remap[
-            self._st["cp_idx"][: self.n_rows]
-        ]
+        ts[:] = np.where(ts >= 0, remap[np.maximum(ts, 0)], -1)
+        self._st["cp_idx"][: self.n_rows] = ts[:, 0]
         self._tables_dirty = True
         self._cp_remapped = True  # device cp rows are stale: full upload
         self._dev_state = None  # cp_idx remapped: full re-upload
@@ -1716,12 +1945,10 @@ class FleetTable:
             # slot's placement against the new snapshot, order-preserving so
             # row cp_idx values stay valid
             self._snapshot_gen = gen
-            self._cp_slot.clear()
             self._static_max = 0
-            for i, (pl, _) in enumerate(self._cp_pl):
+            for i, (pl, _, t) in enumerate(self._cp_pl):
                 cp = self.engine._compiled(pl)
-                self._cp_pl[i] = (pl, cp)
-                self._cp_slot[id(cp)] = i
+                self._cp_pl[i] = (pl, cp, t)
                 self._static_max = max(
                     self._static_max, int(cp.static_weights.max(initial=0))
                 )
@@ -1737,9 +1964,9 @@ class FleetTable:
             """Bitpacked [aff&spread_field | taint] planes: uint8[k, 2*W8]
             (little bit order — _unpack_bits is the device inverse)."""
             aff = np.stack(
-                [(cp.terms[0][1] & cp.spread_field_ok) for _, cp in slots]
+                [(cp.terms[t][1] & cp.spread_field_ok) for _, cp, t in slots]
             )
-            taint = np.stack([cp.taint_ok for _, cp in slots])
+            taint = np.stack([cp.taint_ok for _, cp, _ in slots])
             return np.concatenate(
                 [
                     np.packbits(aff, axis=1, bitorder="little"),
@@ -1750,7 +1977,7 @@ class FleetTable:
 
         def cp_static_np(slots) -> np.ndarray:
             return np.stack(
-                [cp.static_weights.astype(np.int32) for _, cp in slots]
+                [cp.static_weights.astype(np.int32) for _, cp, _ in slots]
             )  # [k, C]
 
         # the mask tables are functions of the snapshot's FILTER fields only
@@ -1842,7 +2069,9 @@ class FleetTable:
             self._dev_spread = None
             if regions is not None:
                 sp = np.zeros((cp_bits_dev.shape[0], N_PARAMS), np.int32)
-                sp[:n_slots] = [constraint_params(cp) for _, cp in self._cp_pl]
+                sp[:n_slots] = [
+                    constraint_params(cp) for _, cp, _ in self._cp_pl
+                ]
                 self._dev_spread = (jnp.asarray(sp), jnp.asarray(regions))
         else:
             _, _, gvk_dev, _, inc_dev = self._dev_tables
@@ -1962,16 +2191,19 @@ class FleetTable:
         the solve gathers per-row state by arbitrary row index, so a
         replica-local gather beats a per-pass broadcast of the whole
         grid from device 0."""
-        arrays = tuple(jnp.asarray(self._st[k]) for k in _STATE_FIELDS)
+        put = (
+            (lambda a: a) if self._mesh is None
+            else partial(jax.device_put, device=NamedSharding(self._mesh, P()))
+        )
         self._last_upload_bytes += sum(
-            self._st[k].nbytes for k in _STATE_FIELDS
+            self._st[k].nbytes for k in (*_STATE_FIELDS, "term_slots")
         )
-        if self._mesh is None:
-            return arrays
-        return tuple(
-            jax.device_put(a, NamedSharding(self._mesh, P()))
-            for a in arrays
-        )
+        # the term kernel's input rides with the state; what it last chose
+        # stays (a row's choice is made again in every pass that holds it)
+        self._dev_term_slots = put(jnp.asarray(self._st["term_slots"]))
+        if self._dev_term_sel is None:
+            self._dev_term_sel = put(jnp.zeros(self.cap, jnp.uint8))
+        return tuple(put(jnp.asarray(self._st[k])) for k in _STATE_FIELDS)
 
     def _sync_device(self) -> None:
         self._last_upload_bytes = 0
@@ -1998,23 +2230,27 @@ class FleetTable:
                 rows_p = np.concatenate(
                     [rows, np.full(pad - len(rows), rows[0], np.int64)]
                 )
-                vals = tuple(self._st[k][rows_p] for k in _STATE_FIELDS)
+                vals = tuple(
+                    self._st[k][rows_p] for k in (*_STATE_FIELDS, "term_slots")
+                )
                 self._last_upload_bytes += rows_p.nbytes + sum(
                     v.nbytes for v in vals
                 )
                 self._mark_trace(
                     "S", self.cap, pad, self._mesh is not None
                 )
-                self._dev_state = _scatter_rows(
-                    self._dev_state, jnp.asarray(rows_p), vals
+                *state, self._dev_term_slots = _scatter_rows(
+                    (*self._dev_state, self._dev_term_slots),
+                    jnp.asarray(rows_p), vals,
                 )
+                self._dev_state = tuple(state)
             self._dirty.clear()
 
     # -- scheduling --------------------------------------------------------
 
     def schedule(
         self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None, select=None,
+        selections=None, select=None, host_rows: int = 0,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -2048,8 +2284,13 @@ class FleetTable:
         stays with the row until a later pass brings another, so a pass
         over the same batch at the same snapshot generation need not bring
         any. A batch brings one or the other (the snapshot's region count
-        decides for all its spread rows), never both."""
+        decides for all its spread rows), never both.
+
+        ``host_rows`` is how many rows of the caller's batch left the fleet
+        for the host path (stamped on the span; 0 on the fast paths)."""
         from ..utils.metrics import (
+            affinity_term_choices,
+            eviction_masked_rows,
             fleet_placement_slots,
             fleet_slots_minted,
             spread_selections,
@@ -2058,7 +2299,7 @@ class FleetTable:
 
         with tracer.span("scheduler.solve") as sp:
             self._phase_marks = []
-            self._select_mark = None
+            self._select_mark = self._terms_mark = None
             res = self._schedule_pass(
                 problems, compiled, delta, selections, select
             )
@@ -2072,7 +2313,29 @@ class FleetTable:
             )
             sp.attrs["slots"] = len(self._cp_pl)
             sp.attrs["slots_minted"] = minted
+            sp.attrs["host_rows"] = int(host_rows)
             self._emit_phase_spans()
+            mark, self._terms_mark = self._terms_mark, None
+            if mark is not None:
+                # the term kernel's span: the host's share (the row vector
+                # and the dispatch), with what the kernel counted (on the
+                # host since the dispatch, read after the pass's fence)
+                t_a, t_b, n_multi, counts, evicted = mark
+                if n_multi:
+                    fallback, unfit = (int(v) for v in np.asarray(counts))
+                    tracer.record(
+                        "scheduler.terms", t_b - t_a, start=t_a,
+                        rows=n_multi, fallback=fallback, unfit=unfit,
+                        evicted_rows=evicted,
+                    )
+                    for outcome, k in (
+                        ("first", n_multi - fallback - unfit),
+                        ("fallback", fallback), ("unfit", unfit),
+                    ):
+                        if k:
+                            affinity_term_choices.inc(k, outcome=outcome)
+                if evicted:
+                    eviction_masked_rows.inc(evicted)
             mark, self._select_mark = self._select_mark, None
             if mark is not None:
                 # the Select stage's span: the host's share of it (the row
@@ -2177,6 +2440,71 @@ class FleetTable:
         counts.copy_to_host_async()
         self._select_mark = (t_a, time.perf_counter(), cache.n, counts)
 
+    def _term_rows(self, rows_np: np.ndarray) -> _TermRows:
+        """The pass's multi-term rows as the term kernel takes them: an
+        int32 row vector padded to whole chunks, kept while the same rows
+        come again unpacked (the identity fast path uploads nothing)."""
+        cache = self._term_cache
+        if cache is not None and cache.rows_np is rows_np:
+            return cache
+        st = self._st
+        multi = rows_np[st["term_slots"][rows_np, 1] >= 0]
+        n = len(multi)
+        chunk = _terms_chunk(n, self.engine.snapshot.num_clusters)
+        n_chunks = max(1, -(-n // chunk))
+        rows_dev = None
+        if n:
+            ar = np.full(chunk * n_chunks, -1, np.int32)
+            ar[:n] = multi
+            rows_dev = jnp.asarray(ar)
+            self._last_upload_bytes += ar.nbytes
+        cache = _TermRows(
+            rows_np, rows_dev, n, chunk, n_chunks,
+            int((st["evict_sites"][rows_np, 0] >= 0).sum()),
+        )
+        self._term_cache = cache
+        return cache
+
+    def _terms_on_device(self, rows_np: np.ndarray) -> None:
+        """Dispatch _fleet_terms over the pass's multi-term rows, if it has
+        any (no host wait: the pass's fence is the only fence). Leaves the
+        chosen slots in the resident ``cp_idx`` and ``term_sel``, and in
+        ``_terms_mark`` what schedule() records of it."""
+        t_a = time.perf_counter()
+        tr = self._term_rows(rows_np)
+        if not tr.n:
+            self._terms_mark = (t_a, t_a, 0, None, tr.evicted)
+            return
+        c = self.engine.snapshot.num_clusters
+        state = self._dev_state
+        args = (
+            *self._dev_tables, tr.rows_dev, self._dev_term_slots,
+            self._dev_term_sel, *state[:-1],  # all but sel_bits
+        )
+        from ..parallel.mesh import mesh_shape as _mesh_shape
+
+        key = (
+            "R", self.cap, c, self._dev_tables[0].shape, tr.chunk,
+            tr.n_chunks, _mesh_shape(self._mesh),
+        )
+        if self._mark_trace(*key) and self._mesh is None:
+            # meshed dispatches stay manifest-unrecorded, as _fleet_bits'
+            self._record_trace(
+                "fleet_terms", key, args, chunk=tr.chunk,
+                n_chunks=tr.n_chunks,
+            )
+        cp_idx, term_sel, counts = _fleet_terms(
+            *args, chunk=tr.chunk, n_chunks=tr.n_chunks
+        )
+        self._dev_state = (cp_idx, *state[1:])
+        self._dev_term_sel = term_sel
+        # both start for the host now and are there by the pass's fence
+        counts.copy_to_host_async()
+        term_sel.copy_to_host_async()
+        self._terms_mark = (
+            t_a, time.perf_counter(), tr.n, counts, tr.evicted
+        )
+
     def device_bytes(self) -> dict[str, int]:
         """Resident device bytes by ledger kind — the EXACT ``nbytes`` of
         the arrays this table holds right now (ISSUE 12 b): the packed
@@ -2193,12 +2521,14 @@ class FleetTable:
             return int(getattr(x, "nbytes", 0))
 
         return {
-            "packed_grid": nb(self._dev_state),
+            "packed_grid": nb(self._dev_state) + nb(self._dev_term_slots)
+            + nb(self._dev_term_sel),
             "slot_tables": nb(self._dev_tables) + nb(self._dev_spread)
             + nb(self._dev_subsets),
             "donated_residents": nb(self._res_dense) + nb(self._res_meta),
             "rows_index": nb(self._all_rows_dev)
-            + (nb(self._select_cache.rows_dev) if self._select_cache else 0),
+            + (nb(self._select_cache.rows_dev) if self._select_cache else 0)
+            + (nb(self._term_cache.rows_dev) if self._term_cache else 0),
         }
 
     def _buffer_platform(self) -> str:
@@ -2371,6 +2701,13 @@ class FleetTable:
             tmr["estimate"] = t0_after - t_a
             t0 = t0_after
         t0 = self._phase(tmr, "sync", t0)
+        # the ordered affinity terms of the pass's multi-term rows, chosen
+        # on the device after the uploads and before the Select stage and
+        # the pass; the host stretch is the scheduler.terms span's
+        self._terms_on_device(rows_np)
+        if self._terms_mark[2]:
+            tmr["terms_dispatch"] = self._terms_mark[1] - t0
+            t0 = self._terms_mark[1]
         if select is not None and len(select):
             # the Select stage on the device, after the pass's uploads and
             # before the pass; its host stretch is the scheduler.select
@@ -2598,7 +2935,7 @@ class FleetTable:
         batches = [
             _FleetBatch(
                 names, self._host_entries, rows_full, bits_src,
-                self, self._result_gen,
+                self, self._result_gen, self._dev_term_sel,
             )
         ]
         terms = [self._terms[r] for r in rows_full]
@@ -3084,7 +3421,7 @@ class FleetTable:
         batches = [
             _FleetBatch(
                 names, self._host_entries, rows_np, bits_src,
-                self, self._result_gen,
+                self, self._result_gen, self._dev_term_sel,
             )
         ]
         terms = [self._terms[r] for r in rows_np]

@@ -89,6 +89,7 @@ _KERNELS = {
     "fleet_entries": True,
     "fleet_bits": False,
     "fleet_select": True,
+    "fleet_terms": True,
     "quota_admit": True,
     "quota_cluster_caps": False,
     "explain_pass": False,

@@ -315,10 +315,10 @@ class CompiledPlacement:
     strategy: int = S_DUPLICATED
     static_weights: np.ndarray = field(default_factory=lambda: np.zeros(0, np.int32))
     spread_constraints: list[SpreadConstraint] = field(default_factory=list)
-    # single-affinity-term + no effective spread constraints: the
-    # placement-level half of the fleet fast-path gate, precomputed by
-    # TensorScheduler._compiled (the per-problem check is a hot loop)
-    fleet_single_term: bool = False
+    # at most fleet.T_CAP affinity terms + no effective spread constraints:
+    # the placement-level half of the fleet-eligibility gate for rows no
+    # selection was made for, precomputed by TensorScheduler._compiled
+    fleet_terms: bool = False
     # single-affinity-term WITH effective spread constraints: the rows the
     # Select stage selects (on the device, scheduler.select, or on the host)
     spread_single_term: bool = False

@@ -456,16 +456,39 @@ spread_host_selected_rows = registry.gauge(
     "when the kernel took them all. Above 0 each of those rows costs host "
     "time in every wave whose snapshot generation moved",
 )
+affinity_term_choices = registry.counter(
+    "karmada_tpu_affinity_term_choices_total",
+    "multi-term (ordered clusterAffinities) rows the fleet table's term "
+    "kernel chose a term for, by outcome: first (the first term fits), "
+    "fallback (a later term is the first that fits: the row failed over), "
+    "unfit (no term fits: the row keeps its last term, whose division "
+    "reports the failure)",
+)
+eviction_masked_rows = registry.counter(
+    "karmada_tpu_eviction_masked_rows_total",
+    "rows of fleet passes that held at least one graceful-eviction task "
+    "(the ClusterEviction filter masked those members on the device)",
+)
+fleet_host_path_rows = registry.gauge(
+    "karmada_tpu_fleet_host_path_rows",
+    "rows of the last batch that left the fleet table for the general host "
+    "path: more affinity terms than the table's term slots "
+    "(scheduler.fleet.T_CAP = 4), more eviction tasks than its task sites "
+    "(K_EVICT = 8), more previous sites or replicas than its caps, or "
+    "several terms together with spread constraints. Above 0 each of "
+    "those rows is packed and solved on the host in every wave",
+)
 fleet_placement_slots = registry.gauge(
     "karmada_tpu_fleet_placement_slots",
-    "placement slots the fleet table holds (one a user placement; a "
-    "spread selection is row state and takes none), set after every pass",
+    "placement slots the fleet table holds (one an affinity term of a user "
+    "placement; a spread selection is row state and takes none), set after "
+    "every pass",
 )
 fleet_slots_minted = registry.counter(
     "karmada_tpu_fleet_slots_minted_total",
-    "placement slots added to a fleet table (a placement's first row, or "
-    "every live placement again after a table rebuild); flat once each "
-    "user placement has its slot",
+    "placement slots added to a fleet table (one an affinity term at a "
+    "placement's first row, or every live placement again after a table "
+    "rebuild); flat once each user placement has its slots",
 )
 fleet_table_rebuilds = registry.counter(
     "karmada_tpu_fleet_table_rebuilds_total",

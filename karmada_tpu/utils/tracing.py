@@ -105,8 +105,17 @@ SPAN_NAMES: dict[str, str] = {
         "kernel does not take (rows / device / hits / computed / "
         "fit_errors / moved attrs)"
     ),
+    "scheduler.terms": (
+        "only when the pass holds multi-term (ordered clusterAffinities) "
+        "rows, under scheduler.solve: the host's share of the fleet "
+        "table's term kernel, its row vector and dispatch (rows / "
+        "fallback / unfit / evicted_rows attrs)"
+    ),
     "scheduler.host": "host-path (non-fleet) scheduling of a batch",
-    "scheduler.solve": "one fleet-table solve pass",
+    "scheduler.solve": (
+        "one fleet-table solve pass (host_rows = rows of the batch that "
+        "left it for the host path)"
+    ),
     "scheduler.explain": (
         "armed-only provenance capture of a pass: per-stage mask "
         "composition + the batched explain dispatch (ISSUE 13)"

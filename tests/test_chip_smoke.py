@@ -116,8 +116,11 @@ def test_engine_stage_tiny():
     # the mixed-policy batch: 2 generations x 512 rows against refimpl
     # (divider_np + spread), one slot a placement on both
     assert facts["policy_rows_checked"] == 1024
-    assert facts["policy_slots"] == chip_smoke.N_POLICIES
+    assert facts["policy_slots"] == chip_smoke.N_POLICY_SLOTS
     assert facts["policy_rows_device_selected"] > 0
+    # the two-term placements' rows, eviction tasks and all, had their term
+    # chosen by the fleet table's kernel
+    assert facts["failover_rows_device_chosen"] > 100
 
 
 def test_kernels_stage_tiny():

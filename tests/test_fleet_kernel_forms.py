@@ -58,6 +58,7 @@ def _row_masks_args(chunk: int, c: int, sites, counts):
         jnp.zeros((chunk,), jnp.int32),  # gvc
         jnp.asarray(sites),
         jnp.asarray(counts),
+        jnp.full((chunk, fleet_mod.K_EVICT), -1, jnp.int32),  # evc: no task
         jnp.ones((chunk,), bool),  # vc
         jnp.full((chunk, w8), 0xFF, jnp.uint8),  # sbc: no row selected down
     )
@@ -81,12 +82,13 @@ def test_row_masks_prev_equals_host_scatter_add(chunk, c, duplicates):
 def test_row_masks_lowers_to_no_scatter():
     chunk, c = 256, 100
     sites, counts = _prev_pairs(chunk, c, True, seed=1)
-    lowered = jax.jit(fleet_mod._row_masks, static_argnums=(10, 11)).lower(
+    lowered = jax.jit(fleet_mod._row_masks, static_argnums=(11, 12)).lower(
         *_row_masks_args(chunk, c, sites, counts), chunk, c
     )
     # without debug info: the locations carry this test's own name
     assert "scatter" not in lowered.as_text()
-    assert "fleet.prev" in lowered.as_text(debug_info=True)
+    scoped = lowered.as_text(debug_info=True)
+    assert "fleet.prev" in scoped and "fleet.evict" in scoped
 
 
 # --------------------------------------------------------------------------

@@ -103,6 +103,7 @@ def _run_kernel(snap, compiled_slots, aff, prof_table, cp, pf, replicas,
         jnp.asarray(cp), jnp.zeros(b, jnp.int32), jnp.asarray(pf),
         jnp.asarray(replicas),
         jnp.asarray(prev_sites), jnp.asarray(prev_counts),
+        jnp.full((b, fleet_mod.K_EVICT), -1, jnp.int32),
         jnp.full((b, w8), 0xFF, jnp.uint8),
         chunk=chunk, n_chunks=n_chunks,
     )
@@ -485,6 +486,7 @@ def test_the_selection_math_is_32_bit_and_scoped():
             ((256,), "int32"))],
         *[jax.ShapeDtypeStruct((256,), "int32")] * 4,
         *[jax.ShapeDtypeStruct((256, K_PREV), "int32")] * 2,
+        jax.ShapeDtypeStruct((256, fleet_mod.K_EVICT), "int32"),
         jax.ShapeDtypeStruct((256, 2), "uint8"),
         chunk=256, n_chunks=1).as_text(debug_info=True)
     assert "fleet.select" in text and "select.paths" in text

@@ -136,13 +136,14 @@ def test_sharded_specs_cover_fleet_kernels():
 def test_one_kernel_set_on_every_declaring_surface():
     # one kernel, several declaration sites (ROADMAP Queue 3 item 6):
     # the registry the engine dispatches from, prewarm's jax-free mirror
-    # and the lint's manifest-bearing entry points name the same eight
+    # and the lint's manifest-bearing entry points name the same nine
     # kernels, and the trace-key families that feed the compile counter
     # name no kernel beyond them and the three ledger-only utilities
     from karmada_tpu.scheduler import fleet, prewarm
 
     want = {
         "fleet_pass", "fleet_entries", "fleet_bits", "fleet_select",
+        "fleet_terms",
         "quota_admit", "quota_cluster_caps", "explain_pass",
         "preempt_select",
     }

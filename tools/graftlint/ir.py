@@ -297,12 +297,12 @@ def _specs_masks_intersects() -> list:
 
 
 def _fleet_dims() -> dict:
-    from karmada_tpu.scheduler.fleet import K_PREV
+    from karmada_tpu.scheduler.fleet import K_EVICT, K_PREV, T_CAP
 
     c = _C
     return {
         "c": c, "w8": (c + 7) // 8, "cap": 256, "chunk": 256,
-        "n_pad": 256, "k_prev": K_PREV,
+        "n_pad": 256, "k_prev": K_PREV, "k_evict": K_EVICT, "t_cap": T_CAP,
     }
 
 
@@ -322,6 +322,7 @@ def _fleet_state(d: dict) -> list:
         [((cap,), "int32")] * 5  # cp_idx gvk_idx prof_idx replicas strategy
         + [((cap,), "bool")]  # fresh
         + [((cap, d["k_prev"]), "int32")] * 2  # prev_sites prev_counts
+        + [((cap, d["k_evict"]), "int32")]  # evict_sites
         + [((cap, d["w8"]), "uint8")]  # sel_bits
     )
 
@@ -405,8 +406,20 @@ def _specs_fleet_select() -> list:
            ((subsets,), "int32"), ((subsets, subsets), "float32")]
         + [((d["n_pad"],), "int32")]
         # the state it reads: cp_idx gvk_idx prof_idx replicas, prev_sites
-        # prev_counts, sel_bits (neither strategy nor fresh)
+        # prev_counts, evict_sites, sel_bits (neither strategy nor fresh)
         + state[:4] + state[6:]
+    )
+    return [KernelSpec("base", shapes, {"chunk": d["chunk"], "n_chunks": 1})]
+
+
+def _specs_fleet_terms() -> list:
+    d = _fleet_dims()
+    shapes = tuple(
+        _fleet_tables(d)
+        + [((d["n_pad"],), "int32"),  # the multi-term rows
+           ((d["cap"], d["t_cap"]), "int32"),  # term_slots
+           ((d["cap"],), "uint8")]  # term_sel
+        + _fleet_state(d)[:-1]  # the state it reads: all but sel_bits
     )
     return [KernelSpec("base", shapes, {"chunk": d["chunk"], "n_chunks": 1})]
 
@@ -419,12 +432,14 @@ def _specs_gather_meta() -> list:
 
 
 def _group_scatter(structs):
-    return tuple(structs[0:9]), structs[9], tuple(structs[10:19])
+    # the state fields and term_slots, the rows, a value for each
+    n = (len(structs) - 1) // 2
+    return tuple(structs[:n]), structs[n], tuple(structs[n + 1:])
 
 
 def _specs_scatter_rows() -> list:
     d = _fleet_dims()
-    state = _fleet_state(d)
+    state = _fleet_state(d) + [((d["cap"], d["t_cap"]), "int32")]
     rows = 16
     vals = [((rows,) + tuple(s[0][1:]), s[1]) for s in state]
     return [KernelSpec(
@@ -563,7 +578,7 @@ ENTRY_POINTS: dict = {
                "_fleet_pass", "karmada_tpu/scheduler/fleet.py",
                _specs_fleet_pass, manifest="fleet_pass",
                row_coupled=True,
-               row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15),
+               row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16),
                spec_deps=_FLEET_DEPS),
         _entry("fleet_entries", "scheduler", "karmada_tpu.scheduler.fleet",
                "_fleet_entries", "karmada_tpu/scheduler/fleet.py",
@@ -573,7 +588,7 @@ ENTRY_POINTS: dict = {
                "_fleet_bits", "karmada_tpu/scheduler/fleet.py",
                _specs_fleet_bits, manifest="fleet_bits",
                row_coupled=False,
-               row_args=(6, 7, 8, 9, 10, 11, 12, 13),
+               row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14),
                spec_deps=_FLEET_DEPS),
         # the Select stage: per-row math, but its writes land at ``rows``
         # (a scatter into the resident sel_bits) and its two counts sum
@@ -582,8 +597,17 @@ ENTRY_POINTS: dict = {
                "_fleet_select", "karmada_tpu/scheduler/fleet.py",
                _specs_fleet_select, manifest="fleet_select",
                row_coupled=True,
-               row_args=(10, 11, 12, 13, 14, 15, 16),
+               row_args=(10, 11, 12, 13, 14, 15, 16, 17),
                spec_deps=_FLEET_DEPS + ("karmada_tpu/scheduler/select.py",)),
+        # the term kernel: per-row math too, and like the Select stage its
+        # writes land at ``rows`` (scatters into the resident cp_idx and
+        # term_sel) and its two counts sum over every row
+        _entry("fleet_terms", "scheduler", "karmada_tpu.scheduler.fleet",
+               "_fleet_terms", "karmada_tpu/scheduler/fleet.py",
+               _specs_fleet_terms, manifest="fleet_terms",
+               row_coupled=True,
+               row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16),
+               spec_deps=_FLEET_DEPS + ("karmada_tpu/ops/masks.py",)),
         _entry("gather_meta", "scheduler", "karmada_tpu.scheduler.fleet",
                "_gather_meta", "karmada_tpu/scheduler/fleet.py",
                _specs_gather_meta, row_coupled=False, row_args=(0,),
