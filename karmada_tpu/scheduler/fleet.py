@@ -1362,10 +1362,10 @@ class FleetTable:
         self.cap = 0
         self.n_rows = 0
         self._key_row: dict[str, int] = {}
+        # the object each row's state was packed from, or one of equal
+        # content that came after it: what upsert compares a newcomer with
         self._problems: list = []
-        self._fps: list = []
         self._terms: list = []  # affinity term name per row
-        self._row_last_used: list[int] = []  # pass counter per row
         self._pass = 0
         # interning slots: one a (placement, affinity term), keyed by the
         # Placement object (pinned below, so its id() is never reused) and
@@ -1462,13 +1462,17 @@ class FleetTable:
         # (target, consecutive passes desired) for a frozen shrink — see
         # the cap tuning in _solve_dense
         self._shrink_desire: tuple = (None, 0)
-        # O(1) batch reuse: (problems_list, compiled_list, rows, select)
-        # of the last scheduled batch — the engine's batch-identity fast
-        # path re-passes the SAME list objects, so identity means the row
-        # mapping is already current (cleared on growth/compaction), and
+        # O(1) batch reuse: (problems_list, compiled_list, rows, select,
+        # ids) of the last scheduled batch — the engine's batch-identity
+        # fast path re-passes the SAME list objects, so identity means the
+        # row mapping is already current (cleared on growth/compaction), and
         # ``select`` (the positions the device selects, or None) with it.
-        # _reuse_pass stands in for the per-row last-used bumps the
-        # skipped upserts would have done (consumed by _compact).
+        # ``ids`` is id() of the object each position's row holds, where a
+        # diff has taken them (upsert), else None: another list of the same
+        # length is diffed against them, and only the positions that hold
+        # another object are visited. _reuse_pass stands in for the per-row
+        # last-used stamps of the positions no pass visited since (consumed
+        # by _compact and when another batch takes the table).
         self._reuse: Optional[tuple] = None
         self._reuse_pass = 0
         # mirror staleness fence for the delta solve: _mirror_epoch bumps
@@ -1490,12 +1494,22 @@ class FleetTable:
         # pass's timed phases, in order: what the phase spans are placed
         # from (_phase / _emit_phase_spans)
         self._phase_marks: list[tuple] = []
-        # rows (re)packed by the current pass (_pack_row increments):
-        # the packed-vs-replayed split the history ring records per wave
+        # rows (re)packed by the current pass (_pack_rows adds): the
+        # packed-vs-replayed split the history ring records per wave; and
+        # the positions its upsert phase looked at (0 on the identity path)
         self._packed_this_pass = 0
-        # placement slots added since the last publish (_pack_row
+        self._visited_this_pass = 0
+        # placement slots added since the last publish (_pack_rows
         # increments; schedule() counts them and stamps its span)
         self._slots_minted_this_pass = 0
+        from ..utils.metrics import fleet_upsert_rows
+
+        # what the upsert phase made of the rows of each pass, added once a
+        # pass: (same, equal, packed)
+        self._upsert_tally = tuple(
+            fleet_upsert_rows.labels(outcome=o)
+            for o in ("same", "equal", "packed")
+        )
         # host->device bytes of the current pass (state upload/scatter +
         # row indices), reset by _sync_device; surfaces as upload_mb
         self._last_upload_bytes = 0
@@ -1609,18 +1623,19 @@ class FleetTable:
         bindings leave stale rows behind — without eviction a create/delete
         churn workload grows the table and its pinned problems without
         bound). Returns True if at least half the rows were reclaimed."""
+        if not self.n_rows:
+            return False
         cutoff = self._pass - self.COMPACT_IDLE_PASSES
-        lu = np.fromiter(self._row_last_used, np.int64, self.n_rows)
+        lu = self._st["last_used"][: self.n_rows]
         if self._reuse is not None:
-            # the batch-reuse fast path skips upsert (and with it the
-            # per-row last-used bump): its rows were live at _reuse_pass
-            lu[self._reuse[2]] = getattr(self, "_reuse_pass", self._pass)
+            # the resident batch's rows are stamped where a pass walked
+            # them; the passes that did not were live at _reuse_pass
+            lu[self._reuse[2]] = self._reuse_pass
         keep = np.flatnonzero(lu >= cutoff).tolist()
         if len(keep) * 2 > self.n_rows:
             return False
-        for k in ("_problems", "_fps", "_terms"):
+        for k in ("_problems", "_terms"):
             setattr(self, k, [getattr(self, k)[r] for r in keep])
-        self._row_last_used = lu[keep].tolist()  # reuse bump persists
         idx = np.asarray(keep, np.int64)
         for name, arr in self._st.items():
             arr[: len(keep)] = arr[idx]
@@ -1686,6 +1701,9 @@ class FleetTable:
             # rows whose resident sel_bits is _fleet_select's, not this
             # mirror's (host bookkeeping, never uploaded)
             "sel_on_dev": np.zeros(new_cap, bool),
+            # the last pass that walked the row (host bookkeeping too:
+            # what _compact reads)
+            "last_used": np.zeros(new_cap, np.int64),
         }
         for k, a in self._st.items():
             st[k][: self.cap] = a
@@ -1697,42 +1715,140 @@ class FleetTable:
         self._reset_dense()  # cap changed: residents reallocate zeroed
         self._reuse = None
 
-    @staticmethod
-    def _fingerprint(p) -> tuple:
-        # rows key on the Placement object: its compiled masks recompile IN
-        # PLACE at the same slot on snapshot swaps. A spread selection is
-        # not part of the fingerprint: it is row state of its own, written
-        # on the device by _fleet_select (or uploaded when it moves, for a
-        # row the host selected: _apply_selections).
-        return (
-            id(p.placement), p.replicas, p.gvk, p.fresh,
-            tuple(p.requests.items()), tuple(p.prev.items()),
-            p.evict_clusters,
+    def _resident_ids(self, rows: np.ndarray) -> Optional[np.ndarray]:
+        """id() of the object each of ``rows`` holds, position by position;
+        None where a row comes twice (a key twice in the batch: its
+        positions depend on one another, which only the walk keeps)."""
+        seen = np.zeros(self.n_rows, bool)
+        seen[rows] = True
+        if int(seen.sum()) != len(rows):
+            return None
+        return np.fromiter(
+            map(id, map(self._problems.__getitem__, rows.tolist())),
+            np.int64, len(rows),
         )
 
-    def upsert(self, problem, compiled) -> int:
-        row = self._key_row.get(problem.key)
-        if row is not None:
-            self._row_last_used[row] = self._pass
-            # O(1) fast path: the same problem object
-            if self._problems[row] is problem:
-                return row
-            fp = self._fingerprint(problem)
-            if fp == self._fps[row]:
-                self._problems[row] = problem
-                return row
+    def _moved_positions(self, problems: Sequence) -> Optional[tuple]:
+        """The diff of ``problems`` against the resident batch: (positions
+        that hold another object than their row does, id() of every
+        position's object), or None where the diff cannot cover the batch:
+        no resident batch (none yet, or a compaction or growth since),
+        another length, a row twice in the resident batch, or a moved
+        position whose key is new to the table or sits at another row. The
+        rows pin their objects, so no id can have been given out again."""
+        ru = self._reuse
+        n = len(problems)
+        if ru is None or len(ru[2]) != n:
+            return None
+        old = ru[4] if ru[4] is not None else self._resident_ids(ru[2])
+        if old is None:
+            return None
+        ids = np.fromiter(map(id, problems), np.int64, n)
+        moved = np.flatnonzero(ids != old)
+        positions = moved.tolist()
+        key_row = self._key_row
+        if [key_row.get(problems[i].key) for i in positions] != (
+            ru[2][moved].tolist()
+        ):
+            return None
+        return positions, ids
+
+    def upsert(self, problems: Sequence, compiled: Sequence) -> tuple:
+        """The batch's rows (int32, position by position), its rows brought
+        to the batch's content; with them id() of every position's object
+        where the diff took them, else None (what ``_reuse`` keeps).
+
+        A batch of the resident batch's length is DIFFED against it by
+        object identity (_moved_positions) and only the positions that hold
+        another object are visited; every other batch is walked position by
+        position. ``_visited_this_pass`` counts the positions looked at. A
+        visited position whose row holds the same object, or one of equal
+        content (compared field by field with the object the row holds,
+        which then gives way to the newcomer), keeps its row state; a new
+        key takes a row; the rest are packed together (_pack_rows). Problem
+        objects are not mutated in place between passes: the identity paths
+        here and in the engine rest on that."""
+        n = len(problems)
+        diff = self._moved_positions(problems)
+        if diff is None:
+            if self._reuse is not None:
+                # another batch takes the table: the resident one's rows
+                # keep the pass they were last live at
+                self._st["last_used"][self._reuse[2]] = self._reuse_pass
+            # reclaim rows of deleted/idle bindings before the table would
+            # grow (compaction reindexes rows, so it must run before the
+            # walk hands out indices). Gated on ACTUAL new keys so the
+            # steady all-rows storm pays one dict sweep at capacity
+            # pressure, not an O(n_rows) compaction scan per pass.
+            if self.n_rows + n > self.cap:
+                new_keys = sum(
+                    1 for p in problems if p.key not in self._key_row
+                )
+                if self.n_rows + new_keys > self.cap:
+                    self._compact()
+            positions, ids = range(n), None
         else:
-            if self.n_rows + 1 > self.cap:
-                self._grow(self.n_rows + 1)
-            row = self.n_rows
-            self.n_rows = row + 1
-            self._key_row[problem.key] = row
-            self._problems.append(problem)
-            self._fps.append(None)
-            self._terms.append("")
-            self._row_last_used.append(self._pass)
-        self._pack_row(row, problem, compiled)
-        return row
+            positions, ids = diff
+        key_row, probs, terms = self._key_row, self._problems, self._terms
+        rows: list = []
+        pack_rows: list = []
+        pack_p: list = []
+        pack_c: list = []
+        same = equal = 0
+        try:
+            for i in positions:
+                p = problems[i]
+                row = key_row.get(p.key)
+                if row is None:
+                    row = self.n_rows
+                    if row + 1 > self.cap:
+                        self._grow(row + 1)
+                    key_row[p.key] = row
+                    self.n_rows = row + 1
+                    probs.append(p)
+                    terms.append("")
+                    q = None
+                else:
+                    # the newcomer is pinned whatever its content: a later
+                    # pass is diffed against it
+                    q = probs[row]
+                    probs[row] = p
+                rows.append(row)
+                if q is p:
+                    same += 1
+                elif (
+                    q is not None
+                    and q.placement is p.placement
+                    and q.replicas == p.replicas
+                    and q.gvk == p.gvk
+                    and q.fresh == p.fresh
+                    and q.requests == p.requests
+                    and q.prev == p.prev
+                    and q.evict_clusters == p.evict_clusters
+                ):
+                    equal += 1
+                else:
+                    pack_rows.append(row)
+                    pack_p.append(p)
+                    pack_c.append(compiled[i])
+        finally:
+            # also when the table refuses to grow (FleetTableTooLarge): the
+            # rows it took before that hold their bindings' state
+            self._pack_rows(pack_rows, pack_p, pack_c)
+        self._visited_this_pass = len(rows)
+        t_same, t_equal, t_packed = self._upsert_tally
+        t_same.inc(n - len(rows) + same)
+        t_equal.inc(equal)
+        t_packed.inc(len(pack_rows))
+        if diff is None:
+            rows_np = np.array(rows, np.int32)
+            if rows:
+                self._st["last_used"][rows_np] = self._pass
+        else:
+            # a fresh array: what is cached by the batch's row vector
+            # (_select_cache, _term_cache) starts anew as after a walk
+            rows_np = self._reuse[2].copy()
+        return rows_np, ids
 
     @staticmethod
     def _slot_key(placement, term: int) -> tuple:
@@ -1740,111 +1856,186 @@ class FleetTable:
         Placement OBJECT (its slot pins it) and the term's index."""
         return (id(placement) if placement is not None else 0, term)
 
-    def _pack_row(self, row: int, problem, compiled) -> None:
-        self._packed_this_pass += 1
-        # the object the row state is packed from: upsert's identity skip
-        # compares against it (left at the row's first object, a binding
-        # whose first object came back after another would skip its repack)
-        self._problems[row] = problem
-        snap = self.engine.snapshot
-        st = self._st
-        # placement slots, one a term (the engine sends no placement with
-        # more than T_CAP terms); cp_idx starts at the first term's and is
-        # the term kernel's to rewrite for a multi-term row
-        pl = problem.placement
+    def _term_slots(self, placement, compiled) -> tuple:
+        """The ordered term slots of a placement, padded to T_CAP with -1,
+        each interned at its first row (the engine sends no placement with
+        more than T_CAP terms)."""
         terms = compiled.terms
-        slots = st["term_slots"][row]
-        slots[:] = -1
+        if len(terms) > T_CAP:
+            raise IndexError(
+                f"a placement of {len(terms)} affinity terms has no room in "
+                f"a row's {T_CAP} term slots"
+            )
+        slots = []
         for t in range(len(terms)):
-            slot = self._cp_slot.get(self._slot_key(pl, t))
+            key = self._slot_key(placement, t)
+            slot = self._cp_slot.get(key)
             if slot is None:
                 slot = len(self._cp_pl)
-                self._cp_slot[self._slot_key(pl, t)] = slot
-                self._cp_pl.append((pl, compiled, t))
+                self._cp_slot[key] = slot
+                self._cp_pl.append((placement, compiled, t))
                 self._slots_minted_this_pass += 1
                 self._static_max = max(
                     self._static_max,
                     int(compiled.static_weights.max(initial=0)),
                 )
                 self._tables_dirty = True
-            slots[t] = slot
-        st["cp_idx"][row] = slots[0]
-        self._term_cache = None
-        # gvk slot
-        gslot = self._gvk_slot.get(problem.gvk)
-        if gslot is None:
-            gslot = len(self._gvk_list)
-            self._gvk_slot[problem.gvk] = gslot
-            self._gvk_list.append(problem.gvk)
+            slots.append(slot)
+        return (*slots, *(-1,) * (T_CAP - len(slots)))
+
+    def _profile_slot(self, problem, qns: int) -> int:
+        """The request-profile slot of a binding (pods-dim adjustment
+        applied BEFORE interning, mirroring _pack_chunk: each replica
+        occupies a pod), interned per (request vector, cap namespace)."""
+        snap = self.engine.snapshot
+        vec = np.zeros(len(snap.dims), np.int64)
+        for d, q in problem.requests.items():
+            j = snap.dim_index(d)
+            if j is not None:
+                vec[j] = q
+        pods = snap.dim_index("pods")
+        if pods is not None and problem.replicas > 0:
+            vec[pods] = max(vec[pods], 1)
+        pkey = vec.tobytes() + qns.to_bytes(4, "little", signed=True)
+        pslot = self._prof_slot.get(pkey)
+        if pslot is None:
+            pslot = len(self._profiles)
+            self._prof_slot[pkey] = pslot
+            self._profiles.append(vec)
+            self._prof_ns.append(qns)
             self._tables_dirty = True
-        st["gvk_idx"][row] = gslot
-        # request profile slot (pods-dim adjustment applied BEFORE interning,
-        # mirroring _pack_chunk: each replica occupies a pod). The identity
-        # check (not ==) on the memo's snapshot pins the dims mapping the
-        # cached slots were built under AND keeps the object alive, so a
-        # recycled id can never alias a stale entry
+        return pslot
+
+    def _pack_rows(self, rows: list, problems: list, compiled: list) -> None:
+        """Row state of ``rows`` from their bindings, by columns: one walk
+        gathers the fields into flat lists (a placement's term slots looked
+        up once a pass; slots, gvks and profiles interned in the order the
+        rows come, so their numbers do not depend on how many rows a call
+        packs), then each field of the staging takes ONE fancy-index
+        assignment. The ONE pack path: a first pass of every key, a delta's
+        sub-batch and a swapped batch's moved rows all come here. A row
+        that comes twice keeps its last binding's state."""
+        k = len(rows)
+        if not k:
+            return
+        self._packed_this_pass += k
+        snap = self.engine.snapshot
+        # the identity check (not ==) on the memo's snapshot pins the dims
+        # mapping the cached slots were built under AND keeps the object
+        # alive, so a recycled id can never alias a stale entry
         if self._req_slot_snap is not snap:
             self._req_slot = {}
             self._req_slot_snap = snap
+        req_slot, gvk_slot, names = self._req_slot, self._gvk_slot, self._terms
         quota = getattr(self.engine, "quota", None)
-        qns = (
-            quota.cap_index.get(problem.namespace, -1)
-            if quota is not None and quota.cap_index
-            else -1
+        cap_index = (
+            quota.cap_index if quota is not None and quota.cap_index else None
         )
-        rkey = (tuple(problem.requests.items()), problem.replicas > 0, qns)
-        pslot = self._req_slot.get(rkey)
-        if pslot is None:
-            vec = np.zeros(len(snap.dims), np.int64)
-            for d, q in problem.requests.items():
-                j = snap.dim_index(d)
-                if j is not None:
-                    vec[j] = q
-            pods = snap.dim_index("pods")
-            if pods is not None and problem.replicas > 0:
-                vec[pods] = max(vec[pods], 1)
-            pkey = vec.tobytes() + qns.to_bytes(4, "little", signed=True)
-            pslot = self._prof_slot.get(pkey)
-            if pslot is None:
-                pslot = len(self._profiles)
-                self._prof_slot[pkey] = pslot
-                self._profiles.append(vec)
-                self._prof_ns.append(qns)
+        # id(placement) -> (compiled, its index in pl_slots / pl_strategy,
+        # the row's term name(s)): rows of one placement share the entry
+        by_pl: dict = {}
+        pl_slots: list = []
+        pl_strategy: list = []
+        pl_of: list = []
+        gvks: list = []
+        profs: list = []
+        reps: list = []
+        fresh: list = []
+        # previous sites and eviction tasks are ragged: (flat cell, value)
+        prev_at: list = []
+        prev_site: list = []
+        prev_count: list = []
+        evict_at: list = []
+        evict_site: list = []
+        site_of = snap.index.get
+        full = False
+        for i, (row, p, cp) in enumerate(zip(rows, problems, compiled)):
+            pl = p.placement
+            ent = by_pl.get(id(pl))
+            if ent is None or ent[0] is not cp:
+                terms = cp.terms
+                ent = by_pl[id(pl)] = (
+                    cp, len(pl_slots),
+                    terms[0][0] if len(terms) == 1
+                    else tuple(name for name, _ in terms),
+                )
+                pl_slots.append(self._term_slots(pl, cp))
+                pl_strategy.append(cp.strategy)
+            pl_of.append(ent[1])
+            names[row] = ent[2]
+            gslot = gvk_slot.get(p.gvk)
+            if gslot is None:
+                gslot = gvk_slot[p.gvk] = len(self._gvk_list)
+                self._gvk_list.append(p.gvk)
                 self._tables_dirty = True
-            self._req_slot[rkey] = pslot
-        st["prof_idx"][row] = pslot
-        st["replicas"][row] = problem.replicas
-        st["strategy"][row] = compiled.strategy
-        st["fresh"][row] = problem.fresh
-        sites = np.zeros(K_PREV, np.int32)
-        cnts = np.zeros(K_PREV, np.int32)
-        k = 0
-        for name, reps_prev in problem.prev.items():
-            j = snap.index.get(name)
-            if j is not None:
-                sites[k] = j
-                cnts[k] = reps_prev
-                k += 1
-        st["prev_sites"][row] = sites
-        st["prev_counts"][row] = cnts
-        evict = st["evict_sites"][row]
-        evict[:] = -1
-        k = 0
-        for name in problem.evict_clusters:
-            j = snap.index.get(name)
-            if j is not None:
-                evict[k] = j
-                k += 1
+            gvks.append(gslot)
+            qns = cap_index.get(p.namespace, -1) if cap_index else -1
+            rkey = (tuple(p.requests.items()), p.replicas > 0, qns)
+            pslot = req_slot.get(rkey)
+            if pslot is None:
+                pslot = req_slot[rkey] = self._profile_slot(p, qns)
+            profs.append(pslot)
+            reps.append(p.replicas)
+            fresh.append(p.fresh)
+            if p.prev:
+                sites = list(map(site_of, p.prev))
+                counts = p.prev.values()
+                if None in sites:  # a site that left the snapshot
+                    counts = [
+                        c for j, c in zip(sites, counts) if j is not None
+                    ]
+                    sites = [j for j in sites if j is not None]
+                full = full or len(sites) > K_PREV
+                prev_at.extend(range(i * K_PREV, i * K_PREV + len(sites)))
+                prev_site.extend(sites)
+                prev_count.extend(counts)
+            if p.evict_clusters:
+                sites = [
+                    j for j in map(site_of, p.evict_clusters) if j is not None
+                ]
+                full = full or len(sites) > K_EVICT
+                evict_at.extend(range(i * K_EVICT, i * K_EVICT + len(sites)))
+                evict_site.extend(sites)
+        if full:
+            # a row's cells would run into the next row's (row_rides keeps
+            # such a binding off the fleet)
+            raise IndexError(
+                f"a binding with more than {K_PREV} previous sites or "
+                f"{K_EVICT} eviction tasks on the snapshot's members has no "
+                "room in a row"
+            )
+        st = self._st
+        at = np.array(rows, np.int64)
+        of = np.array(pl_of, np.int64)
+        term_slots = np.array(pl_slots, np.int32)[of]
+        st["term_slots"][at] = term_slots
+        # cp_idx starts at the first term's slot and is the term kernel's
+        # to rewrite for a multi-term row
+        st["cp_idx"][at] = term_slots[:, 0]
+        st["strategy"][at] = np.array(pl_strategy, np.int32)[of]
+        # np.array(list, int32) refuses a number int32 does not hold, as
+        # an element assignment does
+        st["gvk_idx"][at] = np.array(gvks, np.int32)
+        st["prof_idx"][at] = np.array(profs, np.int32)
+        st["replicas"][at] = np.array(reps, np.int32)
+        st["fresh"][at] = np.array(fresh, bool)
+        sites = np.zeros(k * K_PREV, np.int32)
+        counts = np.zeros(k * K_PREV, np.int32)
+        cells = np.array(prev_at, np.int64)
+        sites[cells] = np.array(prev_site, np.int32)
+        counts[cells] = np.array(prev_count, np.int32)
+        st["prev_sites"][at] = sites.reshape(k, K_PREV)
+        st["prev_counts"][at] = counts.reshape(k, K_PREV)
+        evict = np.full(k * K_EVICT, -1, np.int32)
+        evict[np.array(evict_at, np.int64)] = np.array(evict_site, np.int32)
+        st["evict_sites"][at] = evict.reshape(k, K_EVICT)
         # a (re)packed row starts unselected; the pass's selection, if the
         # row has one, lands after the uploads (_fleet_select, or
         # _apply_selections for a row the host selected)
-        st["sel_bits"][row] = 0xFF
-        st["sel_on_dev"][row] = False
-        self._fps[row] = self._fingerprint(problem)
-        self._terms[row] = (
-            terms[0][0] if len(terms) == 1 else tuple(n for n, _ in terms)
-        )
-        self._dirty.add(row)
+        st["sel_bits"][at] = 0xFF
+        st["sel_on_dev"][at] = False
+        self._term_cache = None
+        self._dirty.update(rows)
 
     def _compact_slots(self) -> None:
         """Drop placement slots no live row references: create/delete
@@ -2257,8 +2448,14 @@ class FleetTable:
         execute / fetch+fold) emitted from the pass breakdown — the
         device/host attribution surface of ISSUE 6 (b). The span carries
         the pass's packed-vs-replayed row split (the churn-attribution
-        series the history ring records per wave, ISSUE 12), and the
-        device-byte ledger publishes after every pass.
+        series the history ring records per wave, ISSUE 12) and
+        ``rows_visited``, the positions of the batch its upsert phase
+        looked at: 0 when the same list objects come again, the positions
+        that hold another object when a list of the resident batch's
+        length comes (a swapped batch costs its swapped rows), every
+        position otherwise (``upsert``); the ``kernel.host`` span of that
+        phase carries it too, beside ``rows_packed``. The device-byte
+        ledger publishes after every pass.
 
         ``delta`` (optional) is a sequence of POSITIONS into ``problems``
         that changed since the last pass; every other position must hold
@@ -2305,6 +2502,7 @@ class FleetTable:
             )
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
+            sp.attrs["rows_visited"] = int(tmr.get("rows_visited", 0))
             sp.attrs["rows_packed"] = int(tmr.get("rows_packed", 0))
             sp.attrs["rows_replayed"] = int(tmr.get("rows_replayed", 0))
             sp.attrs["dirty_rows"] = int(tmr.get("dirty_rows", 0))
@@ -2609,6 +2807,10 @@ class FleetTable:
         # compile_s covers either
         fresh = bool(self.new_trace_last_pass)
         attrs = {
+            "upsert": {
+                "rows_visited": int(tmr.get("rows_visited", 0)),
+                "rows_packed": int(tmr.get("rows_packed", 0)),
+            },
             # the pass's host->device bytes ride the stretch that uploads,
             # so the history sampler (and a dumped wave) can read transfer
             # volume without reaching into the engine
@@ -2650,43 +2852,31 @@ class FleetTable:
         t0 = time.perf_counter()
         self._pass += 1
         self.new_trace_last_pass = False
-        self._packed_this_pass = 0
+        self._packed_this_pass = self._visited_this_pass = 0
         ru = self._reuse
         if ru is not None and ru[0] is problems and ru[1] is compiled:
             # same batch objects as last pass: rows are current (upsert
-            # would O(1)-skip every row anyway — this skips the loop).
-            # _reuse_pass stands in for the per-row _row_last_used bump
-            # the skipped upserts would have done; _compact honors it.
+            # would skip every row anyway — this skips the sweep).
+            # _reuse_pass stands in for the last-used stamp of the rows no
+            # pass visits; _compact honors it.
             rows_np = ru[2]
             self._reuse_pass = self._pass
+            self._upsert_tally[0].inc(len(rows_np))
             if select is None and selections is None:
                 select = ru[3]
             else:
-                self._reuse = (problems, compiled, rows_np, select)
+                self._reuse = (problems, compiled, rows_np, select, ru[4])
         else:
-            # reclaim rows of deleted/idle bindings before the table would
-            # grow (compaction reindexes rows, so it must run before any
-            # upsert of this pass hands out indices). Gated on ACTUAL new
-            # keys so the steady all-rows storm pays one dict sweep at
-            # capacity pressure, not an O(n_rows) compaction scan per pass.
-            if self.n_rows + len(problems) > self.cap:
-                new_keys = sum(
-                    1 for p in problems if p.key not in self._key_row
-                )
-                if self.n_rows + new_keys > self.cap:
-                    self._compact()
-            rows_np = np.fromiter(
-                (self.upsert(p, cp) for p, cp in zip(problems, compiled)),
-                np.int32,
-                len(problems),
-            )
-            self._reuse = (problems, compiled, rows_np, select)
+            rows_np, ids = self.upsert(problems, compiled)
+            self._reuse = (problems, compiled, rows_np, select, ids)
             self._reuse_pass = self._pass
         if selections is not None:
             tmr["sel_moved"] = self._apply_selections(rows_np, selections)
         t0 = self._phase(tmr, "upsert", t0)
-        # packed-vs-replayed split of THIS pass: a replayed row rode its
-        # fingerprint (or the batch-identity fast path) without re-packing
+        # packed-vs-replayed split of THIS pass: a replayed row kept its
+        # state (the same object or one of equal content, or a position the
+        # pass did not visit) without re-packing
+        tmr["rows_visited"] = self._visited_this_pass
         tmr["rows_packed"] = self._packed_this_pass
         tmr["rows_replayed"] = max(
             len(problems) - self._packed_this_pass, 0
@@ -2840,10 +3030,12 @@ class FleetTable:
             # the mirrors without touching the device
             self._pass += 1
             self.new_trace_last_pass = False
-            self._packed_this_pass = 0
-            self._reuse = (problems, compiled, rows_full, ru[3])
+            self._packed_this_pass = self._visited_this_pass = 0
+            self._reuse = (problems, compiled, rows_full, ru[3], None)
             self._reuse_pass = self._pass
+            self._upsert_tally[0].inc(n)
             tmr: dict[str, float] = {
+                "rows_visited": 0.0,
                 "rows_packed": 0.0,
                 "rows_replayed": float(n),
                 "dirty_rows": 0.0,
@@ -2893,8 +3085,9 @@ class FleetTable:
         # the swapped-in rows are never spread-constrained (the engine's
         # delta pass sends such a batch through the full prologue), so the
         # batch's device-selected positions stand
-        self._reuse = (problems, compiled, rows_new, ru[3])
+        self._reuse = (problems, compiled, rows_new, ru[3], None)
         self._reuse_pass = self._pass
+        self._upsert_tally[0].inc(n - n_sub)
         t0 = time.perf_counter()
         res = self._replay_result(problems, rows_new, tmr)
         self._phase(tmr, "post", t0)

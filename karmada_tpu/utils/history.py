@@ -124,7 +124,7 @@ HISTORY_SERIES: dict[str, HistorySeries] = {
         ),
         HistorySeries(
             "rows_replayed", "counter", "span:scheduler.solve",
-            "fleet-table rows served without re-packing (row fingerprint "
+            "fleet-table rows served without re-packing (equal content "
             "or batch-identity replay)",
         ),
         HistorySeries(

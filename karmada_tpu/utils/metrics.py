@@ -490,6 +490,15 @@ fleet_slots_minted = registry.counter(
     "placement's first row, or every live placement again after a table "
     "rebuild); flat once each user placement has its slots",
 )
+fleet_upsert_rows = registry.counter(
+    "karmada_tpu_fleet_upsert_rows_total",
+    "rows of fleet passes by what the table's upsert phase made of them: "
+    "same (the row holds that very object: the batch-identity path, a "
+    "position the diff of a swapped batch did not visit, a replayed "
+    "position of a delta pass), equal (another object of equal content: "
+    "pinned, not repacked), packed (new to the table or content moved: row "
+    "state rewritten and uploaded); added once a pass",
+)
 fleet_table_rebuilds = registry.counter(
     "karmada_tpu_fleet_table_rebuilds_total",
     "fleet tables dropped and rebuilt because their LIVE rows reference "

@@ -114,7 +114,11 @@ SPAN_NAMES: dict[str, str] = {
     "scheduler.host": "host-path (non-fleet) scheduling of a batch",
     "scheduler.solve": (
         "one fleet-table solve pass (host_rows = rows of the batch that "
-        "left it for the host path)"
+        "left it for the host path; rows_visited = positions of the batch "
+        "its upsert phase looked at: 0 when the same lists come again, the "
+        "positions holding another object when a swapped batch is diffed, "
+        "every position when it is walked; rows_packed of them rewrote "
+        "their row state)"
     ),
     "scheduler.explain": (
         "armed-only provenance capture of a pass: per-stage mask "
@@ -126,7 +130,8 @@ SPAN_NAMES: dict[str, str] = {
     ),
     "kernel.host": (
         "one host stretch of a fleet pass, at its true interval: "
-        "phase=upsert|sync|prep before the dispatch, post after the fetch"
+        "phase=upsert|sync|prep before the dispatch, post after the fetch "
+        "(phase=upsert carries rows_visited / rows_packed)"
     ),
     "kernel.dispatch": (
         "kernel dispatch window (sync backends execute inside it; "

@@ -255,7 +255,7 @@ def test_a_moved_selection_changes_that_rows_answer_alone():
     assert metrics.spread_host_selected_rows.value() > 0
     # every row rides (four regions exist here, so no selection fails),
     # none of them device-selected
-    fp, fc, _, select = table._reuse
+    fp, fc, _, select, _ = table._reuse
     assert select is None and len(fp) == len(dep.problems)
     rides = list(range(len(fp)))
     resident = np.asarray(table._dev_state[-1]).copy()
