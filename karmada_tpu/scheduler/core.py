@@ -301,6 +301,9 @@ class TensorScheduler:
         # rows the HOST selected (their selection follows the capacities,
         # so such a batch is not reused across a generation move)
         self._batch_token = None
+        # which branch the pass's solve took (identity | delta | full |
+        # host): the scheduler.schedule span's path attr
+        self._pass_path = "host"
         # the mask_token the host-selection line was last printed for
         self._host_select_told = None
         # the by-reason counts the host-path line was last printed for
@@ -930,53 +933,61 @@ class TensorScheduler:
         without changing content still gets the row re-dispatched when it
         says so. Disarmed (``KARMADA_TPU_DELTA_SOLVE=0``) or absent, the
         pass costs one ``is None`` check over the existing paths."""
-        self.last_preemption = None
-        # new-trace flags are per PASS: cleared here, so a pass that never
-        # reaches the fleet table (the host path's small waves) does not
-        # go on reporting the compile of an earlier one
-        self._engine_new_trace = False
-        if self._fleet is not None:
-            self._fleet.new_trace_last_pass = False
-        self._dirty_keys = set(dirty_keys) if dirty_keys else None
-        try:
-            results = self._schedule_quota(problems)
-        finally:
-            self._dirty_keys = None
-        # the preemption pass runs BEFORE the explain capture so a
-        # re-solved demander's provenance shows its final placement. A
-        # failed preemption pass logs and leaves the demanders' honest
-        # unschedulable results intact — never the wave.
-        if self.preempt_source is not None and problems:
-            try:
-                results = self._preempt_pass(list(problems), results)
-            except Exception as exc:  # noqa: BLE001 — scarcity remedy is
-                # optional; losing it must never lose the solve results.
-                # The outcome is cleared too: a pass that died AFTER
-                # victim selection but BEFORE the re-solve must not hand
-                # the controller victims to evict with no demander placed
-                self.last_preemption = None
-                import logging
+        from ..utils.tracing import tracer as _tracer
 
-                # with the message: a kernel the device refuses to compile
-                # says why only here
-                logging.getLogger("karmada_tpu").warning(
-                    "preemption pass failed (%s: %s)",
-                    type(exc).__name__, str(exc)[:2000],
-                )
-        # the store's enabled gate honors KARMADA_TPU_EXPLAIN_CAP=0:
-        # a disabled ring must not pay the capture dispatch either
-        if self.explain is not None and self.explain.enabled and problems:
+        # the root of an engine wave: entry to return, with the path the
+        # solve took (_schedule_inner stamps it), so no stretch of a pass
+        # lies under no span and an idle device is charged to a stage
+        with _tracer.span("scheduler.schedule", rows=len(problems)) as root:
+            self._pass_path = "host"
+            self.last_preemption = None
+            # new-trace flags are per PASS: cleared here, so a pass that never
+            # reaches the fleet table (the host path's small waves) does not
+            # go on reporting the compile of an earlier one
+            self._engine_new_trace = False
+            if self._fleet is not None:
+                self._fleet.new_trace_last_pass = False
+            self._dirty_keys = set(dirty_keys) if dirty_keys else None
             try:
-                self._capture_explain(list(problems), results)
-            except Exception as exc:  # noqa: BLE001 — provenance is
-                # telemetry: losing a capture must never lose the wave
-                import logging
+                results = self._schedule_quota(problems)
+            finally:
+                self._dirty_keys = None
+                root.attrs["path"] = self._pass_path
+            # the preemption pass runs BEFORE the explain capture so a
+            # re-solved demander's provenance shows its final placement. A
+            # failed preemption pass logs and leaves the demanders' honest
+            # unschedulable results intact — never the wave.
+            if self.preempt_source is not None and problems:
+                try:
+                    results = self._preempt_pass(list(problems), results)
+                except Exception as exc:  # noqa: BLE001 — scarcity remedy is
+                    # optional; losing it must never lose the solve results.
+                    # The outcome is cleared too: a pass that died AFTER
+                    # victim selection but BEFORE the re-solve must not hand
+                    # the controller victims to evict with no demander placed
+                    self.last_preemption = None
+                    import logging
 
-                logging.getLogger("karmada_tpu").warning(
-                    "explain capture failed (%s: %s)",
-                    type(exc).__name__, str(exc)[:2000],
-                )
-        return results
+                    # with the message: a kernel the device refuses to compile
+                    # says why only here
+                    logging.getLogger("karmada_tpu").warning(
+                        "preemption pass failed (%s: %s)",
+                        type(exc).__name__, str(exc)[:2000],
+                    )
+            # the store's enabled gate honors KARMADA_TPU_EXPLAIN_CAP=0:
+            # a disabled ring must not pay the capture dispatch either
+            if self.explain is not None and self.explain.enabled and problems:
+                try:
+                    self._capture_explain(list(problems), results)
+                except Exception as exc:  # noqa: BLE001 — provenance is
+                    # telemetry: losing a capture must never lose the wave
+                    import logging
+
+                    logging.getLogger("karmada_tpu").warning(
+                        "explain capture failed (%s: %s)",
+                        type(exc).__name__, str(exc)[:2000],
+                    )
+            return results
 
     def _schedule_quota(
         self, problems: Sequence[BindingProblem]
@@ -1625,46 +1636,56 @@ class TensorScheduler:
         fleet-eligible, or majority churn where the full pass wins."""
         import time as _time
 
-        if (
+        from ..utils.tracing import tracer as _tracer
+
+        n = len(problems)
+        diff = None
+        if not (
             self._fleet is None
             or self.preempt_source is not None
             or self._batch_gen != self._snapshot_gen
             or not self._delta_enabled()
         ):
-            return None
-        n = len(problems)
-        diff = np.flatnonzero(ids != self._batch_ids)
-        dk = self._dirty_keys
-        if dk:
-            # dirty keys are advisory positions ON TOP of the id diff: a
-            # mapping miss only over-dispatches (safe superset) — a truly
-            # changed row always shows in the id diff as well
-            kp = self._key_pos
-            if kp is None or len(kp) != n:
-                kp = {p.key: i for i, p in enumerate(problems)}
-                self._key_pos = kp
-            extra = [kp[k] for k in dk if k in kp]
-            if extra:
-                diff = np.union1d(diff, np.asarray(extra, np.int64))
-        if diff.size * 2 > n:
+            diff = np.flatnonzero(ids != self._batch_ids)
+            dk = self._dirty_keys
+            if dk:
+                # dirty keys are advisory positions ON TOP of the id diff:
+                # a mapping miss only over-dispatches (safe superset) — a
+                # truly changed row always shows in the id diff as well
+                kp = self._key_pos
+                if kp is None or len(kp) != n:
+                    kp = {p.key: i for i, p in enumerate(problems)}
+                    self._key_pos = kp
+                extra = [kp[k] for k in dk if k in kp]
+                if extra:
+                    diff = np.union1d(diff, np.asarray(extra, np.int64))
+        # the identity check ends at the decision: the sweep the caller
+        # made, the diff and the dirty-key union
+        _tracer.record(
+            "scheduler.identity", _time.perf_counter() - t0, start=t0,
+            rows=n, hit=0, moved=0 if diff is None else int(diff.size),
+        )
+        if diff is None or diff.size * 2 > n:
             return None
         from .fleet import row_rides
 
         fp, fc = self._batch_cache
         fp2 = list(fp)
         fc2 = list(fc)
-        for pos in diff:
-            pos = int(pos)
-            p = problems[pos]
-            cp = self._compiled(p.placement)
-            if not (cp.fleet_terms and row_rides(p, cp)):
-                # a changed row left the fleet-eligible set (or is
-                # spread-constrained, whose selection the full prologue
-                # arranges): the full prologue partitions it
-                return None
-            fp2[pos] = p
-            fc2[pos] = cp
+        with _tracer.span("scheduler.pack", rows=int(diff.size)) as pack:
+            for visited, pos in enumerate(diff.tolist(), 1):
+                p = problems[pos]
+                cp = self._compiled(p.placement)
+                if not (cp.fleet_terms and row_rides(p, cp)):
+                    # a changed row left the fleet-eligible set (or is
+                    # spread-constrained, whose selection the full
+                    # prologue arranges): the full prologue partitions it
+                    pack.attrs["rows"] = visited
+                    return None
+                fp2[pos] = p
+                fc2[pos] = cp
         self.last_breakdown = {"compile": _time.perf_counter() - t0}
+        self._pass_path = "delta"
         self.solve_batches += 1
         res = self._fleet.schedule(fp2, fc2, delta=diff)
         self.last_breakdown.update(self._fleet.last_breakdown)
@@ -1681,6 +1702,8 @@ class TensorScheduler:
         self, problems: Sequence[BindingProblem]
     ) -> list[ScheduleResult]:
         import time as _time
+
+        from ..utils.tracing import tracer as _tracer
 
         # batch-identity fast path: a storm re-scheduling the SAME problem
         # objects is pure in those inputs — compilation and the
@@ -1713,9 +1736,13 @@ class TensorScheduler:
             t0 = _time.perf_counter()
             ids = np.fromiter(map(id, problems), np.int64, len(problems))
             if np.array_equal(ids, self._batch_ids) and not self._dirty_keys:
-                self.last_breakdown = {
-                    "compile": _time.perf_counter() - t0
-                }
+                took = _time.perf_counter() - t0
+                self.last_breakdown = {"compile": took}
+                _tracer.record(
+                    "scheduler.identity", took, start=t0,
+                    rows=len(ids), hit=1, moved=0,
+                )
+                self._pass_path = "identity"
                 fp, fc = self._batch_cache
                 self.solve_batches += 1
                 res = self._fleet.schedule(fp, fc)
@@ -1738,23 +1765,25 @@ class TensorScheduler:
         )
         from contextlib import nullcontext
 
-        from ..utils.tracing import tracer as _tracer
-
         # the host prologue (placement compile + spread selection +
         # eligibility partition) is the wave tree's "pack" phase: one
-        # span, with the Select stage as its child, so a storm's pass
-        # decomposes into pack / solve(dispatch/device/fetch) under
-        # scheduler.pass
+        # span, with its three stages as children at the intervals
+        # last_breakdown times, so a storm's pass decomposes into pack /
+        # handoff / solve(dispatch/device/fetch) / rearm under
+        # scheduler.schedule
         with (
             _tracer.span("scheduler.pack", rows=len(problems))
             if fleet_ok
             else nullcontext()
-        ):
+        ) as pack:
             t0 = _time.perf_counter()
             compiled = [self._compiled(p.placement) for p in problems]
-            self.last_breakdown = {"compile": _time.perf_counter() - t0}
+            took = _time.perf_counter() - t0
+            self.last_breakdown = {"compile": took}
             if fleet_ok:
-                t0 = _time.perf_counter()
+                _tracer.record(
+                    "scheduler.compile", took, start=t0, rows=len(problems)
+                )
                 from .fleet import row_rides
 
                 # spread-constraint rows ride the fleet too: their
@@ -1768,22 +1797,30 @@ class TensorScheduler:
                 # estimators keep their spread rows off the fleet
                 from .select import regions_fit
 
-                spread_idx = [] if self.extra_estimators else [
-                    i for i, cp in enumerate(compiled)
-                    if cp.spread_single_term
-                ]
-                on_device = bool(spread_idx) and regions_fit(self.snapshot)
-                if spread_idx:
-                    self._report_host_selected(
-                        0 if on_device else len(spread_idx)
+                # a with block, so that the host's SelectClusters span
+                # (_select_spread_rows) nests under it
+                with _tracer.span("scheduler.spread") as spread:
+                    spread_idx = [] if self.extra_estimators else [
+                        i for i, cp in enumerate(compiled)
+                        if cp.spread_single_term
+                    ]
+                    on_device = bool(spread_idx) and regions_fit(
+                        self.snapshot
                     )
-                sel_idx, sel_bits = self._select_spread_rows(
-                    problems, compiled, [] if on_device else spread_idx
-                )
-                if on_device:
-                    sel_idx = np.asarray(spread_idx, np.int64)
-                selected = frozenset(sel_idx.tolist())
-                self.last_breakdown["select"] = _time.perf_counter() - t0
+                    if spread_idx:
+                        self._report_host_selected(
+                            0 if on_device else len(spread_idx)
+                        )
+                    sel_idx, sel_bits = self._select_spread_rows(
+                        problems, compiled, [] if on_device else spread_idx
+                    )
+                    if on_device:
+                        sel_idx = np.asarray(spread_idx, np.int64)
+                    selected = frozenset(sel_idx.tolist())
+                    spread.attrs.update(
+                        rows=len(spread_idx), on_device=int(on_device)
+                    )
+                self.last_breakdown["select"] = spread.duration
 
                 t0 = _time.perf_counter()
                 # THE fleet-eligibility predicate, from what the code
@@ -1802,7 +1839,12 @@ class TensorScheduler:
                     if (cp.fleet_terms or i in selected) and row_rides(p, cp)
                 ]
                 self._report_host_path(problems, compiled, fast_idx)
-                self.last_breakdown["eligible"] = _time.perf_counter() - t0
+                took = _time.perf_counter() - t0
+                self.last_breakdown["eligible"] = took
+                _tracer.record(
+                    "scheduler.eligible", took, start=t0,
+                    rows=len(problems), fleet_rows=len(fast_idx),
+                )
         if fleet_ok:
             if len(fast_idx) >= self.fleet_threshold:
                 from .fleet import FleetTable
@@ -1838,40 +1880,56 @@ class TensorScheduler:
                     else:
                         selections = (pos[rides], sel_bits[rides])
                 self.solve_batches += 1
+                self._pass_path = "full"
+                # from the prologue's end to the table's door: the two
+                # comprehensions, a rebuild, the selected rows' positions
+                _tracer.record(
+                    "scheduler.handoff", _time.perf_counter() - pack.end,
+                    start=pack.end, rows=len(fast_idx),
+                )
                 fast_res = self._fleet.schedule(
                     fp, fc, selections=selections, select=select,
                     host_rows=len(problems) - len(fast_idx),
                 )
-                self.last_breakdown.update(self._fleet.last_breakdown)
-                if len(fast_idx) == len(problems):
-                    # all rows rode the fleet: hand back the lazy
-                    # column-oriented result list as-is, and arm the
-                    # batch-identity fast path for the next pass (fp/fc
-                    # are the very list objects the fleet keys its own
-                    # O(1) reuse on)
-                    self._batch_problems = fp
-                    self._batch_ids = np.fromiter(
-                        map(id, fp), np.int64, len(fp)
-                    )
-                    self._batch_gen = self._snapshot_gen
-                    self._batch_cache = (fp, fc)
-                    self._batch_token = (
-                        self.snapshot.mask_token if selections is None
-                        else None
-                    )
-                    return fast_res
-                results: list = [None] * len(problems)
-                for i, res in zip(fast_idx, fast_res):
-                    results[i] = res
-                slow_idx = [i for i in range(len(problems)) if results[i] is None]
-                if slow_idx:
-                    slow_res = self._schedule_host(
-                        [problems[i] for i in slow_idx],
-                        [compiled[i] for i in slow_idx],
-                    )
-                    for i, res in zip(slow_idx, slow_res):
+                # from the table's answer to the engine's: the id() sweep
+                # that arms the identity path, or the merge with the host
+                # path's rows (scheduler.host its child)
+                with _tracer.span(
+                    "scheduler.rearm", rows=len(problems),
+                    host_rows=len(problems) - len(fast_idx),
+                ):
+                    self.last_breakdown.update(self._fleet.last_breakdown)
+                    if len(fast_idx) == len(problems):
+                        # all rows rode the fleet: hand back the lazy
+                        # column-oriented result list as-is, and arm the
+                        # batch-identity fast path for the next pass (fp/fc
+                        # are the very list objects the fleet keys its own
+                        # O(1) reuse on)
+                        self._batch_problems = fp
+                        self._batch_ids = np.fromiter(
+                            map(id, fp), np.int64, len(fp)
+                        )
+                        self._batch_gen = self._snapshot_gen
+                        self._batch_cache = (fp, fc)
+                        self._batch_token = (
+                            self.snapshot.mask_token if selections is None
+                            else None
+                        )
+                        return fast_res
+                    results: list = [None] * len(problems)
+                    for i, res in zip(fast_idx, fast_res):
                         results[i] = res
-                return results
+                    slow_idx = [
+                        i for i in range(len(problems)) if results[i] is None
+                    ]
+                    if slow_idx:
+                        slow_res = self._schedule_host(
+                            [problems[i] for i in slow_idx],
+                            [compiled[i] for i in slow_idx],
+                        )
+                        for i, res in zip(slow_idx, slow_res):
+                            results[i] = res
+                    return results
         # no fleet pass: an engine-level feature, or fewer eligible rows
         # than the threshold, keeps the whole batch on the host path
         from ..utils.metrics import fleet_host_path_rows

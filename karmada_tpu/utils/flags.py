@@ -186,7 +186,7 @@ ENV_FLAGS: dict[str, EnvFlag] = {
             "Must divide KARMADA_TPU_MESH_DEVICES.",
         ),
         EnvFlag(
-            "KARMADA_TPU_TRACE_CAPACITY", "8192",
+            "KARMADA_TPU_TRACE_CAPACITY", "32768",
             "Span capacity of the wave-trace ring "
             "(utils.tracing.WaveTracer): 1M-tier storms outgrow the "
             "default and spans silently aging off the ring degrade "

@@ -77,7 +77,9 @@ FLIGHT_DIR_ENV = "KARMADA_TPU_FLIGHT_DIR"
 FLIGHT_CAP_ENV = "KARMADA_TPU_FLIGHT_CAP"
 TRACE_PEERS_ENV = "KARMADA_TPU_TRACE_PEERS"
 
-_DEFAULT_CAPACITY = 8192
+# holds the busiest traced benchmark window more than twice over (the
+# node-churn cell: 15 spans a wave, 8,778 a window); ~425 bytes a span
+_DEFAULT_CAPACITY = 32768
 _DEFAULT_FLIGHT_CAP = 64
 
 
@@ -93,16 +95,50 @@ SPAN_NAMES: dict[str, str] = {
     "settle": "one run_until_settled drain — the wave's root span",
     "controller.*": "one contiguous drain of one controller worker",
     "scheduler.pass": "one engine pass over a queued binding batch",
+    "scheduler.schedule": (
+        "one TensorScheduler.schedule call, entry to return: the root of "
+        "an engine wave (rows; path = identity, the armed batch came "
+        "again; delta, a minority of its positions moved; full, the "
+        "whole prologue ran and the fleet table took rows; host, no "
+        "fleet pass)"
+    ),
+    "scheduler.identity": (
+        "the batch-identity check: the id() sweep over the batch and, on "
+        "a miss, the diff against the armed batch + the dirty keys (rows "
+        "/ hit / moved attrs)"
+    ),
     "scheduler.pack": (
         "host prologue of a pass: placement compile + spread selection + "
-        "eligibility partition"
+        "eligibility partition (on the delta path: the moved positions' "
+        "compile and eligibility check)"
+    ),
+    "scheduler.compile": (
+        "under scheduler.pack: the compiled-placement look-up of every row"
+    ),
+    "scheduler.spread": (
+        "under scheduler.pack: which rows are spread-constrained and who "
+        "selects for them (rows / on_device attrs); a host selection is "
+        "its scheduler.select child"
+    ),
+    "scheduler.eligible": (
+        "under scheduler.pack: the fleet-eligibility partition of the "
+        "batch (rows / fleet_rows attrs)"
+    ),
+    "scheduler.handoff": (
+        "from scheduler.pack's end to the fleet table's door: the fleet "
+        "rows' lists, a table rebuild, the selected rows' positions"
+    ),
+    "scheduler.rearm": (
+        "from the fleet table's answer to the engine's: the id() sweep "
+        "that arms the identity path, or the merge with the host path's "
+        "rows (scheduler.host its child; rows / host_rows attrs)"
     ),
     "scheduler.select": (
         "only when the batch holds spread-constrained rows: the host's "
         "share of the Select stage. Under scheduler.solve: the dispatch of "
         "the fleet table's select kernel (device = rows it selected); "
-        "under scheduler.pack: SelectClusters on the host for the rows the "
-        "kernel does not take (rows / device / hits / computed / "
+        "under scheduler.spread: SelectClusters on the host for the rows "
+        "the kernel does not take (rows / device / hits / computed / "
         "fit_errors / moved attrs)"
     ),
     "scheduler.terms": (
@@ -370,7 +406,7 @@ class WaveTracer:
 
     def __init__(self, capacity: Optional[int] = None):
         # capacity: explicit argument wins; else KARMADA_TPU_TRACE_CAPACITY
-        # (the 1M-tier storms outgrow the 8192 default — evictions are
+        # (the 1M-tier storms outgrow the default — evictions are
         # counted, never silent)
         self.capacity = _env_capacity() if capacity is None else capacity
         self._lock = threading.Lock()

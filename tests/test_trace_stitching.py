@@ -179,7 +179,7 @@ class TestTracerSatellites:
         monkeypatch.setenv("KARMADA_TPU_TRACE_CAPACITY", "32")
         assert WaveTracer().capacity == 32
         monkeypatch.setenv("KARMADA_TPU_TRACE_CAPACITY", "bogus")
-        assert WaveTracer().capacity == 8192
+        assert WaveTracer().capacity == 32768
         monkeypatch.delenv("KARMADA_TPU_TRACE_CAPACITY")
         assert WaveTracer(capacity=7).capacity == 7
 

@@ -11,11 +11,18 @@
   ``runtime.gc`` span for full ones only, no lock taken in the callback;
 - ``jax.named_scope`` names on the stages of the fleet kernels;
 - new-trace flags are per pass (PERF.md section 7, fault 2).
+
+ISSUE 35: an engine wave accounted for from entry to answer. One engine
+driven through each path (identity, delta, full, host): one
+``scheduler.schedule`` root with the ``path`` it took, its children inside
+it, disjoint and ordered, the identity check and the full prologue's stages
+as spans at the intervals ``last_breakdown`` times.
 """
 
 from __future__ import annotations
 
 import gc
+import itertools
 import threading
 import time
 
@@ -30,7 +37,11 @@ from karmada_tpu.controllers import (
     WorkloadRebalancerSpec,
 )
 from karmada_tpu.controlplane import ControlPlane
-from karmada_tpu.scheduler import ClusterSnapshot, TensorScheduler
+from karmada_tpu.scheduler import (
+    BindingProblem,
+    ClusterSnapshot,
+    TensorScheduler,
+)
 from karmada_tpu.utils import metrics
 from karmada_tpu.utils.builders import (
     dynamic_weight_placement,
@@ -38,10 +49,19 @@ from karmada_tpu.utils.builders import (
     new_deployment,
     synthetic_fleet,
 )
-from karmada_tpu.utils.tracing import GcWatch, WaveTracer, gc_watch, tracer
+from karmada_tpu.scheduler import fleet as fleet_mod
+from karmada_tpu.scheduler import select as select_mod
+from karmada_tpu.utils.tracing import (
+    GcWatch,
+    WaveTracer,
+    gc_watch,
+    span_name_registered,
+    tracer,
+)
 from karmada_tpu.utils.worker import DONE, REQUEUE, Runtime, WriteCount
 
 from test_delta_solve import build_problems, churned
+from test_fleet_select import _clusters, _generation, _problems
 
 HOST_KEYS = ("upsert", "sync", "prep", "post")
 
@@ -149,6 +169,308 @@ class TestPhaseIntervals:
         [solve] = [s for s in tracer.dump() if s["name"] == "scheduler.solve"]
         assert t0 <= pack["start"]
         assert pack["start"] + pack["duration_s"] <= solve["start"] + 2e-6
+
+
+# --------------------------------------------------------------------------
+# A2: the engine wave from entry to answer (ISSUE 35)
+# --------------------------------------------------------------------------
+
+ENGINE_SPANS = (
+    "scheduler.schedule", "scheduler.identity", "scheduler.compile",
+    "scheduler.spread", "scheduler.eligible", "scheduler.handoff",
+    "scheduler.rearm",
+)
+STAGES = {"scheduler.compile": "compile", "scheduler.spread": "select",
+          "scheduler.eligible": "eligible"}
+
+
+def _ring() -> list:
+    """The completed Span objects of the ring (unrounded stamps)."""
+    return [sp for w in tracer.waves() for sp in tracer.spans_for(w)]
+
+
+def _children(spans: list, parent) -> list:
+    return sorted((s for s in spans if s.parent_id == parent.span_id),
+                  key=lambda s: s.start)
+
+
+def _inside_disjoint_ordered(parent, kids: list) -> None:
+    eps = 1e-9  # a recorded span's end is start + duration: one rounding
+    for a, b in zip(kids, kids[1:]):
+        assert a.end <= b.start + eps, (a.name, b.name)
+    if kids:
+        assert parent.start <= kids[0].start + eps, kids[0].name
+        assert kids[-1].end <= parent.end + eps, kids[-1].name
+
+
+_TAINTS = itertools.count()
+
+
+def _moved_token(eng) -> ClusterSnapshot:
+    """The engine's members with one tainted anew: the filter fields move."""
+    from karmada_tpu.api.cluster import Taint
+
+    clusters = list(eng.snapshot.clusters)
+    spare = next(cl for cl in clusters if not cl.spec.taints)
+    spare.spec.taints = [
+        Taint(key=f"issue35-{next(_TAINTS)}", effect="NoSchedule")]
+    try:
+        snap = ClusterSnapshot(clusters)
+        assert snap.mask_token != eng.snapshot.mask_token
+    finally:
+        spare.spec.taints = []
+    return snap
+
+
+def _drive_identity(eng, problems):
+    eng.schedule(problems)  # arms the batch, whatever ran before
+    return problems, lambda: eng.schedule(problems)
+
+
+def _drive_delta(eng, problems):
+    eng.schedule(problems)
+    changed, _ = churned(problems, np.random.default_rng(35), 30)
+    return changed, lambda: eng.schedule(changed)
+
+
+def _drive_full_token(eng, problems):
+    eng.schedule(problems)
+    snap = _moved_token(eng)
+
+    def wave():
+        assert eng.update_snapshot(snap)
+        return eng.schedule(problems)
+    return problems, wave
+
+
+def _drive_full_host_row(eng, problems):
+    eng.schedule(problems)
+    batch = list(problems)
+    names = eng.snapshot.names
+    row = next(i for i, p in enumerate(batch) if p.replicas > 0)
+    batch[row] = BindingProblem(
+        key=batch[row].key, placement=batch[row].placement,
+        replicas=batch[row].replicas, requests=batch[row].requests,
+        gvk=batch[row].gvk,
+        evict_clusters=tuple(names[: fleet_mod.K_EVICT + 1]))
+    return batch, lambda: eng.schedule(batch)
+
+
+PATHS = {
+    # case: (driver, path, the root's children by start)
+    "identity": (_drive_identity, "identity",
+                 ["scheduler.identity", "scheduler.solve"]),
+    "delta": (_drive_delta, "delta",
+              ["scheduler.identity", "scheduler.pack", "scheduler.solve"]),
+    "full-moved-token": (
+        _drive_full_token, "full",
+        ["scheduler.pack", "scheduler.handoff", "scheduler.solve",
+         "scheduler.rearm"]),
+    # the id() sweep and the delta's check of the one moved row come first:
+    # that row left the fleet-eligible set, so the whole prologue runs
+    "full-host-row": (
+        _drive_full_host_row, "full",
+        ["scheduler.identity", "scheduler.pack", "scheduler.pack",
+         "scheduler.handoff", "scheduler.solve", "scheduler.rearm"]),
+}
+
+
+class TestEngineWave:
+    @pytest.mark.parametrize("case", sorted(PATHS))
+    def test_one_root_names_the_path_and_holds_its_children(
+            self, engine, case):
+        eng, problems = engine
+        drive, path, names = PATHS[case]
+        batch, wave = drive(eng, problems)
+        tracer.clear()
+        wave()
+        spans = _ring()
+        [root] = [s for s in spans if s.name == "scheduler.schedule"]
+        assert root.parent_id is None
+        assert (root.attrs["path"], root.attrs["rows"]) == (path, len(batch))
+        kids = _children(spans, root)
+        assert [s.name for s in kids] == names
+        _inside_disjoint_ordered(root, kids)
+        ident = [s for s in spans if s.name == "scheduler.identity"]
+        if case == "identity":
+            [sp] = ident
+            assert (sp.attrs["hit"], sp.attrs["moved"]) == (1, 0)
+            # one clock read, two consumers
+            assert sp.duration == pytest.approx(
+                eng.last_breakdown["compile"], abs=1e-9)
+        elif case == "delta":
+            [sp] = ident
+            assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 30)
+            [pack] = [s for s in kids if s.name == "scheduler.pack"]
+            assert pack.attrs["rows"] == 30
+            assert not _children(spans, pack)
+            # the stamp of the two together is the delta's "compile"
+            assert sp.start + eng.last_breakdown["compile"] == (
+                pytest.approx(pack.end, abs=1e-4))
+        elif case == "full-host-row":
+            [sp] = ident
+            assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 1)
+            tried, _ = [s for s in kids if s.name == "scheduler.pack"]
+            assert tried.attrs["rows"] == 1  # the position it visited
+        else:
+            assert not ident  # a moved token: no sweep is made
+        assert all(s.attrs["rows"] == len(batch) for s in ident)
+
+    @pytest.mark.parametrize("case", ["full-moved-token", "full-host-row"])
+    def test_the_full_prologue_is_staged_under_pack(self, engine, case):
+        eng, problems = engine
+        drive, _, _ = PATHS[case]
+        batch, wave = drive(eng, problems)
+        tracer.clear()
+        wave()
+        spans = _ring()
+        pack = [s for s in spans if s.name == "scheduler.pack"][-1]
+        assert pack.attrs["rows"] == len(batch)
+        stages = _children(spans, pack)
+        assert [s.name for s in stages] == list(STAGES)
+        _inside_disjoint_ordered(pack, stages)
+        bd = eng.last_breakdown
+        for sp in stages:
+            assert sp.duration == pytest.approx(
+                bd[STAGES[sp.name]], abs=1e-9), sp.name
+        compile_, spread, eligible = stages
+        host_rows = 1 if case == "full-host-row" else 0
+        assert compile_.attrs["rows"] == len(batch)
+        assert (spread.attrs["rows"], spread.attrs["on_device"]) == (0, 0)
+        assert (eligible.attrs["rows"], eligible.attrs["fleet_rows"]) == (
+            len(batch), len(batch) - host_rows)
+        # hand-off from pack's end to the table's door, re-arm from its
+        # answer to the engine's
+        [handoff] = [s for s in spans if s.name == "scheduler.handoff"]
+        [solve] = [s for s in spans if s.name == "scheduler.solve"]
+        [rearm] = [s for s in spans if s.name == "scheduler.rearm"]
+        assert handoff.start == pack.end
+        assert handoff.end <= solve.start + 1e-9
+        assert solve.end <= rearm.start
+        assert handoff.attrs["rows"] == len(batch) - host_rows
+        assert rearm.attrs["host_rows"] == host_rows
+        host = [s for s in spans if s.name == "scheduler.host"]
+        if host_rows:
+            [sp] = host
+            assert sp.parent_id == rearm.span_id
+            assert sp.attrs["rows"] == 1
+        else:
+            assert not host
+            # the batch is armed again: the next pass takes the fast path
+            tracer.clear()
+            eng.schedule(batch)
+            [root] = [s for s in _ring() if s.name == "scheduler.schedule"]
+            assert root.attrs["path"] == "identity"
+
+    def test_a_host_selection_is_the_spread_stages_child(self):
+        regions = [f"r{k}" for k in range(select_mod.R_CAP + 1)]
+        clusters = _clusters(regions, c=30)
+        problems = _problems(clusters)
+        eng = TensorScheduler(_generation(clusters, 0), chunk_size=256)
+        tracer.clear()
+        eng.schedule(problems)
+        spans = _ring()
+        [root] = [s for s in spans if s.name == "scheduler.schedule"]
+        assert root.attrs["path"] == "full"
+        [spread] = [s for s in spans if s.name == "scheduler.spread"]
+        [select] = [s for s in spans if s.name == "scheduler.select"]
+        assert select.parent_id == spread.span_id
+        assert spread.start <= select.start and select.end <= spread.end
+        assert spread.attrs["on_device"] == 0
+        assert spread.attrs["rows"] == select.attrs["rows"] > 0
+        assert spread.duration == eng.last_breakdown["select"]
+        # its FitErrors take the host path, inside the merge
+        [rearm] = [s for s in spans if s.name == "scheduler.rearm"]
+        [host] = [s for s in spans if s.name == "scheduler.host"]
+        assert host.parent_id == rearm.span_id
+        assert rearm.attrs["host_rows"] == host.attrs["rows"] > 0
+
+    def test_below_the_threshold_the_path_is_host(self, engine):
+        _, problems = engine
+        small = problems[:40]
+        eng = TensorScheduler(ClusterSnapshot(
+            synthetic_fleet(48, seed=7, taint_fraction=0.08)),
+            trace_manifest="")
+        assert len(small) < eng.fleet_threshold
+        tracer.clear()
+        eng.schedule(small)
+        spans = _ring()
+        [root] = [s for s in spans if s.name == "scheduler.schedule"]
+        assert (root.attrs["path"], root.attrs["rows"]) == ("host", 40)
+        kids = _children(spans, root)
+        assert [s.name for s in kids] == ["scheduler.pack", "scheduler.host"]
+        _inside_disjoint_ordered(root, kids)
+        assert [s.name for s in _children(spans, kids[0])] == list(STAGES)
+        assert not [s for s in spans if s.name in (
+            "scheduler.identity", "scheduler.handoff", "scheduler.rearm",
+            "scheduler.solve")]
+
+    def test_in_a_plane_settle_the_root_nests_under_the_pass(self):
+        cp = ControlPlane(clock=lambda: 5000.0)
+        for i in (1, 2, 3):
+            cp.join_cluster(
+                new_cluster(f"member{i}", cpu="100", memory="200Gi"))
+        for i in range(6):
+            cp.store.apply(new_deployment(f"app{i}", replicas=4))
+        tracer.clear()
+        cp.store.apply(_policy())
+        cp.settle()
+        spans = _ring()
+        by_id = {s.span_id: s for s in spans}
+        roots = [s for s in spans if s.name == "scheduler.schedule"]
+        assert roots, "the settle ran no engine pass"
+        for root in roots:
+            parent = by_id[root.parent_id]
+            assert parent.name == "scheduler.pass"
+            assert parent.start <= root.start and root.end <= parent.end
+            assert root.attrs["path"] in ("identity", "delta", "full", "host")
+
+    def test_the_benchmarks_readers_read_the_engines_own_spans(self, engine):
+        """An ``h h L h`` ring in small: what the program stamps is what the
+        four readers take, wave for wave."""
+        from benchmark.metrics import (
+            identity_check_s,
+            swap_prologue_s,
+            swap_wave_s,
+            wave_unspanned_s,
+        )
+
+        eng, problems = engine
+        eng.schedule(problems)
+        snap = _moved_token(eng)
+        tracer.clear()
+        waves = []
+        for g in range(4):
+            t0 = time.perf_counter()
+            if g == 2:
+                assert eng.update_snapshot(snap)
+            eng.schedule(problems)
+            waves.append((t0, time.perf_counter()))
+        spans = tracer.dump()
+        ctx = {"spans": spans, "waves": waves}
+        roots = [s for s in spans if s["name"] == "scheduler.schedule"]
+        assert [s["attrs"]["path"] for s in roots] == [
+            "identity", "identity", "full", "identity"]
+        swap = roots[2]
+        assert swap_wave_s.read(ctx) == swap["duration_s"]
+        own = sum(s["duration_s"] for s in spans
+                  if s["parent_id"] == swap["span_id"] and s["name"] in (
+                      "scheduler.pack", "scheduler.handoff",
+                      "scheduler.rearm"))
+        assert 0 < swap_prologue_s.read(ctx) == pytest.approx(own)
+        assert swap_prologue_s.read(ctx) < swap_wave_s.read(ctx)
+        ident = sorted(s["duration_s"] for s in spans
+                       if s["name"] == "scheduler.identity")
+        assert len(ident) == 3 and identity_check_s.read(ctx) == ident[1]
+        # the root covers the pass: what is left of a wave is the loop's own
+        dark = wave_unspanned_s.read(ctx)
+        assert 0 <= dark <= max(
+            (b - a) - r["duration_s"] for (a, b), r in zip(waves, roots)
+        ) + 2e-6
+
+    @pytest.mark.parametrize("name", ENGINE_SPANS)
+    def test_the_taxonomy_holds_every_name(self, name):
+        assert span_name_registered(name)
 
 
 class TestRecord:
