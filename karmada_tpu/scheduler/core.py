@@ -212,6 +212,15 @@ class PreemptionOutcome:
     freed_caps: Optional[np.ndarray] = None
 
 
+def _placement_flags(cp: CompiledPlacement) -> tuple:
+    """What the prologue reads of a compiled placement to place a row: the
+    placement half of the fleet-eligibility predicate, whether the Select
+    stage selects for it, and the strategy fleet.row_rides asks. Functions
+    of the PLACEMENT alone, so a snapshot swap leaves them standing (the
+    swap diff compares them to be told if that ever stops being so)."""
+    return cp.fleet_terms, cp.spread_single_term, cp.strategy
+
+
 class TensorScheduler:
     """Schedules batches of bindings against one cluster snapshot."""
 
@@ -301,6 +310,21 @@ class TensorScheduler:
         # rows the HOST selected (their selection follows the capacities,
         # so such a batch is not reused across a generation move)
         self._batch_token = None
+        # the armed batch by placement, for the swap diff (_swap_diff):
+        # (distinct placements, each one's (fleet_terms,
+        # spread_single_term, strategy), intp[n] position -> placement).
+        # None until a swap diff needs it: built from the armed compiled
+        # list then, kept current by the swap diffs after it, dropped by
+        # every other pass that arms a batch
+        self._batch_placements: Optional[tuple] = None
+        from ..utils.metrics import scheduler_prologue_rows
+
+        # positions of each full-path pass the prologue (kept, visited),
+        # added once a pass
+        self._prologue_tally = tuple(
+            scheduler_prologue_rows.labels(outcome=o)
+            for o in ("kept", "visited")
+        )
         # which branch the pass's solve took (identity | delta | full |
         # host): the scheduler.schedule span's path attr
         self._pass_path = "host"
@@ -495,6 +519,7 @@ class TensorScheduler:
             self._batch_ids = None
             self._batch_cache = None
             self._batch_problems = None
+            self._batch_placements = None
             self._quota_cache = None
             self._caps_dev = None
             self._caps_dev_token = None
@@ -923,6 +948,37 @@ class TensorScheduler:
         armed the pass's decision provenance captures AFTER the results
         exist (one extra armed-only dispatch per chunk — telemetry, so
         a capture failure logs and never aborts the wave).
+
+        Which of four routes a batch takes (_schedule_inner) follows from
+        what the engine observes, no option selects one. A batch is ARMED
+        once every row of a pass rode the fleet table; a later batch of
+        the armed batch's length (no custom filter, host-only estimator
+        or disabled plugin set) is diffed against it by object identity,
+        one id() sweep a pass:
+
+        - identity: the snapshot generation stands, or only availability
+          moved (the armed ``mask_token`` is the snapshot's), and every
+          position holds the armed object: the sweep, then the table's
+          pass over the armed lists (root span ``path`` = identity);
+        - delta: the same, but a minority of positions (or the caller's
+          dirty keys) moved at an UNMOVED generation: those rows are
+          compiled, checked and dispatched, the rest replayed
+          (_delta_pass; ``path`` = delta);
+        - swap diff, on the full path: generation AND ``mask_token`` moved
+          (a taint, a label: no answer may be replayed and no compiled
+          placement kept) and a minority of positions moved: the armed
+          batch's distinct placements are compiled anew, the moved
+          positions compiled and held to the fleet-eligibility predicate,
+          every row dispatched (_swap_diff; ``path`` = full,
+          ``scheduler.pack`` carries ``rows`` visited and ``kept``);
+        - the walk, the one general path: no armed batch, another length,
+          host-selected or host-path rows in the last pass, a majority of
+          positions moved, a moved position that leaves the fleet, a
+          guard set: every position compiled and partitioned (``path`` =
+          full, or host where no fleet pass follows).
+
+        Problem objects are not mutated in place between passes: every
+        identity route rests on that.
 
         ``dirty_keys`` (optional) is the caller's per-wave dirty-row set:
         binding keys whose problems changed since the last wave (watch-bus
@@ -1667,23 +1723,17 @@ class TensorScheduler:
         )
         if diff is None or diff.size * 2 > n:
             return None
-        from .fleet import row_rides
-
-        fp, fc = self._batch_cache
-        fp2 = list(fp)
-        fc2 = list(fc)
+        # every other position holds the armed batch's object
+        fp2 = list(problems)
+        fc2 = list(self._batch_cache[1])
         with _tracer.span("scheduler.pack", rows=int(diff.size)) as pack:
-            for visited, pos in enumerate(diff.tolist(), 1):
-                p = problems[pos]
-                cp = self._compiled(p.placement)
-                if not (cp.fleet_terms and row_rides(p, cp)):
-                    # a changed row left the fleet-eligible set (or is
-                    # spread-constrained, whose selection the full
-                    # prologue arranges): the full prologue partitions it
-                    pack.attrs["rows"] = visited
-                    return None
-                fp2[pos] = p
-                fc2[pos] = cp
+            # a changed row that left the fleet-eligible set, or is
+            # spread-constrained (the full prologue arranges its
+            # selection): the full prologue partitions it
+            visited, ride = self._visit_moved(problems, diff.tolist(), fc2)
+            if not ride:
+                pack.attrs["rows"] = visited
+                return None
         self.last_breakdown = {"compile": _time.perf_counter() - t0}
         self._pass_path = "delta"
         self.solve_batches += 1
@@ -1696,7 +1746,159 @@ class TensorScheduler:
         self._batch_problems = fp2
         self._batch_ids = ids
         self._batch_cache = (fp2, fc2)
+        self._batch_placements = None
         return res
+
+    def _visit_moved(
+        self, problems, positions: list, fc: list, spread_rides: bool = False
+    ) -> tuple:
+        """THE per-position loop of the two diffs against the armed batch
+        (_delta_pass, _swap_diff): each of ``positions`` gets its compiled
+        placement under the current snapshot (a look-up; a placement new
+        to the cache compiles) written into ``fc``, and is held to the
+        fleet-eligibility predicate the walk applies (cp.fleet_terms, or a
+        spread-constrained single-term row where ``spread_rides``: the
+        pass has the fleet table select for it; and fleet.row_rides).
+        Returns (positions visited, whether each of them rides the fleet):
+        the loop ends at the first that does not."""
+        from .fleet import row_rides
+
+        # one look-up a placement, not a position (the positions pin
+        # their placements, so no id comes twice in a pass)
+        seen: dict = {}
+        for visited, pos in enumerate(positions, 1):
+            p = problems[pos]
+            cp = seen.get(id(p.placement))
+            if cp is None:
+                cp = seen[id(p.placement)] = self._compiled(p.placement)
+            if not (
+                (cp.fleet_terms or (spread_rides and cp.spread_single_term))
+                and row_rides(p, cp)
+            ):
+                return visited, False
+            fc[pos] = cp
+        return len(positions), True
+
+    def _swap_diff(self, problems, pack) -> tuple:
+        """The full path's diff of a SWAPPED batch against the armed one,
+        where the snapshot generation AND its mask_token moved (a region
+        lost or back: neither the identity branch nor _delta_pass may
+        replay an answer): one id() sweep, and the prologue's work for the
+        moved positions alone. What a moved token leaves standing is what
+        the prologue derives a position from the problem OBJECT (pinned by
+        the armed batch, never mutated in place) and its PLACEMENT
+        (strategy, the term count and the spread constraints are functions
+        of the placement alone): which placement the row names and whether
+        the row rides the fleet. What it does not: a compiled placement.
+        update_snapshot cleared the cache, so every distinct placement of
+        the armed batch is compiled anew (one compile each), the new list
+        is a take over the armed batch's position -> placement index, and
+        the moved positions go through _visit_moved.
+
+        Returns (ids, hand-off | None): the sweep, to re-arm with and to
+        hand the table, and (fp, fc, the spread-constrained rows'
+        positions | None, the new batch by placement); None where the walk
+        has to run: most positions moved, a placement's flags differ under
+        the new snapshot, spread rows the device cannot select for
+        (regions past R_CAP, extra estimators), a moved position that
+        leaves the fleet. Records scheduler.identity, and for a hand-off
+        pack's three stages (their ``rows``: the positions visited), under
+        the open scheduler.pack span."""
+        import time as _time
+
+        from ..utils.metrics import fleet_host_path_rows
+        from ..utils.tracing import tracer as _tracer
+        from .select import regions_fit
+
+        n = len(problems)
+        t_sweep = _time.perf_counter()
+        ids = np.fromiter(map(id, problems), np.int64, n)
+        moved = np.flatnonzero(ids != self._batch_ids)
+        k = int(moved.size)
+        t_compile = _time.perf_counter()
+        _tracer.record(
+            "scheduler.identity", t_compile - t_sweep, start=t_sweep,
+            rows=n, hit=0, moved=k,
+        )
+        if k * 2 > n:
+            return ids, None  # the walk costs no more
+        built = self._batch_placements
+        if built is None:
+            old = self._batch_cache[1]
+            _, first, index = np.unique(
+                np.fromiter(map(id, old), np.int64, n),
+                return_index=True, return_inverse=True,
+            )
+            cps = [old[i] for i in first.tolist()]
+            built = (
+                [cp.placement for cp in cps],
+                [_placement_flags(cp) for cp in cps],
+                index,
+            )
+        placements, flags, index = built
+        cps = [self._compiled(pl) for pl in placements]
+        spread_rides = not self.extra_estimators and regions_fit(
+            self.snapshot
+        )
+        if [_placement_flags(cp) for cp in cps] != flags or (
+            not spread_rides and any(f[1] for f in flags)
+        ):
+            return ids, None
+        table = np.empty(len(cps), object)
+        table[:] = cps
+        fc = table[index].tolist()
+        t_eligible = _time.perf_counter()
+        positions = moved.tolist()
+        if not self._visit_moved(problems, positions, fc, spread_rides)[1]:
+            return ids, None
+        # the index, corrected at the moved positions (a new array: the
+        # armed one stands until the re-arm)
+        slot = {id(cp): j for j, cp in enumerate(cps)}
+        at = list(map(fc.__getitem__, positions))
+        slots = list(map(slot.get, map(id, at)))
+        joined = None in slots
+        if joined:
+            # a placement the armed batch did not name joins the distinct
+            # ones for this pass; the next swap diff builds its index anew
+            flags = list(flags)
+            for j, cp in enumerate(at):
+                if slots[j] is None:
+                    slots[j] = slot.setdefault(id(cp), len(flags))
+                    if slots[j] == len(flags):
+                        flags.append(_placement_flags(cp))
+        index = index.copy()
+        index[moved] = slots
+        built = None if joined else (placements, flags, index)
+        t_spread = _time.perf_counter()
+        select = None
+        spread = np.fromiter((f[1] for f in flags), bool, len(flags))
+        if spread.any():
+            select = np.flatnonzero(spread[index])
+            self._report_host_selected(0)
+        # a list of its own: the caller's may change under the armed batch
+        fp = list(problems)
+        fleet_host_path_rows.set(0)
+        t_end = _time.perf_counter()
+        self.last_breakdown = {
+            "compile": t_eligible - t_compile,
+            "eligible": t_spread - t_eligible,
+            "select": t_end - t_spread,
+        }
+        pack.attrs.update(rows=k, kept=n - k)
+        n_select = 0 if select is None else int(select.size)
+        _tracer.record(
+            "scheduler.compile", t_eligible - t_compile, start=t_compile,
+            rows=k, placements=len(cps),
+        )
+        _tracer.record(
+            "scheduler.eligible", t_spread - t_eligible, start=t_eligible,
+            rows=k, fleet_rows=n,
+        )
+        _tracer.record(
+            "scheduler.spread", t_end - t_spread, start=t_spread,
+            rows=n_select, on_device=int(n_select > 0),
+        )
+        return ids, (fp, fc, select, built)
 
     def _schedule_inner(
         self, problems: Sequence[BindingProblem]
@@ -1705,6 +1907,35 @@ class TensorScheduler:
 
         from ..utils.tracing import tracer as _tracer
 
+        # engine-level features that the device-resident path does not
+        # model force the general host path for the whole batch
+        fleet_ok = not (
+            self.custom_filters
+            or self._host_only_estimators()
+            or self.disabled_plugins
+        )
+        # a batch of the armed batch's length is diffed against it by
+        # object identity: below where the base of its answers stands, in
+        # _swap_diff on the full path where that moved
+        armed = (
+            fleet_ok
+            and self._batch_ids is not None
+            and len(problems) == len(self._batch_ids)
+        )
+        base_stands = (
+            self._batch_gen == self._snapshot_gen
+            # availability-only drift keeps every compiled mask and the
+            # eligibility partition valid (placements key on filter
+            # fields = mask_token), and the fleet table re-selects its
+            # spread rows on the device in every pass, so batches reuse
+            # across the swap — churn passes skip the prologue too
+            # (the token is None for a batch with host-selected rows)
+            or self._batch_token == self.snapshot.mask_token
+        )
+        # id() of every position's object, from whichever diff swept the
+        # batch: what the table diffs by and the batch is re-armed with, so
+        # a pass sweeps once
+        ids = None
         # batch-identity fast path: a storm re-scheduling the SAME problem
         # objects is pure in those inputs — compilation and the
         # eligibility partition key on object identity + the snapshot's
@@ -1714,25 +1945,7 @@ class TensorScheduler:
         # the vectorized form of the per-row `is problem` fast path the
         # fleet's upsert already takes; like it, it assumes problem objects
         # are not mutated in place between passes.
-        if (
-            self._batch_ids is not None
-            and (
-                self._batch_gen == self._snapshot_gen
-                # availability-only drift keeps every compiled mask and the
-                # eligibility partition valid (placements key on filter
-                # fields = mask_token), and the fleet table re-selects its
-                # spread rows on the device in every pass, so batches reuse
-                # across the swap — churn passes skip the prologue too
-                # (the token is None for a batch with host-selected rows)
-                or self._batch_token == self.snapshot.mask_token
-            )
-            and not (
-                self.custom_filters
-                or self._host_only_estimators()
-                or self.disabled_plugins
-            )
-            and len(problems) == len(self._batch_ids)
-        ):
+        if armed and base_stands:
             t0 = _time.perf_counter()
             ids = np.fromiter(map(id, problems), np.int64, len(problems))
             if np.array_equal(ids, self._batch_ids) and not self._dirty_keys:
@@ -1756,13 +1969,6 @@ class TensorScheduler:
             if res is not None:
                 return res
 
-        # engine-level features that the device-resident path does not
-        # model force the general host path for the whole batch
-        fleet_ok = not (
-            self.custom_filters
-            or self._host_only_estimators()
-            or self.disabled_plugins
-        )
         from contextlib import nullcontext
 
         # the host prologue (placement compile + spread selection +
@@ -1770,17 +1976,31 @@ class TensorScheduler:
         # span, with its three stages as children at the intervals
         # last_breakdown times, so a storm's pass decomposes into pack /
         # handoff / solve(dispatch/device/fetch) / rearm under
-        # scheduler.schedule
+        # scheduler.schedule. ``rows`` are the positions the prologue
+        # visited, ``kept`` those a swapped batch's diff spared it
+        swap = None
         with (
-            _tracer.span("scheduler.pack", rows=len(problems))
+            _tracer.span("scheduler.pack", rows=len(problems), kept=0)
             if fleet_ok
             else nullcontext()
         ) as pack:
-            t0 = _time.perf_counter()
-            compiled = [self._compiled(p.placement) for p in problems]
-            took = _time.perf_counter() - t0
-            self.last_breakdown = {"compile": took}
-            if fleet_ok:
+            if (
+                armed
+                # generation AND mask_token moved (a batch with
+                # host-selected rows carries no token: the walk)
+                and not base_stands
+                and self._batch_token is not None
+                and self._fleet is not None
+                and not self._fleet.slots_exhausted
+                and len(problems) >= self.fleet_threshold
+            ):
+                ids, swap = self._swap_diff(problems, pack)
+            if swap is None:
+                t0 = _time.perf_counter()
+                compiled = [self._compiled(p.placement) for p in problems]
+                took = _time.perf_counter() - t0
+                self.last_breakdown = {"compile": took}
+            if fleet_ok and swap is None:
                 _tracer.record(
                     "scheduler.compile", took, start=t0, rows=len(problems)
                 )
@@ -1845,8 +2065,13 @@ class TensorScheduler:
                     "scheduler.eligible", took, start=t0,
                     rows=len(problems), fleet_rows=len(fast_idx),
                 )
-        if fleet_ok:
-            if len(fast_idx) >= self.fleet_threshold:
+        if fleet_ok and (
+            swap is not None or len(fast_idx) >= self.fleet_threshold
+        ):
+            selections = placed = None
+            if swap is not None:
+                fp, fc, select, placed = swap
+            else:
                 from .fleet import FleetTable
 
                 if self._fleet is not None and self._fleet.slots_exhausted:
@@ -1866,7 +2091,7 @@ class TensorScheduler:
                     self._fleet = FleetTable(self)
                 fp = [problems[i] for i in fast_idx]
                 fc = [compiled[i] for i in fast_idx]
-                selections = select = None
+                select = None
                 if selected:
                     # the selected rows' positions in the fleet batch (a
                     # selected row another clause sent to the host path
@@ -1879,57 +2104,65 @@ class TensorScheduler:
                         select = pos[rides]
                     else:
                         selections = (pos[rides], sel_bits[rides])
-                self.solve_batches += 1
-                self._pass_path = "full"
-                # from the prologue's end to the table's door: the two
-                # comprehensions, a rebuild, the selected rows' positions
-                _tracer.record(
-                    "scheduler.handoff", _time.perf_counter() - pack.end,
-                    start=pack.end, rows=len(fast_idx),
-                )
-                fast_res = self._fleet.schedule(
-                    fp, fc, selections=selections, select=select,
-                    host_rows=len(problems) - len(fast_idx),
-                )
-                # from the table's answer to the engine's: the id() sweep
-                # that arms the identity path, or the merge with the host
-                # path's rows (scheduler.host its child)
-                with _tracer.span(
-                    "scheduler.rearm", rows=len(problems),
-                    host_rows=len(problems) - len(fast_idx),
-                ):
-                    self.last_breakdown.update(self._fleet.last_breakdown)
-                    if len(fast_idx) == len(problems):
-                        # all rows rode the fleet: hand back the lazy
-                        # column-oriented result list as-is, and arm the
-                        # batch-identity fast path for the next pass (fp/fc
-                        # are the very list objects the fleet keys its own
-                        # O(1) reuse on)
-                        self._batch_problems = fp
-                        self._batch_ids = np.fromiter(
-                            map(id, fp), np.int64, len(fp)
-                        )
-                        self._batch_gen = self._snapshot_gen
-                        self._batch_cache = (fp, fc)
-                        self._batch_token = (
-                            self.snapshot.mask_token if selections is None
-                            else None
-                        )
-                        return fast_res
-                    results: list = [None] * len(problems)
-                    for i, res in zip(fast_idx, fast_res):
+            host_rows = len(problems) - len(fp)
+            if host_rows:
+                ids = None  # the fleet batch is not the presented one
+            self.solve_batches += 1
+            self._pass_path = "full"
+            kept, visited = self._prologue_tally
+            kept.inc(pack.attrs["kept"])
+            visited.inc(pack.attrs["rows"])
+            # from the prologue's end to the table's door: the two
+            # comprehensions, a rebuild, the selected rows' positions
+            _tracer.record(
+                "scheduler.handoff", _time.perf_counter() - pack.end,
+                start=pack.end, rows=len(fp),
+            )
+            fast_res = self._fleet.schedule(
+                fp, fc, selections=selections, select=select,
+                host_rows=host_rows, ids=ids,
+            )
+            # from the table's answer to the engine's: arming the identity
+            # path (with the pass's sweep; a walk no diff came before makes
+            # its own here), or the merge with the host path's rows
+            # (scheduler.host its child)
+            with _tracer.span(
+                "scheduler.rearm", rows=len(problems), host_rows=host_rows
+            ):
+                self.last_breakdown.update(self._fleet.last_breakdown)
+                if not host_rows:
+                    # all rows rode the fleet: hand back the lazy
+                    # column-oriented result list as-is, and arm the
+                    # batch-identity fast path for the next pass (fp/fc
+                    # are the very list objects the fleet keys its own
+                    # O(1) reuse on)
+                    self._batch_problems = fp
+                    self._batch_ids = (
+                        ids if ids is not None
+                        else np.fromiter(map(id, fp), np.int64, len(fp))
+                    )
+                    self._batch_gen = self._snapshot_gen
+                    self._batch_cache = (fp, fc)
+                    self._batch_token = (
+                        self.snapshot.mask_token if selections is None
+                        else None
+                    )
+                    self._batch_placements = placed
+                    return fast_res
+                results: list = [None] * len(problems)
+                for i, res in zip(fast_idx, fast_res):
+                    results[i] = res
+                slow_idx = [
+                    i for i in range(len(problems)) if results[i] is None
+                ]
+                if slow_idx:
+                    slow_res = self._schedule_host(
+                        [problems[i] for i in slow_idx],
+                        [compiled[i] for i in slow_idx],
+                    )
+                    for i, res in zip(slow_idx, slow_res):
                         results[i] = res
-                    slow_idx = [
-                        i for i in range(len(problems)) if results[i] is None
-                    ]
-                    if slow_idx:
-                        slow_res = self._schedule_host(
-                            [problems[i] for i in slow_idx],
-                            [compiled[i] for i in slow_idx],
-                        )
-                        for i, res in zip(slow_idx, slow_res):
-                            results[i] = res
-                    return results
+                return results
         # no fleet pass: an engine-level feature, or fewer eligible rows
         # than the threshold, keeps the whole batch on the host path
         from ..utils.metrics import fleet_host_path_rows

@@ -1728,10 +1728,13 @@ class FleetTable:
             np.int64, len(rows),
         )
 
-    def _moved_positions(self, problems: Sequence) -> Optional[tuple]:
+    def _moved_positions(
+        self, problems: Sequence, ids: Optional[np.ndarray] = None
+    ) -> Optional[tuple]:
         """The diff of ``problems`` against the resident batch: (positions
         that hold another object than their row does, id() of every
-        position's object), or None where the diff cannot cover the batch:
+        position's object: ``ids`` where the caller swept the batch
+        already), or None where the diff cannot cover the batch:
         no resident batch (none yet, or a compaction or growth since),
         another length, a row twice in the resident batch, or a moved
         position whose key is new to the table or sits at another row. The
@@ -1743,7 +1746,8 @@ class FleetTable:
         old = ru[4] if ru[4] is not None else self._resident_ids(ru[2])
         if old is None:
             return None
-        ids = np.fromiter(map(id, problems), np.int64, n)
+        if ids is None:
+            ids = np.fromiter(map(id, problems), np.int64, n)
         moved = np.flatnonzero(ids != old)
         positions = moved.tolist()
         key_row = self._key_row
@@ -1753,10 +1757,14 @@ class FleetTable:
             return None
         return positions, ids
 
-    def upsert(self, problems: Sequence, compiled: Sequence) -> tuple:
+    def upsert(
+        self, problems: Sequence, compiled: Sequence,
+        ids: Optional[np.ndarray] = None,
+    ) -> tuple:
         """The batch's rows (int32, position by position), its rows brought
         to the batch's content; with them id() of every position's object
-        where the diff took them, else None (what ``_reuse`` keeps).
+        where the diff took them (the caller's ``ids``, its own sweep of
+        ``problems``, or the table's), else None (what ``_reuse`` keeps).
 
         A batch of the resident batch's length is DIFFED against it by
         object identity (_moved_positions) and only the positions that hold
@@ -1769,7 +1777,7 @@ class FleetTable:
         objects are not mutated in place between passes: the identity paths
         here and in the engine rest on that."""
         n = len(problems)
-        diff = self._moved_positions(problems)
+        diff = self._moved_positions(problems, ids)
         if diff is None:
             if self._reuse is not None:
                 # another batch takes the table: the resident one's rows
@@ -2441,7 +2449,7 @@ class FleetTable:
 
     def schedule(
         self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None, select=None, host_rows: int = 0,
+        selections=None, select=None, host_rows: int = 0, ids=None,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -2484,7 +2492,11 @@ class FleetTable:
         decides for all its spread rows), never both.
 
         ``host_rows`` is how many rows of the caller's batch left the fleet
-        for the host path (stamped on the span; 0 on the fast paths)."""
+        for the host path (stamped on the span; 0 on the fast paths).
+
+        ``ids`` (optional) is id() of every position's object (int64[n]),
+        from a caller that swept ``problems`` already (the engine's diffs):
+        the upsert phase diffs by it and makes no sweep of its own."""
         from ..utils.metrics import (
             affinity_term_choices,
             eviction_masked_rows,
@@ -2498,7 +2510,7 @@ class FleetTable:
             self._phase_marks = []
             self._select_mark = self._terms_mark = None
             res = self._schedule_pass(
-                problems, compiled, delta, selections, select
+                problems, compiled, delta, selections, select, ids
             )
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
@@ -2839,7 +2851,7 @@ class FleetTable:
 
     def _schedule_pass(
         self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None, select=None,
+        selections=None, select=None, ids=None,
     ) -> list:
         if delta is not None:
             res = self._schedule_delta(problems, compiled, delta)
@@ -2867,7 +2879,7 @@ class FleetTable:
             else:
                 self._reuse = (problems, compiled, rows_np, select, ru[4])
         else:
-            rows_np, ids = self.upsert(problems, compiled)
+            rows_np, ids = self.upsert(problems, compiled, ids)
             self._reuse = (problems, compiled, rows_np, select, ids)
             self._reuse_pass = self._pass
         if selections is not None:

@@ -499,6 +499,16 @@ fleet_upsert_rows = registry.counter(
     "pinned, not repacked), packed (new to the table or content moved: row "
     "state rewritten and uploaded); added once a pass",
 )
+scheduler_prologue_rows = registry.counter(
+    "karmada_tpu_scheduler_prologue_rows_total",
+    "positions of full-path engine passes by what the host prologue made "
+    "of them: kept (a batch of the armed batch's length under a moved "
+    "mask_token, diffed by object identity: the position holds the armed "
+    "object, so its placement and its fleet eligibility stand and only "
+    "the distinct placements are compiled anew), visited (compiled and "
+    "held to the fleet-eligibility predicate: a moved position of such a "
+    "batch, every position of a batch that is walked); added once a pass",
+)
 fleet_table_rebuilds = registry.counter(
     "karmada_tpu_fleet_table_rebuilds_total",
     "fleet tables dropped and rebuilt because their LIVE rows reference "

@@ -105,15 +105,20 @@ SPAN_NAMES: dict[str, str] = {
     "scheduler.identity": (
         "the batch-identity check: the id() sweep over the batch and, on "
         "a miss, the diff against the armed batch + the dirty keys (rows "
-        "/ hit / moved attrs)"
+        "/ hit / moved attrs); under scheduler.pack where the full path "
+        "makes it (the swap diff: generation and mask_token moved)"
     ),
     "scheduler.pack": (
         "host prologue of a pass: placement compile + spread selection + "
         "eligibility partition (on the delta path: the moved positions' "
-        "compile and eligibility check)"
+        "compile and eligibility check); rows = positions visited, kept = "
+        "positions a swapped batch's diff against the armed one spared it"
     ),
     "scheduler.compile": (
-        "under scheduler.pack: the compiled-placement look-up of every row"
+        "under scheduler.pack: the compiled-placement look-up of every "
+        "position visited (a swap diff: the armed batch's distinct "
+        "placements compiled anew and the take that lists them by "
+        "position; rows / placements attrs)"
     ),
     "scheduler.spread": (
         "under scheduler.pack: which rows are spread-constrained and who "
@@ -122,15 +127,17 @@ SPAN_NAMES: dict[str, str] = {
     ),
     "scheduler.eligible": (
         "under scheduler.pack: the fleet-eligibility partition of the "
-        "batch (rows / fleet_rows attrs)"
+        "batch (a swap diff: the moved positions' look-up and predicate; "
+        "rows = positions visited / fleet_rows attrs)"
     ),
     "scheduler.handoff": (
         "from scheduler.pack's end to the fleet table's door: the fleet "
         "rows' lists, a table rebuild, the selected rows' positions"
     ),
     "scheduler.rearm": (
-        "from the fleet table's answer to the engine's: the id() sweep "
-        "that arms the identity path, or the merge with the host path's "
+        "from the fleet table's answer to the engine's: arming the "
+        "identity path (with the ids of the pass's sweep; a walk no diff "
+        "came before sweeps here), or the merge with the host path's "
         "rows (scheduler.host its child; rows / host_rows attrs)"
     ),
     "scheduler.select": (

@@ -1,0 +1,323 @@
+"""ISSUE 36: a swapped batch costs the engine its swapped rows too. Where a
+batch is armed and the snapshot's generation AND ``mask_token`` moved, the
+full path diffs a batch of the armed batch's length by object identity
+(``TensorScheduler._swap_diff``): one ``id()`` sweep, the armed batch's
+distinct placements compiled anew, the moved positions alone compiled and
+held to the fleet-eligibility predicate.
+
+(a)-(g) equivalence: every row's ``clusters``, ``affinity_name`` and
+    ``error`` against a FRESH engine over the same snapshot and batch; what
+    ``scheduler.pack`` says it visited and kept; the path of the next pass
+    over the same list; no compiled placement of the old token handed on;
+(h) a ring ``L r L r`` with identity passes between, every pass against a
+    fresh engine;
+(i) counting: a swap pass of n rows with k moved calls ``row_rides`` k times
+    and ``_compiled`` O(k + distinct placements) times, and sweeps the batch
+    once, the table's diff included;
+(j) the counter.
+"""
+
+import numpy as np
+import pytest
+
+from karmada_tpu.api.cluster import Taint
+from karmada_tpu.api.policy import SpreadConstraint
+from karmada_tpu.scheduler import (
+    BindingProblem,
+    ClusterSnapshot,
+    TensorScheduler,
+)
+from karmada_tpu.scheduler import core as core_mod
+from karmada_tpu.scheduler import fleet as fleet_mod
+from karmada_tpu.scheduler.fleet import K_EVICT
+from karmada_tpu.scheduler.snapshot import compile_placement
+from karmada_tpu.utils import metrics
+from karmada_tpu.utils.tracing import tracer
+from test_fleet_failover import (
+    NOT_READY,
+    C,
+    _clusters,
+    _copy_out,
+    _place,
+    _placement,
+    _placements,
+    _problem,
+    _same,
+)
+from test_fleet_upsert import _twin
+
+NAMES = [f"m{j:02d}" for j in range(C)]
+N = 400
+SPREAD = (SpreadConstraint(spread_by_field="region", min_groups=1,
+                           max_groups=2),)
+
+
+def _federation(rng) -> tuple:
+    """(healthy, tainted, the lost members' names): region r1 lost."""
+    clusters = _clusters(rng, allocated_share=0.3)
+    healthy = ClusterSnapshot(clusters)
+    lost_at = [j for j in range(C) if _place(j)[0] == "r1"]
+    for j in lost_at:
+        clusters[j].spec.taints = [Taint(key=NOT_READY, effect="NoExecute")]
+    tainted = ClusterSnapshot(clusters)
+    assert healthy.mask_token != tainted.mask_token
+    return healthy, tainted, {NAMES[j] for j in lost_at}
+
+
+def _batch(rng, placements, n=N) -> list:
+    base = [_problem(rng, i, placements[i % len(placements)])
+            for i in range(n)]
+    for p in base:
+        p.evict_clusters = ()
+    return base
+
+
+def _evicted(p, lost, tasks=None):
+    """The binding as the loss presents it: a NEW object, its sites on the
+    lost members turned into eviction tasks."""
+    hit = [n for n in p.prev if n in lost] or sorted(lost)[:1]
+    return _twin(
+        p, prev={n: v for n, v in p.prev.items() if n not in lost},
+        evict_clusters=tuple(hit[:K_EVICT]) if tasks is None else tasks)
+
+
+def _after(base, lost, every=5, **kw) -> list:
+    """The batch after the loss: every ``every``-th position a new object."""
+    return [_evicted(p, lost, **kw) if i % every == 0 else p
+            for i, p in enumerate(base)]
+
+
+def _spans(name: str) -> list:
+    return [s for s in tracer.dump() if s["name"] == name]
+
+
+def _engine(snap) -> TensorScheduler:
+    return TensorScheduler(snap, chunk_size=256, mesh=False)
+
+
+def _same_as_fresh(snap, problems, got) -> None:
+    want = _copy_out(_engine(snap).schedule(problems))
+    assert len(got) == len(want) == len(problems)
+    for i, (a, b) in enumerate(zip(got, want)):
+        _same(a, b, i)
+
+
+def _compiled_under(engine, fc) -> None:
+    """Every compiled placement handed on is the one the engine's cache
+    holds, and the cache was emptied when the token moved; its masks are
+    the placement's under this snapshot."""
+    seen = {}
+    for cp in fc:
+        seen[id(cp)] = cp
+    for cp in seen.values():
+        key = id(cp.placement) if cp.placement is not None else 0
+        assert engine._placement_cache[key][1] is cp
+        now = compile_placement(cp.placement, engine.snapshot)
+        assert np.array_equal(cp.taint_ok, now.taint_ok)
+        for (_, a), (_, b) in zip(cp.terms, now.terms):
+            assert np.array_equal(a, b)
+
+
+def _case(rng, name: str) -> tuple:
+    """(placements' batch before, the batch presented after the token
+    moved, positions visited or None for the walk, rows on the host path)."""
+    healthy, tainted, lost = _federation(rng)
+    placements = _placements(rng, terms=(1, 2, 3))
+    if name in ("spread-rows", "new-placement"):
+        placements += [_placement(rng, s, 1, tol, SPREAD)
+                       for s in ("dynamic", "aggregated", "duplicated")
+                       for tol in (False, True)]
+    base = _batch(rng, placements)
+    if name == "same-list":
+        return healthy, tainted, base, base, 0, 0
+    after = _after(base, lost)
+    moved = sum(1 for a, b in zip(after, base) if a is not b)
+    assert 0 < moved * 2 < N
+    if name == "minority":
+        return healthy, tainted, base, after, moved, 0
+    if name == "spread-rows":
+        spread_at = [i for i, p in enumerate(base)
+                     if p.placement.spread_constraints]
+        assert any(after[i] is base[i] for i in spread_at)
+        assert any(after[i] is not base[i] for i in spread_at)
+        return healthy, tainted, base, after, moved, 0
+    if name == "new-placement":
+        # a moved row names a placement the armed batch did not
+        joined = _placement(rng, "dynamic", 1, True, SPREAD)
+        at = next(i for i, p in enumerate(after) if p is not base[i])
+        after[at] = _twin(after[at], placement=joined)
+        return healthy, tainted, base, after, moved, 0
+    if name == "past-k-evict":
+        at = next(i for i, p in enumerate(after)
+                  if p is not base[i] and p.replicas > 0)
+        after[at] = _twin(after[at],
+                          evict_clusters=tuple(NAMES[: K_EVICT + 1]))
+        return healthy, tainted, base, after, None, 1
+    if name == "all-moved":
+        return healthy, tainted, base, _after(base, lost, every=1), None, 0
+    assert name == "another-length"
+    return healthy, tainted, base, after[:-7], None, 0
+
+
+CASES = ("same-list", "minority", "spread-rows", "new-placement",
+         "past-k-evict", "all-moved", "another-length")
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_a_swapped_batch_answers_as_a_fresh_engine(name):
+    rng = np.random.default_rng(36 + CASES.index(name))
+    healthy, tainted, base, after, visited, host_rows = _case(rng, name)
+    engine = _engine(healthy)
+    engine.schedule(base)
+    old_fc = list(engine._batch_cache[1])
+    assert engine.update_snapshot(tainted)
+    tracer.clear()
+    got = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    assert root["attrs"]["path"] == "full"
+    (pack,) = _spans("scheduler.pack")
+    (ident,) = _spans("scheduler.identity") if len(after) == N else (None,)
+    (solve,) = _spans("scheduler.solve")
+    (rearm,) = _spans("scheduler.rearm")
+    _same_as_fresh(tainted, after, got)
+    n = len(after)
+    if visited is None:
+        assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (n, 0)
+    else:
+        assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (
+            visited, n - visited)
+    if ident is not None:
+        # one sweep, inside pack, whichever way the pass then went
+        assert ident["parent_id"] == pack["span_id"]
+        assert (ident["attrs"]["rows"], ident["attrs"]["hit"]) == (n, 0)
+    assert solve["attrs"]["host_rows"] == host_rows
+    assert rearm["attrs"]["host_rows"] == host_rows
+    assert "eligible" in engine.last_breakdown
+    tracer.clear()
+    again = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    if host_rows:
+        # a host row arms nothing: the next pass walks again
+        assert root["attrs"]["path"] == "full"
+    else:
+        assert root["attrs"]["path"] == "identity"
+        fp, fc = engine._batch_cache
+        assert all(a is b for a, b in zip(fp, after))
+        assert not {id(cp) for cp in fc} & {id(cp) for cp in old_fc}
+        _compiled_under(engine, fc)
+    for i, (a, b) in enumerate(zip(again, got)):
+        _same(a, b, i)
+
+
+@pytest.mark.parametrize("seed", [3, 11])
+def test_a_ring_of_losses_and_returns(seed):
+    """``L r L r``, identity passes between; the second ``L`` loses the
+    region under ANOTHER batch, so the index by placement is corrected
+    twice and built once."""
+    rng = np.random.default_rng(seed)
+    healthy, tainted, lost = _federation(rng)
+    placements = _placements(rng, terms=(1, 2)) + [
+        _placement(rng, "dynamic", 1, True, SPREAD)]
+    base = _batch(rng, placements)
+    first, second = _after(base, lost, every=4), _after(base, lost, every=3)
+    engine = _engine(healthy)
+    engine.schedule(base)
+    built = []
+    for snap, problems in ((tainted, first), (healthy, base),
+                           (tainted, second), (healthy, base)):
+        assert engine.update_snapshot(snap)
+        tracer.clear()
+        got = _copy_out(engine.schedule(problems))
+        (pack,) = _spans("scheduler.pack")
+        assert 0 < pack["attrs"]["rows"] < N // 2
+        assert pack["attrs"]["rows"] + pack["attrs"]["kept"] == N
+        _same_as_fresh(snap, problems, got)
+        built.append(engine._batch_placements)
+        tracer.clear()
+        engine.schedule(problems)
+        (root,) = _spans("scheduler.schedule")
+        assert root["attrs"]["path"] == "identity"
+    # the batch by placement: one list of placements throughout, the index
+    # a new array each pass (the armed one is never written)
+    assert all(b is not None and b[0] is built[0][0] for b in built)
+    assert len({id(b[2]) for b in built}) == 4
+    for (_, _, index), problems in zip(built[-2:], (second, base)):
+        assert [built[0][0][j] for j in index.tolist()] == [
+            p.placement for p in problems]
+
+
+def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
+    rng = np.random.default_rng(53)
+    healthy, tainted, lost = _federation(rng)
+    placements = _placements(rng, terms=(1, 2, 3))
+    n = 2000
+    base = _batch(rng, placements, n)
+    after = _after(base, lost, every=10)
+    k = sum(1 for a, b in zip(after, base) if a is not b)
+    engine = _engine(healthy)
+    engine.schedule(base)
+    # one turn of the ring first: the table's first diff after a walk reads
+    # the ids of the objects its rows hold (a sweep of its own, once)
+    for snap, problems in ((tainted, after), (healthy, base)):
+        assert engine.update_snapshot(snap)
+        engine.schedule(problems)
+    slots = len(engine._fleet._cp_pl)
+    calls = {"row_rides": 0, "compiled": 0, "swept": 0}
+    rides, compiled = fleet_mod.row_rides, engine._compiled
+
+    def counted_rides(p, cp):
+        calls["row_rides"] += 1
+        return rides(p, cp)
+
+    def counted_compiled(placement):
+        calls["compiled"] += 1
+        return compiled(placement)
+
+    def counted_id(obj):
+        calls["swept"] += isinstance(obj, BindingProblem)
+        return id(obj)
+
+    monkeypatch.setattr(fleet_mod, "row_rides", counted_rides)
+    monkeypatch.setattr(engine, "_compiled", counted_compiled)
+    monkeypatch.setattr(core_mod, "id", counted_id, raising=False)
+    monkeypatch.setattr(fleet_mod, "id", counted_id, raising=False)
+    assert engine.update_snapshot(tainted)
+    tracer.clear()
+    got = _copy_out(engine.schedule(after))
+    (pack,) = _spans("scheduler.pack")
+    assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (k, n - k)
+    assert calls["row_rides"] == k
+    # the moved positions, the armed batch's distinct placements, and the
+    # table's own recompile of its slots
+    assert calls["compiled"] <= k + len(placements) + slots
+    assert calls["swept"] == n  # one sweep: none in the table, none to re-arm
+    (solve,) = _spans("scheduler.solve")
+    assert solve["attrs"]["rows_visited"] == k
+    monkeypatch.undo()
+    _same_as_fresh(tainted, after, got)
+
+
+def test_the_counter_tells_kept_from_visited():
+    rng = np.random.default_rng(59)
+    healthy, tainted, lost = _federation(rng)
+    base = _batch(rng, _placements(rng, terms=(1, 2)))
+    after = _after(base, lost)
+    k = sum(1 for a, b in zip(after, base) if a is not b)
+    engine = _engine(healthy)
+
+    def tally():
+        return {o: metrics.scheduler_prologue_rows.value(outcome=o)
+                for o in ("kept", "visited")}
+
+    before = tally()
+    engine.schedule(base)  # the walk
+    walked = tally()
+    assert {o: walked[o] - before[o] for o in walked} == {
+        "kept": 0, "visited": N}
+    engine.schedule(base)  # identity: no prologue
+    assert tally() == walked
+    assert engine.update_snapshot(tainted)
+    engine.schedule(after)
+    swapped = tally()
+    assert {o: swapped[o] - walked[o] for o in walked} == {
+        "kept": N - k, "visited": k}

@@ -17,6 +17,11 @@ driven through each path (identity, delta, full, host): one
 ``scheduler.schedule`` root with the ``path`` it took, its children inside
 it, disjoint and ordered, the identity check and the full prologue's stages
 as spans at the intervals ``last_breakdown`` times.
+
+ISSUE 36: under a moved ``mask_token`` the full path diffs a batch of the
+armed batch's length: the sweep is a ``scheduler.identity`` child of
+``scheduler.pack``, the stages' ``rows`` are the positions visited, and a
+pass sweeps the batch once (``scheduler.rearm`` makes no sweep).
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ from karmada_tpu.utils.builders import (
     new_deployment,
     synthetic_fleet,
 )
+from karmada_tpu.scheduler import core as core_mod
 from karmada_tpu.scheduler import fleet as fleet_mod
 from karmada_tpu.scheduler import select as select_mod
 from karmada_tpu.utils.tracing import (
@@ -182,6 +188,10 @@ ENGINE_SPANS = (
 )
 STAGES = {"scheduler.compile": "compile", "scheduler.spread": "select",
           "scheduler.eligible": "eligible"}
+#: pack's children where the batch was diffed (the swap diff): the sweep,
+#: the distinct placements' compile, the moved positions, the spread rows
+SWAP_STAGES = ["scheduler.identity", "scheduler.compile",
+               "scheduler.eligible", "scheduler.spread"]
 
 
 def _ring() -> list:
@@ -243,6 +253,25 @@ def _drive_full_token(eng, problems):
     return problems, wave
 
 
+def _drive_full_swapped(eng, problems):
+    """A moved token and a new list in which 30 positions hold new objects
+    of equal content: the swap diff visits those."""
+    eng.schedule(problems)
+    snap = _moved_token(eng)
+    batch = list(problems)
+    for i in range(0, 30 * 7, 7):
+        p = batch[i]
+        batch[i] = BindingProblem(
+            key=p.key, placement=p.placement, replicas=p.replicas,
+            requests=dict(p.requests), gvk=p.gvk, prev=dict(p.prev),
+            fresh=p.fresh)
+
+    def wave():
+        assert eng.update_snapshot(snap)
+        return eng.schedule(batch)
+    return batch, wave
+
+
 def _drive_full_host_row(eng, problems):
     eng.schedule(problems)
     batch = list(problems)
@@ -262,8 +291,14 @@ PATHS = {
                  ["scheduler.identity", "scheduler.solve"]),
     "delta": (_drive_delta, "delta",
               ["scheduler.identity", "scheduler.pack", "scheduler.solve"]),
+    # the same list under a moved token, and a swapped one: the sweep and
+    # the diff lie INSIDE pack
     "full-moved-token": (
         _drive_full_token, "full",
+        ["scheduler.pack", "scheduler.handoff", "scheduler.solve",
+         "scheduler.rearm"]),
+    "full-swapped": (
+        _drive_full_swapped, "full",
         ["scheduler.pack", "scheduler.handoff", "scheduler.solve",
          "scheduler.rearm"]),
     # the id() sweep and the delta's check of the one moved row come first:
@@ -313,10 +348,18 @@ class TestEngineWave:
             tried, _ = [s for s in kids if s.name == "scheduler.pack"]
             assert tried.attrs["rows"] == 1  # the position it visited
         else:
-            assert not ident  # a moved token: no sweep is made
+            # a moved token: the full path's own sweep and diff, in pack
+            [sp] = ident
+            [pack] = [s for s in kids if s.name == "scheduler.pack"]
+            assert sp.parent_id == pack.span_id
+            moved = 30 if case == "full-swapped" else 0
+            assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, moved)
+            assert (pack.attrs["rows"], pack.attrs["kept"]) == (
+                moved, len(batch) - moved)
         assert all(s.attrs["rows"] == len(batch) for s in ident)
 
-    @pytest.mark.parametrize("case", ["full-moved-token", "full-host-row"])
+    @pytest.mark.parametrize(
+        "case", ["full-moved-token", "full-swapped", "full-host-row"])
     def test_the_full_prologue_is_staged_under_pack(self, engine, case):
         eng, problems = engine
         drive, _, _ = PATHS[case]
@@ -325,20 +368,34 @@ class TestEngineWave:
         wave()
         spans = _ring()
         pack = [s for s in spans if s.name == "scheduler.pack"][-1]
-        assert pack.attrs["rows"] == len(batch)
+        host_rows = 1 if case == "full-host-row" else 0
+        # the positions the prologue visited: every one where it walked,
+        # those holding another object where it diffed the batch
+        visited = {"full-moved-token": 0, "full-swapped": 30,
+                   "full-host-row": len(batch)}[case]
+        assert (pack.attrs["rows"], pack.attrs["kept"]) == (
+            visited, len(batch) - visited)
         stages = _children(spans, pack)
-        assert [s.name for s in stages] == list(STAGES)
         _inside_disjoint_ordered(pack, stages)
+        if host_rows:
+            assert [s.name for s in stages] == list(STAGES)
+            compile_, spread, eligible = stages
+        else:
+            assert [s.name for s in stages] == SWAP_STAGES
+            ident, compile_, eligible, spread = stages
+            assert (ident.attrs["rows"], ident.attrs["moved"]) == (
+                len(batch), visited)
+            assert compile_.attrs["placements"] == len(
+                {id(p.placement) for p in batch})
         bd = eng.last_breakdown
         for sp in stages:
-            assert sp.duration == pytest.approx(
-                bd[STAGES[sp.name]], abs=1e-9), sp.name
-        compile_, spread, eligible = stages
-        host_rows = 1 if case == "full-host-row" else 0
-        assert compile_.attrs["rows"] == len(batch)
+            if sp.name in STAGES:
+                assert sp.duration == pytest.approx(
+                    bd[STAGES[sp.name]], abs=1e-9), sp.name
+        assert compile_.attrs["rows"] == visited
         assert (spread.attrs["rows"], spread.attrs["on_device"]) == (0, 0)
         assert (eligible.attrs["rows"], eligible.attrs["fleet_rows"]) == (
-            len(batch), len(batch) - host_rows)
+            visited, len(batch) - host_rows)
         # hand-off from pack's end to the table's door, re-arm from its
         # answer to the engine's
         [handoff] = [s for s in spans if s.name == "scheduler.handoff"]
@@ -361,6 +418,25 @@ class TestEngineWave:
             eng.schedule(batch)
             [root] = [s for s in _ring() if s.name == "scheduler.schedule"]
             assert root.attrs["path"] == "identity"
+
+    @pytest.mark.parametrize("case", sorted(PATHS))
+    def test_a_pass_sweeps_its_batch_once(self, engine, case, monkeypatch):
+        """Whichever route a pass takes, the engine's ``id()`` reads each
+        position once: the diff that swept the batch keeps its ids for the
+        re-arm (a walk no diff came before makes the one sweep there). That
+        the table diffs by the same ids: test_engine_swap.py."""
+        eng, problems = engine
+        drive, _, _ = PATHS[case]
+        batch, wave = drive(eng, problems)
+        swept = [0]
+
+        def counted(obj):
+            swept[0] += isinstance(obj, BindingProblem)
+            return id(obj)
+
+        monkeypatch.setattr(core_mod, "id", counted, raising=False)
+        wave()
+        assert swept[0] == len(batch)
 
     def test_a_host_selection_is_the_spread_stages_child(self):
         regions = [f"r{k}" for k in range(select_mod.R_CAP + 1)]
@@ -428,8 +504,11 @@ class TestEngineWave:
     def test_the_benchmarks_readers_read_the_engines_own_spans(self, engine):
         """An ``h h L h`` ring in small: what the program stamps is what the
         four readers take, wave for wave."""
+        import statistics
+
         from benchmark.metrics import (
             identity_check_s,
+            swap_prologue_rows,
             swap_prologue_s,
             swap_wave_s,
             wave_unspanned_s,
@@ -459,9 +538,14 @@ class TestEngineWave:
                       "scheduler.rearm"))
         assert 0 < swap_prologue_s.read(ctx) == pytest.approx(own)
         assert swap_prologue_s.read(ctx) < swap_wave_s.read(ctx)
-        ident = sorted(s["duration_s"] for s in spans
-                       if s["name"] == "scheduler.identity")
-        assert len(ident) == 3 and identity_check_s.read(ctx) == ident[1]
+        # the same list came again: the diff kept every position
+        assert swap_prologue_rows.read(ctx) == 0
+        # one sweep a wave, the swap wave's (inside pack) among them
+        ident = [s["duration_s"] for s in spans
+                 if s["name"] == "scheduler.identity"]
+        assert len(ident) == 4
+        assert identity_check_s.read(ctx) == pytest.approx(
+            statistics.median(ident))
         # the root covers the pass: what is left of a wave is the loop's own
         dark = wave_unspanned_s.read(ctx)
         assert 0 <= dark <= max(
