@@ -2342,7 +2342,11 @@ def run_quota(args) -> dict:
         WorkloadRebalancerSpec,
     )
     from karmada_tpu.refimpl.divider_np import assign_batch_np
-    from karmada_tpu.refimpl.quota_np import admit_wave_np, cluster_caps_seq
+    from karmada_tpu.refimpl.quota_np import (
+        admit_wave_np,
+        asking_ns_ids,
+        cluster_caps_seq,
+    )
     from karmada_tpu.scheduler.quota import QUOTA_EXCEEDED_ERROR
     from karmada_tpu.scheduler.snapshot import compile_placement
     from karmada_tpu.utils.builders import (
@@ -2611,7 +2615,9 @@ def run_quota(args) -> dict:
             delta = p.replicas - sum(p.prev.values())
             if delta > 0:
                 demand[row_i] = req_vec * delta
-        want_admit, _used = admit_wave_np(ns_ids, demand, remaining)
+        want_admit, _used = admit_wave_np(
+            asking_ns_ids(ns_ids, demand), demand, remaining
+        )
         got_admit = [r.error != QUOTA_EXCEEDED_ERROR for r in results]
         adm_checked += len(problems)
         adm_mismatch += sum(

@@ -590,7 +590,11 @@ def _quota_wave(b: int, c: int, n_ns: int = 32) -> tuple:
         StaticClusterAssignment,
     )
     from karmada_tpu.refimpl.divider_np import assign_batch_np
-    from karmada_tpu.refimpl.quota_np import admit_wave_np, cluster_caps_seq
+    from karmada_tpu.refimpl.quota_np import (
+        admit_wave_np,
+        asking_ns_ids,
+        cluster_caps_seq,
+    )
     from karmada_tpu.scheduler import (
         QUOTA_EXCEEDED_ERROR,
         BindingProblem,
@@ -658,7 +662,9 @@ def _quota_wave(b: int, c: int, n_ns: int = 32) -> tuple:
         delta = p.replicas - sum(p.prev.values())
         if delta > 0:
             demand[i] = req_vec * delta
-    want_admit, _ = admit_wave_np(ns_ids, demand, remaining)
+    want_admit, _ = admit_wave_np(
+        asking_ns_ids(ns_ids, demand), demand, remaining
+    )
     got_admit = [r.error != QUOTA_EXCEEDED_ERROR for r in results]
     adm_bad = sum(1 for w_, g in zip(want_admit, got_admit) if w_ != g)
     denied = got_admit.count(False)

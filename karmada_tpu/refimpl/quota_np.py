@@ -19,8 +19,10 @@ availability ceiling, derived with none of the kernel's vectorization.
 
 ``admit_and_place`` composes admission with the per-binding numpy divider
 (refimpl.divider_np) so a whole quota-capped scheduling wave can be
-verified end to end: admitted bindings divide against cap-folded
-availability; denied bindings keep their previous placement untouched.
+verified end to end: a binding that asks for nothing is admitted whatever
+its namespace has left (``asking_ns_ids``); admitted bindings divide
+against cap-folded availability; denied bindings keep their previous
+placement untouched.
 """
 
 from __future__ import annotations
@@ -64,6 +66,20 @@ def admit_wave_np(
                 used[ns, d] += int(demand[i, d])
         admitted.append(ok)
     return admitted, used
+
+
+def asking_ns_ids(ns_ids: Sequence[int], demand: np.ndarray) -> list[int]:
+    """The namespace ids a wave's admission takes: a binding that asks
+    for nothing (its delta is not positive, so its demand is zero on every
+    dimension: it holds what it wants, or scales down) is not the quota's
+    to deny, as upstream's enforcement lets such a delta through. It goes
+    in as a binding without a quota (-1), whatever its namespace has left,
+    and takes no place in its namespace's line. The engine applies the
+    same rule to ``quota_admit``'s inputs on both of its routes."""
+    return [
+        int(ns) if any(int(v) for v in row) else -1
+        for ns, row in zip(ns_ids, np.asarray(demand))
+    ]
 
 
 def cluster_caps_seq(
@@ -114,7 +130,9 @@ def admit_and_place(
     min-folded with its static-assignment cap row. Denied bindings keep
     their previous placement. Returns (admitted by key, placements by
     key)."""
-    flags, _used = admit_wave_np(ns_ids, demand, remaining)
+    flags, _used = admit_wave_np(
+        asking_ns_ids(ns_ids, demand), demand, remaining
+    )
     col = {nm: i for i, nm in enumerate(names)}
     out: dict[str, dict[str, int]] = {}
     admitted_by_key: dict[str, bool] = {}

@@ -325,6 +325,11 @@ class TensorScheduler:
             scheduler_prologue_rows.labels(outcome=o)
             for o in ("kept", "visited")
         )
+        from .quota import AdmissionTally
+
+        # what admission made of each pass's rows, by either route (the
+        # fleet table adds the resident route's)
+        self._quota_tally = AdmissionTally()
         # which branch the pass's solve took (identity | delta | full |
         # host): the scheduler.schedule span's path attr
         self._pass_path = "host"
@@ -358,15 +363,19 @@ class TensorScheduler:
         # request-profile bytes -> availability row [C] (per snapshot gen)
         self._sel_profile_rows: dict = {}
         self._sel_profile_gen = -1
-        # quota plane (scheduler.quota.QuotaSnapshot | None): admission
-        # runs as ONE batched kernel pass before the solve; static-
-        # assignment caps fold into availability as one more estimator.
-        # Disarmed = a single `is None` check per schedule() call.
+        # quota plane (scheduler.quota.QuotaSnapshot | None): a batch that
+        # rides the fleet table whole is admitted from the table's row
+        # state (a denial is a bit beside the row's answer); any other is
+        # partitioned by ONE batched kernel pass before the solve
+        # (_schedule_quota); static-assignment caps fold into availability
+        # as one more estimator. Disarmed = a single `is None` check per
+        # schedule() call.
         self.quota = None
-        # (problem ids, quota generation, admitted sub-list, denied
-        # results) of the last wave with denials: keeps the admitted
-        # sub-list IDENTITY-stable across steady storm passes so the
-        # batch-identity fast paths below still fire under enforcement
+        # the partition route's cache: (problem ids, quota generation,
+        # admitted sub-list, denied results) of the last wave with
+        # denials: keeps the admitted sub-list IDENTITY-stable across
+        # steady storm passes so the batch-identity fast paths below still
+        # fire under enforcement
         self._quota_cache: Optional[tuple] = None
         # device mirror of the static-assignment cap tensor, keyed by the
         # quota snapshot's cap_token (caps change rarely; remaining often)
@@ -500,7 +509,9 @@ class TensorScheduler:
         interned profile slots. A generation-only bump (remaining moved —
         the common case: usage recompute, quota raise) keeps every packed
         row and trace; only the admission partition recomputes — a denied
-        binding clears on a quota raise without a full re-pack."""
+        binding clears on a quota raise without a full re-pack. Another
+        namespace SET is the fleet table's to notice (FleetTable._sync_ns
+        re-derives its rows' namespace column at the next pass)."""
         old = self.quota
         self.quota = quota
         # a quota with NO static assignments bakes nothing into the fleet
@@ -664,14 +675,22 @@ class TensorScheduler:
 
     def _quota_admission(self, problems):
         """One batched admission pass over the wave. Returns
-        ``(partition, pending_debit)``: partition is None when no binding
-        is quota'd or every row admitted, else (admitted sub-list, denied
-        results as (index, ScheduleResult) pairs) — identity-stable
-        across steady passes via _quota_cache so the batch-identity fast
-        paths keep firing under enforcement. ``pending_debit`` is the
-        wave's admitted demand per namespace, to be committed by the
-        caller AFTER the solve (None on cache replay — already
-        committed)."""
+        ``(partition, pending_debit, quota_rows, dispatched)``: partition
+        is None when no binding is quota'd or every row admitted, else
+        (admitted sub-list, denied results as (index, ScheduleResult)
+        pairs) — identity-stable across steady passes via _quota_cache so
+        the batch-identity fast paths keep firing under enforcement.
+        ``pending_debit`` is the wave's admitted demand per namespace, to
+        be committed by the caller AFTER the solve (None on cache replay —
+        already committed). ``quota_rows`` counts the wave's rows in a
+        quota'd namespace, ``dispatched`` says whether the admission
+        kernel ran (False: a replay, or nothing to admit).
+
+        A binding that asks for nothing (its delta is not positive: it
+        holds what it wants, or scales down) is not the quota's to deny,
+        as upstream's enforcement lets such a delta through: it goes to
+        the kernel as a row without a quota, whatever its namespace has
+        left, and takes no place in its namespace's line."""
         from ..ops.quota import quota_admit
         from .quota import QUOTA_EXCEEDED_ERROR
 
@@ -681,8 +700,9 @@ class TensorScheduler:
         ns_ids = np.fromiter(
             (ns_index.get(p.namespace, -1) for p in problems), np.int32, b
         )
-        if not (ns_ids >= 0).any():
-            return None, None
+        quota_rows = int((ns_ids >= 0).sum())
+        if not quota_rows:
+            return None, None, 0, False
         cache = self._quota_cache
         ids = np.fromiter(map(id, problems), np.int64, b)
         if (
@@ -692,17 +712,19 @@ class TensorScheduler:
             and np.array_equal(cache[0], ids)
         ):
             if cache[2] is None:  # cached all-admitted wave
-                return None, None
-            return (cache[2], cache[3]), None
+                return None, None, quota_rows, False
+            return (cache[2], cache[3]), None, quota_rows, False
         out = self._quota_admission_delta(problems, ids, ns_ids, cache)
         if out is not None:
-            return out
+            part, debit, dispatched = out
+            return part, debit, quota_rows, dispatched
         demand = np.zeros((b, len(q.dims)), np.int64)
         for i in np.flatnonzero(ns_ids >= 0):
             p = problems[i]
             delta = p.replicas - sum(p.prev.values())
             if delta > 0:
                 demand[i] = q.demand_row(p.requests, delta)
+        ns_ids[~demand.any(axis=1)] = -1  # asks nothing: not the quota's
         # pow2 row padding bounds the admission kernel's trace count;
         # pad rows are unquota'd zero-demand and always admit
         b_pad = 1 << max(0, (b - 1).bit_length())
@@ -764,7 +786,7 @@ class TensorScheduler:
                 ids, q.generation, None, None, np.zeros(0, np.int64),
                 list(problems),
             )
-            return None, debit
+            return None, debit, quota_rows, True
         denied_idx = np.flatnonzero(~admitted)
         denied = [
             (
@@ -793,7 +815,7 @@ class TensorScheduler:
         self._quota_cache = (
             ids, q.generation, sub, denied, denied_idx, list(problems)
         )
-        return (sub, denied), debit
+        return (sub, denied), debit, quota_rows, True
 
     def _quota_admission_delta(self, problems, ids, ns_ids, cache):
         """Delta admission (ISSUE 20): a wave whose ids moved in a
@@ -809,8 +831,9 @@ class TensorScheduler:
         prior admission stays charged. The returned debit covers ONLY
         the changed rows' delta demand — replayed rows are never
         re-charged (the PR 14 working-remaining restore contract,
-        extended to the delta path). Returns (partition, debit), or None
-        when ineligible (the caller runs the full admission)."""
+        extended to the delta path). Returns (partition, debit, whether
+        the kernel was dispatched), or None when ineligible (the caller
+        runs the full admission)."""
         from ..ops.quota import quota_admit
         from .quota import QUOTA_EXCEEDED_ERROR
 
@@ -836,7 +859,9 @@ class TensorScheduler:
             if delta > 0:
                 demand[j] = q.demand_row(p.requests, delta)
         old_denied = cache[4]
-        if demand.any():
+        dispatched = bool(demand.any())
+        if dispatched:
+            ns_ch[~demand.any(axis=1)] = -1  # asks nothing: not the quota's
             b_pad = 1 << max(0, (m - 1).bit_length())
             ns_pad, dem_pad = ns_ch, demand
             if b_pad > m:
@@ -885,7 +910,7 @@ class TensorScheduler:
                 ids.copy(), q.generation, None, None,
                 np.zeros(0, np.int64), list(problems),
             )
-            return (None, debit)
+            return None, debit, dispatched
         denied = [
             (
                 int(i),
@@ -917,7 +942,7 @@ class TensorScheduler:
             ids.copy(), q.generation, sub, denied, new_denied,
             list(problems),
         )
-        return ((sub, denied), debit)
+        return (sub, denied), debit, dispatched
 
     @property
     def cap_shrink_pending(self) -> bool:
@@ -1048,48 +1073,104 @@ class TensorScheduler:
     def _schedule_quota(
         self, problems: Sequence[BindingProblem]
     ) -> list[ScheduleResult]:
-        """Quota admission wrapper around the solve: when a QuotaSnapshot
-        is set and the wave touches quota'd namespaces, ONE batched
-        admission kernel partitions the wave; denied bindings answer a
-        QuotaExceeded result without being solved, admitted ones ride the
-        unchanged batched paths below. Disarmed quota costs one `is None`
-        check."""
+        """Quota admission around the solve, by one of two routes chosen
+        from what the pass observes (no option selects one). Disarmed
+        quota costs one `is None` check.
+
+        - resident: the batch rides the fleet table WHOLE. The solve takes
+          the presented batch, all of it, with the QuotaSnapshot; the
+          table admits it from its row state in one kernel
+          (FleetTable._admit_on_device) and the result list answers a
+          denied row QuotaExceeded from the verdict's bit. A denial is a
+          bit beside the row's answer, not the row's absence: the batch
+          keeps its length and its list, so a quota generation that moves
+          under an unmoved mask_token is an identity pass.
+        - partition: a batch with rows off the table (row_rides false, a
+          placement the table does not hold, an engine-level feature), a
+          batch under the fleet threshold, one of more than
+          MAX_ADMIT_ROWS rows, a quota packed over other dims than the
+          snapshot's: ONE batched admission kernel over demands the host
+          derives row by row partitions the wave (_quota_admission);
+          denied bindings answer without being solved, the admitted
+          sub-list rides the batched paths. The FIFO prefix is over the
+          presented order either way. A batch that turns out to hold
+          host rows only inside the prologue pays that prologue twice.
+
+        The wave's budget debit COMMITS only after the solve returned: a
+        pass that dies mid-solve (poisoned key, backend error: the worker
+        bisects and retries) must not leave its demand charged, or the
+        retry re-admits against an already-debited remaining and
+        spuriously denies bindings that fit. A failed solve also drops
+        both routes' cached verdicts."""
         q = self.quota
-        if q is not None and q.active:
-            part, debit = self._quota_admission(problems)
-            if part is not None:
-                sub, denied = part
-                try:
-                    sub_res = self._schedule_inner(sub)
-                except BaseException:
-                    # a failed solve charges nothing AND drops the armed
-                    # partition cache: the retry (same or rebuilt problem
-                    # objects) re-admits against the uncharged remaining
+        if q is None or not q.active:
+            return self._schedule_inner(problems)
+        try:
+            if self._quota_rides_table(problems, q):
+                res = self._schedule_inner(problems, quota=q)
+                if res is not None:
+                    fleet = self._fleet
+                    debit, fleet.quota_debit = fleet.quota_debit, None
+                    self._apply_quota_debit(debit)
                     self._quota_cache = None
-                    raise
-                # the wave's budget debit COMMITS only after the solve
-                # returned: a pass that dies mid-solve (poisoned key,
-                # backend error — the worker bisects and retries) must
-                # not leave its demand charged, or the retry re-admits
-                # against an already-debited remaining and spuriously
-                # denies bindings that fit
-                self._apply_quota_debit(debit)
-                results: list = [None] * len(problems)
-                for i, res in denied:
-                    results[i] = res
-                it = iter(sub_res)
-                for i in range(len(problems)):
-                    if results[i] is None:
-                        results[i] = next(it)
-                return results
-            try:
-                res = self._schedule_inner(problems)
-            except BaseException:
-                self._quota_cache = None
-                raise
+                    return res
+            return self._schedule_partitioned(problems, q)
+        except BaseException:
+            self._quota_cache = None
+            if self._fleet is not None:
+                self._fleet._quota_verdict = None
+            raise
+
+    def _quota_rides_table(self, problems, q) -> bool:
+        """What can be told before the prologue of whether the fleet table
+        can admit this batch from its row state: the batch is one the
+        fleet takes, one admission holds its rows, and the quota's demand
+        dims are the profile slots' (the snapshot's)."""
+        from ..ops.quota import MAX_ADMIT_ROWS
+
+        return (
+            self.fleet_threshold <= len(problems) <= MAX_ADMIT_ROWS
+            and not self.custom_filters
+            and not self.disabled_plugins
+            and not self._host_only_estimators()
+            and list(q.dims) == list(self.snapshot.dims)
+        )
+
+    def _schedule_partitioned(self, problems, q) -> list[ScheduleResult]:
+        """The partition route of _schedule_quota: host admission, the
+        solve of the admitted sub-list, the merge."""
+        from ..utils.tracing import tracer as _tracer
+
+        n = len(problems)
+        with _tracer.span(
+            "scheduler.quota", rows=n, host_rows=n, generation=q.generation
+        ) as sp:
+            part, debit, quota_rows, dispatched = self._quota_admission(
+                problems
+            )
+            denied_n = len(part[1]) if part is not None else 0
+            sp.attrs.update(
+                quota_rows=quota_rows, denied=denied_n,
+                dispatched=int(dispatched),
+            )
+        self._quota_tally.add(
+            "partition" if dispatched else "replayed", n, quota_rows, denied_n
+        )
+        if part is None:
+            res = self._schedule_inner(problems)
             self._apply_quota_debit(debit)
             return res
-        return self._schedule_inner(problems)
+        sub, denied = part
+        sub_res = self._schedule_inner(sub)
+        self._apply_quota_debit(debit)
+        results: list = [None] * n
+        for i, res in denied:
+            results[i] = res
+        it = iter(sub_res)
+        for i in range(n):
+            if results[i] is None:
+                results[i] = next(it)
+        return results
 
     def _apply_quota_debit(self, debit) -> None:
         """Commit one admitted wave's demand against the working
@@ -1677,7 +1758,7 @@ class TensorScheduler:
 
         return os.environ.get("KARMADA_TPU_DELTA_SOLVE", "1") != "0"
 
-    def _delta_pass(self, problems, ids, t0):
+    def _delta_pass(self, problems, ids, t0, quota=None):
         """Batch-identity DELTA path (ISSUE 20): the wave has the shape
         of the armed batch but a minority of positions hold new problem
         objects (and/or the caller marked keys dirty). Compiles just the
@@ -1737,7 +1818,7 @@ class TensorScheduler:
         self.last_breakdown = {"compile": _time.perf_counter() - t0}
         self._pass_path = "delta"
         self.solve_batches += 1
-        res = self._fleet.schedule(fp2, fc2, delta=diff)
+        res = self._fleet.schedule(fp2, fc2, delta=diff, quota=quota)
         self.last_breakdown.update(self._fleet.last_breakdown)
         # re-arm the identity token on the swapped lists (gen and mask
         # token are unchanged by construction; swapped-in rows are never
@@ -1901,8 +1982,15 @@ class TensorScheduler:
         return ids, (fp, fc, select, built)
 
     def _schedule_inner(
-        self, problems: Sequence[BindingProblem]
-    ) -> list[ScheduleResult]:
+        self, problems: Sequence[BindingProblem], quota=None
+    ) -> Optional[list[ScheduleResult]]:
+        """The solve of a batch by one of the routes schedule() names.
+
+        ``quota`` (the resident route of _schedule_quota) is the
+        QuotaSnapshot the fleet table admits the batch against: it rides
+        with every fleet pass below, and where the batch does not ride the
+        table whole (rows on the host path, no fleet pass) nothing is
+        solved and None returned: the caller partitions the batch."""
         import time as _time
 
         from ..utils.tracing import tracer as _tracer
@@ -1958,14 +2046,14 @@ class TensorScheduler:
                 self._pass_path = "identity"
                 fp, fc = self._batch_cache
                 self.solve_batches += 1
-                res = self._fleet.schedule(fp, fc)
+                res = self._fleet.schedule(fp, fc, quota=quota)
                 self.last_breakdown.update(self._fleet.last_breakdown)
                 return res
             # not the identical batch: a minority of moved positions (or
             # caller-declared dirty keys) is the DELTA case — pack and
             # dispatch just those rows, replay the rest from the fleet's
             # resident mirrors (ISSUE 20)
-            res = self._delta_pass(problems, ids, t0)
+            res = self._delta_pass(problems, ids, t0, quota)
             if res is not None:
                 return res
 
@@ -2106,6 +2194,8 @@ class TensorScheduler:
                         selections = (pos[rides], sel_bits[rides])
             host_rows = len(problems) - len(fp)
             if host_rows:
+                if quota is not None:
+                    return None  # admission is over the presented batch
                 ids = None  # the fleet batch is not the presented one
             self.solve_batches += 1
             self._pass_path = "full"
@@ -2120,7 +2210,7 @@ class TensorScheduler:
             )
             fast_res = self._fleet.schedule(
                 fp, fc, selections=selections, select=select,
-                host_rows=host_rows, ids=ids,
+                host_rows=host_rows, ids=ids, quota=quota,
             )
             # from the table's answer to the engine's: arming the identity
             # path (with the pass's sweep; a walk no diff came before makes
@@ -2165,6 +2255,8 @@ class TensorScheduler:
                 return results
         # no fleet pass: an engine-level feature, or fewer eligible rows
         # than the threshold, keeps the whole batch on the host path
+        if quota is not None:
+            return None
         from ..utils.metrics import fleet_host_path_rows
 
         fleet_host_path_rows.set(len(problems))

@@ -81,7 +81,16 @@ decode 9 ms):
   schedulability predicate (ops.masks._first_fit_group_kernel, in int32)
   on the availability the pass divides on, and writes the first fitting
   term's slot into the resident ``cp_idx`` and its index into ``term_sel``
-  (one byte a row: what a result's ``affinity_name`` reads).
+  (one byte a row: what a result's ``affinity_name`` reads);
+- a tenant's FederatedResourceQuota is row state as well. A row holds its
+  namespace's index in the engine's QuotaSnapshot (``ns_idx``, -1 = not
+  quota'd), kept BESIDE the state the pass reads. At every quota
+  generation one kernel (_fleet_quota) derives each row's delta demand
+  from the resident replicas, previous counts and request profile, runs
+  ops.quota.quota_admit over the batch in presented order, and answers a
+  denied bit a row: a denial is a bit beside the row's answer (the
+  division the pass computed for it is ignored), never the row's absence,
+  so a quota move keeps the batch, its length and the identity fast path.
 
 Eligibility: a binding rides the fleet path when its placement has at most
 T_CAP affinity terms and, with more than one term, no spread constraints
@@ -120,6 +129,8 @@ from ..ops.explain import explain_pass as _explain_pass
 from ..ops.masks import _first_fit_group_kernel
 from ..ops.preempt import preempt_select as _preempt_select
 from ..ops.quota import (
+    DEMAND_CLAMP,
+    UNLIMITED,
     quota_admit as _quota_admit,
     quota_cluster_caps as _quota_cluster_caps,
 )
@@ -143,6 +154,7 @@ _TRACE_KERNELS = {
     "B": "fleet_bits",
     "T": "fleet_select",
     "R": "fleet_terms",
+    "Q": "fleet_quota",
     "S": "state_scatter",
     "G": "meta_gather",
     "F": "estimate_fold",
@@ -946,6 +958,47 @@ def _fleet_terms(
 
 
 @jax.jit
+def _fleet_quota(
+    prof_reqs,  # int64[P, R] the request vector of each profile slot
+    rows,  # int32[n_pad] the batch's table rows in PRESENTED order (-1 = padding)
+    ns_idx,  # int32[cap] a row's quota namespace (-1 = not quota'd)
+    prev_lost,  # int32[cap] replicas a row holds on members the snapshot lacks
+    prof_idx, replicas, prev_counts,  # the resident row state it reads
+):
+    """What ops.quota.quota_admit takes of a batch, derived on the device
+    from the resident row state, in the batch's PRESENTED order (the
+    position in ``rows``: the FIFO order of admission, whatever slots the
+    table gave the rows): each position's namespace index, and its delta
+    demand ``max(replicas - held, 0)`` times its profile's request vector
+    (``held`` = the previous counts the row state keeps + those on members
+    that left the snapshot), clamped to DEMAND_CLAMP as
+    QuotaSnapshot.demand_row clamps it. Where the product would pass the
+    clamp (request > DEMAND_CLAMP // delta) the clamp is taken and the
+    product never read, so an absurd-but-legal request times a large delta
+    cannot wrap int64 into an admission. A row that asks for nothing (its
+    delta is not positive) is not the quota's to deny, as upstream's
+    enforcement lets such a delta through: it is handed on as a row without
+    a quota (-1), whatever its namespace has left. Returns (int32[n_pad]
+    namespace, int64[n_pad, R] demand, int32 the batch's rows in a quota'd
+    namespace, those that ask nothing included)."""
+    with jax.named_scope("fleet.quota"):
+        r = jnp.maximum(rows, 0)
+        ns = jnp.where(rows >= 0, ns_idx[r], -1)
+        held = prev_counts[r].astype(jnp.int64).sum(axis=1) + prev_lost[r]
+        delta = jnp.maximum(replicas[r].astype(jnp.int64) - held, 0)
+        req = prof_reqs[prof_idx[r]]
+        fits = DEMAND_CLAMP // jnp.maximum(delta, 1)
+        demand = jnp.where(
+            req > fits[:, None], jnp.int64(DEMAND_CLAMP), req * delta[:, None]
+        )
+        demand = jnp.where(((ns >= 0) & (delta > 0))[:, None], demand, 0)
+        asks = demand.any(axis=1)
+        return (
+            jnp.where(asks, ns, -1), demand, (ns >= 0).sum(dtype=jnp.int32)
+        )
+
+
+@jax.jit
 def _gather_meta(res_meta, rows):
     """Changed-meta fallback when phase A's tuned meta buffer overflows:
     one cheap gather instead of a full-solve rerun."""
@@ -967,6 +1020,9 @@ _fleet_bits.row_coupled = False
 _fleet_select.row_coupled = True
 # the same: the chosen slots land at ``rows``, the counts sum over the rows
 _fleet_terms.row_coupled = True
+# rows gathered into presented order (data-dependent placement) and one
+# count over every row
+_fleet_quota.row_coupled = True
 _gather_meta.row_coupled = False
 
 
@@ -983,8 +1039,13 @@ FLEET_KERNELS = {
     "fleet_bits": _fleet_bits,
     "fleet_select": _fleet_select,
     "fleet_terms": _fleet_terms,
-    # quota plane (ops.quota): dispatched engine-side (TensorScheduler's
-    # admission wrapper + cap fold), registered here so prewarm replay and
+    # what admission takes of a batch that rides the table whole (each
+    # row's namespace and demand), derived from its row state
+    "fleet_quota": _fleet_quota,
+    # quota plane (ops.quota): quota_admit dispatched by the table over
+    # fleet_quota's outputs, or engine-side over demands the host derives
+    # (TensorScheduler's partition of a batch that leaves the table in
+    # part); the cap fold engine-side. Registered here so prewarm replay and
     # the graftlint IR tier see them like every other solve-family kernel
     "quota_admit": _quota_admit,
     "quota_cluster_caps": _quota_cluster_caps,
@@ -1196,6 +1257,36 @@ class FleetResult:
         return self._feasible
 
 
+class _QuotaVerdict:
+    """What one quota admission left of a batch: whether each position was
+    denied, from quota_admit's answer (its copy to the host started at the
+    dispatch; read at the first denied-or-not question); with it whose
+    verdict this is (the batch's row vector and the quota generation), so a
+    pass over the same rows at the same generation replays it."""
+
+    __slots__ = (
+        "rows_np", "generation", "n", "_denied", "quota_rows", "denied_rows",
+    )
+
+    def __init__(self, rows_np, generation, n, denied):
+        self.rows_np = rows_np
+        self.generation = generation
+        self.n = n
+        # bool[n] denied, or until its first read the kernel's admitted
+        # bool[n_pad] on the device
+        self._denied = denied
+        # the batch's rows in a quota'd namespace, and those denied: set
+        # when the pass that dispatched the admission ends
+        self.quota_rows = self.denied_rows = 0
+
+    def denied(self) -> np.ndarray:
+        """bool[n]: whether the quota denied the row at each position."""
+        d = self._denied
+        if not isinstance(d, np.ndarray):
+            d = self._denied = ~np.asarray(d)[: self.n]
+        return d
+
+
 class _FleetResultList:
     """Column-oriented result container: the scheduling data lives in the
     fetched numpy arrays; per-binding `FleetResult` views materialize on
@@ -1203,15 +1294,20 @@ class _FleetResultList:
     objects eagerly would cost more host time than the whole device pass —
     consumers that iterate pay the same total, but batch callers that
     sample (bench verification, partial write-backs) don't pay for rows
-    they never touch."""
+    they never touch. Where the pass admitted the batch against a quota,
+    ``quota`` holds the verdict: a denied row answers a plain
+    ScheduleResult carrying QUOTA_EXCEEDED_ERROR and no placement (what
+    the pass divided for it is never read)."""
 
     __slots__ = (
         "_problems", "_terms", "_batches", "_slice_rows", "_n_placed",
-        "_unsched", "_has_cand", "_is_dup", "_cache",
+        "_unsched", "_has_cand", "_is_dup", "_cache", "quota", "_denied",
     )
 
     def __init__(self, problems, terms, batches, slice_rows, n_placed,
                  unsched, has_cand, is_dup):
+        self.quota: Optional[_QuotaVerdict] = None
+        self._denied: Optional[np.ndarray] = None
         self._problems = problems
         self._terms = terms
         self._batches = batches
@@ -1230,6 +1326,17 @@ class _FleetResultList:
         if res is not None:
             return res
         p = self._problems[i]
+        if self.quota is not None:
+            if self._denied is None:
+                self._denied = self.quota.denied()
+            if self._denied[i]:
+                from .core import ScheduleResult
+                from .quota import QUOTA_EXCEEDED_ERROR
+
+                res = self._cache[i] = ScheduleResult(
+                    key=p.key, error=QUOTA_EXCEEDED_ERROR
+                )
+                return res
         if not self._has_cand[i]:
             err = "no clusters fit the placement"
         elif self._unsched[i]:
@@ -1283,6 +1390,12 @@ _STATE_FIELDS = (
 _SELECT_STATE = tuple(
     _STATE_FIELDS.index(k) for k in _STATE_FIELDS
     if k not in ("strategy", "fresh")
+)
+
+
+#: the row state _fleet_quota reads (beside the two quota columns)
+_QUOTA_STATE = tuple(
+    _STATE_FIELDS.index(k) for k in ("prof_idx", "replicas", "prev_counts")
 )
 
 
@@ -1387,6 +1500,8 @@ class FleetTable:
         # divide kernel with no kernel-signature change. Stable for the
         # table's lifetime: the engine drops the table on cap changes.
         self._prof_ns: list[int] = []
+        self._prof_capped = False  # a slot of a capped namespace exists
+        self._caps_fold: Optional[tuple] = None
         # requests-tuple -> profile slot memo over _prof_slot: skips the
         # per-row dim-vector build (zeros + dim_index loop + tobytes) that
         # dominates bulk onboarding (a restart's first wave packs EVERY
@@ -1418,6 +1533,24 @@ class FleetTable:
         self._dev_term_sel = None
         self._term_cache: Optional[_TermRows] = None
         self._terms_mark: Optional[tuple] = None
+        # quota admission from row state (_admit_on_device). The staging's
+        # ``ns_idx`` column is derived from ONE QuotaSnapshot.ns_index,
+        # kept here (None = no quota set: the column is not kept up).
+        # On the device: (ns_idx, prev_lost) beside the state the pass
+        # reads, with the rows packed since their upload; the profile
+        # slots' request vectors, by how many they were. The last verdict
+        # (replayed while rows and generation stand), the quota the
+        # current pass admits against (None: it admits nothing), what
+        # schedule() records of the admission, and the admitted demand the
+        # engine has yet to debit
+        self._ns_src: Optional[dict] = None
+        self._dev_quota: Optional[tuple] = None
+        self._quota_dirty: set[int] = set()
+        self._dev_prof_reqs: Optional[tuple] = None
+        self._quota_verdict: Optional[_QuotaVerdict] = None
+        self._quota_pass = None
+        self._quota_mark: Optional[tuple] = None
+        self.quota_debit: Optional[np.ndarray] = None
         self._all_rows_dev = None
         self._all_rows_n = -1
         self._dirty: set[int] = set()
@@ -1645,6 +1778,7 @@ class FleetTable:
         self._dev_state = None  # full re-upload with the compacted layout
         self._dev_term_sel = None
         self._term_cache = None
+        self._dev_quota = self._quota_verdict = None
         self._all_rows_n = -1
         # row ids were remapped: the delta base is meaningless now, and so
         # is any result view still pointing at the old row layout
@@ -1704,6 +1838,13 @@ class FleetTable:
             # the last pass that walked the row (host bookkeeping too:
             # what _compact reads)
             "last_used": np.zeros(new_cap, np.int64),
+            # quota admission's row state, uploaded BESIDE the state the
+            # pass reads (_admit_on_device): the row's namespace index in
+            # the quota snapshot (-1 = not quota'd) and the replicas it
+            # holds on members the snapshot does not name (prev_counts
+            # keeps none of those; the demand counts them as held)
+            "ns_idx": np.full(new_cap, -1, np.int32),
+            "prev_lost": np.zeros(new_cap, np.int32),
         }
         for k, a in self._st.items():
             st[k][: self.cap] = a
@@ -1712,6 +1853,7 @@ class FleetTable:
         self._dev_state = None  # full re-upload
         self._dev_term_sel = None
         self._term_cache = None
+        self._dev_quota = self._quota_verdict = None
         self._reset_dense()  # cap changed: residents reallocate zeroed
         self._reuse = None
 
@@ -1833,6 +1975,7 @@ class FleetTable:
                     and q.requests == p.requests
                     and q.prev == p.prev
                     and q.evict_clusters == p.evict_clusters
+                    and q.namespace == p.namespace
                 ):
                     equal += 1
                 else:
@@ -1911,6 +2054,7 @@ class FleetTable:
             self._prof_slot[pkey] = pslot
             self._profiles.append(vec)
             self._prof_ns.append(qns)
+            self._prof_capped = self._prof_capped or qns >= 0
             self._tables_dirty = True
         return pslot
 
@@ -1955,6 +2099,9 @@ class FleetTable:
         prev_count: list = []
         evict_at: list = []
         evict_site: list = []
+        # rows holding replicas on members the snapshot lacks: (i, count)
+        lost_at: list = []
+        lost_n: list = []
         site_of = snap.index.get
         full = False
         for i, (row, p, cp) in enumerate(zip(rows, problems, compiled)):
@@ -1989,10 +2136,13 @@ class FleetTable:
                 sites = list(map(site_of, p.prev))
                 counts = p.prev.values()
                 if None in sites:  # a site that left the snapshot
+                    held = sum(counts)
                     counts = [
                         c for j, c in zip(sites, counts) if j is not None
                     ]
                     sites = [j for j in sites if j is not None]
+                    lost_at.append(i)
+                    lost_n.append(held - sum(counts))
                 full = full or len(sites) > K_PREV
                 prev_at.extend(range(i * K_PREV, i * K_PREV + len(sites)))
                 prev_site.extend(sites)
@@ -2042,6 +2192,14 @@ class FleetTable:
         # _apply_selections for a row the host selected)
         st["sel_bits"][at] = 0xFF
         st["sel_on_dev"][at] = False
+        lost = np.zeros(k, np.int32)
+        lost[lost_at] = lost_n
+        st["prev_lost"][at] = lost
+        if self._ns_src is not None:
+            ns_of = self._ns_src.get
+            st["ns_idx"][at] = [ns_of(p.namespace, -1) for p in problems]
+        if self._dev_quota is not None:
+            self._quota_dirty.update(rows)
         self._term_cache = None
         self._dirty.update(rows)
 
@@ -2291,6 +2449,10 @@ class FleetTable:
         # quota-aware table: cap-namespace profile slots get the static-
         # assignment ceiling min-folded into their availability row
         prof_table = self.engine._profile_table_quota(profs_dev, prof_ns)
+        if self._prof_capped:
+            # the cap kernel ran: (profile slots, those of a capped
+            # namespace), stamped on the pass's sync stretch
+            self._caps_fold = (len(profs), int((prof_ns >= 0).sum()))
         _mark("prof_table")
         # host mirror of the estimator max (general + models): the device
         # form is a blocking scalar fetch (one more round-trip) and this
@@ -2450,6 +2612,7 @@ class FleetTable:
     def schedule(
         self, problems: Sequence, compiled: Sequence, delta=None,
         selections=None, select=None, host_rows: int = 0, ids=None,
+        quota=None,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -2496,7 +2659,14 @@ class FleetTable:
 
         ``ids`` (optional) is id() of every position's object (int64[n]),
         from a caller that swept ``problems`` already (the engine's diffs):
-        the upsert phase diffs by it and makes no sweep of its own."""
+        the upsert phase diffs by it and makes no sweep of its own.
+
+        ``quota`` (optional) is the QuotaSnapshot the batch is admitted
+        against, from a caller whose whole batch rides the table: the pass
+        admits it from the row state (_admit_on_device), the result list
+        answers a denied row QUOTA_EXCEEDED_ERROR, and ``quota_debit``
+        holds the admitted demand for the caller to commit (None where the
+        verdict of the same rows at the same generation was replayed)."""
         from ..utils.metrics import (
             affinity_term_choices,
             eviction_masked_rows,
@@ -2508,10 +2678,14 @@ class FleetTable:
 
         with tracer.span("scheduler.solve") as sp:
             self._phase_marks = []
-            self._select_mark = self._terms_mark = None
+            self._select_mark = self._terms_mark = self._quota_mark = None
+            self._quota_pass, self.quota_debit = quota, None
+            self._sync_ns()
             res = self._schedule_pass(
                 problems, compiled, delta, selections, select, ids
             )
+            if quota is not None:
+                self._record_admission(res, quota)
             tmr = self.last_breakdown
             sp.attrs["rows"] = len(problems)
             sp.attrs["rows_visited"] = int(tmr.get("rows_visited", 0))
@@ -2715,6 +2889,195 @@ class FleetTable:
             t_a, time.perf_counter(), tr.n, counts, tr.evicted
         )
 
+    def _sync_ns(self) -> None:
+        """Keep the staging's ``ns_idx`` column derived from the engine's
+        current QuotaSnapshot.ns_index. A quota generation that moves
+        ``remaining`` alone brings the same namespaces (the same dict, or
+        an equal one) and touches no row; another namespace SET re-derives
+        the column from the rows' pinned bindings, once; with no quota set
+        the column is not kept up (the next quota re-derives it)."""
+        q = getattr(self.engine, "quota", None)
+        src = q.ns_index if q is not None and q.ns_index else None
+        cur = self._ns_src
+        if src is cur:
+            return
+        self._ns_src = src
+        if src is None or (cur is not None and src == cur) or not self.n_rows:
+            return
+        ns_of = src.get
+        self._st["ns_idx"][: self.n_rows] = [
+            ns_of(p.namespace, -1) for p in self._problems
+        ]
+        self._dev_quota = self._quota_verdict = None
+
+    def _replicated(self, a):
+        """``a`` on the device; under a mesh on every device of it, as the
+        state the pass gathers from is."""
+        a = jnp.asarray(a)
+        if self._mesh is None:
+            return a
+        return jax.device_put(a, NamedSharding(self._mesh, P()))
+
+    def _rows_padded(self, rows_np: np.ndarray) -> np.ndarray:
+        """A batch's rows padded with -1 to whole chunks, as a pass
+        dispatches them."""
+        n = len(rows_np)
+        eff = min(self.chunk, _pow2(max(n, 256)))
+        ar = np.full(max(eff, -(-n // eff) * eff), -1, np.int32)
+        ar[:n] = rows_np
+        return ar
+
+    def _dispatch_quota(self, quota, rows_dev) -> tuple:
+        """Admit the rows ``rows_dev`` (a batch's table rows in presented
+        order, -1 padded) against ``quota.remaining``: _fleet_quota derives
+        each position's namespace and demand from the row state, and
+        ops.quota.quota_admit, the kernel the engine's host partition
+        dispatches, admits them; its trace rides the ENGINE'S ledger under
+        the engine's key, so one family counts both routes' compiles.
+        First the inputs come to the device: the two quota columns (whole
+        after a growth, a compaction or a namespace-set move, else the
+        rows packed since), the profile slots' request vectors (when a
+        profile was interned) and ``remaining``, padded to a power of two
+        of namespaces with UNLIMITED rows (a few KB a generation). No host
+        wait. Returns (admitted bool[n_pad], int64[N, R] the admitted
+        demand a namespace, the batch's quota'd rows), their copies to the
+        host started."""
+        st = self._st
+        if self._dev_quota is None:
+            self._dev_quota = tuple(
+                self._replicated(st[k]) for k in ("ns_idx", "prev_lost")
+            )
+            self._last_upload_bytes += 8 * self.cap
+        elif self._quota_dirty:
+            dirty = np.fromiter(
+                self._quota_dirty, np.int64, len(self._quota_dirty)
+            )
+            pad = _pow2(len(dirty))
+            rows_p = np.concatenate(
+                [dirty, np.full(pad - len(dirty), dirty[0], np.int64)]
+            )
+            self._mark_trace("S", self.cap, pad, "quota")
+            self._dev_quota = _scatter_rows(
+                self._dev_quota, jnp.asarray(rows_p),
+                (st["ns_idx"][rows_p], st["prev_lost"][rows_p]),
+            )
+            self._last_upload_bytes += 16 * pad
+        self._quota_dirty.clear()
+        n_prof = len(self._profiles)
+        if self._dev_prof_reqs is None or self._dev_prof_reqs[0] != n_prof:
+            profs = np.zeros(
+                (_pow2(max(n_prof, 4)), len(quota.dims)), np.int64
+            )
+            profs[:n_prof] = np.stack(self._profiles)
+            self._dev_prof_reqs = (n_prof, self._replicated(profs))
+        args = (
+            self._dev_prof_reqs[1], rows_dev, *self._dev_quota,
+            *(self._dev_state[k] for k in _QUOTA_STATE),
+        )
+        from ..parallel.mesh import mesh_shape as _mesh_shape
+
+        b_pad = int(rows_dev.shape[0])
+        mesh_el = _mesh_shape(self._mesh)
+        key = ("Q", self.cap, b_pad, args[0].shape, mesh_el)
+        if self._mark_trace(*key) and self._mesh is None:
+            # meshed dispatches stay manifest-unrecorded, as _fleet_bits'
+            self._record_trace("fleet_quota", key, args)
+        ns, demand, quota_rows = _fleet_quota(*args)
+        remaining = quota.remaining
+        n_ns = _pow2(max(remaining.shape[0], 4))
+        if n_ns > remaining.shape[0]:
+            remaining = np.pad(
+                remaining, ((0, n_ns - remaining.shape[0]), (0, 0)),
+                constant_values=UNLIMITED,
+            )
+        admit_args = (ns, demand, jnp.asarray(remaining))
+        engine = self.engine
+        key = ("Q", b_pad, n_ns, int(remaining.shape[1]), mesh_el)
+        if engine._mark_trace(*key) and self._mesh is None:
+            engine._record_trace("quota_admit", key, admit_args)
+        admitted, used = _quota_admit(*admit_args)
+        for a in (admitted, used, quota_rows):
+            a.copy_to_host_async()
+        return admitted, used, quota_rows
+
+    def _admit_on_device(self, rows_np, rows_dev, changed=None) -> None:
+        """The quota admission of the batch whose rows are ``rows_np``
+        (``rows_dev``: the same on the device, -1 padded, or None to have
+        it made here), against the pass's quota: one admission over the
+        batch in presented order (_dispatch_quota), or none where the last
+        verdict is of these rows at this quota generation (it is replayed:
+        within a generation ``remaining`` only falls, by what was
+        admitted).
+
+        ``changed`` = (the row vector the last verdict was given, the
+        positions that moved since): at an unmoved generation the moved
+        positions alone are admitted, as a batch of their own against the
+        working ``remaining``, which already carries what the others were
+        admitted with; every other position keeps its verdict and is not
+        charged again (TensorScheduler._quota_admission_delta's contract).
+
+        Leaves in ``_quota_mark`` what schedule() records: the stretch, the
+        verdict, and the kernels' count and admitted demand still on their
+        way to the host (None for a replay)."""
+        t_a = time.perf_counter()
+        quota = self._quota_pass
+        last = self._quota_verdict
+        n = len(rows_np)
+        stands = last is not None and last.generation == quota.generation
+        if stands and changed is None and last.rows_np is rows_np:
+            self._quota_mark = (t_a, time.perf_counter(), last, None, None)
+            return
+        if stands and changed is not None and last.rows_np is changed[0]:
+            pos = changed[1]
+            sub = np.full(_pow2(len(pos)), -1, np.int32)
+            sub[: len(pos)] = rows_np[pos]
+            admitted, used, _ = self._dispatch_quota(quota, jnp.asarray(sub))
+            denied = last.denied().copy()
+            denied[pos] = ~np.asarray(admitted)[: len(pos)]
+            verdict = _QuotaVerdict(rows_np, quota.generation, n, denied)
+            # the unmoved positions' quota'd rows + these
+            quota_rows = int((self._st["ns_idx"][rows_np] >= 0).sum())
+        else:
+            if rows_dev is None:
+                rows_dev = jnp.asarray(self._rows_padded(rows_np))
+            admitted, used, quota_rows = self._dispatch_quota(quota, rows_dev)
+            verdict = _QuotaVerdict(rows_np, quota.generation, n, admitted)
+        self._quota_verdict = verdict
+        self._quota_mark = (
+            t_a, time.perf_counter(), verdict, quota_rows, used
+        )
+
+    def _record_admission(self, res, quota) -> None:
+        """After the pass: hand the verdict to the result list, keep the
+        admitted demand for the engine to debit, and record the admission
+        (the ``scheduler.quota`` span at its stretch, the two counters).
+        The kernels' outputs were on their way to the host since the
+        dispatch, which lay before the pass's fetch and decode: reading
+        them here waits on little."""
+        from ..utils.tracing import tracer
+
+        t_a, t_b, verdict, quota_rows, used = self._quota_mark
+        self._quota_mark = None
+        res.quota = verdict
+        if used is not None:
+            verdict.quota_rows = int(quota_rows)
+            verdict.denied_rows = int(verdict.denied().sum())
+            wu = np.asarray(used)[: quota.remaining.shape[0]]
+            self.quota_debit = wu if wu.any() else None
+        n, q_rows, denied = verdict.n, verdict.quota_rows, verdict.denied_rows
+        tracer.record(
+            "scheduler.quota", t_b - t_a, start=t_a, rows=n,
+            quota_rows=q_rows, denied=denied,
+            # rows whose demand or partition the host derived: none on
+            # this route (a packed row's namespace lookup is the upsert
+            # phase's, counted in its rows_packed)
+            host_rows=0,
+            dispatched=int(used is not None), generation=quota.generation,
+        )
+        self.engine._quota_tally.add(
+            "resident" if used is not None else "replayed", n, q_rows, denied
+        )
+
     def device_bytes(self) -> dict[str, int]:
         """Resident device bytes by ledger kind — the EXACT ``nbytes`` of
         the arrays this table holds right now (ISSUE 12 b): the packed
@@ -2732,7 +3095,8 @@ class FleetTable:
 
         return {
             "packed_grid": nb(self._dev_state) + nb(self._dev_term_slots)
-            + nb(self._dev_term_sel),
+            + nb(self._dev_term_sel) + nb(self._dev_quota)
+            + (nb(self._dev_prof_reqs[1]) if self._dev_prof_reqs else 0),
             "slot_tables": nb(self._dev_tables) + nb(self._dev_spread)
             + nb(self._dev_subsets),
             "donated_residents": nb(self._res_dense) + nb(self._res_meta),
@@ -2826,7 +3190,11 @@ class FleetTable:
             # the pass's host->device bytes ride the stretch that uploads,
             # so the history sampler (and a dumped wave) can read transfer
             # volume without reaching into the engine
-            "sync": {"upload_mb": tmr.get("upload_mb", 0.0)},
+            "sync": {
+                "upload_mb": tmr.get("upload_mb", 0.0),
+                **{k: int(tmr[k]) for k in ("quota_profiles", "quota_cap_rows")
+                   if k in tmr},
+            },
             "dispatch": {"compile": fresh} if fresh else {},
             "device": {"compile": fresh},
             "fetch": {
@@ -2894,7 +3262,11 @@ class FleetTable:
             len(problems) - self._packed_this_pass, 0
         )
         self._est_window = None
+        self._caps_fold = None
         self._sync_device()
+        if self._caps_fold is not None:
+            # the static-assignment cap kernel ran for the profile table
+            tmr["quota_profiles"], tmr["quota_cap_rows"] = self._caps_fold
         if self._est_window is not None:
             # the estimator stretch is the estimator.* spans' own: the
             # sync phase is what lies before and after it
@@ -3037,9 +3409,13 @@ class FleetTable:
         t_all = time.perf_counter()
         rows_full = ru[2]
         n_sub = int(idx.size)
+        quota = self._quota_pass
         if n_sub == 0:
             # pure replay: nothing changed — serve the whole batch from
-            # the mirrors without touching the device
+            # the mirrors without touching the device (but for a quota
+            # generation that moved: one admission over the batch's rows)
+            if quota is not None:
+                self._admit_on_device(rows_full, None)
             self._pass += 1
             self.new_trace_last_pass = False
             self._packed_this_pass = self._visited_this_pass = 0
@@ -3072,9 +3448,12 @@ class FleetTable:
         self._last_changed = None
         self._last_dtotal = None
         self._delta_live = False
+        # the sub pass admits nothing: admission is over the batch
+        self._quota_pass = None
         try:
             self._schedule_pass(sub_p, sub_c)
         finally:
+            self._quota_pass = quota
             for a, v in zip(self._TUNE_ATTRS, tune):
                 setattr(self, a, v)
         if (
@@ -3100,6 +3479,8 @@ class FleetTable:
         self._reuse = (problems, compiled, rows_new, ru[3], None)
         self._reuse_pass = self._pass
         self._upsert_tally[0].inc(n - n_sub)
+        if quota is not None:
+            self._admit_on_device(rows_new, None, changed=(rows_full, idx))
         t0 = time.perf_counter()
         res = self._replay_result(problems, rows_new, tmr)
         self._phase(tmr, "post", t0)
@@ -3484,6 +3865,14 @@ class FleetTable:
                 mesh=self._entries_mesh,
             )
         t0 = self._phase(tmr, "dispatch", t0)
+        if self._quota_pass is not None:
+            # the batch's quota admission, behind the pass on the device
+            # (it runs while the host fetches and decodes the pass's wire)
+            # and outside the pass's phases: the stretch is the
+            # scheduler.quota span's
+            self._admit_on_device(rows_np, rows_dev)
+            tmr["quota_dispatch"] = self._quota_mark[1] - t0
+            t0 = self._quota_mark[1]
         # device fence at the span boundary: block_until_ready splits
         # phase A's on-device execute (+compile on a fresh trace) from the
         # wire/decode window — the fetch would block on the same event

@@ -90,6 +90,7 @@ _KERNELS = {
     "fleet_bits": False,
     "fleet_select": True,
     "fleet_terms": True,
+    "fleet_quota": True,
     "quota_admit": True,
     "quota_cluster_caps": False,
     "explain_pass": False,

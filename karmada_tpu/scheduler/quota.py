@@ -46,6 +46,34 @@ QUOTA_EXCEEDED_REASON = _REASONS["QuotaExceeded"].code
 QUOTA_EXCEEDED_ERROR = "namespace quota exceeded"
 
 
+class AdmissionTally:
+    """The two admission counters on bound tallies, added once a pass:
+    what admission made of the pass's rows, and the route it took
+    (resident | partition | replayed)."""
+
+    def __init__(self):
+        from ..utils.metrics import (
+            quota_admission_passes,
+            quota_admission_rows,
+        )
+
+        self._rows = tuple(
+            quota_admission_rows.labels(outcome=o)
+            for o in ("admitted", "denied", "unquotad")
+        )
+        self._routes = {
+            r: quota_admission_passes.labels(route=r)
+            for r in ("resident", "partition", "replayed")
+        }
+
+    def add(self, route: str, rows: int, quota_rows: int, denied: int):
+        admitted_t, denied_t, unquotad_t = self._rows
+        admitted_t.inc(quota_rows - denied)
+        denied_t.inc(denied)
+        unquotad_t.inc(rows - quota_rows)
+        self._routes[route].inc()
+
+
 class QuotaSnapshot:
     """Packed view of every FederatedResourceQuota.
 

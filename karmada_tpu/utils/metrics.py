@@ -671,6 +671,24 @@ quota_denied = registry.counter(
     "condition lands on the binding; a denied binding retries on the "
     "next quota generation, not every pass)",
 )
+quota_admission_rows = registry.counter(
+    "karmada_tpu_quota_admission_rows_total",
+    "rows of engine passes under a QuotaSnapshot by what admission made of "
+    "them: admitted (in a quota'd namespace, inside its remaining budget), "
+    "denied (past it: the row answers 'namespace quota exceeded' and keeps "
+    "its previous placement), unquotad (a namespace without a "
+    "FederatedResourceQuota); added once a pass",
+)
+quota_admission_passes = registry.counter(
+    "karmada_tpu_quota_admission_passes_total",
+    "engine passes under a QuotaSnapshot by the route admission took: "
+    "resident (one kernel over the fleet table's row state, the batch "
+    "keeps its length), partition (the host derives every row's namespace "
+    "and demand and hands the solve the admitted sub-list: a batch with "
+    "rows off the fleet table, a tiny batch, more rows than one admission "
+    "takes), replayed (the same rows at the same quota generation: the "
+    "last verdict stands, nothing is dispatched)",
+)
 quota_limit = registry.gauge(
     "karmada_tpu_quota_limit",
     "FederatedResourceQuota spec.overall limit by namespace and resource "

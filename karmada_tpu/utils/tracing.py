@@ -154,6 +154,16 @@ SPAN_NAMES: dict[str, str] = {
         "table's term kernel, its row vector and dispatch (rows / "
         "fallback / unfit / evicted_rows attrs)"
     ),
+    "scheduler.quota": (
+        "only in a pass under an active QuotaSnapshot: the admission "
+        "stretch. Under scheduler.solve where the whole batch rides the "
+        "fleet table (the dispatch of its admission kernel over the row "
+        "state, or the replay of the last verdict: host_rows = 0); under "
+        "scheduler.schedule where the engine partitions the batch on the "
+        "host (host_rows = the batch: the host derives every row's "
+        "namespace and demand). rows / quota_rows / denied / host_rows / "
+        "dispatched / generation attrs"
+    ),
     "scheduler.host": "host-path (non-fleet) scheduling of a batch",
     "scheduler.solve": (
         "one fleet-table solve pass (host_rows = rows of the batch that "
@@ -174,7 +184,9 @@ SPAN_NAMES: dict[str, str] = {
     "kernel.host": (
         "one host stretch of a fleet pass, at its true interval: "
         "phase=upsert|sync|prep before the dispatch, post after the fetch "
-        "(phase=upsert carries rows_visited / rows_packed)"
+        "(phase=upsert carries rows_visited / rows_packed; phase=sync "
+        "quota_profiles / quota_cap_rows where the static-assignment cap "
+        "kernel was dispatched for the profile table)"
     ),
     "kernel.dispatch": (
         "kernel dispatch window (sync backends execute inside it; "

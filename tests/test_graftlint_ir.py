@@ -137,13 +137,13 @@ def test_one_kernel_set_on_every_declaring_surface():
     # one kernel, several declaration sites (ROADMAP Queue 3 item 6):
     # the registry the engine dispatches from, prewarm's jax-free mirror
     # and the lint's manifest-bearing entry points name the same nine
-    # kernels, and the trace-key families that feed the compile counter
+    # kernels (ten since fleet_quota), and the trace-key families that feed the compile counter
     # name no kernel beyond them and the three ledger-only utilities
     from karmada_tpu.scheduler import fleet, prewarm
 
     want = {
         "fleet_pass", "fleet_entries", "fleet_bits", "fleet_select",
-        "fleet_terms",
+        "fleet_terms", "fleet_quota",
         "quota_admit", "quota_cluster_caps", "explain_pass",
         "preempt_select",
     }

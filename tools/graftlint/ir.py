@@ -424,6 +424,19 @@ def _specs_fleet_terms() -> list:
     return [KernelSpec("base", shapes, {"chunk": d["chunk"], "n_chunks": 1})]
 
 
+def _specs_fleet_quota() -> list:
+    d = _fleet_dims()
+    cap = d["cap"]
+    shapes = (
+        ((_P, _R), "int64"),  # prof_reqs
+        ((d["n_pad"],), "int32"),  # the batch's rows, presented order
+        ((cap,), "int32"), ((cap,), "int32"),  # ns_idx prev_lost
+        ((cap,), "int32"), ((cap,), "int32"),  # prof_idx replicas
+        ((cap, d["k_prev"]), "int32"),  # prev_counts
+    )
+    return [KernelSpec("base", shapes)]
+
+
 def _specs_gather_meta() -> list:
     d = _fleet_dims()
     return [KernelSpec(
@@ -608,6 +621,14 @@ ENTRY_POINTS: dict = {
                row_coupled=True,
                row_args=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16),
                spec_deps=_FLEET_DEPS + ("karmada_tpu/ops/masks.py",)),
+        # what quota_admit takes of a batch, from the row state: rows
+        # gathered into presented order and one count over them all
+        # (declared coupled)
+        _entry("fleet_quota", "scheduler", "karmada_tpu.scheduler.fleet",
+               "_fleet_quota", "karmada_tpu/scheduler/fleet.py",
+               _specs_fleet_quota, manifest="fleet_quota",
+               row_coupled=True, row_args=(2, 3, 4, 5, 6),
+               spec_deps=_FLEET_DEPS),
         _entry("gather_meta", "scheduler", "karmada_tpu.scheduler.fleet",
                "_gather_meta", "karmada_tpu/scheduler/fleet.py",
                _specs_gather_meta, row_coupled=False, row_args=(0,),
