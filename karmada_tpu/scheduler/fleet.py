@@ -1423,6 +1423,23 @@ def _fold_estimates(table, answers, n_live):
     return out
 
 
+class _BatchDerived(NamedTuple):
+    """What a pass derives from the row state of a batch's rows, kept with
+    the resident batch (FleetTable._batch_derived): the same rows unpacked
+    derive the same values, whatever the snapshot."""
+
+    rows_np: np.ndarray  # the batch's table rows (identity: whose they are)
+    # affinity term name(s) by position; the result list of every pass over
+    # the record holds this list, so it is never written in place
+    terms: list
+    max_n: int  # the largest ``replicas``
+    max_prev: int  # the largest previous count
+    has_agg: bool  # a row divides Aggregated
+    is_dup: np.ndarray  # bool[n]: the row is Duplicated
+    need_bits: bool  # a row answers by its feasibility bitset
+    is_all: bool  # the rows are the whole table in order (all-rows storm)
+
+
 class _TermRows(NamedTuple):
     """The multi-term rows of a batch, as _fleet_terms takes them."""
 
@@ -1533,6 +1550,11 @@ class FleetTable:
         self._dev_term_sel = None
         self._term_cache: Optional[_TermRows] = None
         self._terms_mark: Optional[tuple] = None
+        # what the passes derive from the current batch's row state, kept
+        # while the same row vector comes again with no row packed (dropped
+        # where _term_cache is); and what the current pass made of it
+        self._derived: Optional[_BatchDerived] = None
+        self._derived_outcome = "kept"
         # quota admission from row state (_admit_on_device). The staging's
         # ``ns_idx`` column is derived from ONE QuotaSnapshot.ns_index,
         # kept here (None = no quota set: the column is not kept up).
@@ -1635,7 +1657,7 @@ class FleetTable:
         # placement slots added since the last publish (_pack_rows
         # increments; schedule() counts them and stamps its span)
         self._slots_minted_this_pass = 0
-        from ..utils.metrics import fleet_upsert_rows
+        from ..utils.metrics import fleet_batch_derived, fleet_upsert_rows
 
         # what the upsert phase made of the rows of each pass, added once a
         # pass: (same, equal, packed)
@@ -1643,6 +1665,11 @@ class FleetTable:
             fleet_upsert_rows.labels(outcome=o)
             for o in ("same", "equal", "packed")
         )
+        # whether each pass kept or built what it derives from its batch's
+        # row state, added once a pass
+        self._derived_tally = {
+            o: fleet_batch_derived.labels(outcome=o) for o in ("kept", "built")
+        }
         # host->device bytes of the current pass (state upload/scatter +
         # row indices), reset by _sync_device; surfaces as upload_mb
         self._last_upload_bytes = 0
@@ -1777,7 +1804,7 @@ class FleetTable:
         self._dirty.clear()
         self._dev_state = None  # full re-upload with the compacted layout
         self._dev_term_sel = None
-        self._term_cache = None
+        self._term_cache = self._derived = None
         self._dev_quota = self._quota_verdict = None
         self._all_rows_n = -1
         # row ids were remapped: the delta base is meaningless now, and so
@@ -1852,7 +1879,7 @@ class FleetTable:
         self.cap = new_cap
         self._dev_state = None  # full re-upload
         self._dev_term_sel = None
-        self._term_cache = None
+        self._term_cache = self._derived = None
         self._dev_quota = self._quota_verdict = None
         self._reset_dense()  # cap changed: residents reallocate zeroed
         self._reuse = None
@@ -1995,10 +2022,13 @@ class FleetTable:
             rows_np = np.array(rows, np.int32)
             if rows:
                 self._st["last_used"][rows_np] = self._pass
-        else:
+        elif rows:
             # a fresh array: what is cached by the batch's row vector
-            # (_select_cache, _term_cache) starts anew as after a walk
+            # (_select_cache, _term_cache, _derived) starts anew as after a
+            # walk
             rows_np = self._reuse[2].copy()
+        else:
+            rows_np = self._reuse[2]  # no position moved: the batch stands
         return rows_np, ids
 
     @staticmethod
@@ -2200,7 +2230,7 @@ class FleetTable:
             st["ns_idx"][at] = [ns_of(p.namespace, -1) for p in problems]
         if self._dev_quota is not None:
             self._quota_dirty.update(rows)
-        self._term_cache = None
+        self._term_cache = self._derived = None
         self._dirty.update(rows)
 
     def _compact_slots(self) -> None:
@@ -2680,6 +2710,7 @@ class FleetTable:
             self._phase_marks = []
             self._select_mark = self._terms_mark = self._quota_mark = None
             self._quota_pass, self.quota_debit = quota, None
+            self._derived_outcome = "kept"
             self._sync_ns()
             res = self._schedule_pass(
                 problems, compiled, delta, selections, select, ids
@@ -2698,6 +2729,8 @@ class FleetTable:
             sp.attrs["slots"] = len(self._cp_pl)
             sp.attrs["slots_minted"] = minted
             sp.attrs["host_rows"] = int(host_rows)
+            sp.attrs["derived"] = self._derived_outcome
+            self._derived_tally[self._derived_outcome].inc()
             self._emit_phase_spans()
             mark, self._terms_mark = self._terms_mark, None
             if mark is not None:
@@ -3195,6 +3228,7 @@ class FleetTable:
                 **{k: int(tmr[k]) for k in ("quota_profiles", "quota_cap_rows")
                    if k in tmr},
             },
+            "prep": {"derived": self._derived_outcome},
             "dispatch": {"compile": fresh} if fresh else {},
             "device": {"compile": fresh},
             "fetch": {
@@ -3216,6 +3250,33 @@ class FleetTable:
             seconds[name] = seconds.get(name, 0.0) + (t1 - t0)
         for name, total in seconds.items():
             kernel_phase_seconds.observe(total, phase=name.split(".")[1])
+
+    def _batch_derived(self, rows_np: np.ndarray) -> _BatchDerived:
+        """What a pass derives from the row state of ``rows_np``: kept while
+        the same row vector comes again and no row was packed since (only
+        _pack_rows writes the columns read here; it, a compaction and a
+        growth drop the record), built anew otherwise."""
+        d = self._derived
+        if d is not None and d.rows_np is rows_np:
+            return d
+        st = self._st
+        n = len(rows_np)
+        reps_sel = st["replicas"][rows_np]
+        strat_sel = st["strategy"][rows_np]
+        is_dup = strat_sel == S_DUPLICATED
+        d = self._derived = _BatchDerived(
+            rows_np,
+            list(map(self._terms.__getitem__, rows_np.tolist())),
+            int(reps_sel.max(initial=0)),
+            int(st["prev_counts"][rows_np].max(initial=0)),
+            bool((strat_sel == AGGREGATED).any()),
+            is_dup,
+            bool(is_dup.any() or (reps_sel == 0).any()),
+            n == self.n_rows
+            and np.array_equal(rows_np, np.arange(n, dtype=np.int32)),
+        )
+        self._derived_outcome = "built"
+        return d
 
     def _schedule_pass(
         self, problems: Sequence, compiled: Sequence, delta=None,
@@ -3296,12 +3357,9 @@ class FleetTable:
         eff_chunk = min(self.chunk, _pow2(max(n, 256)))
         n_pad = max(eff_chunk, -(-n // eff_chunk) * eff_chunk)
         n_chunks = n_pad // eff_chunk
-        st = self._st
+        d = self._batch_derived(rows_np)
         # all-rows storm mode: the row-index upload is cached on device
-        is_all = n == self.n_rows and np.array_equal(
-            rows_np, np.arange(n, dtype=np.int32)
-        )
-        if is_all:
+        if d.is_all:
             if self._all_rows_n != n or self._all_rows_dev is None or (
                 self._all_rows_dev.shape[0] != n_pad
             ):
@@ -3316,22 +3374,16 @@ class FleetTable:
             rows_dev = jnp.asarray(ar)
             self._last_upload_bytes += ar.nbytes
 
-        reps_sel = st["replicas"][rows_np]
-        strat_sel = st["strategy"][rows_np]
-        max_n = int(reps_sel.max(initial=0))
-        max_prev = int(st["prev_counts"][rows_np].max(initial=0))
-        has_agg = bool((strat_sel == AGGREGATED).any())
         c = self.engine.snapshot.num_clusters
         from .core import kernel_variant
 
         wide, fast = kernel_variant(
-            max(self._avail_max, max_n), self._static_max, max_prev, max_n, c
+            max(self._avail_max, d.max_n), self._static_max, d.max_prev,
+            d.max_n, c,
         )
-        k_out = min(max(1, c), _pow2(max(max_n, 1)))
-        is_dup = strat_sel == S_DUPLICATED
-        need_bits = bool(is_dup.any() or (reps_sel == 0).any())
+        k_out = min(max(1, c), _pow2(max(d.max_n, 1)))
         bits_src = None
-        if need_bits:
+        if d.need_bits:
             bits_src = self._bits_src(lambda: rows_dev, eff_chunk, n_chunks)
         # table-validated mesh (see __init__): the row axis shards over
         # "b" on every pass — batches are padded to the pow2 chunk, so
@@ -3359,8 +3411,7 @@ class FleetTable:
         res = self._solve_dense(
             problems=problems, rows_np=rows_np, rows_dev=rows_dev, tmr=tmr,
             n=n, n_pad=n_pad, eff_chunk=eff_chunk, n_chunks=n_chunks,
-            is_all=is_all, c=c, k_out=k_out, wide=wide, fast=fast,
-            has_agg=has_agg, bits_src=bits_src, is_dup=is_dup,
+            c=c, k_out=k_out, wide=wide, fast=fast, bits_src=bits_src,
             mesh=mesh, mesh_el=mesh_el, shard_c=shard_c,
             byte_wire=c <= 0xFFFF,
             # 21-bit entry packing: 2.625 B/entry when the site id fits
@@ -3493,19 +3544,12 @@ class FleetTable:
         was dispatched by the pass that established the mapping (or a
         later one), and the _mirror_epoch fence rejects any realloc in
         between."""
-        st = self._st
         n = len(problems)
-        meta_sel = self._host_meta[rows_full]
-        n_placed = (meta_sel & 0xFF).astype(np.int64)
-        unsched = (meta_sel >> 8) & 1
-        has_cand = (meta_sel >> 9) & 1
-        reps_sel = st["replicas"][rows_full]
-        is_dup = st["strategy"][rows_full] == S_DUPLICATED
-        need_bits = bool(is_dup.any() or (reps_sel == 0).any())
+        d = self._batch_derived(rows_full)
         eff_chunk = min(self.chunk, _pow2(max(n, 256)))
         n_pad = max(eff_chunk, -(-n // eff_chunk) * eff_chunk)
         bits_src = None
-        if need_bits:
+        if d.need_bits:
             # over the FULL reuse rows (a replayed Duplicated row's consumer
             # needs the whole batch's bitsets, not the dirty sub-batch's);
             # the row-index upload waits for the first access: most delta
@@ -3516,18 +3560,25 @@ class FleetTable:
                 return jnp.asarray(ar)
 
             bits_src = self._bits_src(rows_dev, eff_chunk, n_pad // eff_chunk)
+        return self._result_list(problems, d, bits_src, n_pad)
+
+    def _result_list(
+        self, problems, d: _BatchDerived, bits_src, n_pad: int
+    ) -> "_FleetResultList":
+        """The pass's answers over the host mirrors (meta words + entry
+        runs) for the rows of ``d``."""
+        meta_sel = self._host_meta[d.rows_np]
         self._result_gen += 1
-        names = self.engine.snapshot.names
         batches = [
             _FleetBatch(
-                names, self._host_entries, rows_full, bits_src,
-                self, self._result_gen, self._dev_term_sel,
+                self.engine.snapshot.names, self._host_entries, d.rows_np,
+                bits_src, self, self._result_gen, self._dev_term_sel,
             )
         ]
-        terms = [self._terms[r] for r in rows_full]
         return _FleetResultList(
-            problems, terms, batches, n_pad, n_placed, unsched,
-            has_cand, is_dup,
+            problems, d.terms, batches, n_pad,
+            (meta_sel & 0xFF).astype(np.int64), (meta_sel >> 8) & 1,
+            (meta_sel >> 9) & 1, d.is_dup,
         )
 
     def _bits_src(self, rows_dev, chunk: int, n_chunks: int):
@@ -3671,13 +3722,15 @@ class FleetTable:
 
     def _solve_dense(
         self, *, problems, rows_np, rows_dev, tmr, n, n_pad, eff_chunk,
-        n_chunks, is_all, c, k_out, wide, fast, has_agg, bits_src, is_dup,
-        mesh, mesh_el, shard_c, byte_wire, pack21, t0,
+        n_chunks, c, k_out, wide, fast, bits_src, mesh, mesh_el, shard_c,
+        byte_wire, pack21, t0,
     ) -> "_FleetResultList":
         """Two-phase solve: _fleet_pass (divide + dense diff, ~13 KB wire
         on a steady pass) and, only when rows changed, _fleet_entries over
         exactly those rows with an exactly-sized entry buffer (no
         overflow rerun by construction)."""
+        d = self._batch_derived(rows_np)
+        has_agg, is_all = d.has_agg, d.is_all
         if (
             self._res_dense is None
             or self._res_dense.shape != (self.cap, c)
@@ -4006,22 +4059,7 @@ class FleetTable:
         tmr["fetch_mb"] = fetched_bytes / 1e6
         tmr["changed_rows"] = float(total)
 
-        meta_sel = self._host_meta[rows_np]
-        n_placed = (meta_sel & 0xFF).astype(np.int64)
-        unsched = (meta_sel >> 8) & 1
-        has_cand = (meta_sel >> 9) & 1
-        self._result_gen += 1
-        names = self.engine.snapshot.names
-        batches = [
-            _FleetBatch(
-                names, self._host_entries, rows_np, bits_src,
-                self, self._result_gen, self._dev_term_sel,
-            )
-        ]
-        terms = [self._terms[r] for r in rows_np]
+        res = self._result_list(problems, d, bits_src, n_pad)
         self._phase(tmr, "post", t0)
         self.last_breakdown = tmr
-        return _FleetResultList(
-            problems, terms, batches, n_pad, n_placed, unsched,
-            has_cand, is_dup,
-        )
+        return res

@@ -499,6 +499,16 @@ fleet_upsert_rows = registry.counter(
     "pinned, not repacked), packed (new to the table or content moved: row "
     "state rewritten and uploaded); added once a pass",
 )
+fleet_batch_derived = registry.counter(
+    "karmada_tpu_fleet_batch_derived_total",
+    "fleet passes by what became of the values a pass derives from its "
+    "batch's row state (the affinity names, the largest replicas and "
+    "previous count that pick the kernel variant, whether a row divides "
+    "Aggregated or answers by bitset): kept (the resident batch's row "
+    "vector came again and no row of the table was packed since), built "
+    "(another row vector, or a pack, a compaction or a growth since: "
+    "derived again over every row of the batch); added once a pass",
+)
 scheduler_prologue_rows = registry.counter(
     "karmada_tpu_scheduler_prologue_rows_total",
     "positions of full-path engine passes by what the host prologue made "

@@ -171,7 +171,9 @@ SPAN_NAMES: dict[str, str] = {
         "its upsert phase looked at: 0 when the same lists come again, the "
         "positions holding another object when a swapped batch is diffed, "
         "every position when it is walked; rows_packed of them rewrote "
-        "their row state)"
+        "their row state; derived = kept where the pass read what the last "
+        "one derived from the batch's row state, built where it derived it "
+        "anew: another row vector, or a row packed since)"
     ),
     "scheduler.explain": (
         "armed-only provenance capture of a pass: per-stage mask "
@@ -186,7 +188,8 @@ SPAN_NAMES: dict[str, str] = {
         "phase=upsert|sync|prep before the dispatch, post after the fetch "
         "(phase=upsert carries rows_visited / rows_packed; phase=sync "
         "quota_profiles / quota_cap_rows where the static-assignment cap "
-        "kernel was dispatched for the profile table)"
+        "kernel was dispatched for the profile table; phase=prep derived "
+        "= kept|built, as scheduler.solve has it)"
     ),
     "kernel.dispatch": (
         "kernel dispatch window (sync backends execute inside it; "
