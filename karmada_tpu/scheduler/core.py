@@ -193,6 +193,39 @@ class ScheduleResult:
 #: only THIS failure means "freeing capacity could place the binding".
 INSUFFICIENT_ERROR = "clusters available replicas are not enough"
 
+#: why a row leaves the fleet table for the general host path, in the
+#: order _host_path_reason tests them (a row counts for the first it meets)
+HOST_PATH_REASONS = (
+    "terms", "evict_tasks", "terms_spread", "prev_sites", "replicas",
+    "selection",
+)
+#: the spans of a host-path chunk's stages timed by
+#: scheduling_algorithm_duration (Filter, Score, Select, AssignReplicas)
+_HOST_STAGES = (
+    "scheduler.host.pack", "scheduler.host.estimate",
+    "scheduler.host.select", "scheduler.host.assign",
+)
+
+
+def _host_path_reason(p, cp: CompiledPlacement) -> str:
+    """The bound a row the fleet-eligibility predicate turned away passed
+    first: the placement's terms, the binding's eviction tasks, terms
+    beside spread constraints, its previous sites, a Divided row's
+    replicas; else a spread-constrained row that was given no selection."""
+    from .fleet import K_EVICT, K_PREV, MAX_REPLICAS_FAST, S_DUPLICATED, T_CAP
+
+    if len(cp.terms) > T_CAP:
+        return "terms"
+    if len(p.evict_clusters) > K_EVICT:
+        return "evict_tasks"
+    if len(cp.terms) > 1 and not cp.fleet_terms:
+        return "terms_spread"
+    if len(p.prev) > K_PREV:
+        return "prev_sites"
+    if cp.strategy != S_DUPLICATED and p.replicas > MAX_REPLICAS_FAST:
+        return "replicas"
+    return "selection"
+
 
 @dataclass
 class PreemptionOutcome:
@@ -337,6 +370,16 @@ class TensorScheduler:
         self._host_select_told = None
         # the by-reason counts the host-path line was last printed for
         self._host_path_told = None
+        from ..utils.metrics import fleet_host_path_rows_total
+
+        # rows of each pass that left the fleet table, by reason, added
+        # once a pass that has such rows
+        self._host_path_tally = {
+            r: fleet_host_path_rows_total.labels(reason=r)
+            for r in HOST_PATH_REASONS
+        }
+        # chunks the general host path ran under the open scheduler.host
+        self._host_chunks = 0
         # per-pass dirty-key set (ISSUE 20): the controller's invalidation
         # sources (watch bus, quota bumps, estimator movement, evictions)
         # accumulate binding keys whose problems changed since the last
@@ -2146,13 +2189,17 @@ class TensorScheduler:
                     for i, (p, cp) in enumerate(zip(problems, compiled))
                     if (cp.fleet_terms or i in selected) and row_rides(p, cp)
                 ]
-                self._report_host_path(problems, compiled, fast_idx)
+                why = self._report_host_path(problems, compiled, fast_idx)
                 took = _time.perf_counter() - t0
                 self.last_breakdown["eligible"] = took
-                _tracer.record(
+                eligible = _tracer.record(
                     "scheduler.eligible", took, start=t0,
                     rows=len(problems), fleet_rows=len(fast_idx),
                 )
+                if why is not None:
+                    eligible.attrs["wide_rows"] = (
+                        why["prev_sites"] + why["replicas"]
+                    )
         if fleet_ok and (
             swap is not None or len(fast_idx) >= self.fleet_threshold
         ):
@@ -2282,47 +2329,48 @@ class TensorScheduler:
             tokens.append(probe() if probe is not None else None)
         return tuple(tokens)
 
-    def _report_host_path(self, problems, compiled, fast_idx) -> None:
+    def _report_host_path(self, problems, compiled, fast_idx):
         """Say how many rows of the batch leave the fleet table for the
-        host path and why: the gauge every batch, a line on stderr once a
-        batch layout (the counts by reason). Each of those rows is packed
-        and solved on the host in every wave."""
+        host path and why: the gauge every batch; where rows leave, the
+        counter by reason (added once a pass) and a line on stderr once a
+        batch layout. Each of those rows is packed and solved on the host
+        in every wave. Returns the counts by reason (HOST_PATH_REASONS),
+        or None where the batch rides whole; only the leaving rows are
+        looked at."""
         from ..utils.metrics import fleet_host_path_rows
 
-        fleet_host_path_rows.set(len(problems) - len(fast_idx))
-        if len(fast_idx) == len(problems):
-            return
-        from .fleet import K_EVICT, T_CAP
+        n = len(problems)
+        fleet_host_path_rows.set(n - len(fast_idx))
+        if len(fast_idx) == n:
+            return None
+        from .fleet import K_EVICT, K_PREV, MAX_REPLICAS_FAST, T_CAP
 
-        rides = set(fast_idx)
-        why = {"terms": 0, "tasks": 0, "terms+spread": 0, "other": 0}
-        for i, (p, cp) in enumerate(zip(problems, compiled)):
-            if i in rides:
-                continue
-            if len(cp.terms) > T_CAP:
-                why["terms"] += 1
-            elif len(p.evict_clusters) > K_EVICT:
-                why["tasks"] += 1
-            elif len(cp.terms) > 1 and not cp.fleet_terms:
-                why["terms+spread"] += 1
-            else:
-                why["other"] += 1
+        leaving = np.ones(n, bool)
+        leaving[fast_idx] = False
+        why = dict.fromkeys(HOST_PATH_REASONS, 0)
+        for i in np.flatnonzero(leaving).tolist():
+            why[_host_path_reason(problems[i], compiled[i])] += 1
+        for reason, count in why.items():
+            if count:
+                self._host_path_tally[reason].inc(count)
         told = tuple(why.values())
         if told != self._host_path_told:
             import sys as _sys
 
             self._host_path_told = told
             print(
-                f"# fleet host path: {len(problems) - len(fast_idx)} of "
-                f"{len(problems)} rows: {why['terms']} with more than "
-                f"{T_CAP} affinity terms, {why['tasks']} with more than "
-                f"{K_EVICT} eviction tasks, {why['terms+spread']} with "
-                f"several terms and spread constraints, {why['other']} "
-                "past another cap (previous sites, replicas, a failed "
-                "host selection)",
+                f"# fleet host path: {n - len(fast_idx)} of {n} rows: "
+                f"{why['terms']} with more than {T_CAP} affinity terms, "
+                f"{why['evict_tasks']} with more than {K_EVICT} eviction "
+                f"tasks, {why['terms_spread']} with several terms and "
+                f"spread constraints, {why['prev_sites']} with more than "
+                f"{K_PREV} previous sites, {why['replicas']} with more "
+                f"than {MAX_REPLICAS_FAST} replicas, {why['selection']} "
+                "with no spread selection",
                 file=_sys.stderr,
                 flush=True,
             )
+        return why
 
     def _report_host_selected(self, rows: int) -> None:
         """Say how many of the batch's spread rows the HOST selects (the
@@ -2511,8 +2559,35 @@ class TensorScheduler:
     ) -> list[ScheduleResult]:
         from ..utils.tracing import tracer
 
-        with tracer.span("scheduler.host", rows=len(problems)):
-            return self._schedule_host_rounds(problems, compiled)
+        # rows, Σ their replicas, the most previous sites of any of them;
+        # chunks = what the rounds dispatched, each chunk's stages a child
+        with tracer.span(
+            "scheduler.host",
+            rows=len(problems),
+            replicas=sum(p.replicas for p in problems),
+            prev_max=max((len(p.prev) for p in problems), default=0),
+        ) as host:
+            self._host_chunks = 0
+            try:
+                return self._schedule_host_rounds(problems, compiled)
+            finally:
+                host.attrs["chunks"] = self._host_chunks
+
+    def _record_chunk_stages(self, rows: int, stages, unpacked) -> None:
+        """One span a stage of a host-path chunk (children of the open
+        scheduler.host), at the intervals scheduling_algorithm_duration
+        observed: pack (Filter), estimate (Score), select (Select), assign
+        (AssignReplicas); unpack runs from the assign's end to
+        ``unpacked``."""
+        from ..utils.tracing import tracer
+
+        self._host_chunks += 1
+        for name, timed in zip(_HOST_STAGES, stages):
+            tracer.record(name, timed.duration, start=timed.start, rows=rows)
+        end = stages[-1].start + stages[-1].duration
+        tracer.record(
+            "scheduler.host.unpack", unpacked - end, start=end, rows=rows
+        )
 
     def _schedule_host_rounds(
         self,
@@ -2868,10 +2943,12 @@ class TensorScheduler:
         compiled: list[CompiledPlacement],
         term_round: int,
     ) -> list[ScheduleResult]:
+        import time as _time
+
         from ..utils.metrics import scheduling_algorithm_duration as algo_timer
 
         snap = self.snapshot
-        with algo_timer.time(schedule_step="Filter"):
+        with algo_timer.time(schedule_step="Filter") as t_pack:
             feasible, strategy, replicas, static_w, requests, prev, fresh = (
                 self._pack_chunk(problems, compiled, term_round)
             )
@@ -2907,7 +2984,7 @@ class TensorScheduler:
         cap_rows = self._quota_cap_rows(problems)
         if cap_rows is not None and padded > b:
             cap_rows = np.pad(cap_rows, (0, padded - b), constant_values=-1)
-        with algo_timer.time(schedule_step="Score"):
+        with algo_timer.time(schedule_step="Score") as t_est:
             avail = (
                 self._availability_np(requests, replicas, cap_rows)
                 if host_small
@@ -2917,7 +2994,7 @@ class TensorScheduler:
         # Select: spread-constraint group selection narrows the candidate set
         from .spread import select_clusters_batch  # local import (cycle-free)
 
-        with algo_timer.time(schedule_step="Select"):
+        with algo_timer.time(schedule_step="Select") as t_sel:
             # avail stays on device unless a row carries spread constraints
             # (select pulls it lazily) — a constraint-free chunk does zero
             # device->host traffic between estimate and assign
@@ -2940,7 +3017,7 @@ class TensorScheduler:
             )
             lmax = int(prev.max(initial=0)) + 1
             host_small = (wmax + 1) * lmax * snap.num_clusters < 2**63
-        with algo_timer.time(schedule_step="AssignReplicas"):
+        with algo_timer.time(schedule_step="AssignReplicas") as t_assign:
             self.solve_batches += 1
             if host_small:
                 from ..refimpl.divider_np import assign_batch_np
@@ -2956,8 +3033,12 @@ class TensorScheduler:
                 )
                 assignment = np.asarray(res.assignment)
                 unschedulable = np.asarray(res.unschedulable)
-        return self._unpack(problems, compiled, term_round, candidates,
-                            assignment, unschedulable)
+        out = self._unpack(problems, compiled, term_round, candidates,
+                           assignment, unschedulable)
+        self._record_chunk_stages(
+            b, (t_pack, t_est, t_sel, t_assign), _time.perf_counter()
+        )
+        return out
 
     def _schedule_chunk_ranked(
         self,
@@ -2971,12 +3052,14 @@ class TensorScheduler:
         predicate), then solve the WHOLE chunk once against the selected
         masks. T ordered fallback groups cost T batched [B, C] reductions
         plus one solve, instead of up to T sequential solves."""
+        import time as _time
+
         from ..ops import masks as mops
         from ..ops.divide import AGGREGATED as S_AGG, DYNAMIC_WEIGHT as S_DYN
         from ..utils.metrics import scheduling_algorithm_duration as algo_timer
 
         snap = self.snapshot
-        with algo_timer.time(schedule_step="Filter"):
+        with algo_timer.time(schedule_step="Filter") as t_pack:
             base, strategy, replicas, static_w, requests, prev, fresh = (
                 self._pack_chunk(problems, compiled, 0, with_affinity=False)
             )
@@ -3025,14 +3108,14 @@ class TensorScheduler:
         cap_rows = self._quota_cap_rows(problems)
         if cap_rows is not None and padded > b:
             cap_rows = np.pad(cap_rows, (0, padded - b), constant_values=-1)
-        with algo_timer.time(schedule_step="Score"):
+        with algo_timer.time(schedule_step="Score") as t_est:
             avail = (
                 self._availability_np(requests, replicas, cap_rows)
                 if host_small
                 else self._availability(requests, replicas, cap_rows)
             )
 
-        with algo_timer.time(schedule_step="Select"):
+        with algo_timer.time(schedule_step="Select") as t_sel:
             avail_np = np.asarray(avail)
             cand_tc = base[:, None, :] & term_stack[cp_idx]
             rank, _fit = mops.first_fit_group(
@@ -3063,7 +3146,7 @@ class TensorScheduler:
             )
             lmax = int(prev.max(initial=0)) + 1
             host_small = (wmax + 1) * lmax * snap.num_clusters < 2**63
-        with algo_timer.time(schedule_step="AssignReplicas"):
+        with algo_timer.time(schedule_step="AssignReplicas") as t_assign:
             self.solve_batches += 1
             if host_small:
                 from ..refimpl.divider_np import assign_batch_np
@@ -3079,8 +3162,12 @@ class TensorScheduler:
                 )
                 assignment = np.asarray(res.assignment)
                 unschedulable = np.asarray(res.unschedulable)
-        return self._unpack(problems, compiled, rank, candidates,
-                            assignment, unschedulable)
+        out = self._unpack(problems, compiled, rank, candidates,
+                           assignment, unschedulable)
+        self._record_chunk_stages(
+            b, (t_pack, t_est, t_sel, t_assign), _time.perf_counter()
+        )
+        return out
 
     def _select_for_chunk(self, problems, compiled, feasible, avail, prev):
         from .spread import select_clusters_batch

@@ -221,6 +221,17 @@ class Gauge:
             yield f"{self.name}{{{label_s}}} {v}" if label_s else f"{self.name} {v}"
 
 
+class Timed:
+    """One interval ``Histogram.time`` observed: ``start`` (a perf_counter
+    stamp) and ``duration`` in seconds, set when the block ends."""
+
+    __slots__ = ("start", "duration")
+
+    def __init__(self) -> None:
+        self.start = 0.0
+        self.duration = 0.0
+
+
 class Histogram:
     kind = "histogram"
 
@@ -245,11 +256,16 @@ class Histogram:
 
     @contextmanager
     def time(self, **labels):
-        start = time.perf_counter()
+        """Observe the block's wall time. Yields the ``Timed`` interval it
+        observed (filled in on exit), so a caller can record the same
+        stretch as a span without reading the clock again."""
+        timed = Timed()
+        timed.start = time.perf_counter()
         try:
-            yield
+            yield timed
         finally:
-            self.observe(time.perf_counter() - start, **labels)
+            timed.duration = time.perf_counter() - timed.start
+            self.observe(timed.duration, **labels)
 
     def snapshot(self) -> dict[str, dict]:
         """{label string: {count, sum}} — buckets are derivable and the
@@ -477,6 +493,17 @@ fleet_host_path_rows = registry.gauge(
     "(K_EVICT = 8), more previous sites or replicas than its caps, or "
     "several terms together with spread constraints. Above 0 each of "
     "those rows is packed and solved on the host in every wave",
+)
+fleet_host_path_rows_total = registry.counter(
+    "karmada_tpu_fleet_host_path_rows_total",
+    "rows of engine passes that left the fleet table for the general host "
+    "path, by the first bound each row passed (counted once): terms (more "
+    "affinity terms than T_CAP = 4), evict_tasks (more eviction tasks "
+    "than K_EVICT = 8), terms_spread (several terms together with spread "
+    "constraints), prev_sites (more previous sites than K_PREV = 32), "
+    "replicas (a Divided row past MAX_REPLICAS_FAST = 128), selection (a "
+    "spread-constrained row given no selection); added once a pass that "
+    "has such rows",
 )
 fleet_placement_slots = registry.gauge(
     "karmada_tpu_fleet_placement_slots",

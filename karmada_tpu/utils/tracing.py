@@ -128,7 +128,9 @@ SPAN_NAMES: dict[str, str] = {
     "scheduler.eligible": (
         "under scheduler.pack: the fleet-eligibility partition of the "
         "batch (a swap diff: the moved positions' look-up and predicate; "
-        "rows = positions visited / fleet_rows attrs)"
+        "rows = positions visited / fleet_rows attrs; where rows leave "
+        "for the host path, wide_rows = those past the previous-site or "
+        "replica bound)"
     ),
     "scheduler.handoff": (
         "from scheduler.pack's end to the fleet table's door: the fleet "
@@ -164,7 +166,32 @@ SPAN_NAMES: dict[str, str] = {
         "namespace and demand). rows / quota_rows / denied / host_rows / "
         "dispatched / generation attrs"
     ),
-    "scheduler.host": "host-path (non-fleet) scheduling of a batch",
+    "scheduler.host": (
+        "host-path (non-fleet) scheduling of a batch (rows / replicas = "
+        "their sum / prev_max = the most previous sites of a row / chunks "
+        "attrs); each chunk's stages are its children"
+    ),
+    "scheduler.host.pack": (
+        "under scheduler.host: one chunk's Filter stage, the dense (B x C) "
+        "inputs packed on the host (rows attr)"
+    ),
+    "scheduler.host.estimate": (
+        "under scheduler.host: one chunk's Score stage, the availability "
+        "estimate (rows attr)"
+    ),
+    "scheduler.host.select": (
+        "under scheduler.host: one chunk's Select stage, the spread "
+        "selection narrowing its candidates (rows attr)"
+    ),
+    "scheduler.host.assign": (
+        "under scheduler.host: one chunk's AssignReplicas stage, the "
+        "division (the dense divide_replicas kernel, or the numpy divider "
+        "for a small chunk) and its fetch (rows attr)"
+    ),
+    "scheduler.host.unpack": (
+        "under scheduler.host: one chunk's answers built from the "
+        "division (rows attr)"
+    ),
     "scheduler.solve": (
         "one fleet-table solve pass (host_rows = rows of the batch that "
         "left it for the host path; rows_visited = positions of the batch "
