@@ -50,7 +50,9 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.decode2.argtypes = [p_u8, i64, p_i32]
     lib.decode21.argtypes = [p_u8, i64, p_i32]
     lib.fold_entries.argtypes = [p_i32, i64, p_i32, p_i64, i64, p_i32]
-    lib.apply_deltas.argtypes = [p_i32, i64, p_i32, p_i64, i64, p_i32, p_i32]
+    lib.apply_deltas.argtypes = [
+        p_i32, i64, p_i32, p_i64, i64, p_i32, p_i32, i64,
+    ]
     return lib
 
 
@@ -106,6 +108,13 @@ def decode2(raw: np.ndarray) -> np.ndarray:
     return out
 
 
+def decode4(raw: np.ndarray) -> np.ndarray:
+    """uint8[4n] little-endian words -> int32[n] (the wire of a table with
+    two-byte cells: entries and cell deltas take 4 bytes)."""
+    n = len(raw) // 4
+    return np.ascontiguousarray(raw[: 4 * n]).view("<i4").astype(np.int32)
+
+
 def decode21(raw: np.ndarray, n: int) -> np.ndarray:
     """21-bit little-endian bitstream -> int32[n]; ``raw`` must extend at
     least 3 bytes past the packed payload (the device wire pads)."""
@@ -158,16 +167,19 @@ def apply_deltas(
     mirror: np.ndarray,  # int32[cap, k_res] C-contiguous
     rows: np.ndarray,  # per delta row (any int dtype)
     dcounts: np.ndarray,  # deltas per row
-    stream: np.ndarray,  # int32 (site<<9 | newcount+1), row order,
-    # site-ascending within each row
+    stream: np.ndarray,  # int32 (site<<(cell_bits+1) | newcount+1), row
+    # order, site-ascending within each row
+    cell_bits: int = 8,  # bits of a count: 8, or 16 for two-byte cells
 ) -> None:
-    """Merge cell deltas into the host mirror's sorted entry runs
-    (newcount 0 removes the site, otherwise set/insert). In-place on
-    ``mirror``; rows are clamped to k_res merged entries like
+    """Merge cell deltas into the host mirror's sorted (site << cell_bits |
+    count) entry runs (newcount 0 removes the site, otherwise set/insert).
+    In-place on ``mirror``; rows are clamped to k_res merged entries like
     fold_entries."""
     k_res = mirror.shape[1]
     lib = get()
     if lib is None or not mirror.flags["C_CONTIGUOUS"]:
+        sb = cell_bits
+        mask, dmask = (1 << sb) - 1, (2 << sb) - 1
         off = 0
         for r, nd in zip(rows, dcounts):
             nd = int(nd)
@@ -176,16 +188,16 @@ def apply_deltas(
             if not nd:
                 continue
             run = mirror[r]
-            sites = {int(v) >> 8: int(v) & 0xFF for v in run if v != 0}
+            sites = {int(v) >> sb: int(v) & mask for v in run if v != 0}
             for v in d:
                 v = int(v)
-                site, cnt = v >> 9, (v & 0x1FF) - 1
+                site, cnt = v >> (sb + 1), (v & dmask) - 1
                 if cnt > 0:
                     sites[site] = cnt
                 else:
                     sites.pop(site, None)
             merged = [
-                (s << 8) | c for s, c in sorted(sites.items())
+                (s << sb) | c for s, c in sorted(sites.items())
             ][:k_res]
             mirror[r] = 0
             mirror[r, : len(merged)] = merged
@@ -198,4 +210,5 @@ def apply_deltas(
         len(rows),
         np.ascontiguousarray(stream, np.int32),
         scratch,
+        cell_bits,
     )

@@ -68,15 +68,19 @@ void fold_entries(int32_t *mirror, int64_t k_res, const int32_t *rows,
     }
 }
 
-/* Cell-delta fold: merge per-row sorted (site<<9 | newcount+1) deltas
- * into the [cap, k_res] host mirror of sorted (site<<8 | count) entry
- * runs. newcount 0 removes the site; an existing site updates in place;
- * a new site inserts in site order. The merged row is clamped to k_res
- * entries (same clamp as fold_entries) and zero-padded. `scratch` must
- * hold k_res int32s. */
+/* Cell-delta fold: merge per-row sorted (site<<(sb+1) | newcount+1)
+ * deltas into the [cap, k_res] host mirror of sorted (site<<sb | count)
+ * entry runs, sb = cell_bits (8, or 16 for a table of two-byte cells).
+ * newcount 0 removes the site; an existing site updates in place; a new
+ * site inserts in site order. The merged row is clamped to k_res entries
+ * (same clamp as fold_entries) and zero-padded. `scratch` must hold k_res
+ * int32s. */
 void apply_deltas(int32_t *mirror, int64_t k_res, const int32_t *rows,
                   const int64_t *dcounts, int64_t n_rows,
-                  const int32_t *stream, int32_t *scratch) {
+                  const int32_t *stream, int32_t *scratch,
+                  int64_t cell_bits) {
+    const int sb = (int)cell_bits;
+    const int32_t dmask = (int32_t)((2 << sb) - 1);
     int64_t off = 0;
     for (int64_t i = 0; i < n_rows; i++) {
         int32_t *row = mirror + (int64_t)rows[i] * k_res;
@@ -86,19 +90,19 @@ void apply_deltas(int32_t *mirror, int64_t k_res, const int32_t *rows,
         if (nd == 0) continue;
         int64_t e = 0, j = 0, out = 0;
         while (e < k_res && row[e] != 0 && j < nd) {
-            int32_t site_e = row[e] >> 8;
-            int32_t site_d = d[j] >> 9;
-            int32_t cnt_d = (d[j] & 0x1FF) - 1;
+            int32_t site_e = row[e] >> sb;
+            int32_t site_d = d[j] >> (sb + 1);
+            int32_t cnt_d = (d[j] & dmask) - 1;
             if (site_e < site_d) {
                 if (out < k_res) scratch[out++] = row[e];
                 e++;
             } else if (site_e > site_d) {
                 if (cnt_d > 0 && out < k_res)
-                    scratch[out++] = (site_d << 8) | cnt_d;
+                    scratch[out++] = (site_d << sb) | cnt_d;
                 j++;
             } else {
                 if (cnt_d > 0 && out < k_res)
-                    scratch[out++] = (site_d << 8) | cnt_d;
+                    scratch[out++] = (site_d << sb) | cnt_d;
                 e++;
                 j++;
             }
@@ -108,9 +112,9 @@ void apply_deltas(int32_t *mirror, int64_t k_res, const int32_t *rows,
             e++;
         }
         for (; j < nd; j++) {
-            int32_t cnt_d = (d[j] & 0x1FF) - 1;
+            int32_t cnt_d = (d[j] & dmask) - 1;
             if (cnt_d > 0 && out < k_res)
-                scratch[out++] = ((d[j] >> 9) << 8) | cnt_d;
+                scratch[out++] = ((d[j] >> (sb + 1)) << sb) | cnt_d;
         }
         memcpy(row, scratch, (size_t)(out * 4));
         memset(row + out, 0, (size_t)((k_res - out) * 4));

@@ -149,8 +149,8 @@ def _first_fit_group_kernel(
     or less, so each element is first cut at replicas + 1. A sum that holds
     a cut element is at least replicas + 1 and answers every comparison as
     the exact sum would; a sum that holds none is exact. In int32 the
-    caller keeps (replicas + 1) x (C + previous sites) of a dynamic row
-    under 2^31 (scheduler.fleet: MAX_REPLICAS_FAST)."""
+    caller keeps twice (replicas + 1) x C of a dynamic row under 2^31
+    (scheduler.fleet.replicas_bound: 65,535 below 16,384 members)."""
     planes = (
         cand_tc if isinstance(cand_tc, (list, tuple))
         else [cand_tc[:, ti, :] for ti in range(cand_tc.shape[1])]

@@ -196,8 +196,7 @@ INSUFFICIENT_ERROR = "clusters available replicas are not enough"
 #: why a row leaves the fleet table for the general host path, in the
 #: order _host_path_reason tests them (a row counts for the first it meets)
 HOST_PATH_REASONS = (
-    "terms", "evict_tasks", "terms_spread", "prev_sites", "replicas",
-    "selection",
+    "terms", "evict_tasks", "terms_spread", "replicas", "selection",
 )
 #: the spans of a host-path chunk's stages timed by
 #: scheduling_algorithm_duration (Filter, Score, Select, AssignReplicas)
@@ -210,9 +209,10 @@ _HOST_STAGES = (
 def _host_path_reason(p, cp: CompiledPlacement) -> str:
     """The bound a row the fleet-eligibility predicate turned away passed
     first: the placement's terms, the binding's eviction tasks, terms
-    beside spread constraints, its previous sites, a Divided row's
-    replicas; else a spread-constrained row that was given no selection."""
-    from .fleet import K_EVICT, K_PREV, MAX_REPLICAS_FAST, S_DUPLICATED, T_CAP
+    beside spread constraints, a Divided row's replicas (past what a cell
+    holds, fleet.replicas_bound); else a spread-constrained row that was
+    given no selection."""
+    from .fleet import K_EVICT, T_CAP, row_rides
 
     if len(cp.terms) > T_CAP:
         return "terms"
@@ -220,9 +220,7 @@ def _host_path_reason(p, cp: CompiledPlacement) -> str:
         return "evict_tasks"
     if len(cp.terms) > 1 and not cp.fleet_terms:
         return "terms_spread"
-    if len(p.prev) > K_PREV:
-        return "prev_sites"
-    if cp.strategy != S_DUPLICATED and p.replicas > MAX_REPLICAS_FAST:
+    if not row_rides(p, cp):
         return "replicas"
     return "selection"
 
@@ -2180,9 +2178,10 @@ class TensorScheduler:
                 # no spread constraints beside several of them; a
                 # spread-constrained single-term row passes through the
                 # selection it was given), the per-binding half
-                # fleet.row_rides (at most K_EVICT eviction tasks and
-                # K_PREV previous sites, Divided replicas within the entry
-                # vector): the one expression _delta_pass applies too.
+                # fleet.row_rides (at most K_EVICT eviction tasks, a
+                # Divided row's counts within a cell of the table; any
+                # previous result): the one expression _delta_pass applies
+                # too.
                 # Rows past it take the host path, row by row, below
                 fast_idx = [
                     i
@@ -2197,9 +2196,7 @@ class TensorScheduler:
                     rows=len(problems), fleet_rows=len(fast_idx),
                 )
                 if why is not None:
-                    eligible.attrs["wide_rows"] = (
-                        why["prev_sites"] + why["replicas"]
-                    )
+                    eligible.attrs["wide_rows"] = why["replicas"]
         if fleet_ok and (
             swap is not None or len(fast_idx) >= self.fleet_threshold
         ):
@@ -2343,7 +2340,7 @@ class TensorScheduler:
         fleet_host_path_rows.set(n - len(fast_idx))
         if len(fast_idx) == n:
             return None
-        from .fleet import K_EVICT, K_PREV, MAX_REPLICAS_FAST, T_CAP
+        from .fleet import K_EVICT, T_CAP, replicas_bound
 
         leaving = np.ones(n, bool)
         leaving[fast_idx] = False
@@ -2363,9 +2360,9 @@ class TensorScheduler:
                 f"{why['terms']} with more than {T_CAP} affinity terms, "
                 f"{why['evict_tasks']} with more than {K_EVICT} eviction "
                 f"tasks, {why['terms_spread']} with several terms and "
-                f"spread constraints, {why['prev_sites']} with more than "
-                f"{K_PREV} previous sites, {why['replicas']} with more "
-                f"than {MAX_REPLICAS_FAST} replicas, {why['selection']} "
+                f"spread constraints, {why['replicas']} with more than "
+                f"{replicas_bound(self.snapshot.num_clusters)} replicas, "
+                f"{why['selection']} "
                 "with no spread selection",
                 file=_sys.stderr,
                 flush=True,

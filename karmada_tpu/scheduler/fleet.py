@@ -15,10 +15,15 @@ device syncs), which capped the engine at ~4k bindings/s while the kernel
 alone did 100k x 5k in 0.74 s. The fleet table removes all per-pass O(B)
 host packing for unchanged bindings and all but one device round-trip.
 
-There is ONE resident layout: the dense assignment, uint8[cap, C], with
-one meta word a row, donated into every pass and updated in place. A table
-whose cap x C would pass DENSE_RESIDENT_MAX_BYTES raises FleetTableTooLarge
-where it grows: there is no second layout to fall back to.
+There is ONE resident layout: the dense assignment, [cap, C] cells, with
+one meta word a row, donated into every pass and updated in place. A cell
+is one byte (uint8) while every resident Divided row asks at most
+NARROW_CELL_MAX replicas, and two (uint16) from the first row that asks
+more: the cell width is the table's, widened once from what its rows
+observe, as its cap grows, and the meta, cell-delta and entry words widen
+with it. A table whose cap x C would pass DENSE_RESIDENT_MAX_BYTES raises
+FleetTableTooLarge where it grows: there is no second layout to fall back
+to.
 
 What the layout minimises: bytes moved between host and device per pass,
 and blocking host<->device round-trips per pass. Where a pass's time goes
@@ -38,7 +43,8 @@ decode 9 ms):
 - DELTA FETCH: _fleet_pass diffs each row's new dense vector against the
   resident and ships home a changed-row bitmask, the changed rows' meta
   words and their changed CELLS (site << 9 | count + 1), folded into a
-  host-side mirror of every row's (site << 8 | count) entry vector. A
+  host-side mirror of every row's (site << 8 | count) entry vector (with
+  two-byte cells site << 17 | count + 1 and site << 16 | count). A
   steady rebalance storm re-divides all 100k bindings on device and
   fetches a few tens of KB; a churn pass ships the cells that moved;
 - rows with more than 62 changed cells, and passes whose deltas overflow
@@ -92,16 +98,28 @@ decode 9 ms):
   division the pass computed for it is ignored), never the row's absence,
   so a quota move keeps the batch, its length and the identity fast path.
 
+- a previous result of more than K_PREV sites is row state too: the row
+  takes a slot of the WIDE table (int32[W, C], the whole previous result
+  as a dense row; W the slots in use, rounded up to a power of two) and
+  its first previous site names the slot (-1 - slot; its K_PREV columns
+  hold nothing else). Every reader of the previous result (_row_masks, so
+  _fleet_pass, _fleet_bits, _fleet_select and _fleet_terms) adds the
+  slot's row to the compare-and-sum of the columns; _fleet_quota reads
+  the row's held total from ``prev_rest``. The table and its argument
+  exist from the first wide row on: a table that never held one runs the
+  traces it ran before the form existed.
+
 Eligibility: a binding rides the fleet path when its placement has at most
 T_CAP affinity terms and, with more than one term, no spread constraints
 (a single-term placement rides with spread constraints or without: a
 FitError of the device selection is the row's empty candidate set; where
 the host selects, the selection must have accepted the row), and the
-binding holds <= K_EVICT eviction tasks, <= K_PREV previous sites, and
-(for Divided strategies) replicas <= MAX_REPLICAS_FAST so the per-row
-entry-vector bound holds. Everything else takes the general host path, row
-by row in the same batch — the two paths are differentially fuzz-tested
-for identical placements.
+binding holds <= K_EVICT eviction tasks and (for Divided strategies)
+replicas <= replicas_bound(C): what a two-byte cell holds
+(MAX_REPLICAS_FAST) below WIDE_CLUSTERS members, what a one-byte cell
+holds from there on. Any previous result rides. Everything else takes the
+general host path, row by row in the same batch — the two paths are
+differentially fuzz-tested for identical placements.
 """
 
 from __future__ import annotations
@@ -160,10 +178,15 @@ _TRACE_KERNELS = {
     "F": "estimate_fold",
 }
 
-K_PREV = 32  # max previous-assignment sites on the fast path (small fleets
-# legitimately spread one binding over dozens of clusters; rows beyond this
-# take the general host path)
-MAX_REPLICAS_FAST = 128  # divided-strategy replica cap (bounds the entry vector)
+K_PREV = 32  # (site, count) columns of a row's previous result (small
+# fleets legitimately spread one binding over dozens of clusters; a row
+# with more takes a slot of the wide table)
+NARROW_CELL_MAX = 0xFF  # the most a one-byte cell of the dense resident holds
+MAX_REPLICAS_FAST = 0xFFFF  # a Divided row's replicas: a two-byte cell holds
+# any of its counts
+WIDE_CLUSTERS = 1 << 14  # two-byte cells need fewer members: the cell-delta
+# word (site << 17 | count + 1) and the term and select kernels' int32 sums
+# ((replicas + 1) x C, twice) hold there
 T_CAP = 4  # ordered affinity terms a row holds as term slots (ClusterAffinities
 # is "primary, then backup": placements past this take the general host path)
 K_EVICT = 8  # graceful-eviction tasks a row holds as cluster indices (a task
@@ -182,17 +205,31 @@ MAX_SLOTS_HARD = 65536  # interning-dict / host-staging sanity bound
 E_ROUND = 1 << 18  # entry-buffer quantum (bounds trace churn)
 
 
+def replicas_bound(c: int) -> int:
+    """The most replicas a Divided row may ask to ride a table of ``c``
+    members: what a two-byte cell holds where its words fit int32 (fewer
+    than WIDE_CLUSTERS members), else what a one-byte cell holds."""
+    return MAX_REPLICAS_FAST if c < WIDE_CLUSTERS else NARROW_CELL_MAX
+
+
 def row_rides(p, cp) -> bool:
     """The per-binding half of THE fleet-eligibility predicate (the
     placement half is CompiledPlacement.fleet_terms, or the selection a
     spread-constrained row was given): the row state has room for the
-    binding's eviction tasks and previous sites, and a Divided row's
-    replicas bound its entry vector."""
-    return (
-        len(p.evict_clusters) <= K_EVICT
-        and len(p.prev) <= K_PREV
-        and (cp.strategy == S_DUPLICATED or p.replicas <= MAX_REPLICAS_FAST)
+    binding's eviction tasks (any previous result has room: past K_PREV
+    sites in a wide slot), and a cell holds a Divided row's counts."""
+    return len(p.evict_clusters) <= K_EVICT and (
+        cp.strategy == S_DUPLICATED
+        or p.replicas <= replicas_bound(len(cp.taint_ok))
     )
+
+
+def _le_bytes(x, n: int):
+    """int32[...] -> uint8[..., n] flattened: the low ``n`` bytes of each
+    value, little-endian (the wire's byte order)."""
+    return jnp.stack(
+        [x & 0xFF] + [(x >> (8 * k)) & 0xFF for k in range(1, n)], axis=-1
+    ).astype(jnp.uint8).reshape(-1)
 
 
 def _pow2(n: int) -> int:
@@ -243,18 +280,16 @@ def _pack21(stream, e_cap: int):
     return ((lo | hi) & 0xFF).astype(jnp.uint8)
 
 
-def _entry_wire(stream, e_cap: int, pack21: bool):
+def _entry_wire(stream, e_cap: int, pack21: bool, cell_bytes: int = 1):
     """The entry stream's byte-wire serialization (_decode_entry_wire is
     its inverse): 21-bit packed (+3 pad bytes for the host's
-    4-byte-window decoder) or plain 3-byte entries."""
+    4-byte-window decoder), plain 3-byte entries, or with two-byte cells
+    4-byte entries."""
     if pack21:
         return jnp.concatenate(
             [_pack21(stream, e_cap), jnp.zeros((3,), jnp.uint8)]
         )
-    return jnp.stack(
-        [stream & 0xFF, (stream >> 8) & 0xFF, (stream >> 16) & 0xFF],
-        axis=-1,
-    ).astype(jnp.uint8).reshape(-1)
+    return _le_bytes(stream, cell_bytes + 2)
 
 
 def _compact_rows(slots, counts, cap: int):
@@ -307,13 +342,32 @@ def _unpack_bits(bits_u8, c: int):
     return x.reshape(bits_u8.shape[0], -1)[:, :c] != 0
 
 
+def _wide_prev_rows(wide_prev, psc):
+    """int32[chunk, C]: each row's previous result kept in a slot of the
+    wide table (its first previous site names the slot as -1 - slot),
+    zeros for a row without one. A caller zeroes the sites of padding."""
+    slot = -1 - psc[:, 0]
+    live = slot >= 0
+    return jnp.where(live[:, None], wide_prev[jnp.where(live, slot, 0)], 0)
+
+
+def _live_sites(ps, valid, wide_prev):
+    """``ps`` with the previous sites of padding zeroed where a wide table
+    is read (a padding position reads row 0, which may name a slot)."""
+    if wide_prev is None:
+        return ps
+    return jnp.where(valid[:, None], ps, 0)
+
+
 def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-               pcc, evc, vc, sbc, chunk: int, c: int):
+               pcc, evc, vc, sbc, chunk: int, c: int, wide_prev=None):
     """Per-chunk previous-assignment grid + THE feasibility algebra,
     shared by every kernel that needs it (_fleet_pass, _fleet_bits) so
     the mask expression cannot drift between the solve and the
     lazily-computed feasibility bitsets. Returns (prev, static_w,
-    feasible); callers apply their own sharding constraints.
+    feasible); callers apply their own sharding constraints. With
+    ``wide_prev`` (the wide table, where the table holds one) a wide
+    row's previous result is its slot's row.
 
     The affinity and taint planes ship BITPACKED (uint8, 8 clusters per
     byte): the per-row cp gather was the second-largest term of the 1M
@@ -336,6 +390,8 @@ def _row_masks(cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
         prev = jnp.where(
             psc[:, :, None] == iota_c, pcc[:, :, None], 0
         ).sum(axis=1, dtype=jnp.int32)
+        if wide_prev is not None:
+            prev = prev + _wide_prev_rows(wide_prev, psc)
     with jax.named_scope("fleet.evict"):
         # the same compare-and-reduce: a row's K_EVICT task sites against
         # the cluster iota (-1 meets no cluster)
@@ -414,7 +470,7 @@ def d_round(v: int) -> int:
     jax.jit,
     static_argnames=(
         "chunk", "n_chunks", "wide", "fast", "has_aggregated",
-        "all_rows", "m_cap", "d_cap", "mesh", "shard_c",
+        "all_rows", "m_cap", "d_cap", "mesh", "shard_c", "cell_bytes",
     ),
     donate_argnames=("res_dense", "res_meta"),
 )
@@ -431,8 +487,9 @@ def _fleet_pass(
     prev_sites, prev_counts,  # int32[cap, K_PREV]
     evict_sites,  # int32[cap, K_EVICT] eviction-task cluster indices (-1 = none)
     sel_bits,  # uint8[cap, W8] bitpacked spread selection a row (ones = none)
-    res_dense,  # uint8[cap, C] last pass's dense assignment (donated)
+    res_dense,  # uint8|uint16[cap, C] last pass's dense assignment (donated)
     res_meta,  # int32[cap] last pass's meta words (donated)
+    wide_prev=None,  # int32[W, C] the wide table, where the table holds one
     *,
     chunk: int,
     n_chunks: int,
@@ -444,6 +501,7 @@ def _fleet_pass(
     d_cap: int = 0,
     mesh=None,
     shard_c: bool = False,
+    cell_bytes: int = 1,
 ):
     """Phase A: divide every row, diff against the dense resident, ship the
     changed bitmask + changed metas — and, when ``d_cap`` > 0, the CELL
@@ -451,10 +509,17 @@ def _fleet_pass(
     so a typical churn pass (a few cells move per changed row) needs no
     phase B at all. Returns (flat_wire_u8, changed_rowbuf, new_res_dense,
     new_res_meta); feasibility bitsets are _fleet_bits' separate, lazily
-    dispatched job."""
+    dispatched job.
+
+    ``cell_bytes`` is the resident's cell width: a count takes ``sb`` = 8
+    or 16 bits, the meta word is n_placed | unsched << sb | has_cand <<
+    sb + 1 (+ the changed-cell count << sb + 2 on the wire, cell_bytes + 1
+    bytes a word), a cell delta site << sb + 1 | count + 1 (cell_bytes + 2
+    bytes)."""
     c = cp_static.shape[1]
     cap = res_dense.shape[0]
     c_ax = "c" if (mesh is not None and shard_c) else None
+    sb = 8 * cell_bytes
     # per-row delta slots: 62 exact + the 63 overflow sentinel fit the
     # meta word's 6 spare bits; rows with more changed cells fall back to
     # a full-row phase B fetch
@@ -474,14 +539,14 @@ def _fleet_pass(
         reps = jnp.where(valid, replicas[r], 0)
         st = strategy[r]
         fr = fresh[r] & valid
-        ps = prev_sites[r]
+        ps = _live_sites(prev_sites[r], valid, wide_prev)
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
         # an all-rows pass reads row i at position i (the padding past the
         # last row is masked by ``valid``), so the eviction sites and the
         # selection masks are sliced from the residents as they lie: no
         # gather
         ev = evict_sites if all_rows else evict_sites[r]
-        sb = sel_bits if all_rows else sel_bits[r]
+        sel = sel_bits if all_rows else sel_bits[r]
 
     def body(carry, i):
         rd, rm = carry
@@ -491,7 +556,7 @@ def _fleet_pass(
             )
             cpc, gvc, pfc = sl(cp), sl(gv), sl(pf)
             repsc, stc, frc, vc = sl(reps), sl(st), sl(fr), sl(valid)
-            psc, pcc, evc, sbc = sl(ps), sl(pc), sl(ev), sl(sb)
+            psc, pcc, evc, sbc = sl(ps), sl(pc), sl(ev), sl(sel)
             rc = sl(r)
             repsc, stc, frc, vc = (
                 shard(repsc, "b"), shard(stc, "b"), shard(frc, "b"),
@@ -505,7 +570,7 @@ def _fleet_pass(
         with jax.named_scope("fleet.masks"):
             prev, static_w, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, evc, vc, sbc, chunk, c,
+                pcc, evc, vc, sbc, chunk, c, wide_prev,
             )
             prev = shard(prev, "b", c_ax)
             feasible = shard(feasible, "b", c_ax)
@@ -524,14 +589,18 @@ def _fleet_pass(
                 jnp.where((stc == S_DUPLICATED)[:, None], 0, assignment),
                 "b", c_ax,
             )
-            # counts <= MAX_REPLICAS_FAST
-            dense8 = assignment.astype(jnp.uint8)
+            # a cell holds every count: the table widens its cells before
+            # a row asks more than a one-byte cell holds (row_rides bounds
+            # the two-byte cell)
+            dense8 = assignment.astype(
+                jnp.uint8 if cell_bytes == 1 else jnp.uint16
+            )
             n_placed = (assignment > 0).sum(axis=1).astype(jnp.int32)
             has_cand = feasible.any(axis=1)
             meta = (
                 n_placed
-                | (unsched.astype(jnp.int32) << 8)
-                | (has_cand.astype(jnp.int32) << 9)
+                | (unsched.astype(jnp.int32) << sb)
+                | (has_cand.astype(jnp.int32) << (sb + 1))
             )
         with jax.named_scope("fleet.diff"):
             # diff + in-place resident update. all_rows reads/writes
@@ -568,7 +637,7 @@ def _fleet_pass(
                     d8, chm = op
                     dp = jnp.where(
                         chm,
-                        (idxs32 << 9) | (d8.astype(jnp.int32) + 1),
+                        (idxs32 << (sb + 1)) | (d8.astype(jnp.int32) + 1),
                         jnp.int32(2**31 - 1),
                     )
                     srt = lax.sort(dp, is_stable=False)[:, :d_slots]
@@ -609,7 +678,7 @@ def _fleet_pass(
         # bits) plus min(dcount, 63) in the 6 spare bits; res_meta stores
         # STATE ONLY — dcount is pass-relative and must not trip the next
         # pass's meta diff.
-        wire_meta = meta | (jnp.minimum(dcounts, 63) << 10)
+        wire_meta = meta | (jnp.minimum(dcounts, 63) << (sb + 2))
         cnt = jnp.cumsum(changed.astype(jnp.int32)) - changed
         total = cnt[-1] + changed[-1].astype(jnp.int32)
         write = jnp.where(changed & (cnt < m_cap), cnt, m_cap)
@@ -631,9 +700,7 @@ def _fleet_pass(
         total_u8 = jnp.stack(
             [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
         ).astype(jnp.uint8)
-        meta_u8 = jnp.stack(
-            [mstream & 0xFF, (mstream >> 8) & 0xFF], axis=-1
-        ).astype(jnp.uint8).reshape(-1)
+        meta_u8 = _le_bytes(mstream, cell_bytes + 1)
         parts = [total_u8, mask_u8, meta_u8]
         if d_cap:
             # cell-delta stream: deltas of changed rows whose dcount fits
@@ -649,12 +716,7 @@ def _fleet_pass(
             dtotal_u8 = jnp.stack(
                 [(dtotal >> s) & 0xFF for s in (0, 8, 16, 24)]
             ).astype(jnp.uint8)
-            d_u8 = jnp.stack(
-                [dstream & 0xFF, (dstream >> 8) & 0xFF,
-                 (dstream >> 16) & 0xFF],
-                axis=-1,
-            ).astype(jnp.uint8).reshape(-1)
-            parts += [dtotal_u8, d_u8]
+            parts += [dtotal_u8, _le_bytes(dstream, cell_bytes + 2)]
         flat = jnp.concatenate(parts)
     return flat, rowbuf, res_dense, res_meta
 
@@ -663,11 +725,11 @@ def _fleet_pass(
     jax.jit,
     static_argnames=(
         "chunk", "n_chunks", "k_out", "e_cap", "byte_wire", "pack21",
-        "mesh",
+        "mesh", "cell_bytes",
     ),
 )
 def _fleet_entries(
-    res_dense,  # uint8[cap, C] — the dense resident phase A just updated
+    res_dense,  # uint8|uint16[cap, C] — the dense resident phase A updated
     rows,  # int32[m_pad] changed table rows (-1 = padding)
     *,
     chunk: int,
@@ -677,11 +739,13 @@ def _fleet_entries(
     byte_wire: bool,
     pack21: bool = False,
     mesh=None,  # the resident's mesh: gathers cross shards; scans replicate
+    cell_bytes: int = 1,
 ):
     """Phase B: sort-compact ONLY the changed rows' dense vectors into the
-    row-major (site << 8 | count) entry stream. Runs at the changed-row
-    count, not the table size."""
+    row-major (site << 8 | count) entry stream (site << 16 | count with
+    two-byte cells). Runs at the changed-row count, not the table size."""
     cap, c = res_dense.shape
+    sb = 8 * cell_bytes
     idxs = jnp.arange(c, dtype=jnp.int32)[None, :]
 
     def body(carry, i):
@@ -692,7 +756,7 @@ def _fleet_entries(
             dense = jnp.where(vc[:, None], dense, 0)
         with jax.named_scope("fleet.compact"):
             packed_full = jnp.where(
-                dense > 0, (idxs << 8) | dense, jnp.int32(2**31 - 1)
+                dense > 0, (idxs << sb) | dense, jnp.int32(2**31 - 1)
             )
             srt = lax.sort(packed_full, is_stable=False)[:, :k_out]
         return carry, jnp.where(srt == 2**31 - 1, 0, srt)
@@ -715,22 +779,25 @@ def _fleet_entries(
             total_u8 = jnp.stack(
                 [(total >> s) & 0xFF for s in (0, 8, 16, 24)]
             ).astype(jnp.uint8)
-            e_u8 = _entry_wire(stream, e_cap, pack21)
+            e_u8 = _entry_wire(stream, e_cap, pack21, cell_bytes)
             return jnp.concatenate([total_u8, e_u8])
         return jnp.concatenate([total[None], stream])
 
 
-def _decode_entry_wire(raw2, cap_used: int, byte_wire: bool, pack21: bool):
+def _decode_entry_wire(
+    raw2, cap_used: int, byte_wire: bool, pack21: bool, cell_bytes: int = 1
+):
     """(total, stream) from a phase-B entry wire buffer."""
     from .. import native
 
     if byte_wire:
         total2 = native.le32(raw2)
-        stream = (
-            native.decode21(raw2[4:], cap_used)
-            if pack21
-            else native.decode3(raw2[4:])
-        )
+        if pack21:
+            stream = native.decode21(raw2[4:], cap_used)
+        elif cell_bytes == 1:
+            stream = native.decode3(raw2[4:])
+        else:
+            stream = native.decode4(raw2[4:])
         return total2, stream
     return int(raw2[0]), raw2[1:]
 
@@ -739,8 +806,8 @@ def _decode_entry_wire(raw2, cap_used: int, byte_wire: bool, pack21: bool):
 def _fleet_bits(
     cp_bits, cp_static, gvk_bits, prof_table, incomplete_en, rows,
     cp_idx, gvk_idx, prof_idx, replicas, strategy, fresh,
-    prev_sites, prev_counts, evict_sites, sel_bits, *, chunk: int,
-    n_chunks: int,
+    prev_sites, prev_counts, evict_sites, sel_bits, wide_prev=None, *,
+    chunk: int, n_chunks: int,
 ):
     """Feasibility bitsets as their own lazily-DISPATCHED kernel: only
     Duplicated / zero-replica rows ever read them (their result IS the
@@ -756,7 +823,7 @@ def _fleet_bits(
         r = jnp.maximum(rows, 0)
         cp = cp_idx[r]
         gv = gvk_idx[r]
-        ps = prev_sites[r]
+        ps = _live_sites(prev_sites[r], valid, wide_prev)
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
         ev = evict_sites[r]
         sb = sel_bits[r]
@@ -769,7 +836,7 @@ def _fleet_bits(
             psc, pcc, sbc = sl(ps), sl(pc), sl(sb)
             _, _, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc, psc,
-                pcc, sl(ev), vc, sbc, chunk, c,
+                pcc, sl(ev), vc, sbc, chunk, c, wide_prev,
             )
             pad = (-c) % 32
             f = jnp.pad(feasible, ((0, 0), (0, pad)))
@@ -801,7 +868,7 @@ def _fleet_select(
     sub_bits, sub_prefix,  # subset_table(R_CAP)
     rows,  # int32[n_pad] the spread-constrained table rows (-1 = padding)
     cp_idx, gvk_idx, prof_idx, replicas, prev_sites, prev_counts,
-    evict_sites, sel_bits, *, chunk: int, n_chunks: int,
+    evict_sites, sel_bits, wide_prev=None, *, chunk: int, n_chunks: int,
 ):
     """The Select stage (SelectClusters) of the batch's spread-constrained
     rows, from the resident row state, written into the resident
@@ -821,7 +888,7 @@ def _fleet_select(
         gv = gvk_idx[r]
         pf = prof_idx[r]
         reps = jnp.where(valid, replicas[r], 0)
-        ps = prev_sites[r]
+        ps = _live_sites(prev_sites[r], valid, wide_prev)
         pc = jnp.where(valid[:, None], prev_counts[r], 0)
         ev = evict_sites[r]
         old = sel_bits[r]
@@ -836,7 +903,7 @@ def _fleet_select(
             cpc, gvc, pfc, repsc, vc = sl(cp), sl(gv), sl(pf), sl(reps), sl(valid)
             prev, _, feasible = _row_masks(
                 cp_bits, cp_static, gvk_bits, incomplete_en, cpc, gvc,
-                sl(ps), sl(pc), sl(ev), vc, unselected, chunk, c,
+                sl(ps), sl(pc), sl(ev), vc, unselected, chunk, c, wide_prev,
             )
             avail = merge_estimates(repsc, (prof_table[pfc],))
             params = sp_params[cpc]
@@ -884,7 +951,7 @@ def _fleet_terms(
     term_slots,  # int32[cap, T_CAP] a row's ordered term slots (-1 = unused)
     term_sel,  # uint8[cap] the index of the term each row was last given
     cp_idx, gvk_idx, prof_idx, replicas, strategy, fresh,
-    prev_sites, prev_counts, evict_sites,
+    prev_sites, prev_counts, evict_sites, wide_prev=None,
     *, chunk: int, n_chunks: int,
 ):
     """Ordered ClusterAffinities on the device: for the batch's multi-term
@@ -916,11 +983,13 @@ def _fleet_terms(
             )
             rc, vc = sl(r), sl(valid)
             ts = term_slots[rc]
-            gvc, psc, evc = gvk_idx[rc], prev_sites[rc], evict_sites[rc]
+            gvc, evc = gvk_idx[rc], evict_sites[rc]
+            psc = _live_sites(prev_sites[rc], vc, wide_prev)
             pcc = jnp.where(vc[:, None], prev_counts[rc], 0)
-            # a Divided row rides the fleet at replicas <= MAX_REPLICAS_FAST;
-            # the cut keeps the sums of the other rows (whose predicate is
-            # "a candidate exists") inside int32 as well
+            # a Divided row rides the fleet at replicas <=
+            # replicas_bound(C), which never passes MAX_REPLICAS_FAST: the
+            # cut changes none of them, and keeps the sums of the other rows
+            # (whose predicate is "a candidate exists") inside int32 as well
             reps = jnp.where(
                 vc, jnp.minimum(replicas[rc], MAX_REPLICAS_FAST), 0
             )
@@ -931,7 +1000,7 @@ def _fleet_terms(
                 prev, _, feasible = _row_masks(
                     cp_bits, cp_static, gvk_bits, incomplete_en,
                     jnp.maximum(slot, 0), gvc, psc, pcc, evc,
-                    vc & (slot >= 0), unselected, chunk, c,
+                    vc & (slot >= 0), unselected, chunk, c, wide_prev,
                 )
                 planes.append(feasible)
             avail = merge_estimates(reps, (prof_table[prof_idx[rc]],))
@@ -962,7 +1031,7 @@ def _fleet_quota(
     prof_reqs,  # int64[P, R] the request vector of each profile slot
     rows,  # int32[n_pad] the batch's table rows in PRESENTED order (-1 = padding)
     ns_idx,  # int32[cap] a row's quota namespace (-1 = not quota'd)
-    prev_lost,  # int32[cap] replicas a row holds on members the snapshot lacks
+    prev_rest,  # int32[cap] replicas a row holds that prev_counts does not
     prof_idx, replicas, prev_counts,  # the resident row state it reads
 ):
     """What ops.quota.quota_admit takes of a batch, derived on the device
@@ -970,8 +1039,9 @@ def _fleet_quota(
     position in ``rows``: the FIFO order of admission, whatever slots the
     table gave the rows): each position's namespace index, and its delta
     demand ``max(replicas - held, 0)`` times its profile's request vector
-    (``held`` = the previous counts the row state keeps + those on members
-    that left the snapshot), clamped to DEMAND_CLAMP as
+    (``held`` = the previous counts the row's columns keep + ``prev_rest``:
+    those on members that left the snapshot, and a wide row's whole
+    previous result, which its columns do not keep), clamped to DEMAND_CLAMP as
     QuotaSnapshot.demand_row clamps it. Where the product would pass the
     clamp (request > DEMAND_CLAMP // delta) the clamp is taken and the
     product never read, so an absurd-but-legal request times a large delta
@@ -984,7 +1054,7 @@ def _fleet_quota(
     with jax.named_scope("fleet.quota"):
         r = jnp.maximum(rows, 0)
         ns = jnp.where(rows >= 0, ns_idx[r], -1)
-        held = prev_counts[r].astype(jnp.int64).sum(axis=1) + prev_lost[r]
+        held = prev_counts[r].astype(jnp.int64).sum(axis=1) + prev_rest[r]
         delta = jnp.maximum(replicas[r].astype(jnp.int64) - held, 0)
         req = prof_reqs[prof_idx[r]]
         fits = DEMAND_CLAMP // jnp.maximum(delta, 1)
@@ -998,14 +1068,12 @@ def _fleet_quota(
         )
 
 
-@jax.jit
-def _gather_meta(res_meta, rows):
+@partial(jax.jit, static_argnames=("cell_bytes",))
+def _gather_meta(res_meta, rows, *, cell_bytes: int = 1):
     """Changed-meta fallback when phase A's tuned meta buffer overflows:
     one cheap gather instead of a full-solve rerun."""
     m = jnp.where(rows >= 0, res_meta[jnp.maximum(rows, 0)], 0)
-    return jnp.stack(
-        [m & 0xFF, (m >> 8) & 0xFF], axis=-1
-    ).astype(jnp.uint8).reshape(-1)
+    return _le_bytes(m, cell_bytes + 1)
 
 
 # row_coupled: the graftlint-dep delta-safety declarations (IR006-
@@ -1121,13 +1189,15 @@ class _FleetBatch:
 
     __slots__ = (
         "names", "host_entries", "rows", "_bits_dev", "_bits_np",
-        "_table", "_gen", "_term_sel",
+        "_table", "_gen", "_term_sel", "cell_bits",
     )
 
     def __init__(self, names, host_entries, rows, bits_dev, table, gen,
-                 term_sel=None):
+                 term_sel=None, cell_bits=8):
         self.names = names
-        self.host_entries = host_entries  # int32[cap, k_out] (site<<8|count)
+        # int32[cap, k_out] (site << cell_bits | count)
+        self.host_entries = host_entries
+        self.cell_bits = cell_bits
         self.rows = rows  # int32[n] table row per result position
         # device uint32[n_pad, W], a zero-arg thunk that DISPATCHES the
         # bitset kernel over this pass's captured inputs (the lazy form —
@@ -1240,8 +1310,10 @@ class FleetResult:
             else:
                 b = self._batch
                 names = b.names
+                sb = b.cell_bits
+                mask = (1 << sb) - 1
                 self._clusters = {
-                    names[int(e) >> 8]: int(e) & 0xFF
+                    names[int(e) >> sb]: int(e) & mask
                     for e in b.entries_for(self._pos)[: self._n]
                 }
         return self._clusters
@@ -1433,7 +1505,10 @@ class _BatchDerived(NamedTuple):
     # the record holds this list, so it is never written in place
     terms: list
     max_n: int  # the largest ``replicas``
-    max_prev: int  # the largest previous count
+    max_prev: int  # the largest previous count (a wide row's slot included)
+    # rows in a wide slot or Divided past a one-byte cell: the rows only the
+    # wide form carries
+    wide_rows: int
     has_agg: bool  # a row divides Aggregated
     is_dup: np.ndarray  # bool[n]: the row is Duplicated
     need_bits: bool  # a row answers by its feasibility bitset
@@ -1558,7 +1633,7 @@ class FleetTable:
         # quota admission from row state (_admit_on_device). The staging's
         # ``ns_idx`` column is derived from ONE QuotaSnapshot.ns_index,
         # kept here (None = no quota set: the column is not kept up).
-        # On the device: (ns_idx, prev_lost) beside the state the pass
+        # On the device: (ns_idx, prev_rest) beside the state the pass
         # reads, with the rows packed since their upload; the profile
         # slots' request vectors, by how many they were. The last verdict
         # (replayed while rows and generation stand), the quota the
@@ -1575,6 +1650,18 @@ class FleetTable:
         self.quota_debit: Optional[np.ndarray] = None
         self._all_rows_dev = None
         self._all_rows_n = -1
+        # the wide form, engaged from what the rows observe: the bytes of a
+        # dense resident cell (2 from the first Divided row past
+        # NARROW_CELL_MAX), and the wide table (host int32[W, C], W a
+        # power of two; None until a row holds more than K_PREV previous
+        # sites) with its free slots, the slots handed out so far, the
+        # slots written since the upload, and its device copy
+        self._cell_bytes = 1
+        self._wide_prev: Optional[np.ndarray] = None
+        self._wide_free: list[int] = []
+        self._wide_n = 0
+        self._wide_dirty: set[int] = set()
+        self._dev_wide = None
         self._dirty: set[int] = set()
         self._tables_dirty = True
         self._avail_max = 0
@@ -1657,7 +1744,11 @@ class FleetTable:
         # placement slots added since the last publish (_pack_rows
         # increments; schedule() counts them and stamps its span)
         self._slots_minted_this_pass = 0
-        from ..utils.metrics import fleet_batch_derived, fleet_upsert_rows
+        from ..utils.metrics import (
+            fleet_batch_derived,
+            fleet_upsert_rows,
+            fleet_wide_rows,
+        )
 
         # what the upsert phase made of the rows of each pass, added once a
         # pass: (same, equal, packed)
@@ -1665,6 +1756,8 @@ class FleetTable:
             fleet_upsert_rows.labels(outcome=o)
             for o in ("same", "equal", "packed")
         )
+        # the rows of each pass in the wide form, added once a pass
+        self._wide_tally = fleet_wide_rows.labels()
         # whether each pass kept or built what it derives from its batch's
         # row state, added once a pass
         self._derived_tally = {
@@ -1799,6 +1892,16 @@ class FleetTable:
         idx = np.asarray(keep, np.int64)
         for name, arr in self._st.items():
             arr[: len(keep)] = arr[idx]
+        # the rows past the kept ones name no wide slot (a row taken there
+        # later must not free one a kept row holds); the slots only the
+        # dropped rows held are free again
+        self._st["prev_sites"][len(keep) : self.n_rows] = 0
+        if self._wide_prev is not None:
+            first = self._st["prev_sites"][: len(keep), 0]
+            held = set((-1 - first[first < 0]).tolist())
+            self._wide_free = [
+                s for s in range(self._wide_n - 1, -1, -1) if s not in held
+            ]
         self._key_row = {p.key: i for i, p in enumerate(self._problems)}
         self.n_rows = len(keep)
         self._dirty.clear()
@@ -1868,10 +1971,11 @@ class FleetTable:
             # quota admission's row state, uploaded BESIDE the state the
             # pass reads (_admit_on_device): the row's namespace index in
             # the quota snapshot (-1 = not quota'd) and the replicas it
-            # holds on members the snapshot does not name (prev_counts
-            # keeps none of those; the demand counts them as held)
+            # holds that prev_counts does not keep: on members the snapshot
+            # does not name, and a wide row's whole previous result (the
+            # demand counts them as held)
             "ns_idx": np.full(new_cap, -1, np.int32),
-            "prev_lost": np.zeros(new_cap, np.int32),
+            "prev_rest": np.zeros(new_cap, np.int32),
         }
         for k, a in self._st.items():
             st[k][: self.cap] = a
@@ -2129,9 +2233,13 @@ class FleetTable:
         prev_count: list = []
         evict_at: list = []
         evict_site: list = []
-        # rows holding replicas on members the snapshot lacks: (i, count)
-        lost_at: list = []
-        lost_n: list = []
+        # rows holding replicas their columns do not: (i, count)
+        rest_at: list = []
+        rest_n: list = []
+        # the wide rows: row -> its slot, and (slot, sites, counts) of each
+        slot_of: dict = {}
+        wide: list = []
+        widen = False
         site_of = snap.index.get
         full = False
         for i, (row, p, cp) in enumerate(zip(rows, problems, compiled)):
@@ -2162,18 +2270,33 @@ class FleetTable:
             profs.append(pslot)
             reps.append(p.replicas)
             fresh.append(p.fresh)
+            widen = widen or (
+                cp.strategy != S_DUPLICATED and p.replicas > NARROW_CELL_MAX
+            )
             if p.prev:
                 sites = list(map(site_of, p.prev))
                 counts = p.prev.values()
+                rest = 0
                 if None in sites:  # a site that left the snapshot
                     held = sum(counts)
                     counts = [
                         c for j, c in zip(sites, counts) if j is not None
                     ]
                     sites = [j for j in sites if j is not None]
-                    lost_at.append(i)
-                    lost_n.append(held - sum(counts))
-                full = full or len(sites) > K_PREV
+                    rest = held - sum(counts)
+                if len(sites) > K_PREV:
+                    # more sites than the row's columns: the whole previous
+                    # result in a slot of the wide table, which the first
+                    # column names; the columns hold none of it
+                    slot = slot_of.get(row)
+                    if slot is None:
+                        slot = slot_of[row] = self._wide_slot(row)
+                    wide.append((slot, sites, counts))
+                    rest += sum(counts)
+                    sites, counts = (-1 - slot,), (0,)
+                if rest:
+                    rest_at.append(i)
+                    rest_n.append(rest)
                 prev_at.extend(range(i * K_PREV, i * K_PREV + len(sites)))
                 prev_site.extend(sites)
                 prev_count.extend(counts)
@@ -2188,12 +2311,13 @@ class FleetTable:
             # a row's cells would run into the next row's (row_rides keeps
             # such a binding off the fleet)
             raise IndexError(
-                f"a binding with more than {K_PREV} previous sites or "
-                f"{K_EVICT} eviction tasks on the snapshot's members has no "
-                "room in a row"
+                f"a binding with more than {K_EVICT} eviction tasks on the "
+                "snapshot's members has no room in a row"
             )
         st = self._st
         at = np.array(rows, np.int64)
+        # the slots the rows held before this call (their first column)
+        first = st["prev_sites"][at, 0]
         of = np.array(pl_of, np.int64)
         term_slots = np.array(pl_slots, np.int32)[of]
         st["term_slots"][at] = term_slots
@@ -2222,16 +2346,63 @@ class FleetTable:
         # _apply_selections for a row the host selected)
         st["sel_bits"][at] = 0xFF
         st["sel_on_dev"][at] = False
-        lost = np.zeros(k, np.int32)
-        lost[lost_at] = lost_n
-        st["prev_lost"][at] = lost
+        rest = np.zeros(k, np.int32)
+        rest[rest_at] = rest_n
+        st["prev_rest"][at] = rest
         if self._ns_src is not None:
             ns_of = self._ns_src.get
             st["ns_idx"][at] = [ns_of(p.namespace, -1) for p in problems]
         if self._dev_quota is not None:
             self._quota_dirty.update(rows)
+        if slot_of or (first < 0).any():
+            self._settle_wide(at, first, slot_of, wide)
+        if widen and self._cell_bytes == 1:
+            # the first Divided row past a one-byte cell: the residents
+            # and the mirrors start again at two bytes a cell
+            self._cell_bytes = 2
+            self._reset_dense()
         self._term_cache = self._derived = None
         self._dirty.update(rows)
+
+    def _wide_slot(self, row: int) -> int:
+        """The wide-table slot for ``row``: the one it holds (its first
+        previous site names it), else a free one, else a new one (the host
+        table grows to the next power of two, at least 16 rows)."""
+        first = int(self._st["prev_sites"][row, 0])
+        if first < 0:
+            return -1 - first
+        if self._wide_free:
+            return self._wide_free.pop()
+        slot = self._wide_n
+        self._wide_n += 1
+        if self._wide_prev is None or slot >= len(self._wide_prev):
+            table = np.zeros(
+                (_pow2(max(16, slot + 1)), self.engine.snapshot.num_clusters),
+                np.int32,
+            )
+            if self._wide_prev is not None:
+                table[: len(self._wide_prev)] = self._wide_prev
+            self._wide_prev = table
+            self._dev_wide = None  # another shape: a whole upload
+        return slot
+
+    def _settle_wide(self, at, first, slot_of: dict, wide: list) -> None:
+        """After a pack: each written wide row's previous result into its
+        slot (marked for upload where it moved), and the slot of every row
+        whose last binding in the pack is narrow freed."""
+        for slot, sites, counts in wide:
+            vec = np.zeros(self._wide_prev.shape[1], np.int32)
+            vec[list(sites)] = list(counts)
+            if not np.array_equal(self._wide_prev[slot], vec):
+                self._wide_prev[slot] = vec
+                self._wide_dirty.add(slot)
+        had = first < 0
+        held = dict(zip(at[had].tolist(), (-1 - first[had]).tolist()))
+        held.update(slot_of)
+        now = self._st["prev_sites"][list(held), 0]
+        self._wide_free.extend(
+            s for s, f in zip(held.values(), now.tolist()) if f >= 0
+        )
 
     def _compact_slots(self) -> None:
         """Drop placement slots no live row references: create/delete
@@ -2636,6 +2807,31 @@ class FleetTable:
                 )
                 self._dev_state = tuple(state)
             self._dirty.clear()
+        if self._wide_prev is not None and (
+            self._dev_wide is None or self._wide_dirty
+        ):
+            self._sync_wide()
+
+    def _sync_wide(self) -> None:
+        """The wide table to the device: whole where it is new or took
+        another shape (or most of it moved), else the slots written since,
+        by one scatter padded to a power of two of slots."""
+        table, dirty = self._wide_prev, self._wide_dirty
+        if self._dev_wide is None or len(dirty) * 2 > len(table):
+            self._dev_wide = self._replicated(table)
+            self._last_upload_bytes += table.nbytes
+        else:
+            slots = np.fromiter(dirty, np.int64, len(dirty))
+            pad = _pow2(len(slots))
+            slots = np.concatenate(
+                [slots, np.full(pad - len(slots), slots[0], np.int64)]
+            )
+            self._mark_trace("S", table.shape, pad, "wide")
+            (self._dev_wide,) = _scatter_rows(
+                (self._dev_wide,), jnp.asarray(slots), (table[slots],)
+            )
+            self._last_upload_bytes += slots.nbytes + table[slots].nbytes
+        dirty.clear()
 
     # -- scheduling --------------------------------------------------------
 
@@ -2731,6 +2927,9 @@ class FleetTable:
             sp.attrs["host_rows"] = int(host_rows)
             sp.attrs["derived"] = self._derived_outcome
             self._derived_tally[self._derived_outcome].inc()
+            sp.attrs["wide_rows"] = wide = self._derived.wide_rows
+            sp.attrs["cell_bytes"] = self._cell_bytes
+            self._wide_tally.inc(wide)
             self._emit_phase_spans()
             mark, self._terms_mark = self._terms_mark, None
             if mark is not None:
@@ -2836,12 +3035,13 @@ class FleetTable:
         args = (
             *self._dev_tables, *self._dev_spread, *self._dev_subsets,
             cache.rows_dev, *(state[k] for k in _SELECT_STATE),
+            *self._wide_args(),
         )
         from ..parallel.mesh import mesh_shape as _mesh_shape
 
         key = (
             "T", self.cap, c, self._dev_tables[0].shape, chunk, n_chunks,
-            _mesh_shape(self._mesh),
+            _mesh_shape(self._mesh), self._wide_shape(),
         )
         if self._mark_trace(*key) and self._mesh is None:
             # meshed dispatches stay manifest-unrecorded, as _fleet_bits'
@@ -2897,12 +3097,13 @@ class FleetTable:
         args = (
             *self._dev_tables, tr.rows_dev, self._dev_term_slots,
             self._dev_term_sel, *state[:-1],  # all but sel_bits
+            *self._wide_args(),
         )
         from ..parallel.mesh import mesh_shape as _mesh_shape
 
         key = (
             "R", self.cap, c, self._dev_tables[0].shape, tr.chunk,
-            tr.n_chunks, _mesh_shape(self._mesh),
+            tr.n_chunks, _mesh_shape(self._mesh), self._wide_shape(),
         )
         if self._mark_trace(*key) and self._mesh is None:
             # meshed dispatches stay manifest-unrecorded, as _fleet_bits'
@@ -2943,6 +3144,16 @@ class FleetTable:
         ]
         self._dev_quota = self._quota_verdict = None
 
+    def _wide_args(self) -> tuple:
+        """The wide table as a kernel's trailing argument: (table,) where
+        the table holds one, else () (the kernel then reads none)."""
+        return () if self._dev_wide is None else (self._dev_wide,)
+
+    def _wide_shape(self) -> Optional[tuple]:
+        """The wide table's shape, as the trace keys carry it (None where
+        the table holds none)."""
+        return None if self._dev_wide is None else self._dev_wide.shape
+
     def _replicated(self, a):
         """``a`` on the device; under a mesh on every device of it, as the
         state the pass gathers from is."""
@@ -2978,7 +3189,7 @@ class FleetTable:
         st = self._st
         if self._dev_quota is None:
             self._dev_quota = tuple(
-                self._replicated(st[k]) for k in ("ns_idx", "prev_lost")
+                self._replicated(st[k]) for k in ("ns_idx", "prev_rest")
             )
             self._last_upload_bytes += 8 * self.cap
         elif self._quota_dirty:
@@ -2992,7 +3203,7 @@ class FleetTable:
             self._mark_trace("S", self.cap, pad, "quota")
             self._dev_quota = _scatter_rows(
                 self._dev_quota, jnp.asarray(rows_p),
-                (st["ns_idx"][rows_p], st["prev_lost"][rows_p]),
+                (st["ns_idx"][rows_p], st["prev_rest"][rows_p]),
             )
             self._last_upload_bytes += 16 * pad
         self._quota_dirty.clear()
@@ -3128,7 +3339,7 @@ class FleetTable:
 
         return {
             "packed_grid": nb(self._dev_state) + nb(self._dev_term_slots)
-            + nb(self._dev_term_sel) + nb(self._dev_quota)
+            + nb(self._dev_term_sel) + nb(self._dev_quota) + nb(self._dev_wide)
             + (nb(self._dev_prof_reqs[1]) if self._dev_prof_reqs else 0),
             "slot_tables": nb(self._dev_tables) + nb(self._dev_spread)
             + nb(self._dev_subsets),
@@ -3228,7 +3439,11 @@ class FleetTable:
                 **{k: int(tmr[k]) for k in ("quota_profiles", "quota_cap_rows")
                    if k in tmr},
             },
-            "prep": {"derived": self._derived_outcome},
+            "prep": {
+                "derived": self._derived_outcome,
+                "wide_rows": self._derived.wide_rows,
+                "cell_bytes": self._cell_bytes,
+            },
             "dispatch": {"compile": fresh} if fresh else {},
             "device": {"compile": fresh},
             "fetch": {
@@ -3264,11 +3479,22 @@ class FleetTable:
         reps_sel = st["replicas"][rows_np]
         strat_sel = st["strategy"][rows_np]
         is_dup = strat_sel == S_DUPLICATED
+        max_prev = int(st["prev_counts"][rows_np].max(initial=0))
+        wide_rows = 0
+        if self._wide_prev is not None or self._cell_bytes > 1:
+            first = st["prev_sites"][rows_np, 0]
+            slots = -1 - first[first < 0]
+            if slots.size:
+                max_prev = max(max_prev, int(self._wide_prev[slots].max()))
+            wide_rows = int(
+                ((first < 0) | (~is_dup & (reps_sel > NARROW_CELL_MAX))).sum()
+            )
         d = self._derived = _BatchDerived(
             rows_np,
             list(map(self._terms.__getitem__, rows_np.tolist())),
             int(reps_sel.max(initial=0)),
-            int(st["prev_counts"][rows_np].max(initial=0)),
+            max_prev,
+            wide_rows,
             bool((strat_sel == AGGREGATED).any()),
             is_dup,
             bool(is_dup.any() or (reps_sel == 0).any()),
@@ -3415,8 +3641,8 @@ class FleetTable:
             mesh=mesh, mesh_el=mesh_el, shard_c=shard_c,
             byte_wire=c <= 0xFFFF,
             # 21-bit entry packing: 2.625 B/entry when the site id fits
-            # 13 bits
-            pack21=c <= (1 << 13), t0=t0,
+            # 13 bits and the count 8
+            pack21=c <= (1 << 13) and self._cell_bytes == 1, t0=t0,
         )
         # this pass dispatched every reuse row, so the mirrors now cover
         # them at the current epoch — the delta-eligibility fence
@@ -3569,16 +3795,17 @@ class FleetTable:
         runs) for the rows of ``d``."""
         meta_sel = self._host_meta[d.rows_np]
         self._result_gen += 1
+        sb = 8 * self._cell_bytes
         batches = [
             _FleetBatch(
                 self.engine.snapshot.names, self._host_entries, d.rows_np,
-                bits_src, self, self._result_gen, self._dev_term_sel,
+                bits_src, self, self._result_gen, self._dev_term_sel, sb,
             )
         ]
         return _FleetResultList(
             problems, d.terms, batches, n_pad,
-            (meta_sel & 0xFF).astype(np.int64), (meta_sel >> 8) & 1,
-            (meta_sel >> 9) & 1, d.is_dup,
+            (meta_sel & ((1 << sb) - 1)).astype(np.int64),
+            (meta_sel >> sb) & 1, (meta_sel >> (sb + 1)) & 1, d.is_dup,
         )
 
     def _bits_src(self, rows_dev, chunk: int, n_chunks: int):
@@ -3589,7 +3816,8 @@ class FleetTable:
         (the row indices on the device). Dispatched at most once per
         batch, on the first feasible/cluster access (_FleetBatch)."""
         _tables = self._dev_tables
-        _state = self._dev_state
+        _state = (*self._dev_state, *self._wide_args())
+        _wide_shape = self._wide_shape()
 
         def bits_src():
             from ..parallel.mesh import mesh_shape as _bits_mesh_shape
@@ -3601,6 +3829,8 @@ class FleetTable:
             key = (
                 "B", chunk, n_chunks, _tables[0].shape,
                 int(rows.shape[0]), int(_state[0].shape[0]),
+                # the wide table, where the batch's table held one
+                _wide_shape,
                 # canonical mesh shape: the bits inputs commit to the
                 # mesh (replicated), so each shape is a distinct
                 # executable — a bool here let a mesh=2 manifest
@@ -3654,7 +3884,7 @@ class FleetTable:
         return (
             "E", self._res_dense.shape[0], self._res_dense.shape[1],
             chunk, n_chunks, k_out, e_cap, byte_wire, pack21,
-            self._resident_mesh,
+            self._resident_mesh, self._cell_bytes,
         )
 
     @property
@@ -3673,7 +3903,7 @@ class FleetTable:
                 "fleet_entries", key, (self._res_dense, rows_dev),
                 chunk=chunk, n_chunks=n_chunks, k_out=k_out, e_cap=e_cap,
                 byte_wire=byte_wire, pack21=pack21,
-                mesh=self._entries_mesh,
+                mesh=self._entries_mesh, cell_bytes=self._cell_bytes,
             )
 
     def _fetch_fold_exact(
@@ -3706,12 +3936,15 @@ class FleetTable:
             byte_wire=byte_wire,
             pack21=pack21 and byte_wire,
             mesh=self._entries_mesh,
+            cell_bytes=self._cell_bytes,
         )
         tmr["dispatch_b"] = time.perf_counter() - t_b
         t_b = time.perf_counter()
         raw2 = np.asarray(flat2)
         tmr["fetch_b"] = time.perf_counter() - t_b
-        total2, stream = _decode_entry_wire(raw2, e_cap, byte_wire, pack21)
+        total2, stream = _decode_entry_wire(
+            raw2, e_cap, byte_wire, pack21, self._cell_bytes
+        )
         assert total2 == e_want, (total2, e_want)
         from .. import native
 
@@ -3731,13 +3964,16 @@ class FleetTable:
         overflow rerun by construction)."""
         d = self._batch_derived(rows_np)
         has_agg, is_all = d.has_agg, d.is_all
+        cb = self._cell_bytes
+        sb = 8 * cb  # bits of a count in the meta and entry words
         if (
             self._res_dense is None
             or self._res_dense.shape != (self.cap, c)
             or self._resident_mesh != mesh_el
         ):
             self._res_dense = self._alloc_resident(
-                (self.cap, c), jnp.uint8, mesh, c_axis=shard_c
+                (self.cap, c), jnp.uint8 if cb == 1 else jnp.uint16, mesh,
+                c_axis=shard_c,
             )
             self._res_meta = self._alloc_resident(
                 (self.cap,), jnp.int32, mesh
@@ -3773,7 +4009,7 @@ class FleetTable:
             return (
                 "A", self.cap, c, self._dev_tables[0].shape, eff_chunk,
                 n_chunks, wide, fast, has_agg, is_all, m, d,
-                mesh_el, shard_c,
+                mesh_el, shard_c, cb, self._wide_shape(),
             )
 
         # cap tuning, demand-based. Every distinct (m_cap, d_cap) pair is a
@@ -3795,7 +4031,9 @@ class FleetTable:
             self._last_changed * 5 // 4 < n
         ):
             needed_m = min(needed_m, m_round(self._last_changed * 5 // 4))
-        d_on = byte_wire and c <= (1 << 15)
+        # the cell-delta word (cb + 2 bytes on the wire, an int32) holds
+        # the site above its sb + 1 count bits
+        d_on = byte_wire and c <= 1 << (min(8 * (cb + 2), 31) - (sb + 1))
         last = self._last_dtotal or 0
         d_need_min = (d_round(last * 9 // 8) if last else D_FLOOR) if d_on else 0
         d_need_tgt = (
@@ -3843,14 +4081,15 @@ class FleetTable:
         self._d_cap_cur = d_cap if d_on else None
 
         t0 = self._phase(tmr, "prep", t0)
+        wide_args = self._wide_args()
         if self._mark_trace(*a_key(m_cap, d_cap)):
             self._record_trace(
                 "fleet_pass", a_key(m_cap, d_cap),
                 (*self._dev_tables, rows_dev, *self._dev_state,
-                 self._res_dense, self._res_meta),
+                 self._res_dense, self._res_meta, *wide_args),
                 chunk=eff_chunk, n_chunks=n_chunks, wide=wide, fast=fast,
                 has_aggregated=has_agg, all_rows=is_all, m_cap=m_cap,
-                d_cap=d_cap, mesh=mesh, shard_c=shard_c,
+                d_cap=d_cap, mesh=mesh, shard_c=shard_c, cell_bytes=cb,
             )
         # the dense residents are DONATED into the pass: detach the
         # attributes first so a dispatch that dies cannot leave deleted-
@@ -3864,6 +4103,8 @@ class FleetTable:
             *self._dev_state,
             rd_in,
             rm_in,
+            *wide_args,
+            cell_bytes=cb,
             chunk=eff_chunk,
             n_chunks=n_chunks,
             wide=wide,
@@ -3916,6 +4157,7 @@ class FleetTable:
                 byte_wire=byte_wire,
                 pack21=pack21 and byte_wire,
                 mesh=self._entries_mesh,
+                cell_bytes=cb,
             )
         t0 = self._phase(tmr, "dispatch", t0)
         if self._quota_pass is not None:
@@ -3953,8 +4195,12 @@ class FleetTable:
         assert len(ch_pos) == total, (len(ch_pos), total)
         ch_rows = rows_np[ch_pos] if total else np.empty(0, np.int64)
         have_dcounts = total <= m_cap
+        # a meta word is cb + 1 bytes on the wire, a cell delta cb + 2
+        decode_meta = native.decode2 if cb == 1 else native.decode3
+        decode_delta = native.decode3 if cb == 1 else native.decode4
+        mb = cb + 1
         if have_dcounts:
-            metas = native.decode2(raw[4 + nb : 4 + nb + 2 * m_cap])[:total]
+            metas = decode_meta(raw[4 + nb : 4 + nb + mb * m_cap])[:total]
         else:
             # tuned buffer overflow (churn onset): one gather round-trip.
             # res_meta stores STATE only, so the per-row delta counts are
@@ -3962,22 +4208,27 @@ class FleetTable:
             m_pad_f = max(4096, _pow2(total))
             rows_f = np.full(m_pad_f, -1, np.int32)
             rows_f[:total] = ch_rows
-            self._mark_trace("G", self.cap, m_pad_f, self._resident_mesh)
+            self._mark_trace(
+                "G", self.cap, m_pad_f, self._resident_mesh, cb
+            )
             mraw = np.asarray(
-                _gather_meta(self._res_meta, jnp.asarray(rows_f))
+                _gather_meta(
+                    self._res_meta, jnp.asarray(rows_f), cell_bytes=cb
+                )
             )
             fetched_bytes += mraw.nbytes
-            metas = native.decode2(mraw)[:total]
+            metas = decode_meta(mraw)[:total]
         self._last_changed = total
-        state = metas & 0x3FF  # n_placed | unsched<<8 | has_cand<<9
-        off_d = 4 + nb + 2 * m_cap
+        # n_placed | unsched << sb | has_cand << sb + 1
+        state = metas & ((1 << (sb + 2)) - 1)
+        off_d = 4 + nb + mb * m_cap
         dtotal = native.le32(raw[off_d : off_d + 4]) if d_cap else None
 
         # fold: cell deltas when they fit, full-row phase B otherwise
         use_delta = False
         if total:
             self._host_meta[ch_rows] = state
-            counts = (state & 0xFF).astype(np.int64)
+            counts = (state & ((1 << sb) - 1)).astype(np.int64)
             e_total = int(counts.sum())
             self._last_total = e_total
             use_delta = bool(
@@ -3985,18 +4236,20 @@ class FleetTable:
             )
             if use_delta:
                 t_b = time.perf_counter()
-                dch = metas >> 10  # min(changed cells, 63) per changed row
+                # min(changed cells, 63) per changed row
+                dch = metas >> (sb + 2)
                 norm = dch <= 62
                 nd_norm = dch[norm].astype(np.int64)
                 assert int(nd_norm.sum()) == dtotal, (
                     int(nd_norm.sum()), dtotal,
                 )
                 if dtotal:
-                    dstream = native.decode3(
-                        raw[off_d + 4 : off_d + 4 + 3 * dtotal]
+                    dstream = decode_delta(
+                        raw[off_d + 4 : off_d + 4 + (cb + 2) * dtotal]
                     )
                     native.apply_deltas(
-                        self._host_entries, ch_rows[norm], nd_norm, dstream
+                        self._host_entries, ch_rows[norm], nd_norm, dstream,
+                        cell_bits=sb,
                     )
                 # decode+merge time only; an overflow-row fetch below
                 # reports its own dispatch_b/fetch_b
@@ -4029,7 +4282,7 @@ class FleetTable:
                     fetched_bytes += raw2.nbytes
                     tmr["fetch_b"] = time.perf_counter() - t_b
                     total2, stream = _decode_entry_wire(
-                        raw2, spec_cap, byte_wire, pack21
+                        raw2, spec_cap, byte_wire, pack21, cb
                     )
                     assert total2 == e_total, (total2, e_total)
                     native.fold_entries(

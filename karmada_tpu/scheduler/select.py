@@ -28,7 +28,8 @@ Integer width: orderings compare exact 32-bit values (credited =
 availability + previous replicas fits uint32); every running sum clamps its
 addends at the threshold it is compared with (a sum of min(a, t) reaches t
 exactly when the sum of a does), and a Divided row rides the fleet only with
-replicas <= MAX_REPLICAS_FAST, so no sum leaves int32.
+replicas <= fleet.replicas_bound(C) (65,535 below 16,384 members, 255 from
+there on), so no sum leaves int32.
 """
 
 from __future__ import annotations

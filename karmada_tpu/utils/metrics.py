@@ -490,7 +490,7 @@ fleet_host_path_rows = registry.gauge(
     "rows of the last batch that left the fleet table for the general host "
     "path: more affinity terms than the table's term slots "
     "(scheduler.fleet.T_CAP = 4), more eviction tasks than its task sites "
-    "(K_EVICT = 8), more previous sites or replicas than its caps, or "
+    "(K_EVICT = 8), a Divided row of more replicas than a cell holds, or "
     "several terms together with spread constraints. Above 0 each of "
     "those rows is packed and solved on the host in every wave",
 )
@@ -500,10 +500,10 @@ fleet_host_path_rows_total = registry.counter(
     "path, by the first bound each row passed (counted once): terms (more "
     "affinity terms than T_CAP = 4), evict_tasks (more eviction tasks "
     "than K_EVICT = 8), terms_spread (several terms together with spread "
-    "constraints), prev_sites (more previous sites than K_PREV = 32), "
-    "replicas (a Divided row past MAX_REPLICAS_FAST = 128), selection (a "
-    "spread-constrained row given no selection); added once a pass that "
-    "has such rows",
+    "constraints), replicas (a Divided row past what a cell of the fleet "
+    "table holds: MAX_REPLICAS_FAST = 65535, or 255 at 16,384 members or "
+    "more), selection (a spread-constrained row given no selection); added "
+    "once a pass that has such rows",
 )
 fleet_placement_slots = registry.gauge(
     "karmada_tpu_fleet_placement_slots",
@@ -525,6 +525,13 @@ fleet_upsert_rows = registry.counter(
     "position of a delta pass), equal (another object of equal content: "
     "pinned, not repacked), packed (new to the table or content moved: row "
     "state rewritten and uploaded); added once a pass",
+)
+fleet_wide_rows = registry.counter(
+    "karmada_tpu_fleet_wide_rows_total",
+    "rows of fleet passes that ride in the table's wide form: a previous "
+    "result of more than K_PREV = 32 sites kept in a slot of the wide "
+    "table, or a Divided row of more replicas than a one-byte cell holds "
+    "(the table's cells two bytes wide); added once a pass",
 )
 fleet_batch_derived = registry.counter(
     "karmada_tpu_fleet_batch_derived_total",

@@ -129,8 +129,7 @@ SPAN_NAMES: dict[str, str] = {
         "under scheduler.pack: the fleet-eligibility partition of the "
         "batch (a swap diff: the moved positions' look-up and predicate; "
         "rows = positions visited / fleet_rows attrs; where rows leave "
-        "for the host path, wide_rows = those past the previous-site or "
-        "replica bound)"
+        "for the host path, wide_rows = those past the replica bound)"
     ),
     "scheduler.handoff": (
         "from scheduler.pack's end to the fleet table's door: the fleet "
@@ -200,7 +199,11 @@ SPAN_NAMES: dict[str, str] = {
         "every position when it is walked; rows_packed of them rewrote "
         "their row state; derived = kept where the pass read what the last "
         "one derived from the batch's row state, built where it derived it "
-        "anew: another row vector, or a row packed since)"
+        "anew: another row vector, or a row packed since; wide_rows = "
+        "rows of the pass in the wide form: more previous sites than a "
+        "row's columns hold, in a slot of the wide table, or Divided past "
+        "a one-byte cell; cell_bytes = the dense resident's cell width, 1 "
+        "or 2)"
     ),
     "scheduler.explain": (
         "armed-only provenance capture of a pass: per-stage mask "
@@ -216,7 +219,8 @@ SPAN_NAMES: dict[str, str] = {
         "(phase=upsert carries rows_visited / rows_packed; phase=sync "
         "quota_profiles / quota_cap_rows where the static-assignment cap "
         "kernel was dispatched for the profile table; phase=prep derived "
-        "= kept|built, as scheduler.solve has it)"
+        "= kept|built, wide_rows and cell_bytes, as scheduler.solve has "
+        "them)"
     ),
     "kernel.dispatch": (
         "kernel dispatch window (sync backends execute inside it; "

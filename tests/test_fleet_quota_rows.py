@@ -429,7 +429,7 @@ def test_replicas_held_on_a_member_that_left_count_as_held(snap):
     res = eng.schedule(problems)
     assert isinstance(res, fleet_mod._FleetResultList)
     rows = eng._fleet._reuse[2]
-    assert int(eng._fleet._st["prev_lost"][rows].sum()) == 4 * len(
+    assert int(eng._fleet._st["prev_rest"][rows].sum()) == 4 * len(
         range(0, len(problems), 3))
     # admission alone: the divider's oracle has no column for such a site
     admitted, _ = oracle(snap, problems, quota, before)
@@ -471,8 +471,11 @@ def test_a_row_that_asks_nothing_is_admitted_behind_the_cut(snap, route):
             problems[i], prev=held, replicas=5 if k % 2 else 4,  # or 1 down
             namespace="t0" if k % 4 < 2 else "t1", fresh=False)
     if route == "partition":
+        # a row off the fleet, in a namespace without a quota (its demand
+        # would hold the front of a quota'd line)
         problems[3] = dataclasses.replace(
-            problems[3], replicas=fleet_mod.MAX_REPLICAS_FAST + 9, prev={})
+            problems[3], replicas=fleet_mod.MAX_REPLICAS_FAST + 9, prev={},
+            namespace="t5")
     quota = make_quota(snap, problems, share=0.3)
     dims = list(snap.dims)
     quota.remaining[quota.ns_index["t1"], dims.index("cpu")] = 0

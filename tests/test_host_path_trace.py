@@ -7,7 +7,7 @@ away leave the fleet table for ``_schedule_host``, which records
   ``.assign`` / ``.unpack``, each with ``rows``) at the very intervals
   ``scheduling_algorithm_duration`` observed;
 - on ``scheduler.eligible``, ``wide_rows``: the leaving rows past the
-  previous-site or replica bound, each counted once;
+  replica bound (what a cell of the fleet table holds), each counted once;
 - ``karmada_tpu_fleet_host_path_rows_total{reason}``, added once a pass.
 
 A batch that rides the fleet table whole records none of it."""
@@ -54,7 +54,7 @@ def _terms(n: int) -> list:
 
 PLAIN = builders.dynamic_weight_placement()
 #: a placement for each reason a placement alone gives; the binding's own
-#: reasons (tasks, sites, replicas) ride on PLAIN
+#: reasons (tasks, replicas) ride on PLAIN
 PLACEMENTS = {
     "terms": builders.dynamic_weight_placement(
         cluster_affinities=_terms(T_CAP + 1)),
@@ -72,7 +72,9 @@ def _problem(rng, key: str, reason: str | None) -> BindingProblem:
     replicas = int(rng.integers(1, 40))
     sites = rng.choice(C, int(rng.integers(0, 9)), replace=False)
     evict = ()
-    if reason == "prev_sites":
+    if reason == "terms":
+        # a previous result wider than a row's columns: the host path
+        # takes it whole (on the fleet it would ride in a wide slot)
         sites = rng.choice(C, K_PREV + 1 + int(rng.integers(0, 4)),
                            replace=False)
     elif reason == "replicas":
@@ -156,7 +158,7 @@ def test_the_host_span_and_its_stages_over_rows_past_every_bound():
             secs, abs=1e-6 * count), step
     assert added == {r: PER_REASON for r in HOST_PATH_REASONS}
     (eligible,) = [s for s in spans if s["name"] == "scheduler.eligible"]
-    assert eligible["attrs"]["wide_rows"] == 2 * PER_REASON
+    assert eligible["attrs"]["wide_rows"] == PER_REASON
     assert eligible["attrs"]["fleet_rows"] == len(problems) - len(leaving)
 
 
@@ -168,7 +170,7 @@ def test_wide_rows_and_the_counter_by_reason(reason, capfd):
     assert added == {r: PER_REASON if r == reason else 0
                      for r in HOST_PATH_REASONS}
     (eligible,) = [s for s in spans if s["name"] == "scheduler.eligible"]
-    wide = PER_REASON if reason in ("prev_sites", "replicas") else 0
+    wide = PER_REASON if reason == "replicas" else 0
     assert eligible["attrs"]["wide_rows"] == wide
     assert metrics.fleet_host_path_rows.value() == PER_REASON
     (line,) = [ln for ln in capfd.readouterr().err.splitlines()
@@ -178,7 +180,6 @@ def test_wide_rows_and_the_counter_by_reason(reason, capfd):
         "terms": f"with more than {T_CAP} affinity terms",
         "evict_tasks": f"with more than {K_EVICT} eviction tasks",
         "terms_spread": "with several terms and spread constraints",
-        "prev_sites": f"with more than {K_PREV} previous sites",
         "replicas": f"with more than {MAX_REPLICAS_FAST} replicas",
         "selection": "with no spread selection",
     }

@@ -430,7 +430,7 @@ def _specs_fleet_quota() -> list:
     shapes = (
         ((_P, _R), "int64"),  # prof_reqs
         ((d["n_pad"],), "int32"),  # the batch's rows, presented order
-        ((cap,), "int32"), ((cap,), "int32"),  # ns_idx prev_lost
+        ((cap,), "int32"), ((cap,), "int32"),  # ns_idx prev_rest
         ((cap,), "int32"), ((cap,), "int32"),  # prof_idx replicas
         ((cap, d["k_prev"]), "int32"),  # prev_counts
     )
