@@ -1030,13 +1030,16 @@ class TensorScheduler:
           dirty keys) moved at an UNMOVED generation: those rows are
           compiled, checked and dispatched, the rest replayed
           (_delta_pass; ``path`` = delta);
-        - swap diff, on the full path: generation AND ``mask_token`` moved
-          (a taint, a label: no answer may be replayed and no compiled
-          placement kept) and a minority of positions moved: the armed
-          batch's distinct placements are compiled anew, the moved
+        - swap diff, on the full path: the generation moved (no answer
+          may be replayed) and a minority of positions moved: the moved
           positions compiled and held to the fleet-eligibility predicate,
           every row dispatched (_swap_diff; ``path`` = full,
-          ``scheduler.pack`` carries ``rows`` visited and ``kept``);
+          ``scheduler.pack`` carries ``rows`` visited and ``kept``). Where
+          ``mask_token`` moved too (a taint, a label: no compiled
+          placement kept) the armed batch's distinct placements are
+          compiled anew; where it stands (only availability drifted) the
+          armed compiled list is kept and the identity branch's sweep
+          reused;
         - the walk, the one general path: no armed batch, another length,
           host-selected or host-path rows in the last pass, a majority of
           positions moved, a moved position that leaves the fleet, a
@@ -1817,14 +1820,14 @@ class TensorScheduler:
         from ..utils.tracing import tracer as _tracer
 
         n = len(problems)
-        diff = None
-        if not (
+        diff = np.flatnonzero(ids != self._batch_ids)
+        replay = not (
             self._fleet is None
             or self.preempt_source is not None
             or self._batch_gen != self._snapshot_gen
             or not self._delta_enabled()
-        ):
-            diff = np.flatnonzero(ids != self._batch_ids)
+        )
+        if replay:
             dk = self._dirty_keys
             if dk:
                 # dirty keys are advisory positions ON TOP of the id diff:
@@ -1841,9 +1844,9 @@ class TensorScheduler:
         # made, the diff and the dirty-key union
         _tracer.record(
             "scheduler.identity", _time.perf_counter() - t0, start=t0,
-            rows=n, hit=0, moved=0 if diff is None else int(diff.size),
+            rows=n, hit=0, moved=int(diff.size),
         )
-        if diff is None or diff.size * 2 > n:
+        if not replay or diff.size * 2 > n:
             return None
         # every other position holds the armed batch's object
         fp2 = list(problems)
@@ -1901,31 +1904,35 @@ class TensorScheduler:
             fc[pos] = cp
         return len(positions), True
 
-    def _swap_diff(self, problems, pack) -> tuple:
+    def _swap_diff(self, problems, pack, ids=None) -> tuple:
         """The full path's diff of a SWAPPED batch against the armed one,
-        where the snapshot generation AND its mask_token moved (a region
-        lost or back: neither the identity branch nor _delta_pass may
-        replay an answer): one id() sweep, and the prologue's work for the
-        moved positions alone. What a moved token leaves standing is what
-        the prologue derives a position from the problem OBJECT (pinned by
-        the armed batch, never mutated in place) and its PLACEMENT
-        (strategy, the term count and the spread constraints are functions
-        of the placement alone): which placement the row names and whether
-        the row rides the fleet. What it does not: a compiled placement.
-        update_snapshot cleared the cache, so every distinct placement of
-        the armed batch is compiled anew (one compile each), the new list
-        is a take over the armed batch's position -> placement index, and
-        the moved positions go through _visit_moved.
+        where the snapshot generation moved (neither the identity branch
+        nor _delta_pass may replay an answer: every row is dispatched):
+        the prologue's work for the moved positions alone. What a moved
+        generation leaves standing is what the prologue derives a position
+        from the problem OBJECT (pinned by the armed batch, never mutated
+        in place) and its PLACEMENT (strategy, the term count and the
+        spread constraints are functions of the placement alone): which
+        placement the row names and whether the row rides the fleet. What
+        a moved ``mask_token`` does not (a region lost or back): a compiled
+        placement. update_snapshot then cleared the cache, so every
+        distinct placement of the armed batch is compiled anew (one compile
+        each) and the new list is a take over the armed batch's position ->
+        placement index; where the token stands (only availability drifted)
+        the distinct placements are look-ups and the armed compiled list is
+        copied. Either way the moved positions go through _visit_moved.
 
-        Returns (ids, hand-off | None): the sweep, to re-arm with and to
-        hand the table, and (fp, fc, the spread-constrained rows'
-        positions | None, the new batch by placement); None where the walk
-        has to run: most positions moved, a placement's flags differ under
-        the new snapshot, spread rows the device cannot select for
-        (regions past R_CAP, extra estimators), a moved position that
-        leaves the fleet. Records scheduler.identity, and for a hand-off
-        pack's three stages (their ``rows``: the positions visited), under
-        the open scheduler.pack span."""
+        ``ids``: the identity branch's sweep where it ran (the token
+        stands), else the sweep is made here. Returns (ids, hand-off |
+        None): the sweep, to re-arm with and to hand the table, and (fp,
+        fc, the spread-constrained rows' positions | None, the new batch by
+        placement); None where the walk has to run: most positions moved, a
+        placement's flags differ under the new snapshot, spread rows the
+        device cannot select for (regions past R_CAP, extra estimators), a
+        moved position that leaves the fleet. Records scheduler.identity
+        where it sweeps, and for a hand-off pack's three stages (their
+        ``rows``: the positions visited), under the open scheduler.pack
+        span."""
         import time as _time
 
         from ..utils.metrics import fleet_host_path_rows
@@ -1933,15 +1940,18 @@ class TensorScheduler:
         from .select import regions_fit
 
         n = len(problems)
+        swept = ids is None
         t_sweep = _time.perf_counter()
-        ids = np.fromiter(map(id, problems), np.int64, n)
+        if swept:
+            ids = np.fromiter(map(id, problems), np.int64, n)
         moved = np.flatnonzero(ids != self._batch_ids)
         k = int(moved.size)
         t_compile = _time.perf_counter()
-        _tracer.record(
-            "scheduler.identity", t_compile - t_sweep, start=t_sweep,
-            rows=n, hit=0, moved=k,
-        )
+        if swept:
+            _tracer.record(
+                "scheduler.identity", t_compile - t_sweep, start=t_sweep,
+                rows=n, hit=0, moved=k,
+            )
         if k * 2 > n:
             return ids, None  # the walk costs no more
         built = self._batch_placements
@@ -1958,6 +1968,7 @@ class TensorScheduler:
                 index,
             )
         placements, flags, index = built
+        # a compile each where the token moved, a look-up where it stands
         cps = [self._compiled(pl) for pl in placements]
         spread_rides = not self.extra_estimators and regions_fit(
             self.snapshot
@@ -1966,9 +1977,13 @@ class TensorScheduler:
             not spread_rides and any(f[1] for f in flags)
         ):
             return ids, None
-        table = np.empty(len(cps), object)
-        table[:] = cps
-        fc = table[index].tolist()
+        if self._batch_token == self.snapshot.mask_token:
+            # the armed list holds the cache's compiled placements
+            fc = list(self._batch_cache[1])
+        else:
+            table = np.empty(len(cps), object)
+            table[:] = cps
+            fc = table[index].tolist()
         t_eligible = _time.perf_counter()
         positions = moved.tolist()
         if not self._visit_moved(problems, positions, fc, spread_rides)[1]:
@@ -2045,7 +2060,7 @@ class TensorScheduler:
         )
         # a batch of the armed batch's length is diffed against it by
         # object identity: below where the base of its answers stands, in
-        # _swap_diff on the full path where that moved
+        # _swap_diff on the full path where the generation moved
         armed = (
             fleet_ok
             and self._batch_ids is not None
@@ -2115,15 +2130,16 @@ class TensorScheduler:
         ) as pack:
             if (
                 armed
-                # generation AND mask_token moved (a batch with
+                # the generation moved, under a moved mask_token or one
+                # whose identity check missed above (a batch with
                 # host-selected rows carries no token: the walk)
-                and not base_stands
+                and self._batch_gen != self._snapshot_gen
                 and self._batch_token is not None
                 and self._fleet is not None
                 and not self._fleet.slots_exhausted
                 and len(problems) >= self.fleet_threshold
             ):
-                ids, swap = self._swap_diff(problems, pack)
+                ids, swap = self._swap_diff(problems, pack, ids)
             if swap is None:
                 t0 = _time.perf_counter()
                 compiled = [self._compiled(p.placement) for p in problems]

@@ -106,7 +106,9 @@ SPAN_NAMES: dict[str, str] = {
         "the batch-identity check: the id() sweep over the batch and, on "
         "a miss, the diff against the armed batch + the dirty keys (rows "
         "/ hit / moved attrs); under scheduler.pack where the full path "
-        "makes it (the swap diff: generation and mask_token moved)"
+        "makes it (the swap diff where generation and mask_token moved; "
+        "under a standing token the swap diff takes the identity "
+        "branch's sweep)"
     ),
     "scheduler.pack": (
         "host prologue of a pass: placement compile + spread selection + "
@@ -118,7 +120,8 @@ SPAN_NAMES: dict[str, str] = {
         "under scheduler.pack: the compiled-placement look-up of every "
         "position visited (a swap diff: the armed batch's distinct "
         "placements compiled anew and the take that lists them by "
-        "position; rows / placements attrs)"
+        "position, or under a standing mask_token their look-ups and the "
+        "armed list copied; rows / placements attrs)"
     ),
     "scheduler.spread": (
         "under scheduler.pack: which rows are spread-constrained and who "

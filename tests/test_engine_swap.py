@@ -15,7 +15,18 @@ held to the fleet-eligibility predicate.
     and ``_compiled`` O(k + distinct placements) times, and sweeps the batch
     once, the table's diff included;
 (j) the counter.
+
+The same route where the generation moved under an UNMOVED
+``mask_token`` (availability alone drifted, the armed batch missed its
+identity check and ``_delta_pass`` declined for the moved generation): the
+identity branch's sweep reused, the armed compiled list kept (no compile),
+the moved positions visited, every row dispatched. (a) and (i) again under
+such a move, a ring of drifts with a minority of swapped objects a step, and
+the route under an active ``QuotaSnapshot`` against the partition route.
 """
+
+import copy
+import dataclasses
 
 import numpy as np
 import pytest
@@ -45,6 +56,7 @@ from test_fleet_failover import (
     _same,
 )
 from test_fleet_upsert import _twin
+import test_fleet_quota_rows as quota_rows
 
 NAMES = [f"m{j:02d}" for j in range(C)]
 N = 400
@@ -56,12 +68,27 @@ def _federation(rng) -> tuple:
     """(healthy, tainted, the lost members' names): region r1 lost."""
     clusters = _clusters(rng, allocated_share=0.3)
     healthy = ClusterSnapshot(clusters)
+    clusters = copy.deepcopy(clusters)  # healthy's members stay untainted
     lost_at = [j for j in range(C) if _place(j)[0] == "r1"]
     for j in lost_at:
         clusters[j].spec.taints = [Taint(key=NOT_READY, effect="NoExecute")]
     tainted = ClusterSnapshot(clusters)
     assert healthy.mask_token != tainted.mask_token
     return healthy, tainted, {NAMES[j] for j in lost_at}
+
+
+def _drifted(snap, rng, low=0.1, high=0.6) -> ClusterSnapshot:
+    """The same members with another share of each one's cpu allocated:
+    availability alone moved, so the mask_token stands."""
+    clusters = copy.deepcopy(snap.clusters)
+    for cl in clusters:
+        summary = cl.status.resource_summary
+        summary.allocated = {"cpu": int(
+            summary.allocatable["cpu"] * rng.uniform(low, high))}
+    out = ClusterSnapshot(clusters)
+    assert out.mask_token == snap.mask_token
+    assert not np.array_equal(out.available_cap, snap.available_cap)
+    return out
 
 
 def _batch(rng, placements, n=N) -> list:
@@ -118,10 +145,14 @@ def _compiled_under(engine, fc) -> None:
             assert np.array_equal(a, b)
 
 
-def _case(rng, name: str) -> tuple:
-    """(placements' batch before, the batch presented after the token
-    moved, positions visited or None for the walk, rows on the host path)."""
+def _case(rng, name: str, move: str = "token") -> tuple:
+    """(the snapshot before, the one after the move, the batch before, the
+    batch presented after it, positions visited or None for the walk, rows
+    on the host path). ``move``: ``token`` (region r1 lost) or ``drift``
+    (availability alone; the lost region's sites still become tasks)."""
     healthy, tainted, lost = _federation(rng)
+    if move == "drift":
+        tainted = _drifted(healthy, rng)
     placements = _placements(rng, terms=(1, 2, 3))
     if name in ("spread-rows", "new-placement"):
         placements += [_placement(rng, s, 1, tol, SPREAD)
@@ -161,12 +192,19 @@ def _case(rng, name: str) -> tuple:
 
 CASES = ("same-list", "minority", "spread-rows", "new-placement",
          "past-k-evict", "all-moved", "another-length")
+# the same list at a moved generation under a standing token is the
+# identity path's, not this route's
+MOVES = [("token", c) for c in CASES] + [
+    ("drift", c) for c in CASES if c != "same-list"]
 
 
-@pytest.mark.parametrize("name", CASES)
-def test_a_swapped_batch_answers_as_a_fresh_engine(name):
+@pytest.mark.parametrize(
+    ("move", "name"), MOVES,
+    ids=[c if m == "token" else f"{m}-{c}" for m, c in MOVES])
+def test_a_swapped_batch_answers_as_a_fresh_engine(move, name):
     rng = np.random.default_rng(36 + CASES.index(name))
-    healthy, tainted, base, after, visited, host_rows = _case(rng, name)
+    healthy, tainted, base, after, visited, host_rows = _case(
+        rng, name, move)
     engine = _engine(healthy)
     engine.schedule(base)
     old_fc = list(engine._batch_cache[1])
@@ -187,9 +225,13 @@ def test_a_swapped_batch_answers_as_a_fresh_engine(name):
         assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (
             visited, n - visited)
     if ident is not None:
-        # one sweep, inside pack, whichever way the pass then went
-        assert ident["parent_id"] == pack["span_id"]
+        # one sweep, whichever way the pass then went: inside pack where
+        # the token moved, the identity branch's before it where it stands
+        parent = pack if move == "token" else root
+        assert ident["parent_id"] == parent["span_id"]
         assert (ident["attrs"]["rows"], ident["attrs"]["hit"]) == (n, 0)
+        assert ident["attrs"]["moved"] == sum(
+            1 for a, b in zip(after, base) if a is not b)
     assert solve["attrs"]["host_rows"] == host_rows
     assert rearm["attrs"]["host_rows"] == host_rows
     assert "eligible" in engine.last_breakdown
@@ -203,7 +245,13 @@ def test_a_swapped_batch_answers_as_a_fresh_engine(name):
         assert root["attrs"]["path"] == "identity"
         fp, fc = engine._batch_cache
         assert all(a is b for a, b in zip(fp, after))
-        assert not {id(cp) for cp in fc} & {id(cp) for cp in old_fc}
+        if move == "token":
+            # no compiled placement of the old token handed on
+            assert not {id(cp) for cp in fc} & {id(cp) for cp in old_fc}
+        else:
+            # the armed compiled list kept: nothing compiled anew
+            assert all(cp is old_fc[i] for i, (cp, p, q) in enumerate(
+                zip(fc, after, base)) if p.placement is q.placement)
         _compiled_under(engine, fc)
     for i, (a, b) in enumerate(zip(again, got)):
         _same(a, b, i)
@@ -246,7 +294,11 @@ def test_a_ring_of_losses_and_returns(seed):
             p.placement for p in problems]
 
 
-def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
+def _count_a_swap_pass(monkeypatch, move: str) -> None:
+    """A swap pass of n rows with k moved, counted: ``row_rides`` k times,
+    ``_compiled`` at most k + the distinct placements + the table's slots,
+    one sweep of the batch (the table's diff and the re-arm included), the
+    table's ``rows_visited`` k."""
     rng = np.random.default_rng(53)
     healthy, tainted, lost = _federation(rng)
     placements = _placements(rng, terms=(1, 2, 3))
@@ -256,14 +308,24 @@ def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
     k = sum(1 for a, b in zip(after, base) if a is not b)
     engine = _engine(healthy)
     engine.schedule(base)
+    if move == "drift":
+        ring = [_drifted(healthy, rng) for _ in range(3)]
+        tainted = ring[-1]
+    else:
+        ring = [tainted, healthy, tainted]
     # one turn of the ring first: the table's first diff after a walk reads
     # the ids of the objects its rows hold (a sweep of its own, once)
-    for snap, problems in ((tainted, after), (healthy, base)):
+    for snap, problems in zip(ring[:2], (after, base)):
         assert engine.update_snapshot(snap)
         engine.schedule(problems)
     slots = len(engine._fleet._cp_pl)
-    calls = {"row_rides": 0, "compiled": 0, "swept": 0}
+    calls = {"row_rides": 0, "compiled": 0, "swept": 0, "compiles": 0}
     rides, compiled = fleet_mod.row_rides, engine._compiled
+    compile_ = core_mod.compile_placement
+
+    def counted_compile(placement, snapshot):
+        calls["compiles"] += 1
+        return compile_(placement, snapshot)
 
     def counted_rides(p, cp):
         calls["row_rides"] += 1
@@ -279,6 +341,7 @@ def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
 
     monkeypatch.setattr(fleet_mod, "row_rides", counted_rides)
     monkeypatch.setattr(engine, "_compiled", counted_compiled)
+    monkeypatch.setattr(core_mod, "compile_placement", counted_compile)
     monkeypatch.setattr(core_mod, "id", counted_id, raising=False)
     monkeypatch.setattr(fleet_mod, "id", counted_id, raising=False)
     assert engine.update_snapshot(tainted)
@@ -290,11 +353,24 @@ def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
     # the moved positions, the armed batch's distinct placements, and the
     # table's own recompile of its slots
     assert calls["compiled"] <= k + len(placements) + slots
+    # a standing token compiles nothing: the armed list and look-ups
+    assert calls["compiles"] == (
+        0 if move == "drift" else len(placements))
     assert calls["swept"] == n  # one sweep: none in the table, none to re-arm
     (solve,) = _spans("scheduler.solve")
     assert solve["attrs"]["rows_visited"] == k
     monkeypatch.undo()
     _same_as_fresh(tainted, after, got)
+
+
+def test_a_swap_pass_costs_its_moved_positions(monkeypatch):
+    _count_a_swap_pass(monkeypatch, "token")
+
+
+def test_a_drifted_swap_pass_costs_its_moved_positions(monkeypatch):
+    """Under a standing token: no placement compiles (look-ups alone), and
+    the sweep is the identity branch's."""
+    _count_a_swap_pass(monkeypatch, "drift")
 
 
 def test_the_counter_tells_kept_from_visited():
@@ -321,3 +397,125 @@ def test_the_counter_tells_kept_from_visited():
     swapped = tally()
     assert {o: swapped[o] - walked[o] for o in walked} == {
         "kept": N - k, "visited": k}
+
+
+@pytest.mark.parametrize("seed", [5, 17])
+def test_a_ring_of_drifts_with_swapped_objects(seed):
+    """Availability drifts a step (the token stands); in each step a seeded
+    minority of positions holds a rescaled copy of its binding, and the
+    previous step's copies go back to their base objects (the ClusterLoader2
+    scale phase): spread-constrained, multi-term and Divided rows among
+    them. Every pass answers as a fresh engine; its prologue visits the
+    positions that hold another object than the armed batch's; the batch
+    by placement is built once and kept; the same list again is an identity
+    pass."""
+    rng = np.random.default_rng(seed)
+    healthy, _, _ = _federation(rng)
+    placements = _placements(rng, terms=(1, 2, 3)) + [
+        _placement(rng, s, 1, tol, SPREAD)
+        for s in ("dynamic", "aggregated", "duplicated")
+        for tol in (False, True)]
+    base = _batch(rng, placements)
+    engine = _engine(healthy)
+    engine.schedule(base)
+
+    def tally():
+        return [metrics.scheduler_prologue_rows.value(outcome=o)
+                for o in ("kept", "visited")]
+
+    armed, built, kinds = base, [], set()
+    for step in range(6):
+        snap = _drifted(healthy, rng)
+        at = set(rng.choice(N, int(rng.integers(N // 20, N // 6)),
+                            replace=False).tolist())
+        problems = [
+            _twin(p, replicas=max(1, p.replicas * int(rng.integers(1, 4))
+                                  // 2))
+            if i in at else p for i, p in enumerate(base)]
+        moved = [i for i, (a, b) in enumerate(zip(problems, armed))
+                 if a is not b]
+        k = len(moved)
+        assert 0 < k * 2 < N
+        kinds |= {(len(problems[i].placement.cluster_affinities) > 1,
+                   bool(problems[i].placement.spread_constraints),
+                   problems[i].placement.replica_scheduling
+                   .replica_scheduling_type) for i in moved}
+        before = tally()
+        assert engine.update_snapshot(snap)
+        tracer.clear()
+        got = _copy_out(engine.schedule(problems))
+        (root,) = _spans("scheduler.schedule")
+        assert root["attrs"]["path"] == "full"
+        (ident,) = _spans("scheduler.identity")
+        assert ident["parent_id"] == root["span_id"]
+        assert (ident["attrs"]["hit"], ident["attrs"]["moved"]) == (0, k)
+        (pack,) = _spans("scheduler.pack")
+        assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (k, N - k)
+        (solve,) = _spans("scheduler.solve")
+        assert solve["attrs"]["rows_visited"] == k
+        assert [b - a for a, b in zip(before, tally())] == [N - k, k]
+        _same_as_fresh(snap, problems, got)
+        built.append(engine._batch_placements)
+        if step % 2:
+            tracer.clear()
+            again = _copy_out(engine.schedule(problems))
+            (root,) = _spans("scheduler.schedule")
+            assert root["attrs"]["path"] == "identity"
+            for i, (a, b) in enumerate(zip(again, got)):
+                _same(a, b, i)
+        armed = problems
+    # multi-term, spread-constrained and Divided rows were among the moved
+    assert {m for m, _, _ in kinds} == {False, True}
+    assert {sp for _, sp, _ in kinds} == {False, True}
+    assert "Divided" in {t for _, _, t in kinds}
+    # built from the armed compiled list by the first such pass, then kept
+    assert all(b is not None and b[0] is built[0][0] for b in built)
+    assert [built[0][0][j] for j in built[-1][2].tolist()] == [
+        p.placement for p in armed]
+
+
+def test_a_drifted_swap_under_quota_answers_as_the_partition_route():
+    """The route under an active QuotaSnapshot: the batch rides the table
+    whole and is admitted from its row state, FIFO over the presented
+    order, as the partition route admits and places it (a fresh engine held
+    under the fleet threshold) and as the reference does."""
+    rng = np.random.default_rng(42)
+    snap = ClusterSnapshot([
+        quota_rows.new_cluster(f"m{i:02d}", cpu=str(600 + 40 * (i % 5)),
+                               memory="4000Gi", pods=100_000)
+        for i in range(quota_rows.C)
+    ])
+    base = quota_rows.build_problems(snap)
+    engine = quota_rows.engine(snap)
+    engine.set_quota(quota_rows.make_quota(snap, base, generation=1))
+    engine.schedule(base)
+    drifted = _drifted(snap, rng, 0.05, 0.3)
+    after = [dataclasses.replace(p, replicas=p.replicas + 3)
+             if i % 7 == 3 else p for i, p in enumerate(base)]
+    k = sum(1 for a, b in zip(after, base) if a is not b)
+    quota = quota_rows.make_quota(drifted, after, generation=2)
+    before = quota.remaining.copy()
+    resident = quota_rows.route_count("resident")
+    assert engine.update_snapshot(drifted)
+    engine.set_quota(quota)
+    tracer.clear()
+    got = engine.schedule(after)
+    assert quota_rows.route_count("resident") == resident + 1
+    (root,) = _spans("scheduler.schedule")
+    assert root["attrs"]["path"] == "full"
+    (pack,) = _spans("scheduler.pack")
+    assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (
+        k, len(after) - k)
+    (span,) = _spans("scheduler.quota")
+    assert span["attrs"]["host_rows"] == 0
+    denied = quota_rows.assert_wave(drifted, after, got, quota, before)
+    assert denied
+    partition = quota_rows.route_count("partition")
+    ref = quota_rows.engine(drifted)
+    ref.fleet_threshold = 10 ** 9  # every batch under it: the partition
+    ref.set_quota(quota_rows.make_quota(drifted, after, generation=2))
+    want = ref.schedule(after)
+    assert quota_rows.route_count("partition") == partition + 1
+    for i, (a, b) in enumerate(zip(got, want)):
+        assert (a.key, a.error) == (b.key, b.error), i
+        assert dict(a.clusters) == dict(b.clusters), i

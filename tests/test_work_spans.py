@@ -22,10 +22,16 @@ ISSUE 36: under a moved ``mask_token`` the full path diffs a batch of the
 armed batch's length: the sweep is a ``scheduler.identity`` child of
 ``scheduler.pack``, the stages' ``rows`` are the positions visited, and a
 pass sweeps the batch once (``scheduler.rearm`` makes no sweep).
+
+Where the generation moved under a standing ``mask_token`` the
+same diff takes the identity branch's sweep: ``scheduler.identity`` is the
+root's child before ``scheduler.pack``, and pack holds the other three
+stages.
 """
 
 from __future__ import annotations
 
+import copy
 import gc
 import itertools
 import threading
@@ -253,11 +259,9 @@ def _drive_full_token(eng, problems):
     return problems, wave
 
 
-def _drive_full_swapped(eng, problems):
-    """A moved token and a new list in which 30 positions hold new objects
-    of equal content: the swap diff visits those."""
-    eng.schedule(problems)
-    snap = _moved_token(eng)
+def _swapped(problems) -> list:
+    """A new list in which 30 positions hold new objects of equal
+    content."""
     batch = list(problems)
     for i in range(0, 30 * 7, 7):
         p = batch[i]
@@ -265,6 +269,46 @@ def _drive_full_swapped(eng, problems):
             key=p.key, placement=p.placement, replicas=p.replicas,
             requests=dict(p.requests), gvk=p.gvk, prev=dict(p.prev),
             fresh=p.fresh)
+    return batch
+
+
+def _drive_full_swapped(eng, problems):
+    """A moved token and a new list in which 30 positions hold new objects
+    of equal content: the swap diff visits those."""
+    eng.schedule(problems)
+    snap = _moved_token(eng)
+    batch = _swapped(problems)
+
+    def wave():
+        assert eng.update_snapshot(snap)
+        return eng.schedule(batch)
+    return batch, wave
+
+
+def _drifted(eng) -> ClusterSnapshot:
+    """The engine's members with other cpu allocations: availability alone
+    moved, the filter fields stand."""
+    clusters = copy.deepcopy(eng.snapshot.clusters)
+    for j, cl in enumerate(clusters):
+        summary = cl.status.resource_summary
+        summary.allocated = dict(
+            summary.allocated,
+            cpu=summary.allocatable["cpu"] * (1 + j % 5) // 10)
+    snap = ClusterSnapshot(clusters)
+    assert snap.mask_token == eng.snapshot.mask_token
+    return snap
+
+
+def _drive_full_drifted(eng, problems):
+    """Availability drifted and 30 positions hold new objects of equal
+    content: the identity branch misses, the delta declines for the moved
+    generation, the swap diff visits the 30 with the branch's sweep."""
+    # packed anew from the members as they stand (the moved-token case
+    # takes its taint off the shared objects again)
+    assert eng.update_snapshot(ClusterSnapshot(eng.snapshot.clusters))
+    eng.schedule(problems)
+    snap = _drifted(eng)
+    batch = _swapped(problems)
 
     def wave():
         assert eng.update_snapshot(snap)
@@ -301,6 +345,12 @@ PATHS = {
         _drive_full_swapped, "full",
         ["scheduler.pack", "scheduler.handoff", "scheduler.solve",
          "scheduler.rearm"]),
+    # a moved generation under a standing token: the identity branch's
+    # sweep and diff first, then the swap diff's stages in pack
+    "full-drifted": (
+        _drive_full_drifted, "full",
+        ["scheduler.identity", "scheduler.pack", "scheduler.handoff",
+         "scheduler.solve", "scheduler.rearm"]),
     # the id() sweep and the delta's check of the one moved row come first:
     # that row left the fleet-eligible set, so the whole prologue runs
     "full-host-row": (
@@ -347,6 +397,13 @@ class TestEngineWave:
             assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 1)
             tried, _ = [s for s in kids if s.name == "scheduler.pack"]
             assert tried.attrs["rows"] == 1  # the position it visited
+        elif case == "full-drifted":
+            [sp] = ident
+            assert sp.parent_id == root.span_id
+            assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 30)
+            [pack] = [s for s in kids if s.name == "scheduler.pack"]
+            assert (pack.attrs["rows"], pack.attrs["kept"]) == (
+                30, len(batch) - 30)
         else:
             # a moved token: the full path's own sweep and diff, in pack
             [sp] = ident
@@ -359,7 +416,8 @@ class TestEngineWave:
         assert all(s.attrs["rows"] == len(batch) for s in ident)
 
     @pytest.mark.parametrize(
-        "case", ["full-moved-token", "full-swapped", "full-host-row"])
+        "case",
+        ["full-moved-token", "full-swapped", "full-host-row", "full-drifted"])
     def test_the_full_prologue_is_staged_under_pack(self, engine, case):
         eng, problems = engine
         drive, _, _ = PATHS[case]
@@ -372,7 +430,7 @@ class TestEngineWave:
         # the positions the prologue visited: every one where it walked,
         # those holding another object where it diffed the batch
         visited = {"full-moved-token": 0, "full-swapped": 30,
-                   "full-host-row": len(batch)}[case]
+                   "full-host-row": len(batch), "full-drifted": 30}[case]
         assert (pack.attrs["rows"], pack.attrs["kept"]) == (
             visited, len(batch) - visited)
         stages = _children(spans, pack)
@@ -380,6 +438,12 @@ class TestEngineWave:
         if host_rows:
             assert [s.name for s in stages] == list(STAGES)
             compile_, spread, eligible = stages
+        elif case == "full-drifted":
+            # the sweep was the identity branch's, before pack
+            assert [s.name for s in stages] == SWAP_STAGES[1:]
+            compile_, eligible, spread = stages
+            assert compile_.attrs["placements"] == len(
+                {id(p.placement) for p in batch})
         else:
             assert [s.name for s in stages] == SWAP_STAGES
             ident, compile_, eligible, spread = stages
