@@ -1391,34 +1391,26 @@ def run_engine_north_star(args) -> dict:
             tier_status["scale-1M"] = f"error: {m_bad} mismatches"
         del m_engine, m_res
         gc.collect()
-        # bit-identity oracle for the row-churn tiers: a fresh engine with
-        # the delta path killed (KARMADA_TPU_DELTA_SOLVE=0) full-solves
-        # each tier's final problem state; every row's placement must hash
-        # identical to what the delta passes returned.
-        saved_delta = os.environ.get("KARMADA_TPU_DELTA_SOLVE")
-        os.environ["KARMADA_TPU_DELTA_SOLVE"] = "0"
-        try:
+        # bit-identity oracle for the row-churn tiers: a fresh engine (no
+        # armed batch: it walks) full-solves each tier's final problem
+        # state; every row's placement must hash identical to what the
+        # delta passes returned.
+        for label, o_probs, digests in m_churn_states:
             o_engine = TensorScheduler(snap, chunk_size=args.chunk)
-            for label, o_probs, digests in m_churn_states:
-                t0 = time.perf_counter()
-                o_res = o_engine.schedule(o_probs)
-                o_dig = _digest_rows(o_res, b_m)
-                bad = int(np.count_nonzero(o_dig != digests))
-                print(
-                    f"# 1M row-churn {label} oracle: full solve "
-                    f"{time.perf_counter() - t0:.1f}s, {bad} rows diverge",
-                    file=sys.stderr,
-                )
-                assert bad == 0, (
-                    f"row-churn {label}: {bad} placements diverge from the "
-                    "full-solve oracle"
-                )
+            t0 = time.perf_counter()
+            o_res = o_engine.schedule(o_probs)
+            o_dig = _digest_rows(o_res, b_m)
+            bad = int(np.count_nonzero(o_dig != digests))
+            print(
+                f"# 1M row-churn {label} oracle: full solve "
+                f"{time.perf_counter() - t0:.1f}s, {bad} rows diverge",
+                file=sys.stderr,
+            )
+            assert bad == 0, (
+                f"row-churn {label}: {bad} placements diverge from the "
+                "full-solve oracle"
+            )
             del o_engine, o_res
-        finally:
-            if saved_delta is None:
-                os.environ.pop("KARMADA_TPU_DELTA_SOLVE", None)
-            else:
-                os.environ["KARMADA_TPU_DELTA_SOLVE"] = saved_delta
         del m_problems, m_churn_states
         gc.collect()
         return {

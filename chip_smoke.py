@@ -390,7 +390,7 @@ def stage_engine(
     fleet = engine._fleet
     _check(fleet is not None, "the fleet table never engaged")
     _check(
-        engine._batch_ids is not None and len(engine._batch_ids) == b,
+        fleet.batch.armed and len(fleet.batch.problems) == b,
         f"not all {b} rows rode the fleet table",
     )
     platform = jax.devices()[0].platform
@@ -504,7 +504,7 @@ def stage_engine(
     timings["policy_passes_s"] = time.perf_counter() - t0
     # the spread rows' Select stage ran on the device (the fleet table's
     # own kernel) unless the federation holds more regions than its table
-    pol_select = pol_eng._fleet._select_cache
+    pol_select = pol_eng._fleet.batch.select_rows
     pol_device_rows = pol_select.n if pol_select is not None else 0
     _check(
         pol_device_rows > 0 or pol_eng._fleet._dev_spread is None,
@@ -520,7 +520,7 @@ def stage_engine(
     )
     # the rows under ordered affinity terms had their term chosen on the
     # device (the fleet table's term kernel), at this stage's width
-    pol_terms = pol_eng._fleet._term_cache
+    pol_terms = pol_eng._fleet.batch.terms
     failover_rows = pol_terms.n if pol_terms is not None else 0
     _check(
         failover_rows == sum(
@@ -536,7 +536,7 @@ def stage_engine(
     mem = jax.devices()[0].memory_stats() or {}
     return {
         "shape": f"{b}x{c}",
-        "rows_on_fleet": f"{len(engine._batch_ids)}/{b}",
+        "rows_on_fleet": f"{len(fleet.batch.problems)}/{b}",
         "buffer_platform": fleet._buffer_platform(),
         "settle_passes": settle,
         "numpy_checked": len(np_idx) + len(dirty) + len(churn_idx),

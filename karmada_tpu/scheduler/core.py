@@ -248,7 +248,8 @@ def _placement_flags(cp: CompiledPlacement) -> tuple:
     placement half of the fleet-eligibility predicate, whether the Select
     stage selects for it, and the strategy fleet.row_rides asks. Functions
     of the PLACEMENT alone, so a snapshot swap leaves them standing (the
-    swap diff compares them to be told if that ever stops being so)."""
+    moved-positions pass compares them to be told if that ever stops being
+    so)."""
     return cp.fleet_terms, cp.spread_single_term, cp.strategy
 
 
@@ -327,27 +328,11 @@ class TensorScheduler:
         ] = OrderedDict()
         # device-resident fleet table (scheduler.fleet): engaged for large
         # batches of fleet-eligible bindings; generation counter lets the
-        # table detect in-place snapshot swaps (update_snapshot)
+        # table detect in-place snapshot swaps (update_snapshot). The table
+        # holds the record of the batch it last scheduled (``batch``),
+        # which schedule() diffs a later batch against once it is armed
         self._fleet = None
         self._snapshot_gen = 0
-        # batch-identity fast path (see schedule()): id() array of the last
-        # all-fleet batch + the compiled lists; _batch_problems pins the
-        # problem objects so a recycled id() cannot alias a stale batch
-        self._batch_ids: Optional[np.ndarray] = None
-        self._batch_gen = -1
-        self._batch_cache: Optional[tuple] = None
-        self._batch_problems: Optional[list] = None
-        # snapshot.mask_token at cache time; None where the batch holds
-        # rows the HOST selected (their selection follows the capacities,
-        # so such a batch is not reused across a generation move)
-        self._batch_token = None
-        # the armed batch by placement, for the swap diff (_swap_diff):
-        # (distinct placements, each one's (fleet_terms,
-        # spread_single_term, strategy), intp[n] position -> placement).
-        # None until a swap diff needs it: built from the armed compiled
-        # list then, kept current by the swap diffs after it, dropped by
-        # every other pass that arms a batch
-        self._batch_placements: Optional[tuple] = None
         from ..utils.metrics import scheduler_prologue_rows
 
         # positions of each full-path pass the prologue (kept, visited),
@@ -382,12 +367,9 @@ class TensorScheduler:
         # sources (watch bus, quota bumps, estimator movement, evictions)
         # accumulate binding keys whose problems changed since the last
         # wave; schedule() stages them here and the batch-identity diff
-        # unions them with the id()-diff to form the delta positions.
+        # unions them with the id()-diff to form the moved positions.
         # None = caller supplied no dirty info (diff alone decides).
         self._dirty_keys: Optional[set] = None
-        # key -> position map of the armed batch (lazily built, only when
-        # dirty keys need resolving against a large wave)
-        self._key_pos: Optional[dict] = None
         # binding key -> (row fingerprint, pinned placement, selection
         # bits | None): the last SelectClusters result of a spread row the
         # HOST selected (a snapshot with more regions than the device
@@ -567,11 +549,7 @@ class TensorScheduler:
             old.cap_token if old is not None and old.cap_index else None
         )
         if new_tok != old_tok:
-            self._fleet = None
-            self._batch_ids = None
-            self._batch_cache = None
-            self._batch_problems = None
-            self._batch_placements = None
+            self._fleet = None  # and the batch it held
             self._quota_cache = None
             self._caps_dev = None
             self._caps_dev_token = None
@@ -822,7 +800,7 @@ class TensorScheduler:
             # cache the all-admitted outcome: a steady storm re-passing
             # the same wave skips the demand rebuild and the kernel.
             # The problems list is PINNED so a recycled id() cannot alias
-            # a stale partition (the _batch_problems hazard).
+            # a stale partition (the resident batch's hazard).
             self._quota_cache = (
                 ids, q.generation, None, None, np.zeros(0, np.int64),
                 list(problems),
@@ -884,7 +862,6 @@ class TensorScheduler:
             cache is None
             or cache[1] != q.generation
             or len(cache[0]) != b
-            or not self._delta_enabled()
         ):
             return None
         ch = np.flatnonzero(ids != cache[0])
@@ -1016,35 +993,38 @@ class TensorScheduler:
         a capture failure logs and never aborts the wave).
 
         Which of four routes a batch takes (_schedule_inner) follows from
-        what the engine observes, no option selects one. A batch is ARMED
-        once every row of a pass rode the fleet table; a later batch of
-        the armed batch's length (no custom filter, host-only estimator
-        or disabled plugin set) is diffed against it by object identity,
-        one id() sweep a pass:
+        what the engine observes, no option selects one. The fleet table
+        holds ONE record of the batch it last scheduled (ResidentBatch),
+        ARMED once every row of a pass rode the table. A later batch of
+        its length (no custom filter, host-only estimator or disabled
+        plugin set) is diffed against it once (ResidentBatch.diff: one id()
+        sweep, the positions that hold another object, the caller's dirty
+        keys, and whether most moved), and the routes are the outcomes of
+        that one diff:
 
-        - identity: the snapshot generation stands, or only availability
-          moved (the armed ``mask_token`` is the snapshot's), and every
-          position holds the armed object: the sweep, then the table's
-          pass over the armed lists (root span ``path`` = identity);
-        - delta: the same, but a minority of positions (or the caller's
-          dirty keys) moved at an UNMOVED generation: those rows are
-          compiled, checked and dispatched, the rest replayed
-          (_delta_pass; ``path`` = delta);
-        - swap diff, on the full path: the generation moved (no answer
-          may be replayed) and a minority of positions moved: the moved
-          positions compiled and held to the fleet-eligibility predicate,
-          every row dispatched (_swap_diff; ``path`` = full,
-          ``scheduler.pack`` carries ``rows`` visited and ``kept``). Where
-          ``mask_token`` moved too (a taint, a label: no compiled
-          placement kept) the armed batch's distinct placements are
-          compiled anew; where it stands (only availability drifted) the
-          armed compiled list is kept and the identity branch's sweep
-          reused;
+        - identity: nothing moved, no key was named dirty, and the armed
+          compiled list stands (the generation it was armed at, or only
+          availability moved: the armed ``mask_token`` is the
+          snapshot's): the table's pass over the armed lists (root span
+          ``path`` = identity);
+        - delta and swap: a minority moved. The moved positions alone are
+          compiled and held to the fleet-eligibility predicate, over the
+          armed compiled list where it stands, else over the armed batch's
+          distinct placements compiled anew (_moved_pass;
+          ``scheduler.pack`` carries ``rows`` visited and ``kept``), and
+          the table visits those positions alone. The table decides
+          whether the rest REPLAY from its mirrors (``path`` = delta: the
+          generation the batch was armed at stands, and neither the
+          mirrors, the estimators, the preemption plane nor a moved spread
+          row say otherwise; dirty keys that name no moved position are a
+          pure replay) or every row is dispatched (``path`` = full). Rows
+          that keep the host's selections keep the record's token None;
         - the walk, the one general path: no armed batch, another length,
-          host-selected or host-path rows in the last pass, a majority of
-          positions moved, a moved position that leaves the fleet, a
-          guard set: every position compiled and partitioned (``path`` =
-          full, or host where no fleet pass follows).
+          host-path rows in the last pass, most positions moved, a moved
+          position that leaves the fleet, host-selected spread rows under
+          a moved generation (no diff is made), a guard set: every
+          position compiled and partitioned (``path`` = full, or host
+          where no fleet pass follows).
 
         Problem objects are not mutated in place between passes: every
         identity route rests on that.
@@ -1052,12 +1032,11 @@ class TensorScheduler:
         ``dirty_keys`` (optional) is the caller's per-wave dirty-row set:
         binding keys whose problems changed since the last wave (watch-bus
         spec/generation movement, quota bumps, estimator pings, eviction
-        displacements — the controller accumulates them). It rides beside
-        the batch-identity token: the delta solve unions it with the
-        object-identity diff, so a caller that rebuilds a problem object
-        without changing content still gets the row re-dispatched when it
-        says so. Disarmed (``KARMADA_TPU_DELTA_SOLVE=0``) or absent, the
-        pass costs one ``is None`` check over the existing paths."""
+        displacements — the controller accumulates them). The diff unions
+        it with the object-identity diff, so a caller that rebuilds a
+        problem object without changing content still gets the row
+        re-dispatched when it says so. Absent, the pass costs one ``is
+        None`` check."""
         from ..utils.tracing import tracer as _tracer
 
         # the root of an engine wave: entry to return, with the path the
@@ -1793,99 +1772,18 @@ class TensorScheduler:
             assignment=assignment,
         )
 
-    def _delta_enabled(self) -> bool:
-        """The ISSUE 20 kill switch, read per pass so flipping
-        ``KARMADA_TPU_DELTA_SOLVE=0`` takes effect on the next wave with
-        no restart. Disarmed, every delta site collapses to one cheap
-        check and the pre-existing full paths run untouched."""
-        import os
-
-        return os.environ.get("KARMADA_TPU_DELTA_SOLVE", "1") != "0"
-
-    def _delta_pass(self, problems, ids, t0, quota=None):
-        """Batch-identity DELTA path (ISSUE 20): the wave has the shape
-        of the armed batch but a minority of positions hold new problem
-        objects (and/or the caller marked keys dirty). Compiles just the
-        changed rows, verifies each against the fleet-eligibility
-        predicate, and hands the fleet the swapped lists plus the dirty
-        positions — the table packs and dispatches only those rows and
-        replays the rest from its resident mirrors. Returns None when
-        ineligible and the caller runs the full prologue: armed
-        preemption (preempt_select is row_coupled — a partial wave
-        cannot see the plane-wide victim cumsum), a moved snapshot
-        generation (the replay base is stale), a changed row that is not
-        fleet-eligible, or majority churn where the full pass wins."""
-        import time as _time
-
-        from ..utils.tracing import tracer as _tracer
-
-        n = len(problems)
-        diff = np.flatnonzero(ids != self._batch_ids)
-        replay = not (
-            self._fleet is None
-            or self.preempt_source is not None
-            or self._batch_gen != self._snapshot_gen
-            or not self._delta_enabled()
-        )
-        if replay:
-            dk = self._dirty_keys
-            if dk:
-                # dirty keys are advisory positions ON TOP of the id diff:
-                # a mapping miss only over-dispatches (safe superset) — a
-                # truly changed row always shows in the id diff as well
-                kp = self._key_pos
-                if kp is None or len(kp) != n:
-                    kp = {p.key: i for i, p in enumerate(problems)}
-                    self._key_pos = kp
-                extra = [kp[k] for k in dk if k in kp]
-                if extra:
-                    diff = np.union1d(diff, np.asarray(extra, np.int64))
-        # the identity check ends at the decision: the sweep the caller
-        # made, the diff and the dirty-key union
-        _tracer.record(
-            "scheduler.identity", _time.perf_counter() - t0, start=t0,
-            rows=n, hit=0, moved=int(diff.size),
-        )
-        if not replay or diff.size * 2 > n:
-            return None
-        # every other position holds the armed batch's object
-        fp2 = list(problems)
-        fc2 = list(self._batch_cache[1])
-        with _tracer.span("scheduler.pack", rows=int(diff.size)) as pack:
-            # a changed row that left the fleet-eligible set, or is
-            # spread-constrained (the full prologue arranges its
-            # selection): the full prologue partitions it
-            visited, ride = self._visit_moved(problems, diff.tolist(), fc2)
-            if not ride:
-                pack.attrs["rows"] = visited
-                return None
-        self.last_breakdown = {"compile": _time.perf_counter() - t0}
-        self._pass_path = "delta"
-        self.solve_batches += 1
-        res = self._fleet.schedule(fp2, fc2, delta=diff, quota=quota)
-        self.last_breakdown.update(self._fleet.last_breakdown)
-        # re-arm the identity token on the swapped lists (gen and mask
-        # token are unchanged by construction; swapped-in rows are never
-        # spread-constrained, and the rows that are keep the selection
-        # the fleet's row state holds)
-        self._batch_problems = fp2
-        self._batch_ids = ids
-        self._batch_cache = (fp2, fc2)
-        self._batch_placements = None
-        return res
-
     def _visit_moved(
         self, problems, positions: list, fc: list, spread_rides: bool = False
     ) -> tuple:
-        """THE per-position loop of the two diffs against the armed batch
-        (_delta_pass, _swap_diff): each of ``positions`` gets its compiled
-        placement under the current snapshot (a look-up; a placement new
-        to the cache compiles) written into ``fc``, and is held to the
-        fleet-eligibility predicate the walk applies (cp.fleet_terms, or a
-        spread-constrained single-term row where ``spread_rides``: the
-        pass has the fleet table select for it; and fleet.row_rides).
-        Returns (positions visited, whether each of them rides the fleet):
-        the loop ends at the first that does not."""
+        """THE per-position loop over the moved positions (_moved_pass):
+        each of ``positions`` gets its compiled placement under the current
+        snapshot (a look-up; a placement new to the cache compiles) written
+        into ``fc``, and is held to the fleet-eligibility predicate the
+        walk applies (cp.fleet_terms, or a spread-constrained single-term
+        row where ``spread_rides``: the pass has the fleet table select for
+        it; and fleet.row_rides). Returns (positions visited, whether each
+        of them rides the fleet): the loop ends at the first that does
+        not."""
         from .fleet import row_rides
 
         # one look-up a placement, not a position (the positions pin
@@ -1904,35 +1802,31 @@ class TensorScheduler:
             fc[pos] = cp
         return len(positions), True
 
-    def _swap_diff(self, problems, pack, ids=None) -> tuple:
-        """The full path's diff of a SWAPPED batch against the armed one,
-        where the snapshot generation moved (neither the identity branch
-        nor _delta_pass may replay an answer: every row is dispatched):
-        the prologue's work for the moved positions alone. What a moved
-        generation leaves standing is what the prologue derives a position
-        from the problem OBJECT (pinned by the armed batch, never mutated
-        in place) and its PLACEMENT (strategy, the term count and the
-        spread constraints are functions of the placement alone): which
-        placement the row names and whether the row rides the fleet. What
-        a moved ``mask_token`` does not (a region lost or back): a compiled
-        placement. update_snapshot then cleared the cache, so every
-        distinct placement of the armed batch is compiled anew (one compile
-        each) and the new list is a take over the armed batch's position ->
-        placement index; where the token stands (only availability drifted)
-        the distinct placements are look-ups and the armed compiled list is
-        copied. Either way the moved positions go through _visit_moved.
+    def _moved_pass(self, problems, rec, moved, pack) -> Optional[tuple]:
+        """The prologue's work for the positions the diff found moved
+        against the armed batch ``rec`` (a minority), the others spared:
+        what the prologue derives a position from is its problem OBJECT
+        (pinned by the record, never mutated in place) and its PLACEMENT
+        (strategy, the term count and the spread constraints are functions
+        of the placement alone): which placement the row names and whether
+        it rides the fleet. Its compiled placement stands too where the
+        armed compiled list does (the generation it was made at, or the
+        same ``mask_token``: only availability drifted), and the list is
+        copied; where the token moved (a taint, a label: update_snapshot
+        cleared the cache) every distinct placement of the armed batch is
+        compiled anew (one compile each) and the list is a take over the
+        record's position -> placement index. Either way the moved
+        positions go through _visit_moved, the index is corrected at them,
+        and the spread rows' select positions are read from it.
 
-        ``ids``: the identity branch's sweep where it ran (the token
-        stands), else the sweep is made here. Returns (ids, hand-off |
-        None): the sweep, to re-arm with and to hand the table, and (fp,
-        fc, the spread-constrained rows' positions | None, the new batch by
-        placement); None where the walk has to run: most positions moved, a
+        Returns (fp, fc, the spread rows' positions | None, the batch by
+        placement) for the table, or None where the walk has to run: a
         placement's flags differ under the new snapshot, spread rows the
-        device cannot select for (regions past R_CAP, extra estimators), a
-        moved position that leaves the fleet. Records scheduler.identity
-        where it sweeps, and for a hand-off pack's three stages (their
-        ``rows``: the positions visited), under the open scheduler.pack
-        span."""
+        device cannot select for (regions past R_CAP, extra estimators)
+        under a moved generation, a moved position that leaves the fleet or
+        whose selection the device cannot make. For a hand-off records the
+        prologue's three stages (their ``rows``: the positions visited)
+        under the open scheduler.pack span."""
         import time as _time
 
         from ..utils.metrics import fleet_host_path_rows
@@ -1940,23 +1834,11 @@ class TensorScheduler:
         from .select import regions_fit
 
         n = len(problems)
-        swept = ids is None
-        t_sweep = _time.perf_counter()
-        if swept:
-            ids = np.fromiter(map(id, problems), np.int64, n)
-        moved = np.flatnonzero(ids != self._batch_ids)
         k = int(moved.size)
         t_compile = _time.perf_counter()
-        if swept:
-            _tracer.record(
-                "scheduler.identity", t_compile - t_sweep, start=t_sweep,
-                rows=n, hit=0, moved=k,
-            )
-        if k * 2 > n:
-            return ids, None  # the walk costs no more
-        built = self._batch_placements
+        built = rec.placements
         if built is None:
-            old = self._batch_cache[1]
+            old = rec.compiled
             _, first, index = np.unique(
                 np.fromiter(map(id, old), np.int64, n),
                 return_index=True, return_inverse=True,
@@ -1970,16 +1852,18 @@ class TensorScheduler:
         placements, flags, index = built
         # a compile each where the token moved, a look-up where it stands
         cps = [self._compiled(pl) for pl in placements]
+        gen_stands = rec.gen == self._snapshot_gen
         spread_rides = not self.extra_estimators and regions_fit(
             self.snapshot
         )
         if [_placement_flags(cp) for cp in cps] != flags or (
-            not spread_rides and any(f[1] for f in flags)
+            # the host's selections follow the capacities
+            not spread_rides and not gen_stands and any(f[1] for f in flags)
         ):
-            return ids, None
-        if self._batch_token == self.snapshot.mask_token:
+            return None
+        if gen_stands or rec.token == self.snapshot.mask_token:
             # the armed list holds the cache's compiled placements
-            fc = list(self._batch_cache[1])
+            fc = list(rec.compiled)
         else:
             table = np.empty(len(cps), object)
             table[:] = cps
@@ -1987,7 +1871,7 @@ class TensorScheduler:
         t_eligible = _time.perf_counter()
         positions = moved.tolist()
         if not self._visit_moved(problems, positions, fc, spread_rides)[1]:
-            return ids, None
+            return None
         # the index, corrected at the moved positions (a new array: the
         # armed one stands until the re-arm)
         slot = {id(cp): j for j, cp in enumerate(cps)}
@@ -1996,7 +1880,7 @@ class TensorScheduler:
         joined = None in slots
         if joined:
             # a placement the armed batch did not name joins the distinct
-            # ones for this pass; the next swap diff builds its index anew
+            # ones for this pass; the next diff builds its index anew
             flags = list(flags)
             for j, cp in enumerate(at):
                 if slots[j] is None:
@@ -2009,7 +1893,7 @@ class TensorScheduler:
         t_spread = _time.perf_counter()
         select = None
         spread = np.fromiter((f[1] for f in flags), bool, len(flags))
-        if spread.any():
+        if spread_rides and spread.any():
             select = np.flatnonzero(spread[index])
             self._report_host_selected(0)
         # a list of its own: the caller's may change under the armed batch
@@ -2035,7 +1919,7 @@ class TensorScheduler:
             "scheduler.spread", t_end - t_spread, start=t_spread,
             rows=n_select, on_device=int(n_select > 0),
         )
-        return ids, (fp, fc, select, built)
+        return fp, fc, select, built
 
     def _schedule_inner(
         self, problems: Sequence[BindingProblem], quota=None
@@ -2059,58 +1943,41 @@ class TensorScheduler:
             or self.disabled_plugins
         )
         # a batch of the armed batch's length is diffed against it by
-        # object identity: below where the base of its answers stands, in
-        # _swap_diff on the full path where the generation moved
-        armed = (
-            fleet_ok
-            and self._batch_ids is not None
-            and len(problems) == len(self._batch_ids)
-        )
-        base_stands = (
-            self._batch_gen == self._snapshot_gen
+        # object identity (ResidentBatch.diff), once a pass
+        rec = self._fleet.batch if fleet_ok and self._fleet else None
+        if rec is not None and not (
+            rec.armed and len(rec.problems) == len(problems)
+        ):
+            rec = None
+        diff = None
+        if rec is not None and (
+            rec.gen == self._snapshot_gen
             # availability-only drift keeps every compiled mask and the
             # eligibility partition valid (placements key on filter
             # fields = mask_token), and the fleet table re-selects its
             # spread rows on the device in every pass, so batches reuse
             # across the swap — churn passes skip the prologue too
-            # (the token is None for a batch with host-selected rows)
-            or self._batch_token == self.snapshot.mask_token
-        )
-        # id() of every position's object, from whichever diff swept the
-        # batch: what the table diffs by and the batch is re-armed with, so
-        # a pass sweeps once
-        ids = None
-        # batch-identity fast path: a storm re-scheduling the SAME problem
-        # objects is pure in those inputs — compilation and the
-        # eligibility partition key on object identity + the snapshot's
-        # filter fields, and the spread selection is the fleet table's own
-        # stage (_fleet_select, in every pass), so one id() sweep (~8ms at
-        # 100k) replaces the ~55ms host prologue. This is
-        # the vectorized form of the per-row `is problem` fast path the
-        # fleet's upsert already takes; like it, it assumes problem objects
-        # are not mutated in place between passes.
-        if armed and base_stands:
-            t0 = _time.perf_counter()
-            ids = np.fromiter(map(id, problems), np.int64, len(problems))
-            if np.array_equal(ids, self._batch_ids) and not self._dirty_keys:
-                took = _time.perf_counter() - t0
-                self.last_breakdown = {"compile": took}
-                _tracer.record(
-                    "scheduler.identity", took, start=t0,
-                    rows=len(ids), hit=1, moved=0,
-                )
+            # (the token is None for a batch with host-selected rows:
+            # those follow the capacities)
+            or rec.token == self.snapshot.mask_token
+        ):
+            # batch-identity fast path: a storm re-scheduling the SAME
+            # problem objects is pure in those inputs — compilation and the
+            # eligibility partition key on object identity + the
+            # snapshot's filter fields, and the spread selection is the
+            # fleet table's own stage (_fleet_select, in every pass), so
+            # one id() sweep (~8ms at 100k) replaces the ~55ms host
+            # prologue. Like the table's own reuse, it assumes problem
+            # objects are not mutated in place between passes.
+            diff = rec.diff(problems, self._dirty_keys, True)
+            if diff.hit:
+                self.last_breakdown = {"compile": diff.took}
                 self._pass_path = "identity"
-                fp, fc = self._batch_cache
                 self.solve_batches += 1
-                res = self._fleet.schedule(fp, fc, quota=quota)
+                res = self._fleet.schedule(
+                    rec.problems, rec.compiled, quota=quota
+                )
                 self.last_breakdown.update(self._fleet.last_breakdown)
-                return res
-            # not the identical batch: a minority of moved positions (or
-            # caller-declared dirty keys) is the DELTA case — pack and
-            # dispatch just those rows, replay the rest from the fleet's
-            # resident mirrors (ISSUE 20)
-            res = self._delta_pass(problems, ids, t0, quota)
-            if res is not None:
                 return res
 
         from contextlib import nullcontext
@@ -2121,7 +1988,7 @@ class TensorScheduler:
         # last_breakdown times, so a storm's pass decomposes into pack /
         # handoff / solve(dispatch/device/fetch) / rearm under
         # scheduler.schedule. ``rows`` are the positions the prologue
-        # visited, ``kept`` those a swapped batch's diff spared it
+        # visited, ``kept`` those a diff spared it
         swap = None
         with (
             _tracer.span("scheduler.pack", rows=len(problems), kept=0)
@@ -2129,17 +1996,17 @@ class TensorScheduler:
             else nullcontext()
         ) as pack:
             if (
-                armed
-                # the generation moved, under a moved mask_token or one
-                # whose identity check missed above (a batch with
-                # host-selected rows carries no token: the walk)
-                and self._batch_gen != self._snapshot_gen
-                and self._batch_token is not None
-                and self._fleet is not None
+                rec is not None
+                # host-selected rows under a moved generation: the walk
+                and (diff is not None or rec.token is not None)
                 and not self._fleet.slots_exhausted
-                and len(problems) >= self.fleet_threshold
             ):
-                ids, swap = self._swap_diff(problems, pack, ids)
+                if diff is None:
+                    # the generation moved under a moved mask_token: the
+                    # diff is the prologue's first stage
+                    diff = rec.diff(problems, self._dirty_keys, False)
+                if diff.few:
+                    swap = self._moved_pass(problems, rec, diff.moved, pack)
             if swap is None:
                 t0 = _time.perf_counter()
                 compiled = [self._compiled(p.placement) for p in problems]
@@ -2196,8 +2063,8 @@ class TensorScheduler:
                 # selection it was given), the per-binding half
                 # fleet.row_rides (at most K_EVICT eviction tasks, a
                 # Divided row's counts within a cell of the table; any
-                # previous result): the one expression _delta_pass applies
-                # too.
+                # previous result): the one expression _visit_moved
+                # applies too.
                 # Rows past it take the host path, row by row, below
                 fast_idx = [
                     i
@@ -2253,12 +2120,12 @@ class TensorScheduler:
                     else:
                         selections = (pos[rides], sel_bits[rides])
             host_rows = len(problems) - len(fp)
-            if host_rows:
-                if quota is not None:
-                    return None  # admission is over the presented batch
-                ids = None  # the fleet batch is not the presented one
+            if host_rows and quota is not None:
+                return None  # admission is over the presented batch
+            # the table visits the diff's moved positions alone (and may
+            # replay the rest) where the batch it gets is the one diffed
+            moved = None if diff is None or host_rows else diff.moved
             self.solve_batches += 1
-            self._pass_path = "full"
             kept, visited = self._prologue_tally
             kept.inc(pack.attrs["kept"])
             visited.inc(pack.attrs["rows"])
@@ -2269,13 +2136,14 @@ class TensorScheduler:
                 start=pack.end, rows=len(fp),
             )
             fast_res = self._fleet.schedule(
-                fp, fc, selections=selections, select=select,
-                host_rows=host_rows, ids=ids, quota=quota,
+                fp, fc, moved, selections=selections, select=select,
+                host_rows=host_rows, quota=quota,
             )
-            # from the table's answer to the engine's: arming the identity
-            # path (with the pass's sweep; a walk no diff came before makes
-            # its own here), or the merge with the host path's rows
-            # (scheduler.host its child)
+            self._pass_path = "delta" if self._fleet.replayed else "full"
+            # from the table's answer to the engine's: arming the batch the
+            # table holds (with the pass's sweep; a walk no diff came
+            # before makes its own here), or the merge with the host
+            # path's rows (scheduler.host its child)
             with _tracer.span(
                 "scheduler.rearm", rows=len(problems), host_rows=host_rows
             ):
@@ -2283,21 +2151,18 @@ class TensorScheduler:
                 if not host_rows:
                     # all rows rode the fleet: hand back the lazy
                     # column-oriented result list as-is, and arm the
-                    # batch-identity fast path for the next pass (fp/fc
-                    # are the very list objects the fleet keys its own
-                    # O(1) reuse on)
-                    self._batch_problems = fp
-                    self._batch_ids = (
-                        ids if ids is not None
-                        else np.fromiter(map(id, fp), np.int64, len(fp))
+                    # batch for the next pass's diff. Rows that hold the
+                    # host's selections, the walk's or those a moved pass
+                    # spared, arm no token
+                    held = selections is not None or (
+                        swap is not None and rec.token is None
                     )
-                    self._batch_gen = self._snapshot_gen
-                    self._batch_cache = (fp, fc)
-                    self._batch_token = (
-                        self.snapshot.mask_token if selections is None
-                        else None
+                    self._fleet.batch.arm(
+                        diff.ids if diff is not None
+                        else np.fromiter(map(id, fp), np.int64, len(fp)),
+                        placed, self._snapshot_gen,
+                        None if held else self.snapshot.mask_token,
                     )
-                    self._batch_placements = placed
                     return fast_res
                 results: list = [None] * len(problems)
                 for i, res in zip(fast_idx, fast_res):

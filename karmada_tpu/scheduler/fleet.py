@@ -1128,48 +1128,6 @@ FLEET_KERNELS = {
 }
 
 
-#: solve-path kernels the delta pass drives with PARTIAL batches — the
-#: runtime consumes the dep-lint tier's jaxpr row-dependence certification
-#: (tools/graftlint/dep.delta_safe_registry) instead of re-declaring
-#: independence here. row_coupled kernels (quota_admit's FIFO segments,
-#: preempt_select's plane-wide cumsum) are NOT in this list: their waves
-#: force a scoped full pass (see TensorScheduler.schedule).
-_DELTA_SAFE_REQUIRED = (
-    "divide_replicas", "take_by_weight_batch", "general_estimate",
-)
-
-_DELTA_CERT: Optional[bool] = None
-
-
-def delta_certified() -> bool:
-    """True when the dep-lint tier proves every kernel the delta solve
-    dispatches row-independent (``delta_safe``: declared uncoupled AND
-    jaxpr-analyzed "independent"). Cached per process — the registry
-    traces every entry spec once. Fail-closed: an import failure, a
-    missing registry row, or a coupled/unproven verdict DISARMS the
-    delta path rather than risk a partial dispatch of a row-coupled
-    kernel silently dropping cross-row effects."""
-    global _DELTA_CERT
-    if _DELTA_CERT is None:
-        try:
-            from tools.graftlint.dep import delta_safe_registry
-
-            rows = {r["name"]: r for r in delta_safe_registry()}
-            _DELTA_CERT = all(
-                rows[k]["delta_safe"] for k in _DELTA_SAFE_REQUIRED
-            )
-        except Exception:  # noqa: BLE001 — certification is a gate, not
-            # a dependency: anything short of a positive verdict disarms
-            _DELTA_CERT = False
-        if not _DELTA_CERT:
-            log.warning(
-                "delta solve disarmed: dep-lint certification of %s "
-                "did not prove row-independence",
-                ", ".join(_DELTA_SAFE_REQUIRED),
-            )
-    return _DELTA_CERT
-
-
 # --------------------------------------------------------------------------
 # results
 # --------------------------------------------------------------------------
@@ -1537,6 +1495,135 @@ class _SelectRows(NamedTuple):
     n_chunks: int
 
 
+def _few(moved: int, n: int) -> bool:
+    """THE majority rule: at most half of a batch's ``n`` positions moved,
+    so visiting (or dispatching) them alone beats the walk."""
+    return moved * 2 <= n
+
+
+class _Diff(NamedTuple):
+    """A batch diffed against the armed one (ResidentBatch.diff)."""
+
+    ids: np.ndarray  # int64[n]: id() of every position's object
+    # sorted positions whose object moved or whose key the caller named
+    # dirty: what the table visits
+    moved: np.ndarray
+    # at most half of the positions moved (_few): the prologue visits
+    # them alone
+    few: bool
+    # nothing moved, no key was named dirty and the armed compiled list
+    # stands: the identity route
+    hit: bool
+    took: float  # the seconds the scheduler.identity record spans
+
+
+class ResidentBatch:
+    """The batch the fleet table last scheduled: ONE record of it, which
+    the table owns and the engine reads. Its lists pin every position's
+    objects (so no id() in it is given out again), ``rows_np`` holds each
+    position's table row, and what the passes derive from those rows hangs
+    off it (``derived``, ``terms``, ``select_rows``): they go with the
+    record when a pass replaces it, unless the replacement keeps the same
+    row vector. A compaction or a growth remaps the rows: the record loses
+    its rows and what hangs off them in place (``rows_np`` None), keeps its
+    lists and armed state, and the next pass walks the batch.
+
+    The engine arms a record once a whole batch of its rode the table
+    (``arm``); an armed record is what a later batch is diffed against
+    (``diff``). ``gen`` and ``token`` are the engine's snapshot generation
+    and ``mask_token`` the engine armed it at (``token`` None where rows of
+    the batch hold the host's selections: they follow the capacities, so
+    the record stands only at its own generation); ``placements`` the
+    armed batch by placement: (distinct placements, each one's flags,
+    intp[n] position -> placement), None until a diff needs it."""
+
+    __slots__ = (
+        "problems", "compiled", "rows_np", "select", "unique", "gen",
+        "token", "live", "epoch", "ids", "placements", "key_pos",
+        "derived", "terms", "select_rows",
+    )
+
+    def __init__(self, problems, compiled, rows_np, select, unique, live,
+                 epoch):
+        self.problems = problems
+        self.compiled = compiled
+        self.rows_np: Optional[np.ndarray] = rows_np
+        # positions whose SelectClusters stage runs on the device, or None
+        self.select = select
+        self.unique = unique  # no table row comes twice in the batch
+        # the pass the rows were last live at: it stands in for the
+        # last-used stamps of the rows no pass walked since (_compact)
+        self.live = live
+        # the mirror epoch that covers the rows' answers (-1: none)
+        self.epoch = epoch
+        self.ids: Optional[np.ndarray] = None  # None: not armed
+        self.gen = self.token = None
+        self.placements: Optional[tuple] = None
+        self.key_pos: Optional[dict] = None  # key -> position, built lazily
+        self.derived: Optional[_BatchDerived] = None
+        self.terms: Optional[_TermRows] = None
+        self.select_rows: Optional[_SelectRows] = None
+
+    @property
+    def armed(self) -> bool:
+        return self.ids is not None
+
+    def arm(self, ids: np.ndarray, placements: Optional[tuple], gen,
+            token) -> None:
+        """Let the engine diff a later batch against this one: ``ids`` is
+        id() of every position's object, ``placements`` the batch by
+        placement where the pass built it, ``gen`` and ``token`` as the
+        class says."""
+        self.ids, self.placements = ids, placements
+        self.gen, self.token = gen, token
+
+    def unmap(self) -> None:
+        """The rows were remapped (a compaction, a growth): none of the
+        record's rows, nor what hangs off them, stands."""
+        self.rows_np = self.derived = self.terms = self.select_rows = None
+        self.epoch = -1
+
+    def diff(self, problems: Sequence, dirty_keys, stands: bool) -> _Diff:
+        """THE diff of a batch of the armed batch's length against it: one
+        id() sweep, the positions whose object is another, and the
+        positions of the caller's ``dirty_keys`` (advisory: a key the
+        batch does not hold maps nowhere; a row that truly changed shows
+        in the sweep as well), and whether at most half of them moved (the
+        majority rule). Records ``scheduler.identity`` (``hit``: no
+        position moved, no key was named and the armed compiled list
+        ``stands``, the identity route), the one place that does."""
+        from ..utils.tracing import tracer
+
+        t0 = time.perf_counter()
+        n = len(problems)
+        ids = np.fromiter(map(id, problems), np.int64, n)
+        moved = np.flatnonzero(ids != self.ids)
+        if dirty_keys:
+            kp = self.key_pos
+            if kp is None:
+                kp = self.key_pos = {
+                    p.key: i for i, p in enumerate(self.problems)
+                }
+            extra = [kp[k] for k in dirty_keys if k in kp]
+            if extra:
+                moved = np.union1d(moved, np.asarray(extra, np.int64))
+        k = int(moved.size)
+        hit = stands and k == 0 and not dirty_keys
+        took = time.perf_counter() - t0
+        tracer.record(
+            "scheduler.identity", took, start=t0,
+            rows=n, hit=int(hit), moved=k,
+        )
+        return _Diff(ids, moved, _few(k, n), hit, took)
+
+
+def _distinct(rows: np.ndarray, n_rows: int) -> bool:
+    """Whether no table row comes twice in ``rows``."""
+    seen = np.zeros(n_rows, bool)
+    seen[rows] = True
+    return int(seen.sum()) == len(rows)
+
+
 class FleetTable:
     """Device-resident binding table bound to one TensorScheduler."""
 
@@ -1610,25 +1697,18 @@ class FleetTable:
         # with them; None where the snapshot holds more than R_CAP regions
         self._dev_spread: Optional[tuple] = None
         self._dev_subsets: Optional[tuple] = None  # subset_table, uploaded once
-        # the device selection of the current batch, kept while the same
-        # positions of the same rows are asked for again
-        self._select_cache: Optional[_SelectRows] = None
         # the last select dispatch's (perf_counter start, end, rows,
         # device counts), until schedule() records its span
         self._select_mark: Optional[tuple] = None
         # the rows' ordered term slots and the term each was last given
-        # (device int32[cap, T_CAP] / uint8[cap]); the multi-term rows of
-        # the current batch as _fleet_terms takes them; the last term
+        # (device int32[cap, T_CAP] / uint8[cap]); the last term
         # dispatch's (start, end, rows, device counts, evicted rows) until
         # schedule() records its span
         self._dev_term_slots = None
         self._dev_term_sel = None
-        self._term_cache: Optional[_TermRows] = None
         self._terms_mark: Optional[tuple] = None
-        # what the passes derive from the current batch's row state, kept
-        # while the same row vector comes again with no row packed (dropped
-        # where _term_cache is); and what the current pass made of it
-        self._derived: Optional[_BatchDerived] = None
+        # whether the current pass kept or built what it derives from its
+        # batch's row state (the record's ``derived``)
         self._derived_outcome = "kept"
         # quota admission from row state (_admit_on_device). The staging's
         # ``ns_idx`` column is derived from ONE QuotaSnapshot.ns_index,
@@ -1704,28 +1784,18 @@ class FleetTable:
         # (target, consecutive passes desired) for a frozen shrink — see
         # the cap tuning in _solve_dense
         self._shrink_desire: tuple = (None, 0)
-        # O(1) batch reuse: (problems_list, compiled_list, rows, select,
-        # ids) of the last scheduled batch — the engine's batch-identity
-        # fast path re-passes the SAME list objects, so identity means the
-        # row mapping is already current (cleared on growth/compaction), and
-        # ``select`` (the positions the device selects, or None) with it.
-        # ``ids`` is id() of the object each position's row holds, where a
-        # diff has taken them (upsert), else None: another list of the same
-        # length is diffed against them, and only the positions that hold
-        # another object are visited. _reuse_pass stands in for the per-row
-        # last-used stamps of the positions no pass visited since (consumed
-        # by _compact and when another batch takes the table).
-        self._reuse: Optional[tuple] = None
-        self._reuse_pass = 0
-        # mirror staleness fence for the delta solve: _mirror_epoch bumps
-        # whenever a resident/mirror pair is (re)allocated zeroed, and
-        # _reuse_epoch records the epoch whose mirrors fully cover the
-        # reuse rows (synced at the end of every full pass). A delta pass
-        # only replays untouched rows when the epochs agree — a realloc
-        # between the covering pass and now means the mirrors no longer
-        # hold those rows' results.
+        # the batch the table last scheduled (ResidentBatch): a pass that
+        # brings the same list objects again reuses its rows
+        self.batch: Optional[ResidentBatch] = None
+        # whether the last pass replayed its untouched rows from the
+        # mirrors (_schedule_delta), or dispatched every row
+        self.replayed = False
+        # mirror staleness fence for the replay: bumps whenever a
+        # resident/mirror pair is (re)allocated zeroed; the record keeps
+        # the epoch whose mirrors cover its rows (the end of every full
+        # pass), and a delta replays its untouched rows only while the
+        # two agree
         self._mirror_epoch = 0
-        self._reuse_epoch = -1
         # bumped whenever _host_entries is rewritten (each pass, and on
         # compaction remaps); _FleetBatch captures it so stale result
         # views fail loudly instead of decoding another pass's entries
@@ -1880,10 +1950,11 @@ class FleetTable:
             return False
         cutoff = self._pass - self.COMPACT_IDLE_PASSES
         lu = self._st["last_used"][: self.n_rows]
-        if self._reuse is not None:
+        rec = self.batch
+        if rec is not None and rec.rows_np is not None:
             # the resident batch's rows are stamped where a pass walked
-            # them; the passes that did not were live at _reuse_pass
-            lu[self._reuse[2]] = self._reuse_pass
+            # them; the passes that did not were live at ``rec.live``
+            lu[rec.rows_np] = rec.live
         keep = np.flatnonzero(lu >= cutoff).tolist()
         if len(keep) * 2 > self.n_rows:
             return False
@@ -1907,13 +1978,12 @@ class FleetTable:
         self._dirty.clear()
         self._dev_state = None  # full re-upload with the compacted layout
         self._dev_term_sel = None
-        self._term_cache = self._derived = None
         self._dev_quota = self._quota_verdict = None
         self._all_rows_n = -1
         # row ids were remapped: the delta base is meaningless now, and so
         # is any result view still pointing at the old row layout
         self._reset_dense()
-        self._reuse = None  # row ids remapped
+        self._unmap_batch()
         self._result_gen += 1
         return True
 
@@ -1983,79 +2053,57 @@ class FleetTable:
         self.cap = new_cap
         self._dev_state = None  # full re-upload
         self._dev_term_sel = None
-        self._term_cache = self._derived = None
         self._dev_quota = self._quota_verdict = None
         self._reset_dense()  # cap changed: residents reallocate zeroed
-        self._reuse = None
+        self._unmap_batch()
 
-    def _resident_ids(self, rows: np.ndarray) -> Optional[np.ndarray]:
-        """id() of the object each of ``rows`` holds, position by position;
-        None where a row comes twice (a key twice in the batch: its
-        positions depend on one another, which only the walk keeps)."""
-        seen = np.zeros(self.n_rows, bool)
-        seen[rows] = True
-        if int(seen.sum()) != len(rows):
-            return None
-        return np.fromiter(
-            map(id, map(self._problems.__getitem__, rows.tolist())),
-            np.int64, len(rows),
-        )
-
-    def _moved_positions(
-        self, problems: Sequence, ids: Optional[np.ndarray] = None
-    ) -> Optional[tuple]:
-        """The diff of ``problems`` against the resident batch: (positions
-        that hold another object than their row does, id() of every
-        position's object: ``ids`` where the caller swept the batch
-        already), or None where the diff cannot cover the batch:
-        no resident batch (none yet, or a compaction or growth since),
-        another length, a row twice in the resident batch, or a moved
-        position whose key is new to the table or sits at another row. The
-        rows pin their objects, so no id can have been given out again."""
-        ru = self._reuse
-        n = len(problems)
-        if ru is None or len(ru[2]) != n:
-            return None
-        old = ru[4] if ru[4] is not None else self._resident_ids(ru[2])
-        if old is None:
-            return None
-        if ids is None:
-            ids = np.fromiter(map(id, problems), np.int64, n)
-        moved = np.flatnonzero(ids != old)
-        positions = moved.tolist()
-        key_row = self._key_row
-        if [key_row.get(problems[i].key) for i in positions] != (
-            ru[2][moved].tolist()
-        ):
-            return None
-        return positions, ids
+    def _unmap_batch(self) -> None:
+        """The rows were remapped (a compaction, a growth): the resident
+        batch keeps its lists and armed state, and the next pass walks it."""
+        if self.batch is not None:
+            self.batch.unmap()
 
     def upsert(
         self, problems: Sequence, compiled: Sequence,
-        ids: Optional[np.ndarray] = None,
+        moved: Optional[np.ndarray] = None,
     ) -> tuple:
         """The batch's rows (int32, position by position), its rows brought
-        to the batch's content; with them id() of every position's object
-        where the diff took them (the caller's ``ids``, its own sweep of
-        ``problems``, or the table's), else None (what ``_reuse`` keeps).
+        to the batch's content, and whether no row comes twice in it.
 
-        A batch of the resident batch's length is DIFFED against it by
-        object identity (_moved_positions) and only the positions that hold
-        another object are visited; every other batch is walked position by
-        position. ``_visited_this_pass`` counts the positions looked at. A
-        visited position whose row holds the same object, or one of equal
-        content (compared field by field with the object the row holds,
-        which then gives way to the newcomer), keeps its row state; a new
-        key takes a row; the rest are packed together (_pack_rows). Problem
-        objects are not mutated in place between passes: the identity paths
-        here and in the engine rest on that."""
+        ``moved`` (sorted positions, from the engine's diff against the
+        resident batch, ResidentBatch.diff) says which positions hold
+        another object than the resident batch does: only those are
+        visited, where the resident batch has rows of the same length and
+        each moved position's key sits at the row its position had. Every
+        other batch is walked position by position. ``_visited_this_pass``
+        counts the positions looked at. A visited position whose row holds
+        the same object, or one of equal content (compared field by field
+        with the object the row holds, which then gives way to the
+        newcomer), keeps its row state; a new key takes a row; the rest are
+        packed together (_pack_rows). Problem objects are not mutated in
+        place between passes: the identity paths here and in the engine
+        rest on that."""
         n = len(problems)
-        diff = self._moved_positions(problems, ids)
-        if diff is None:
-            if self._reuse is not None:
+        rec = self.batch
+        positions = None
+        if (
+            moved is not None
+            and rec is not None
+            and rec.rows_np is not None
+            and rec.unique
+            and len(rec.rows_np) == n
+        ):
+            positions = moved.tolist()
+            key_row = self._key_row
+            if [key_row.get(problems[i].key) for i in positions] != (
+                rec.rows_np[moved].tolist()
+            ):
+                positions = None
+        if positions is None:
+            if rec is not None and rec.rows_np is not None:
                 # another batch takes the table: the resident one's rows
                 # keep the pass they were last live at
-                self._st["last_used"][self._reuse[2]] = self._reuse_pass
+                self._st["last_used"][rec.rows_np] = rec.live
             # reclaim rows of deleted/idle bindings before the table would
             # grow (compaction reindexes rows, so it must run before the
             # walk hands out indices). Gated on ACTUAL new keys so the
@@ -2067,9 +2115,9 @@ class FleetTable:
                 )
                 if self.n_rows + new_keys > self.cap:
                     self._compact()
-            positions, ids = range(n), None
-        else:
-            positions, ids = diff
+        # the rows move under the record from here: none stands until the
+        # pass holds the new one
+        self.batch = None
         key_row, probs, terms = self._key_row, self._problems, self._terms
         rows: list = []
         pack_rows: list = []
@@ -2077,7 +2125,7 @@ class FleetTable:
         pack_c: list = []
         same = equal = 0
         try:
-            for i in positions:
+            for i in range(n) if positions is None else positions:
                 p = problems[i]
                 row = key_row.get(p.key)
                 if row is None:
@@ -2122,18 +2170,16 @@ class FleetTable:
         t_same.inc(n - len(rows) + same)
         t_equal.inc(equal)
         t_packed.inc(len(pack_rows))
-        if diff is None:
+        if positions is None:
             rows_np = np.array(rows, np.int32)
             if rows:
                 self._st["last_used"][rows_np] = self._pass
-        elif rows:
-            # a fresh array: what is cached by the batch's row vector
-            # (_select_cache, _term_cache, _derived) starts anew as after a
-            # walk
-            rows_np = self._reuse[2].copy()
-        else:
-            rows_np = self._reuse[2]  # no position moved: the batch stands
-        return rows_np, ids
+            return rows_np, _distinct(rows_np, self.n_rows)
+        if rows:
+            # the same rows in a fresh array: what hangs off the record by
+            # its row vector starts anew as after a walk
+            return rec.rows_np.copy(), True
+        return rec.rows_np, True  # no position moved: the batch stands
 
     @staticmethod
     def _slot_key(placement, term: int) -> tuple:
@@ -2361,7 +2407,6 @@ class FleetTable:
             # and the mirrors start again at two bytes a cell
             self._cell_bytes = 2
             self._reset_dense()
-        self._term_cache = self._derived = None
         self._dirty.update(rows)
 
     def _wide_slot(self, row: int) -> int:
@@ -2836,9 +2881,8 @@ class FleetTable:
     # -- scheduling --------------------------------------------------------
 
     def schedule(
-        self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None, select=None, host_rows: int = 0, ids=None,
-        quota=None,
+        self, problems: Sequence, compiled: Sequence, moved=None,
+        selections=None, select=None, host_rows: int = 0, quota=None,
     ) -> list:
         """One fleet pass, wrapped in a ``scheduler.solve`` wave span with
         per-phase kernel child spans (host pack / dispatch / fenced device
@@ -2854,15 +2898,13 @@ class FleetTable:
         phase carries it too, beside ``rows_packed``. The device-byte
         ledger publishes after every pass.
 
-        ``delta`` (optional) is a sequence of POSITIONS into ``problems``
-        that changed since the last pass; every other position must hold
-        the same problem/compiled objects the last pass scheduled (the
-        caller's contract — the engine's batch-identity diff and the
-        dirty-key plumbing both construct batches that way). When the
-        table can prove its resident mirrors still cover the untouched
-        rows, only the delta positions are packed and dispatched and the
-        rest replay from the mirrors; otherwise the pass silently runs
-        full.
+        ``moved`` (optional) is the sorted POSITIONS of ``problems`` that
+        the engine's diff against the resident batch found moved (another
+        object, or a key the caller named dirty); every other position
+        holds the object the resident batch does. The upsert phase visits
+        those positions alone. Whether the untouched rows REPLAY from the
+        host mirrors (only the moved positions packed and dispatched) or
+        every row is dispatched is decided here, once (_replays).
 
         ``select`` (optional) is the POSITIONS of the spread-constrained
         rows whose SelectClusters stage runs on the device: _fleet_select
@@ -2882,10 +2924,6 @@ class FleetTable:
 
         ``host_rows`` is how many rows of the caller's batch left the fleet
         for the host path (stamped on the span; 0 on the fast paths).
-
-        ``ids`` (optional) is id() of every position's object (int64[n]),
-        from a caller that swept ``problems`` already (the engine's diffs):
-        the upsert phase diffs by it and makes no sweep of its own.
 
         ``quota`` (optional) is the QuotaSnapshot the batch is admitted
         against, from a caller whose whole batch rides the table: the pass
@@ -2909,7 +2947,7 @@ class FleetTable:
             self._derived_outcome = "kept"
             self._sync_ns()
             res = self._schedule_pass(
-                problems, compiled, delta, selections, select, ids
+                problems, compiled, moved, selections, select
             )
             if quota is not None:
                 self._record_admission(res, quota)
@@ -2927,7 +2965,7 @@ class FleetTable:
             sp.attrs["host_rows"] = int(host_rows)
             sp.attrs["derived"] = self._derived_outcome
             self._derived_tally[self._derived_outcome].inc()
-            sp.attrs["wide_rows"] = wide = self._derived.wide_rows
+            sp.attrs["wide_rows"] = wide = self.batch.derived.wide_rows
             sp.attrs["cell_bytes"] = self._cell_bytes
             self._wide_tally.inc(wide)
             self._emit_phase_spans()
@@ -3008,7 +3046,7 @@ class FleetTable:
                 "scheduler.select.region_table, as this table does)"
             )
         c = self.engine.snapshot.num_clusters
-        cache = self._select_cache
+        cache = self.batch.select_rows
         if not (
             cache is not None
             and cache.rows_np is rows_np
@@ -3025,7 +3063,7 @@ class FleetTable:
             cache = _SelectRows(
                 select, rows_np, jnp.asarray(ar), n, chunk, n_chunks
             )
-            self._select_cache = cache
+            self.batch.select_rows = cache
         chunk, n_chunks = cache.chunk, cache.n_chunks
         if self._dev_subsets is None:
             self._dev_subsets = tuple(
@@ -3061,7 +3099,7 @@ class FleetTable:
         """The pass's multi-term rows as the term kernel takes them: an
         int32 row vector padded to whole chunks, kept while the same rows
         come again unpacked (the identity fast path uploads nothing)."""
-        cache = self._term_cache
+        cache = self.batch.terms
         if cache is not None and cache.rows_np is rows_np:
             return cache
         st = self._st
@@ -3079,7 +3117,7 @@ class FleetTable:
             rows_np, rows_dev, n, chunk, n_chunks,
             int((st["evict_sites"][rows_np, 0] >= 0).sum()),
         )
-        self._term_cache = cache
+        self.batch.terms = cache
         return cache
 
     def _terms_on_device(self, rows_np: np.ndarray) -> None:
@@ -3337,6 +3375,7 @@ class FleetTable:
                 return sum(nb(v) for v in x)
             return int(getattr(x, "nbytes", 0))
 
+        rec = self.batch
         return {
             "packed_grid": nb(self._dev_state) + nb(self._dev_term_slots)
             + nb(self._dev_term_sel) + nb(self._dev_quota) + nb(self._dev_wide)
@@ -3344,9 +3383,11 @@ class FleetTable:
             "slot_tables": nb(self._dev_tables) + nb(self._dev_spread)
             + nb(self._dev_subsets),
             "donated_residents": nb(self._res_dense) + nb(self._res_meta),
-            "rows_index": nb(self._all_rows_dev)
-            + (nb(self._select_cache.rows_dev) if self._select_cache else 0)
-            + (nb(self._term_cache.rows_dev) if self._term_cache else 0),
+            "rows_index": nb(self._all_rows_dev) + (
+                nb(rec.select_rows and rec.select_rows.rows_dev)
+                + nb(rec.terms and rec.terms.rows_dev)
+                if rec is not None else 0
+            ),
         }
 
     def _buffer_platform(self) -> str:
@@ -3441,7 +3482,7 @@ class FleetTable:
             },
             "prep": {
                 "derived": self._derived_outcome,
-                "wide_rows": self._derived.wide_rows,
+                "wide_rows": self.batch.derived.wide_rows,
                 "cell_bytes": self._cell_bytes,
             },
             "dispatch": {"compile": fresh} if fresh else {},
@@ -3466,14 +3507,16 @@ class FleetTable:
         for name, total in seconds.items():
             kernel_phase_seconds.observe(total, phase=name.split(".")[1])
 
-    def _batch_derived(self, rows_np: np.ndarray) -> _BatchDerived:
-        """What a pass derives from the row state of ``rows_np``: kept while
-        the same row vector comes again and no row was packed since (only
-        _pack_rows writes the columns read here; it, a compaction and a
-        growth drop the record), built anew otherwise."""
-        d = self._derived
-        if d is not None and d.rows_np is rows_np:
-            return d
+    def _batch_derived(self) -> _BatchDerived:
+        """What a pass derives from the row state of the resident batch's
+        rows: kept with the record while the same row vector comes again
+        (only _pack_rows writes the columns read here, and a pass that
+        packs a row of the batch holds a new record), built anew
+        otherwise."""
+        rec = self.batch
+        if rec.derived is not None:
+            return rec.derived
+        rows_np = rec.rows_np
         st = self._st
         n = len(rows_np)
         reps_sel = st["replicas"][rows_np]
@@ -3489,7 +3532,7 @@ class FleetTable:
             wide_rows = int(
                 ((first < 0) | (~is_dup & (reps_sel > NARROW_CELL_MAX))).sum()
             )
-        d = self._derived = _BatchDerived(
+        d = rec.derived = _BatchDerived(
             rows_np,
             list(map(self._terms.__getitem__, rows_np.tolist())),
             int(reps_sel.max(initial=0)),
@@ -3504,39 +3547,97 @@ class FleetTable:
         self._derived_outcome = "built"
         return d
 
+    def _hold(
+        self, old, problems, compiled, rows_np, select, unique, epoch,
+    ) -> None:
+        """Replace the resident batch's record ``old`` (the one the pass
+        began with) by an unarmed one. What hangs off it by its row vector
+        is kept where the rows are the same array (no row of the batch was
+        packed since)."""
+        rec = ResidentBatch(
+            problems, compiled, rows_np, select, unique, self._pass, epoch,
+        )
+        if old is not None and old.rows_np is rows_np:
+            rec.derived, rec.terms = old.derived, old.terms
+            rec.select_rows = old.select_rows
+        self.batch = rec
+
+    def _replays(self, compiled, moved, selections) -> bool:
+        """THE replay decision of a batch the engine diffed against the
+        resident one: the untouched rows' answers are served from the host
+        mirrors only while they are what a full pass would answer. That
+        needs the resident rows, no row twice among them (a key twice in
+        the batch: its positions depend on one another, which only a full
+        pass keeps), mirrors that cover them (the record's epoch is the
+        mirrors'), the snapshot generation the batch was armed at (a
+        moved generation moves every answer), the estimators' folded
+        answers, no preemption plane (preempt_select ranks victims across
+        rows: a partial wave cannot see them), at most half of the rows
+        moved (_few), and no moved position that is spread-constrained or
+        host-selected (its selection is the full pass's to arrange)."""
+        rec = self.batch
+        engine = self.engine
+        return (
+            rec is not None
+            and rec.rows_np is not None
+            and rec.unique
+            and len(rec.rows_np) == len(compiled)
+            and _few(len(moved), len(compiled))
+            and selections is None
+            and self._host_entries is not None
+            and self._host_meta is not None
+            and rec.gen == getattr(engine, "_snapshot_gen", 0)
+            and rec.epoch == self._mirror_epoch
+            and getattr(engine, "preempt_source", None) is None
+            and not self._estimates_moved()
+            and not any(compiled[i].spread_single_term for i in moved.tolist())
+        )
+
     def _schedule_pass(
-        self, problems: Sequence, compiled: Sequence, delta=None,
-        selections=None, select=None, ids=None,
+        self, problems: Sequence, compiled: Sequence, moved=None,
+        selections=None, select=None,
     ) -> list:
-        if delta is not None:
-            res = self._schedule_delta(problems, compiled, delta)
+        self.replayed = False
+        if moved is not None and self._replays(compiled, moved, selections):
+            res = self._schedule_delta(problems, compiled, moved, select)
             if res is not None:
                 return res
-            # ineligible (stale mirrors / uncertified / majority dirty):
-            # fall through to the full pass below
+            # the sub pass moved the residents: the full pass below
 
         tmr: dict[str, float] = {}
         t0 = time.perf_counter()
         self._pass += 1
         self.new_trace_last_pass = False
         self._packed_this_pass = self._visited_this_pass = 0
-        ru = self._reuse
-        if ru is not None and ru[0] is problems and ru[1] is compiled:
-            # same batch objects as last pass: rows are current (upsert
-            # would skip every row anyway — this skips the sweep).
-            # _reuse_pass stands in for the last-used stamp of the rows no
-            # pass visits; _compact honors it.
-            rows_np = ru[2]
-            self._reuse_pass = self._pass
-            self._upsert_tally[0].inc(len(rows_np))
-            if select is None and selections is None:
-                select = ru[3]
+        rec = self.batch
+        if (
+            rec is not None
+            and rec.problems is problems
+            and rec.compiled is compiled
+        ):
+            if rec.rows_np is None:
+                # the rows were remapped under the same lists: walk them
+                rec.rows_np, rec.unique = self.upsert(problems, compiled)
+                self.batch = rec
             else:
-                self._reuse = (problems, compiled, rows_np, select, ru[4])
+                # same batch objects as last pass: rows are current
+                # (upsert would skip every row anyway — this skips the
+                # sweep)
+                self._upsert_tally[0].inc(len(rec.rows_np))
+            # ``live`` stands in for the last-used stamp of the rows no
+            # pass visits; _compact honors it.
+            rec.live = self._pass
+            if select is None and selections is None:
+                select = rec.select
+            else:
+                rec.select = select
         else:
-            rows_np, ids = self.upsert(problems, compiled, ids)
-            self._reuse = (problems, compiled, rows_np, select, ids)
-            self._reuse_pass = self._pass
+            rows_np, unique = self.upsert(problems, compiled, moved)
+            self._hold(
+                rec, problems, compiled, rows_np, select, unique,
+                -1,  # the pass's end sets the epoch that covers the rows
+            )
+        rows_np = self.batch.rows_np
         if selections is not None:
             tmr["sel_moved"] = self._apply_selections(rows_np, selections)
         t0 = self._phase(tmr, "upsert", t0)
@@ -3583,7 +3684,7 @@ class FleetTable:
         eff_chunk = min(self.chunk, _pow2(max(n, 256)))
         n_pad = max(eff_chunk, -(-n // eff_chunk) * eff_chunk)
         n_chunks = n_pad // eff_chunk
-        d = self._batch_derived(rows_np)
+        d = self._batch_derived()
         # all-rows storm mode: the row-index upload is cached on device
         if d.is_all:
             if self._all_rows_n != n or self._all_rows_dev is None or (
@@ -3644,9 +3745,9 @@ class FleetTable:
             # 13 bits and the count 8
             pack21=c <= (1 << 13) and self._cell_bytes == 1, t0=t0,
         )
-        # this pass dispatched every reuse row, so the mirrors now cover
-        # them at the current epoch — the delta-eligibility fence
-        self._reuse_epoch = self._mirror_epoch
+        # this pass dispatched every row of the batch, so the mirrors now
+        # cover them at the current epoch — the replay fence
+        self.batch.epoch = self._mirror_epoch
         return res
 
     #: full-pass buffer-tuning attributes frozen across a delta sub-pass:
@@ -3657,35 +3758,17 @@ class FleetTable:
         "_last_changed", "_last_dtotal", "_delta_live",
     )
 
-    def _schedule_delta(self, problems, compiled, delta):
-        """Partial pass: pack + dispatch ONLY the ``delta`` positions,
-        replay every other row's result from the host mirrors. Returns
-        None when ineligible — stale mirrors (a resident realloc since
-        the covering pass), a moved snapshot generation, an uncertified
-        kernel set, or a majority-dirty batch where the full pass is
-        simply cheaper — and the caller runs the full pass."""
-        ru = self._reuse
+    def _schedule_delta(self, problems, compiled, moved, select):
+        """Partial pass: pack + dispatch ONLY the ``moved`` positions,
+        replay every other row's result from the host mirrors (_replays
+        decided it may). Returns None where the sub pass reallocated the
+        residents or grew the table (the replay base is gone), and the
+        caller runs the full pass."""
+        rec = self.batch
         n = len(problems)
-        if (
-            ru is None
-            or len(ru[0]) != n
-            or len(ru[2]) != n
-            or self._host_entries is None
-            or self._host_meta is None
-            or getattr(self.engine, "_snapshot_gen", 0) != self._snapshot_gen
-            or self._reuse_epoch != self._mirror_epoch
-            or not delta_certified()
-            or self._estimates_moved()
-        ):
-            return None
-        idx = np.unique(np.asarray(list(delta), np.int64))
-        if idx.size and (idx[0] < 0 or idx[-1] >= n):
-            return None
-        if idx.size * 2 > n:
-            return None  # majority dirty: the full pass wins
         t_all = time.perf_counter()
-        rows_full = ru[2]
-        n_sub = int(idx.size)
+        rows_full = rec.rows_np
+        n_sub = int(moved.size)
         quota = self._quota_pass
         if n_sub == 0:
             # pure replay: nothing changed — serve the whole batch from
@@ -3696,8 +3779,10 @@ class FleetTable:
             self._pass += 1
             self.new_trace_last_pass = False
             self._packed_this_pass = self._visited_this_pass = 0
-            self._reuse = (problems, compiled, rows_full, ru[3], None)
-            self._reuse_pass = self._pass
+            self._hold(
+                rec, problems, compiled, rows_full, select, rec.unique,
+                rec.epoch,
+            )
             self._upsert_tally[0].inc(n)
             tmr: dict[str, float] = {
                 "rows_visited": 0.0,
@@ -3705,12 +3790,14 @@ class FleetTable:
                 "rows_replayed": float(n),
                 "dirty_rows": 0.0,
             }
-            res = self._replay_result(problems, rows_full, tmr)
+            res = self._replay_result(problems, tmr)
             self._phase(tmr, "post", t_all)
             self.last_breakdown = tmr
+            self.replayed = True
             return res
-        sub_p = [problems[int(i)] for i in idx]
-        sub_c = [compiled[int(i)] for i in idx]
+        idx = moved.tolist()
+        sub_p = [problems[i] for i in idx]
+        sub_c = [compiled[i] for i in idx]
         epoch = self._mirror_epoch
         cap_before = self.cap
         tune = tuple(getattr(self, a) for a in self._TUNE_ATTRS)
@@ -3736,50 +3823,54 @@ class FleetTable:
         if (
             self._mirror_epoch != epoch
             or self.cap != cap_before
-            or self._reuse is None
+            or self.batch is None
         ):
             # a resident/mirror realloc (or table growth) happened inside
             # the sub pass: the replay base for the untouched rows is
             # gone — hand back to the caller for a full pass
             return None
-        sub_rows = self._reuse[2]
-        rows_new = rows_full
-        if not np.array_equal(sub_rows, rows_full[idx]):
+        sub_rows = self.batch.rows_np
+        rows_new, unique = rows_full, rec.unique
+        if not np.array_equal(sub_rows, rows_full[moved]):
             rows_new = rows_full.copy()
-            rows_new[idx] = sub_rows
+            rows_new[moved] = sub_rows
+            unique = _distinct(rows_new, self.n_rows)
         tmr = self.last_breakdown  # the sub pass's phase breakdown
         tmr["rows_replayed"] = float(n - n_sub)
         tmr["dirty_rows"] = float(n_sub)
-        # the swapped-in rows are never spread-constrained (the engine's
-        # delta pass sends such a batch through the full prologue), so the
-        # batch's device-selected positions stand
-        self._reuse = (problems, compiled, rows_new, ru[3], None)
-        self._reuse_pass = self._pass
+        # the moved rows are never spread-constrained (_replays), so the
+        # batch's device-selected positions are the engine's
+        self._hold(
+            self.batch, problems, compiled, rows_new, select, unique,
+            self._mirror_epoch,
+        )
         self._upsert_tally[0].inc(n - n_sub)
         if quota is not None:
-            self._admit_on_device(rows_new, None, changed=(rows_full, idx))
+            self._admit_on_device(rows_new, None, changed=(rows_full, moved))
         t0 = time.perf_counter()
-        res = self._replay_result(problems, rows_new, tmr)
+        res = self._replay_result(problems, tmr)
         self._phase(tmr, "post", t0)
+        self.replayed = True
         return res
 
-    def _replay_result(self, problems, rows_full, tmr):
-        """Batch result for ``rows_full`` built entirely from the host
-        mirrors (entry runs + meta words) — the replay half of a delta
-        pass. The mirrors cover every reuse row by induction: each row
-        was dispatched by the pass that established the mapping (or a
-        later one), and the _mirror_epoch fence rejects any realloc in
-        between."""
+    def _replay_result(self, problems, tmr):
+        """Batch result for the resident batch's rows built entirely from
+        the host mirrors (entry runs + meta words) — the replay half of a
+        delta pass. The mirrors cover every row of the batch by induction:
+        each row was dispatched by the pass that established the mapping
+        (or a later one), and the _mirror_epoch fence rejects any realloc
+        in between."""
         n = len(problems)
-        d = self._batch_derived(rows_full)
+        rows_full = self.batch.rows_np
+        d = self._batch_derived()
         eff_chunk = min(self.chunk, _pow2(max(n, 256)))
         n_pad = max(eff_chunk, -(-n // eff_chunk) * eff_chunk)
         bits_src = None
         if d.need_bits:
-            # over the FULL reuse rows (a replayed Duplicated row's consumer
-            # needs the whole batch's bitsets, not the dirty sub-batch's);
-            # the row-index upload waits for the first access: most delta
-            # batches never decode a Duplicated row
+            # over the FULL batch's rows (a replayed Duplicated row's
+            # consumer needs the whole batch's bitsets, not the dirty
+            # sub-batch's); the row-index upload waits for the first
+            # access: most delta batches never decode a Duplicated row
             def rows_dev():
                 ar = np.full(n_pad, -1, np.int32)
                 ar[:n] = rows_full
@@ -3962,7 +4053,7 @@ class FleetTable:
         on a steady pass) and, only when rows changed, _fleet_entries over
         exactly those rows with an exactly-sized entry buffer (no
         overflow rerun by construction)."""
-        d = self._batch_derived(rows_np)
+        d = self._batch_derived()
         has_agg, is_all = d.has_agg, d.is_all
         cb = self._cell_bytes
         sb = 8 * cb  # bits of a count in the meta and entry words

@@ -278,17 +278,6 @@ ENV_FLAGS: dict[str, EnvFlag] = {
             "engine pass (the quota/fault-injection pattern).",
         ),
         EnvFlag(
-            "KARMADA_TPU_DELTA_SOLVE", "1",
-            "Incremental (dirty-row) solve kill switch (scheduler engine "
-            "+ fleet table): 0 forces every pass back onto the full "
-            "repack/resolve path — churn waves re-dispatch the whole "
-            "batch instead of packing only dirty rows against the "
-            "resident mesh state. Disarmed costs one env read per pass; "
-            "eligibility is additionally gated on the graftlint "
-            "delta-safety certification (tools/graftlint/dep.py) at "
-            "arm time.",
-        ),
-        EnvFlag(
             "KARMADA_TPU_DESCHEDULE_MAX_DISRUPTION", "64",
             "Continuous-descheduler disruption budget: the maximum "
             "bindings one drift-rebalance round may stamp "

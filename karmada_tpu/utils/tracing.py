@@ -98,27 +98,26 @@ SPAN_NAMES: dict[str, str] = {
     "scheduler.schedule": (
         "one TensorScheduler.schedule call, entry to return: the root of "
         "an engine wave (rows; path = identity, the armed batch came "
-        "again; delta, a minority of its positions moved; full, the "
-        "whole prologue ran and the fleet table took rows; host, no "
-        "fleet pass)"
+        "again; delta, a minority of its positions moved and the fleet "
+        "table replayed the rest; full, the fleet table dispatched every "
+        "row; host, no fleet pass)"
     ),
     "scheduler.identity": (
-        "the batch-identity check: the id() sweep over the batch and, on "
-        "a miss, the diff against the armed batch + the dirty keys (rows "
-        "/ hit / moved attrs); under scheduler.pack where the full path "
-        "makes it (the swap diff where generation and mask_token moved; "
-        "under a standing token the swap diff takes the identity "
-        "branch's sweep)"
+        "the one diff of a batch against the armed one (ResidentBatch."
+        "diff): the id() sweep, the compare, the dirty keys, the majority "
+        "rule (rows / hit / moved attrs); at most one a pass, under "
+        "scheduler.pack where generation and mask_token both moved"
     ),
     "scheduler.pack": (
         "host prologue of a pass: placement compile + spread selection + "
-        "eligibility partition (on the delta path: the moved positions' "
-        "compile and eligibility check); rows = positions visited, kept = "
-        "positions a swapped batch's diff against the armed one spared it"
+        "eligibility partition (where a minority moved: the moved "
+        "positions' compile and eligibility check); rows = positions "
+        "visited, kept = positions the diff against the armed batch "
+        "spared it"
     ),
     "scheduler.compile": (
         "under scheduler.pack: the compiled-placement look-up of every "
-        "position visited (a swap diff: the armed batch's distinct "
+        "position visited (a diffed batch: the armed batch's distinct "
         "placements compiled anew and the take that lists them by "
         "position, or under a standing mask_token their look-ups and the "
         "armed list copied; rows / placements attrs)"
@@ -130,7 +129,7 @@ SPAN_NAMES: dict[str, str] = {
     ),
     "scheduler.eligible": (
         "under scheduler.pack: the fleet-eligibility partition of the "
-        "batch (a swap diff: the moved positions' look-up and predicate; "
+        "batch (a diffed batch: the moved positions' look-up and predicate; "
         "rows = positions visited / fleet_rows attrs; where rows leave "
         "for the host path, wide_rows = those past the replica bound)"
     ),
@@ -140,9 +139,9 @@ SPAN_NAMES: dict[str, str] = {
     ),
     "scheduler.rearm": (
         "from the fleet table's answer to the engine's: arming the "
-        "identity path (with the ids of the pass's sweep; a walk no diff "
-        "came before sweeps here), or the merge with the host path's "
-        "rows (scheduler.host its child; rows / host_rows attrs)"
+        "table's record of the batch (with the ids of the pass's diff; a "
+        "walk no diff came before sweeps here), or the merge with the host "
+        "path's rows (scheduler.host its child; rows / host_rows attrs)"
     ),
     "scheduler.select": (
         "only when the batch holds spread-constrained rows: the host's "

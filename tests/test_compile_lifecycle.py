@@ -330,13 +330,19 @@ class TestRestoreContract:
         reaches its 300 rows only in the storm, so the recorded cold
         start (256 rows) ran the floor bucket at another shape. Full
         passes only (the delta path freezes cap tuning, so shrink
-        dynamics live on the full-pass side); the delta quanta are cut to
-        toy size so 300 rows x 50 clusters move them."""
+        dynamics live on the full-pass side: every pass moves the
+        snapshot generation, so no answer is replayed); the delta quanta
+        are cut to toy size so 300 rows x 50 clusters move them."""
         import karmada_tpu.scheduler.fleet as fleet_mod
 
         monkeypatch.setattr(fleet_mod, "D_FLOOR", 64)
         monkeypatch.setattr(fleet_mod, "D_ROUND", 256)
-        monkeypatch.setenv("KARMADA_TPU_DELTA_SOLVE", "0")
+
+        def full_pass(eng, problems):
+            # the same snapshot under a new generation: the pass
+            # dispatches every row
+            assert eng.update_snapshot(eng.snapshot)
+            return eng.schedule(problems)
 
         def churned(problems, seed):
             rng = np.random.default_rng(seed)
@@ -396,7 +402,7 @@ class TestRestoreContract:
                 set(eng._fleet._seen_traces) if eng._fleet is not None
                 else eng.trace_manifest.warmed_keys()
             )
-            eng.schedule(problems)
+            full_pass(eng, problems)
             return [
                 k for k in eng._fleet._seen_traces - before
                 if k[0] in solve_fams
@@ -406,7 +412,7 @@ class TestRestoreContract:
         snap = ClusterSnapshot(synthetic_fleet(C, seed=7))
         eng = TensorScheduler(snap, trace_manifest=str(path))
         for problems in recorded_life():
-            eng.schedule(problems)
+            full_pass(eng, problems)
         churn_records = path.read_bytes()
         settle_start = problems
         # the repro: keep settling THIS engine (light churn, demand near

@@ -18,13 +18,12 @@ Coverage map:
   delta path without debiting the live quota plane;
 - chaos-seeded churn: a PR 7 fault-injection cluster kill lands mid
   churn sequence; placements must exclude the dead member, preserve
-  totals, and match a delta-disabled full re-solve bit for bit.
+  totals, and match a fresh engine's full re-solve bit for bit.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import numpy as np
 import pytest
@@ -129,17 +128,16 @@ def churned(problems, rng, count):
 
 
 def full_solve(engine, problems):
-    """One pass with the delta path killed (the KARMADA_TPU_DELTA_SOLVE
-    switch is read per pass) — the full-solve oracle side."""
-    saved = os.environ.get("KARMADA_TPU_DELTA_SOLVE")
-    os.environ["KARMADA_TPU_DELTA_SOLVE"] = "0"
-    try:
-        return engine.schedule(problems)
-    finally:
-        if saved is None:
-            os.environ.pop("KARMADA_TPU_DELTA_SOLVE", None)
-        else:
-            os.environ["KARMADA_TPU_DELTA_SOLVE"] = saved
+    """The full-solve oracle side: a fresh engine over ``engine``'s
+    snapshot and mesh holds no armed batch, so it walks the batch and
+    dispatches every row."""
+    fresh = TensorScheduler(
+        engine.snapshot,
+        mesh=engine.mesh if engine.mesh is not None else False,
+        trace_manifest="",
+    )
+    fresh.fleet_threshold = engine.fleet_threshold
+    return fresh.schedule(problems)
 
 
 def decoded(results):
@@ -163,7 +161,7 @@ class TestDeltaVsFullIdentity:
     @pytest.mark.parametrize("devices", (1, 2, 4, 8))
     def test_identity_across_mesh_sizes(self, snap, devices):
         """The same churn sequence through a delta engine and a
-        delta-disabled full engine on every mesh shape the conftest
+        fresh full-solve engine on every mesh shape the conftest
         virtual devices can host: placements bit-identical each round,
         and the delta engine's breakdown proves each round dispatched
         exactly the churn set."""
@@ -468,12 +466,12 @@ class TestChaosChurn:
     def teardown_method(self):
         faultinject.disarm()
 
-    def test_seeded_cluster_kill_mid_churn(self, monkeypatch):
+    def test_seeded_cluster_kill_mid_churn(self):
         """A PR 7 seeded fault (cluster.health=down) lands in the middle
         of a churn sequence: the snapshot swap invalidates the resident
         base, fresh placements must avoid the tainted member, totals
         hold for churned bindings, and the settled plane's placements
-        match a delta-disabled full re-solve of every binding bit for
+        match a fresh engine's full re-solve of every binding bit for
         bit."""
         cp, _members = small_plane()
         n_bindings = 6
@@ -533,16 +531,23 @@ class TestChaosChurn:
         faultinject.disarm()
         cp.settle()
 
-        # the settled plane vs a delta-disabled full re-solve: Steady
-        # semantics credit prev, so a full solve of the same problems
-        # answers the committed placements exactly
-        monkeypatch.setenv("KARMADA_TPU_DELTA_SOLVE", "0")
+        # the settled plane vs a full re-solve by a fresh engine over the
+        # plane's snapshot (no armed batch: it walks): Steady semantics
+        # credit prev, so a full solve of the same problems answers the
+        # committed placements exactly
         sched = cp.scheduler
+        engine = sched._inproc_engine()
         final = placements()
         for i in range(n_bindings):
             key = f"default/w{i}-deployment"
             rb = cp.store.get("ResourceBinding", key)
             problem = sched._problem_for(key, rb, False)
-            res = sched.dry_solve([problem])
+            fresh = TensorScheduler(
+                engine.snapshot,
+                extra_estimators=engine.extra_estimators,
+                disabled_plugins=engine.disabled_plugins,
+                custom_filters=engine.custom_filters,
+            )
+            res = fresh.schedule([problem])
             assert res[0].success, (key, res[0].error)
             assert dict(res[0].clusters) == final[key], key

@@ -1,9 +1,9 @@
 """ISSUE 36: a swapped batch costs the engine its swapped rows too. Where a
 batch is armed and the snapshot's generation AND ``mask_token`` moved, the
 full path diffs a batch of the armed batch's length by object identity
-(``TensorScheduler._swap_diff``): one ``id()`` sweep, the armed batch's
-distinct placements compiled anew, the moved positions alone compiled and
-held to the fleet-eligibility predicate.
+(``ResidentBatch.diff``, then ``TensorScheduler._moved_pass``): one ``id()``
+sweep, the armed batch's distinct placements compiled anew, the moved
+positions alone compiled and held to the fleet-eligibility predicate.
 
 (a)-(g) equivalence: every row's ``clusters``, ``affinity_name`` and
     ``error`` against a FRESH engine over the same snapshot and batch; what
@@ -18,11 +18,14 @@ held to the fleet-eligibility predicate.
 
 The same route where the generation moved under an UNMOVED
 ``mask_token`` (availability alone drifted, the armed batch missed its
-identity check and ``_delta_pass`` declined for the moved generation): the
-identity branch's sweep reused, the armed compiled list kept (no compile),
-the moved positions visited, every row dispatched. (a) and (i) again under
-such a move, a ring of drifts with a minority of swapped objects a step, and
-the route under an active ``QuotaSnapshot`` against the partition route.
+identity check and the table declined the replay for the moved
+generation): the identity branch's sweep reused, the armed compiled list
+kept (no compile), the moved positions visited, every row dispatched. (a)
+and (i) again under such a move, a ring of drifts with a minority of
+swapped objects a step, and the route under an active ``QuotaSnapshot``
+against the partition route. Last, the route choice itself: seven waves,
+one diff each, four routes; host selections a moved pass spares, a pass
+with host rows, and a key twice in the batch, each against a fresh engine.
 """
 
 import copy
@@ -207,7 +210,7 @@ def test_a_swapped_batch_answers_as_a_fresh_engine(move, name):
         rng, name, move)
     engine = _engine(healthy)
     engine.schedule(base)
-    old_fc = list(engine._batch_cache[1])
+    old_fc = list(engine._fleet.batch.compiled)
     assert engine.update_snapshot(tainted)
     tracer.clear()
     got = _copy_out(engine.schedule(after))
@@ -243,7 +246,7 @@ def test_a_swapped_batch_answers_as_a_fresh_engine(move, name):
         assert root["attrs"]["path"] == "full"
     else:
         assert root["attrs"]["path"] == "identity"
-        fp, fc = engine._batch_cache
+        fp, fc = engine._fleet.batch.problems, engine._fleet.batch.compiled
         assert all(a is b for a, b in zip(fp, after))
         if move == "token":
             # no compiled placement of the old token handed on
@@ -280,7 +283,7 @@ def test_a_ring_of_losses_and_returns(seed):
         assert 0 < pack["attrs"]["rows"] < N // 2
         assert pack["attrs"]["rows"] + pack["attrs"]["kept"] == N
         _same_as_fresh(snap, problems, got)
-        built.append(engine._batch_placements)
+        built.append(engine._fleet.batch.placements)
         tracer.clear()
         engine.schedule(problems)
         (root,) = _spans("scheduler.schedule")
@@ -455,7 +458,7 @@ def test_a_ring_of_drifts_with_swapped_objects(seed):
         assert solve["attrs"]["rows_visited"] == k
         assert [b - a for a, b in zip(before, tally())] == [N - k, k]
         _same_as_fresh(snap, problems, got)
-        built.append(engine._batch_placements)
+        built.append(engine._fleet.batch.placements)
         if step % 2:
             tracer.clear()
             again = _copy_out(engine.schedule(problems))
@@ -519,3 +522,232 @@ def test_a_drifted_swap_under_quota_answers_as_the_partition_route():
     for i, (a, b) in enumerate(zip(got, want)):
         assert (a.key, a.error) == (b.key, b.error), i
         assert dict(a.clusters) == dict(b.clusters), i
+
+
+# -- the route choice: one diff, four routes ---------------------------------
+
+
+WAVES = ("identity", "delta", "swap-drift", "swap-token", "walk",
+         "dirty-unmapped", "delta-spread")
+
+
+@pytest.mark.parametrize("wave", WAVES)
+def test_one_diff_chooses_the_route(wave):
+    """Each wave kind after an armed pass: the root ``path``, exactly one
+    ``scheduler.identity`` record (its ``hit`` and ``moved``), what
+    ``scheduler.pack`` visited and kept, what the table visited, and every
+    answer as a fresh engine gives it. ``dirty-unmapped``: the same list
+    with a dirty key the batch does not hold, which the table serves as a
+    pure replay; ``delta-spread``: a delta at the standing generation that
+    moves spread-constrained rows, whose selection the full pass arranges:
+    every row is dispatched."""
+    rng = np.random.default_rng(4300 + WAVES.index(wave))
+    healthy, tainted, lost = _federation(rng)
+    placements = _placements(rng, terms=(1, 2))
+    if wave == "delta-spread":
+        placements += [_placement(rng, s, 1, False, SPREAD)
+                       for s in ("dynamic", "aggregated", "duplicated")]
+    base = _batch(rng, placements)
+    engine = _engine(healthy)
+    engine.schedule(base)
+    snap, after, dirty = healthy, _after(base, lost), None
+    if wave in ("identity", "dirty-unmapped"):
+        after = base
+    if wave == "dirty-unmapped":
+        dirty = {"no-such-binding"}
+    elif wave == "swap-drift":
+        snap = _drifted(healthy, rng)  # the generation moved, the token not
+    elif wave == "swap-token":
+        snap = tainted  # both moved
+    elif wave == "walk":
+        after = _after(base, lost, every=1)  # every position moved
+    elif wave == "delta-spread":
+        assert any(after[i] is not p and p.placement.spread_constraints
+                   for i, p in enumerate(base))
+    if snap is not healthy:
+        assert engine.update_snapshot(snap)
+    k = sum(1 for a, b in zip(after, base) if a is not b)
+    assert (k == 0) == (after is base) and (k == N) == (wave == "walk")
+    replays = wave in ("delta", "dirty-unmapped")
+    tracer.clear()
+    got = _copy_out(engine.schedule(after, dirty_keys=dirty))
+    (root,) = _spans("scheduler.schedule")
+    assert root["attrs"]["path"] == (
+        "identity" if wave == "identity" else "delta" if replays else "full")
+    (ident,) = _spans("scheduler.identity")
+    assert (ident["attrs"]["rows"], ident["attrs"]["hit"],
+            ident["attrs"]["moved"]) == (N, int(wave == "identity"), k)
+    packs = _spans("scheduler.pack")
+    if wave == "identity":
+        assert packs == []
+    else:
+        (pack,) = packs
+        assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (
+            (N, 0) if wave == "walk" else (k, N - k))
+        # the sweep is the prologue's first stage where the token moved
+        parent = pack if wave == "swap-token" else root
+        assert ident["parent_id"] == parent["span_id"]
+    (solve,) = _spans("scheduler.solve")
+    assert solve["attrs"]["rows_visited"] == k
+    assert solve["attrs"]["dirty_rows"] == (k if replays else 0)
+    _same_as_fresh(snap, after, got)
+    # and the next pass over the same list is the identity route
+    tracer.clear()
+    again = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    assert root["attrs"]["path"] == "identity"
+    for i, (a, b) in enumerate(zip(again, got)):
+        _same(a, b, i)
+
+
+def test_host_selections_a_moved_pass_spared_are_not_served_after_a_drift():
+    """More regions than the device's subset table: the host selects for
+    the spread rows, and the batch is armed with no token. A minority of
+    other rows moves at the standing generation with the preemption plane
+    armed (the table dispatches every row, no replay): the spared rows
+    keep the host's selections, so the record still carries no token, and
+    the drift that follows walks the batch and selects anew."""
+    from karmada_tpu.scheduler import BindingProblem
+    from karmada_tpu.scheduler import select as select_mod
+    from karmada_tpu.utils import builders
+
+    regions = [f"r{k}" for k in range(select_mod.R_CAP + 1)]
+    rng = np.random.default_rng(4301)
+    clusters = [
+        builders.new_cluster(
+            f"m{j:03d}", cpu=str(int(rng.integers(40, 400))),
+            memory="2048Gi", pods=5000, region=regions[j % len(regions)])
+        for j in range(3 * len(regions))
+    ]
+
+    def generation(g):
+        # the regions with room trade places from one generation to the
+        # next, and with them the host's selections
+        for j, cl in enumerate(clusters):
+            full = (j % len(regions) < len(regions) // 2) == (g == 0)
+            alloc = cl.status.resource_summary.allocatable
+            cl.status.resource_summary.allocated = {
+                d: int(v * (0.97 if full else 0.1))
+                for d, v in alloc.items()}
+        return ClusterSnapshot(copy.deepcopy(clusters))
+
+    spread = [builders.dynamic_weight_placement(spread_constraints=[
+        SpreadConstraint(spread_by_field="region", min_groups=2,
+                         max_groups=3),
+        SpreadConstraint(spread_by_field="cluster", min_groups=2,
+                         max_groups=6)]),
+        builders.dynamic_weight_placement(spread_constraints=[
+            SpreadConstraint(spread_by_field="cluster", min_groups=2,
+                             max_groups=4)])]
+    plain = [builders.dynamic_weight_placement(),
+             builders.duplicated_placement()]
+    problems = [
+        BindingProblem(
+            key=f"b{i}", placement=(spread + plain)[i % 4],
+            replicas=int(rng.integers(1, 30)),
+            requests={"cpu": int(rng.choice([250, 1000])),
+                      "memory": 1 << 30},
+            gvk="apps/v1/Deployment")
+        for i in range(360)
+    ]
+
+    def engine_at(snap):
+        eng = TensorScheduler(snap, chunk_size=256, mesh=False)
+        eng.set_preemption(lambda exclude: [])
+        return eng
+
+    snap = generation(0)
+    assert select_mod.region_table(snap) is None
+    engine = engine_at(snap)
+    engine.schedule(problems)
+    rec = engine._fleet.batch
+    assert rec.armed and rec.token is None  # every row rode, host-selected
+    # plain rows move, the spread rows (i % 4 < 2) are spared
+    after = [_twin(p, replicas=p.replicas + 1) if i % 12 == 2 else p
+             for i, p in enumerate(problems)]
+    k = sum(1 for a, b in zip(after, problems) if a is not b)
+    tracer.clear()
+    got = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    (pack,) = _spans("scheduler.pack")
+    assert root["attrs"]["path"] == "full"
+    assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (k, len(after) - k)
+    assert engine._fleet.batch.token is None
+    want = _copy_out(engine_at(snap).schedule(after))
+    for i, (a, b) in enumerate(zip(got, want)):
+        _same(a, b, i)
+    # availability alone drifts: the token stands
+    token = snap.mask_token
+    snap = generation(1)
+    assert engine.update_snapshot(snap) and snap.mask_token == token
+    tracer.clear()
+    got = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    (pack,) = _spans("scheduler.pack")
+    assert root["attrs"]["path"] == "full"
+    assert _spans("scheduler.identity") == []
+    assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (len(after), 0)
+    want = _copy_out(engine_at(snap).schedule(after))
+    for i, (a, b) in enumerate(zip(got, want)):
+        _same(a, b, i)
+
+
+def test_a_pass_with_host_rows_disarms_the_batch():
+    """A pass whose batch leaves a row to the host path holds the fleet
+    rows of that batch, not the armed one: a later batch of the armed
+    batch's length is walked, and none of its answers is another
+    binding's."""
+    rng = np.random.default_rng(4302)
+    healthy, _, _ = _federation(rng)
+    base = _batch(rng, _placements(rng, terms=(1, 2)))
+    engine = _engine(healthy)
+    engine.schedule(base)
+    # the same bindings in reverse order, and one past the eviction tasks
+    # a row holds: N rows ride the table, one takes the host path
+    extra = _twin(base[0], key=base[0].key + "-x",
+                  evict_clusters=tuple(NAMES[: K_EVICT + 1]))
+    engine.schedule(list(reversed(base)) + [extra])
+    assert not engine._fleet.batch.armed
+    after = list(base)
+    after[3] = _twin(base[3], replicas=base[3].replicas + 1)
+    tracer.clear()
+    got = _copy_out(engine.schedule(after))
+    (root,) = _spans("scheduler.schedule")
+    (pack,) = _spans("scheduler.pack")
+    assert root["attrs"]["path"] == "full"
+    assert _spans("scheduler.identity") == []
+    assert (pack["attrs"]["rows"], pack["attrs"]["kept"]) == (N, 0)
+    _same_as_fresh(healthy, after, got)
+
+
+def test_a_key_twice_in_the_batch_replays_nothing():
+    """A batch that holds a binding's key at two positions is armed: the
+    same list again is the identity route, and a swap diffs it. A delta
+    at the standing generation replays nothing (the two positions share a
+    row, so an answer replayed for one may be the other's): every row is
+    dispatched, and every answer is a fresh engine's."""
+    rng = np.random.default_rng(4303)
+    healthy, _, _ = _federation(rng)
+    base = _batch(rng, _placements(rng, terms=(1, 2)))
+    base[7] = _twin(base[20])  # base[20]'s key at position 7 too
+    engine = _engine(healthy)
+    engine.schedule(base)
+    assert engine._fleet.batch.armed and not engine._fleet.batch.unique
+    drifted = _drifted(healthy, rng)
+    moved = {"identity": (), "delta": (7, 30, 41), "swap": (20, 50)}
+    for wave, at in moved.items():
+        after = [_twin(p, replicas=p.replicas + 1) if i in at else p
+                 for i, p in enumerate(base)]
+        snap = drifted if wave == "swap" else healthy
+        if wave == "swap":
+            assert engine.update_snapshot(snap)
+        tracer.clear()
+        got = _copy_out(engine.schedule(after))
+        (root,) = _spans("scheduler.schedule")
+        assert root["attrs"]["path"] == (
+            "identity" if wave == "identity" else "full"), wave
+        packs = _spans("scheduler.pack")
+        assert [p["attrs"]["rows"] for p in packs] == (
+            [] if wave == "identity" else [len(at)]), wave
+        _same_as_fresh(snap, after, got)
+        base = after

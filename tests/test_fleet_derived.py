@@ -1,10 +1,10 @@
 """ISSUE 38: a resident batch keeps what a pass derives from its rows. The
-fleet table holds ONE record (``FleetTable._derived``, read through
-``_batch_derived``) of what a pass derives from its batch's row state: the
+fleet table's record of its resident batch (``ResidentBatch.derived``, read
+through ``_batch_derived``) holds what a pass derives from its row state: the
 affinity names by position, the largest ``replicas`` and previous count
 (``kernel_variant``'s inputs), ``has_agg``, ``is_dup``, ``need_bits``,
 ``is_all``. It is kept while the same row vector comes again and no row of
-the table was packed, and built anew otherwise.
+the batch was packed, and built anew otherwise.
 
 (a) two identity passes: ``derived`` built then kept, one ``terms`` list,
     every row as a fresh table answers it; a result list of the first pass
@@ -54,8 +54,7 @@ def _tally() -> tuple:
 
 
 def _record(engine):
-    table = engine._fleet
-    return table._batch_derived(table._reuse[2])
+    return engine._fleet._batch_derived()
 
 
 def _same_record(got, want) -> None:
@@ -132,7 +131,7 @@ def test_a_swap_builds_once_then_keeps():
     fresh = _same_as_fresh(tainted, after, got)
     _same_as_fresh(tainted, after, got2)
     # multi-term rows hold their terms' names and read the chosen one
-    terms = engine._fleet._derived.terms
+    terms = engine._fleet.batch.derived.terms
     multi = [i for i, t in enumerate(terms) if t.__class__ is tuple]
     assert len(multi) > 100
     assert {terms[i].index(got[i].affinity_name) for i in multi} >= {0, 1}
@@ -186,8 +185,8 @@ def test_rows_repacked_in_place_rebuild_the_record(route):
     engine.schedule(base)
     res_before = engine.schedule(base)
     table = engine._fleet
-    rows_full = table._reuse[2]
-    before = table._derived
+    rows_full = table.batch.rows_np
+    before = table.batch.derived
     assert before.rows_np is rows_full
     assert (before.max_n, before.max_prev) == (9, 3)
     assert not (before.has_agg or before.need_bits)
@@ -202,14 +201,20 @@ def test_rows_repacked_in_place_rebuild_the_record(route):
         fresh = _same_as_fresh(snap, after, got)
     else:
         # what the delta path's sub-pass does to the table: the moved
-        # positions' rows packed in place, the batch's row vector untouched
+        # positions' rows packed in place; the record goes with them, and
+        # one held again over the batch's same row vector derives anew
         sub = [after[i] for i in moved]
         table.upsert(sub, [engine._compiled(p.placement) for p in sub])
+        assert table.batch is None
+        table._hold(
+            None, after, [engine._compiled(p.placement) for p in after],
+            rows_full, None, True, -1,
+        )
         fresh = _engine(snap)
         fresh.schedule(after)
     # the batch's row vector kept its object, the record did not stand
-    assert table._reuse[2] is rows_full
-    now = table._batch_derived(rows_full)
+    assert table.batch.rows_np is rows_full
+    now = table._batch_derived()
     assert now is not before
     _same_record(now, _record(fresh))
     assert (now.max_n, now.max_prev) == (100, 90)
@@ -267,7 +272,7 @@ def test_what_drops_the_record_and_what_does_not(event):
     table = engine._fleet
     rebuilds = metrics.fleet_table_rebuilds.value()
     snap, batch, outcome = event(engine, *rest)
-    assert (table._derived is None) == (outcome == "built")
+    assert (table.batch.derived is None) == (outcome == "built")
     tracer.clear()
     got = _copy_out(engine.schedule(batch))
     (solve,) = _spans("scheduler.solve")

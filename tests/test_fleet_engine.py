@@ -232,7 +232,7 @@ def test_batch_reuse_survives_compaction():
     for _ in range(8):  # advance _pass well past COMPACT_IDLE_PASSES
         eng.schedule(problems)
     ft = eng._fleet
-    assert ft._reuse is not None  # the fast path engaged
+    assert ft.batch.armed  # the fast path engaged
     keys_before = set(ft._key_row)
     assert not ft._compact()  # live batch: nothing to reclaim
     assert set(ft._key_row) == keys_before

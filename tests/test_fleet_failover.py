@@ -392,7 +392,7 @@ def test_the_predicate_is_32_bit_and_holds_near_max_int32():
     walk(jaxpr.jaxpr)
     assert not wide, wide
     table = engine._fleet
-    tr = table._term_cache
+    tr = table.batch.terms
     text = fleet_mod._fleet_terms.lower(
         *table._dev_tables, tr.rows_dev, table._dev_term_slots,
         table._dev_term_sel, *table._dev_state[:-1],

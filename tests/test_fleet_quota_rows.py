@@ -207,7 +207,7 @@ def test_a_quotad_batch_rides_the_table_whole(snap):
              for o in ("admitted", "denied", "unquotad")]
     res = eng.schedule(problems)
     assert isinstance(res, fleet_mod._FleetResultList)
-    assert len(eng._fleet._reuse[2]) == len(problems)
+    assert len(eng._fleet.batch.rows_np) == len(problems)
     assert route_count("resident") == resident + 1
     first_denied = int(np.flatnonzero(res.quota.denied())[0])
     assert type(res[first_denied]) is ScheduleResult
@@ -297,7 +297,7 @@ def test_fifo_follows_the_presented_order_not_the_slot_order(snap):
     before = quota.remaining.copy()
     eng.set_quota(quota)
     res = eng.schedule(shuffled)
-    rows = eng._fleet._reuse[2]
+    rows = eng._fleet.batch.rows_np
     assert not np.array_equal(rows, np.sort(rows))  # slots are not in order
     assert isinstance(res, fleet_mod._FleetResultList)
     denied = assert_wave(snap, shuffled, res, quota, before)
@@ -355,7 +355,7 @@ def test_a_namespace_set_move_rederives_the_column(snap):
     problems = build_problems(snap)
     eng.set_quota(make_quota(snap, problems, capped=(), generation=1))
     eng.schedule(problems)
-    col = eng._fleet._st["ns_idx"][eng._fleet._reuse[2]].copy()
+    col = eng._fleet._st["ns_idx"][eng._fleet.batch.rows_np].copy()
     moved = make_quota(snap, problems, quotad=("t1", "t2", "t4"), capped=(),
                        generation=2)
     before = moved.remaining.copy()
@@ -363,7 +363,7 @@ def test_a_namespace_set_move_rederives_the_column(snap):
     res = eng.schedule(problems)
     assert last_span("scheduler.schedule")["attrs"]["path"] == "identity"
     assert last_span("scheduler.solve")["attrs"]["rows_packed"] == 0
-    now = eng._fleet._st["ns_idx"][eng._fleet._reuse[2]]
+    now = eng._fleet._st["ns_idx"][eng._fleet.batch.rows_np]
     assert not np.array_equal(col, now)
     assert now.tolist() == [
         moved.ns_index.get(p.namespace, -1) for p in problems]
@@ -375,7 +375,7 @@ def test_a_namespace_set_move_rederives_the_column(snap):
     eng.set_quota(same)
     eng.schedule(problems)
     assert eng._fleet._ns_src is same.ns_index
-    assert eng._fleet._st["ns_idx"][eng._fleet._reuse[2]].tolist() == (
+    assert eng._fleet._st["ns_idx"][eng._fleet.batch.rows_np].tolist() == (
         now.tolist())
 
 
@@ -428,7 +428,7 @@ def test_replicas_held_on_a_member_that_left_count_as_held(snap):
     eng.set_quota(quota)
     res = eng.schedule(problems)
     assert isinstance(res, fleet_mod._FleetResultList)
-    rows = eng._fleet._reuse[2]
+    rows = eng._fleet.batch.rows_np
     assert int(eng._fleet._st["prev_rest"][rows].sum()) == 4 * len(
         range(0, len(problems), 3))
     # admission alone: the divider's oracle has no column for such a site

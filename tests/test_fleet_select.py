@@ -421,7 +421,7 @@ def test_more_regions_than_the_table_keeps_the_host_selection(capfd):
                 if s["name"] == "scheduler.host"] == [a["fit_errors"]]
     assert metrics.spread_selections.value(outcome="device") == device0
     assert metrics.spread_selections.value(outcome="computed") > computed0
-    assert engine._batch_token is None  # not reused across a generation
+    assert engine._fleet.batch.token is None  # not reused across a generation
     told = [ln for ln in capfd.readouterr().err.splitlines()
             if ln.startswith("# spread selection on the host")]
     assert len(told) == 1 and f"{select_mod.R_CAP + 1} regions" in told[0]
@@ -436,12 +436,13 @@ def test_one_trace_over_eight_waves_of_a_drifting_ring():
     traces = {k for k in table._seen_traces if k[0] == "T"}
     assert len(traces) == 1
     size = fleet_mod._fleet_select._cache_size()
-    rows_dev = table._select_cache.rows_dev
+    rows_dev = table.batch.select_rows.rows_dev
     for g in range(8):
         assert engine.update_snapshot(_generation(clusters, g % 4))
         engine.schedule(problems)
         assert not engine.last_pass_new_trace, g
-        assert table._select_cache.rows_dev is rows_dev  # one upload for the ring
+        # one upload for the ring
+        assert table.batch.select_rows.rows_dev is rows_dev
     assert {k for k in table._seen_traces if k[0] == "T"} == traces
     assert fleet_mod._fleet_select._cache_size() == size
 

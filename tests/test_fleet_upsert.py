@@ -73,7 +73,7 @@ class RowByRow(FleetTable):
             self._fps = [self._fingerprint(p) for p in self._problems]
         return done
 
-    def upsert(self, problems, compiled):
+    def upsert(self, problems, compiled, moved=None):
         if self.n_rows + len(problems) > self.cap:
             new_keys = sum(
                 1 for p in problems if p.key not in self._key_row
@@ -85,7 +85,7 @@ class RowByRow(FleetTable):
             np.int32, len(problems),
         )
         self._visited_this_pass = len(problems)
-        return rows, None
+        return rows, len(set(rows.tolist())) == len(rows)
 
     def _upsert_one(self, problem, compiled) -> int:
         row = self._key_row.get(problem.key)
@@ -133,7 +133,6 @@ class RowByRow(FleetTable):
                 self._tables_dirty = True
             slots[t] = slot
         st["cp_idx"][row] = slots[0]
-        self._term_cache = None
         gslot = self._gvk_slot.get(problem.gvk)
         if gslot is None:
             gslot = len(self._gvk_list)
@@ -209,13 +208,21 @@ def _engine(rng, tainted=()):
 
 def _upsert_pass(table, engine, problems) -> tuple:
     """The upsert phase of one pass as ``_schedule_pass`` runs it, without
-    the device: (rows, visited, packed, the rows it left dirty)."""
+    the device, over the moved positions of the engine's diff against the
+    armed record (ResidentBatch.diff), and the record held and armed as
+    the engine arms it: (rows, visited, packed, the rows it left dirty)."""
     compiled = [engine._compiled(p.placement) for p in problems]
+    rec = table.batch
+    diff = None
+    if rec is not None and rec.armed and len(rec.problems) == len(problems):
+        diff = rec.diff(problems, None, False)
     table._pass += 1
     table._packed_this_pass = table._visited_this_pass = 0
-    rows, ids = table.upsert(problems, compiled)
-    table._reuse = (problems, compiled, rows, None, ids)
-    table._reuse_pass = table._pass
+    rows, unique = table.upsert(
+        problems, compiled, None if diff is None else diff.moved)
+    table._hold(rec, problems, compiled, rows, None, unique, -1)
+    table.batch.arm(np.fromiter(map(id, problems), np.int64, len(problems)),
+                    None, engine._snapshot_gen, engine.snapshot.mask_token)
     dirty = set(table._dirty)
     table._dirty.clear()  # what _sync_device does once it has uploaded them
     return rows, table._visited_this_pass, table._packed_this_pass, dirty
@@ -234,8 +241,8 @@ def _assert_same_table(a: FleetTable, b: FleetTable) -> None:
     lu = []
     for t in (a, b):
         eff = t._st["last_used"][: t.n_rows].copy()
-        if t._reuse is not None:
-            eff[t._reuse[2]] = t._reuse_pass
+        if t.batch is not None and t.batch.rows_np is not None:
+            eff[t.batch.rows_np] = t.batch.live
         lu.append(eff.tolist())
     assert lu[0] == lu[1]
     assert all(x is y for x, y in zip(a._problems, b._problems))
@@ -452,7 +459,7 @@ def test_a_swapped_batch_is_diffed_not_walked():
             assert upsert["attrs"]["rows_packed"] == packed
             assert upsert["parent_id"] == solve["span_id"]
             # every row holds the object the pass brought
-            rows = table._reuse[2]
+            rows = table.batch.rows_np
             assert all(table._problems[r] is p
                        for r, p in zip(rows, problems))
             want = _host_path(snap, problems)
@@ -525,8 +532,8 @@ def test_a_remapped_table_falls_back_to_the_walk(event):
             table._grow(table.cap * 2)
         else:
             assert table._compact() and table.n_rows == 100
-        assert table._reuse is None
-        # the resident batch's length, but no resident batch: the walk
+        assert table.batch.rows_np is None
+        # the resident batch's length, but no rows of it: the walk
         _, visited, packed, _ = _upsert_pass(table, engine, b2)
         assert (visited, packed) == (100, 34)
     _assert_same_table(got, want)

@@ -375,7 +375,7 @@ def test_the_held_sum_of_a_wide_row_admits_as_the_reference_does():
     eng.set_quota(quota)
     got = eng.schedule(problems)
     assert isinstance(got, fleet_mod._FleetResultList)
-    rows = eng._fleet._reuse[2]
+    rows = eng._fleet.batch.rows_np
     wide = [i for i, p in enumerate(problems) if len(p.prev) > K_PREV]
     assert wide
     assert eng._fleet._st["prev_rest"][rows[wide]].tolist() == [

@@ -209,12 +209,77 @@ def test_delta_safe_registry_matches_contract(safe_rows):
             r["row_coupled"] is False and r["verdict"] == "independent"
         )
     # the anchor kernels of each class (pinned so a lattice regression
-    # that degrades proofs to 'unproven' cannot pass silently)
-    assert by_name["divide_replicas"]["delta_safe"] is True
+    # that degrades proofs to 'unproven' cannot pass silently). The
+    # kernels the fleet table's delta sub-pass dispatches over a partial
+    # batch are the delta route's eligibility contract: nothing checks
+    # them at run time, so a change that loses a certificate fails here
+    for name in (
+        "divide_replicas", "take_by_weight_batch", "general_estimate",
+    ):
+        assert by_name[name]["delta_safe"] is True, name
     assert by_name["explain_pass"]["delta_safe"] is True
     assert by_name["quota_admit"]["verdict"] == "coupled"
     assert by_name["masks.first_fit_group"]["plane_coupled"] is True
     assert not by_name["quota_admit"]["delta_safe"]
+
+
+#: a serving process with no ``tools`` package: the scheduler imports,
+#: schedules a batch, then a churned copy of it, which takes the delta
+#: route (its untouched rows replayed), and ``tools`` was never imported
+_NO_TOOLS = """
+import importlib.abc, sys
+import numpy as np
+
+
+class NoTools(importlib.abc.MetaPathFinder):
+    def find_spec(self, name, path=None, target=None):
+        if name == "tools" or name.startswith("tools."):
+            raise ImportError("no tools package in a serving process")
+
+
+sys.meta_path.insert(0, NoTools())
+sys.modules.pop("tools", None)
+from karmada_tpu.scheduler import BindingProblem, ClusterSnapshot
+from karmada_tpu.scheduler.core import TensorScheduler
+import karmada_tpu.scheduler.fleet  # noqa: F401
+from karmada_tpu.utils.builders import dynamic_weight_placement
+from karmada_tpu.utils.builders import synthetic_fleet
+from karmada_tpu.utils.tracing import tracer
+
+snap = ClusterSnapshot(synthetic_fleet(16, seed=7))
+pl = dynamic_weight_placement()
+first = [BindingProblem(key=f"b{i}", placement=pl, replicas=1 + i % 7,
+                        requests={"cpu": 500}, gvk="apps/v1/Deployment")
+         for i in range(300)]
+engine = TensorScheduler(snap, mesh=False, trace_manifest="")
+engine.fleet_threshold = 1
+engine.schedule(first)
+second = list(first)
+for i in (3, 40, 41, 200):
+    p = first[i]
+    second[i] = BindingProblem(key=p.key, placement=pl,
+                               replicas=p.replicas + 1,
+                               requests=p.requests, gvk=p.gvk)
+tracer.clear()
+engine.schedule(second)
+(root,) = [s for s in tracer.dump() if s["name"] == "scheduler.schedule"]
+assert root["attrs"]["path"] == "delta", root["attrs"]
+assert engine._fleet.last_breakdown["dirty_rows"] == 4
+assert not any(m == "tools" or m.startswith("tools.") for m in sys.modules)
+print("delta")
+"""
+
+
+def test_the_delta_route_engages_without_the_tools_package(tmp_path):
+    import os
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_TOOLS], cwd=tmp_path, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().splitlines()[-1] == "delta"
 
 
 def test_delta_safe_table_renders_every_kernel(safe_rows):

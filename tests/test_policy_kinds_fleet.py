@@ -251,11 +251,12 @@ def test_a_moved_selection_changes_that_rows_answer_alone():
     engine = TensorScheduler(first, chunk_size=256)
     base = _copy_out(engine.schedule(dep.problems))
     table = engine._fleet
-    assert table._dev_spread is None and table._select_cache is None
+    assert table._dev_spread is None and table.batch.select_rows is None
     assert metrics.spread_host_selected_rows.value() > 0
     # every row rides (four regions exist here, so no selection fails),
     # none of them device-selected
-    fp, fc, _, select, _ = table._reuse
+    rec = table.batch
+    fp, fc, select = rec.problems, rec.compiled, rec.select
     assert select is None and len(fp) == len(dep.problems)
     rides = list(range(len(fp)))
     resident = np.asarray(table._dev_state[-1]).copy()

@@ -333,8 +333,11 @@ PATHS = {
     # case: (driver, path, the root's children by start)
     "identity": (_drive_identity, "identity",
                  ["scheduler.identity", "scheduler.solve"]),
+    # the one diff, then the moved positions' prologue in pack; the table
+    # replays the rest
     "delta": (_drive_delta, "delta",
-              ["scheduler.identity", "scheduler.pack", "scheduler.solve"]),
+              ["scheduler.identity", "scheduler.pack", "scheduler.handoff",
+               "scheduler.solve", "scheduler.rearm"]),
     # the same list under a moved token, and a swapped one: the sweep and
     # the diff lie INSIDE pack
     "full-moved-token": (
@@ -351,12 +354,13 @@ PATHS = {
         _drive_full_drifted, "full",
         ["scheduler.identity", "scheduler.pack", "scheduler.handoff",
          "scheduler.solve", "scheduler.rearm"]),
-    # the id() sweep and the delta's check of the one moved row come first:
-    # that row left the fleet-eligible set, so the whole prologue runs
+    # the id() sweep and diff come first; in pack the check of the one
+    # moved row finds it left the fleet-eligible set, so the whole
+    # prologue runs there
     "full-host-row": (
         _drive_full_host_row, "full",
-        ["scheduler.identity", "scheduler.pack", "scheduler.pack",
-         "scheduler.handoff", "scheduler.solve", "scheduler.rearm"]),
+        ["scheduler.identity", "scheduler.pack", "scheduler.handoff",
+         "scheduler.solve", "scheduler.rearm"]),
 }
 
 
@@ -387,16 +391,18 @@ class TestEngineWave:
             [sp] = ident
             assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 30)
             [pack] = [s for s in kids if s.name == "scheduler.pack"]
-            assert pack.attrs["rows"] == 30
-            assert not _children(spans, pack)
-            # the stamp of the two together is the delta's "compile"
-            assert sp.start + eng.last_breakdown["compile"] == (
-                pytest.approx(pack.end, abs=1e-4))
+            assert (pack.attrs["rows"], pack.attrs["kept"]) == (
+                30, len(batch) - 30)
+            # the moved positions' stages, as a swapped batch's
+            assert [s.name for s in _children(spans, pack)] == (
+                SWAP_STAGES[1:])
+            [solve] = [s for s in kids if s.name == "scheduler.solve"]
+            assert solve.attrs["dirty_rows"] == 30
         elif case == "full-host-row":
             [sp] = ident
             assert (sp.attrs["hit"], sp.attrs["moved"]) == (0, 1)
-            tried, _ = [s for s in kids if s.name == "scheduler.pack"]
-            assert tried.attrs["rows"] == 1  # the position it visited
+            [pack] = [s for s in kids if s.name == "scheduler.pack"]
+            assert pack.attrs["rows"] == len(batch)  # the walk
         elif case == "full-drifted":
             [sp] = ident
             assert sp.parent_id == root.span_id
@@ -485,10 +491,10 @@ class TestEngineWave:
 
     @pytest.mark.parametrize("case", sorted(PATHS))
     def test_a_pass_sweeps_its_batch_once(self, engine, case, monkeypatch):
-        """Whichever route a pass takes, the engine's ``id()`` reads each
-        position once: the diff that swept the batch keeps its ids for the
-        re-arm (a walk no diff came before makes the one sweep there). That
-        the table diffs by the same ids: test_engine_swap.py."""
+        """Whichever route a pass takes, ``id()`` reads each position once:
+        the one diff (ResidentBatch.diff) keeps its ids for the re-arm (a
+        walk no diff came before makes the one sweep there), and the table
+        visits the diff's moved positions without a sweep of its own."""
         eng, problems = engine
         drive, _, _ = PATHS[case]
         batch, wave = drive(eng, problems)
@@ -499,6 +505,7 @@ class TestEngineWave:
             return id(obj)
 
         monkeypatch.setattr(core_mod, "id", counted, raising=False)
+        monkeypatch.setattr(fleet_mod, "id", counted, raising=False)
         wave()
         assert swept[0] == len(batch)
 
