@@ -1806,6 +1806,9 @@ class FleetTable:
         # pass's timed phases, in order: what the phase spans are placed
         # from (_phase / _emit_phase_spans)
         self._phase_marks: list[tuple] = []
+        # (route, start, end, rows, entries, fetched bytes) of each phase-B
+        # stretch of the current pass, in order: the kernel.entries spans
+        self._entry_marks: list[tuple] = []
         # rows (re)packed by the current pass (_pack_rows adds): the
         # packed-vs-replayed split the history ring records per wave; and
         # the positions its upsert phase looked at (0 on the identity path)
@@ -1816,6 +1819,9 @@ class FleetTable:
         self._slots_minted_this_pass = 0
         from ..utils.metrics import (
             fleet_batch_derived,
+            fleet_changed_rows,
+            fleet_delta_cells,
+            fleet_entry_speculations,
             fleet_upsert_rows,
             fleet_wide_rows,
         )
@@ -1832,6 +1838,17 @@ class FleetTable:
         # row state, added once a pass
         self._derived_tally = {
             o: fleet_batch_derived.labels(outcome=o) for o in ("kept", "built")
+        }
+        # the wire home of each dispatched pass, added once a pass: changed
+        # rows by route (delta, entries), the cells the delta wire carried,
+        # and what became of a speculative phase B
+        self._wire_tally = tuple(
+            fleet_changed_rows.labels(route=r) for r in ("delta", "entries")
+        )
+        self._cells_tally = fleet_delta_cells.labels()
+        self._spec_tally = {
+            o: fleet_entry_speculations.labels(outcome=o)
+            for o in ("used", "drained")
         }
         # host->device bytes of the current pass (state upload/scatter +
         # row indices), reset by _sync_device; surfaces as upload_mb
@@ -2942,6 +2959,7 @@ class FleetTable:
 
         with tracer.span("scheduler.solve") as sp:
             self._phase_marks = []
+            self._entry_marks = []
             self._select_mark = self._terms_mark = self._quota_mark = None
             self._quota_pass, self.quota_debit = quota, None
             self._derived_outcome = "kept"
@@ -3453,11 +3471,12 @@ class FleetTable:
         of one ``scheduler.solve`` are therefore disjoint and ordered, and
         a device-idle gap is charged to the phase that really held the
         host (ISSUE 25). Components are DISJOINT: ``fetch`` is the whole
-        post-device window (wire transfer + decode + entry folds — its
-        internal dispatch_b/fetch_b/delta_fold live inside it), and the
-        fenced ``device`` window carries the compile attribution flag when
-        this pass minted a fresh XLA trace. The histogram is observed once
-        a phase a pass (``host`` with the stretches' sum)."""
+        post-device window (wire transfer + decode + entry folds — each
+        phase-B stretch inside it is a ``kernel.entries`` child, at its
+        interval), and the fenced ``device`` window carries the compile
+        attribution flag when this pass minted a fresh XLA trace. The
+        histogram is observed once a phase a pass (``host`` with the
+        stretches' sum)."""
         from ..utils.metrics import kernel_phase_seconds
         from ..utils.tracing import tracer
 
@@ -3490,6 +3509,8 @@ class FleetTable:
             "fetch": {
                 "fetch_mb": tmr.get("fetch_mb", 0.0),
                 "changed_rows": tmr.get("changed_rows", 0.0),
+                **{k: int(tmr.get(k, 0))
+                   for k in ("delta_rows", "delta_cells", "entry_rows")},
             },
         }
         seconds: dict[str, float] = {}
@@ -3497,13 +3518,23 @@ class FleetTable:
             if t1 <= t0:
                 continue
             name = self._PHASE_SPANS[key]
-            tracer.record(
+            sp = tracer.record(
                 name, t1 - t0, start=t0,
                 kind="device" if key == "device" else "host",
                 **({"phase": key} if name == "kernel.host" else {}),
                 **attrs.get(key, {}),
             )
             seconds[name] = seconds.get(name, 0.0) + (t1 - t0)
+            if key != "fetch":
+                continue
+            # phase B's stretches inside this fetch window, its children
+            for route, e0, e1, rows, entries, nbytes in self._entry_marks:
+                if t0 <= e0 <= t1:
+                    tracer.record(
+                        "kernel.entries", e1 - e0, start=e0, parent=sp,
+                        kind="host", route=route, rows=rows,
+                        entries=entries, fetch_mb=nbytes / 1e6,
+                    )
         for name, total in seconds.items():
             kernel_phase_seconds.observe(total, phase=name.split(".")[1])
 
@@ -3998,12 +4029,14 @@ class FleetTable:
             )
 
     def _fetch_fold_exact(
-        self, rows, counts, *, eff_chunk, k_out, byte_wire, pack21, tmr,
+        self, rows, counts, *, route, eff_chunk, k_out, byte_wire, pack21,
     ) -> int:
         """Dispatch an exact phase B over ``rows``, fetch its entry wire,
         and fold the full runs into the host mirror. The entry cap is
         host-summed from ``counts`` so overflow is structurally
-        impossible. Returns the fetched byte count."""
+        impossible. The stretch, dispatch to fold, is kept for the
+        ``kernel.entries`` span under ``route`` (overflow | exact).
+        Returns the fetched byte count."""
         e_want = int(counts.sum())
         m_pad_b = max(2048, _pow2(len(rows)))
         b_chunk = min(eff_chunk, m_pad_b)
@@ -4029,10 +4062,7 @@ class FleetTable:
             mesh=self._entries_mesh,
             cell_bytes=self._cell_bytes,
         )
-        tmr["dispatch_b"] = time.perf_counter() - t_b
-        t_b = time.perf_counter()
         raw2 = np.asarray(flat2)
-        tmr["fetch_b"] = time.perf_counter() - t_b
         total2, stream = _decode_entry_wire(
             raw2, e_cap, byte_wire, pack21, self._cell_bytes
         )
@@ -4041,6 +4071,9 @@ class FleetTable:
 
         native.fold_entries(
             self._host_entries, rows, counts, np.asarray(stream, np.int32)
+        )
+        self._entry_marks.append(
+            (route, t_b, time.perf_counter(), len(rows), e_want, raw2.nbytes)
         )
         return raw2.nbytes
 
@@ -4057,6 +4090,7 @@ class FleetTable:
         has_agg, is_all = d.has_agg, d.is_all
         cb = self._cell_bytes
         sb = 8 * cb  # bits of a count in the meta and entry words
+        marks0 = len(self._entry_marks)  # this pass's phase-B stretches
         if (
             self._res_dense is None
             or self._res_dense.shape != (self.cap, c)
@@ -4317,6 +4351,7 @@ class FleetTable:
 
         # fold: cell deltas when they fit, full-row phase B otherwise
         use_delta = False
+        delta_rows = delta_cells = 0
         if total:
             self._host_meta[ch_rows] = state
             counts = (state & ((1 << sb) - 1)).astype(np.int64)
@@ -4343,17 +4378,18 @@ class FleetTable:
                         cell_bits=sb,
                     )
                 # decode+merge time only; an overflow-row fetch below
-                # reports its own dispatch_b/fetch_b
+                # is a kernel.entries stretch of its own
                 tmr["delta_fold"] = time.perf_counter() - t_b
-                tmr["delta_rows"] = float(int(norm.sum()))
+                delta_rows = int(norm.sum())
+                delta_cells = int(dtotal)
                 rows_over = ch_rows[~norm]
                 if rows_over.size:
                     # rows whose delta count overflowed the 6-bit meta
                     # field: fetch their full entry runs exactly
                     fetched_bytes += self._fetch_fold_exact(
-                        rows_over, counts[~norm], eff_chunk=eff_chunk,
-                        k_out=k_out, byte_wire=byte_wire, pack21=pack21,
-                        tmr=tmr,
+                        rows_over, counts[~norm], route="overflow",
+                        eff_chunk=eff_chunk, k_out=k_out,
+                        byte_wire=byte_wire, pack21=pack21,
                     )
             elif not e_total:
                 # every changed row lost its placements: clear the runs
@@ -4371,7 +4407,6 @@ class FleetTable:
                     t_b = time.perf_counter()
                     raw2 = np.asarray(spec_flat)
                     fetched_bytes += raw2.nbytes
-                    tmr["fetch_b"] = time.perf_counter() - t_b
                     total2, stream = _decode_entry_wire(
                         raw2, spec_cap, byte_wire, pack21, cb
                     )
@@ -4380,12 +4415,16 @@ class FleetTable:
                         self._host_entries, ch_rows, counts,
                         np.asarray(stream, np.int32),
                     )
+                    self._entry_marks.append((
+                        "speculative", t_b, time.perf_counter(), total,
+                        e_total, raw2.nbytes,
+                    ))
                 else:
                     # exact fallback: churn onset (no speculation) or the
                     # speculative caps were too small
                     fetched_bytes += self._fetch_fold_exact(
-                        ch_rows, counts, eff_chunk=eff_chunk, k_out=k_out,
-                        byte_wire=byte_wire, pack21=pack21, tmr=tmr,
+                        ch_rows, counts, route="exact", eff_chunk=eff_chunk,
+                        k_out=k_out, byte_wire=byte_wire, pack21=pack21,
                     )
         else:
             self._last_total = 0
@@ -4395,13 +4434,27 @@ class FleetTable:
             # dispatch would otherwise drain into the next pass's fetch
             t_b = time.perf_counter()
             spec_flat.block_until_ready()
-            tmr["spec_drain"] = time.perf_counter() - t_b
+            self._entry_marks.append(
+                ("drained", t_b, time.perf_counter(), 0, 0, 0)
+            )
         self._delta_live = use_delta
         if d_cap:
             self._last_dtotal = int(dtotal)
         t0 = self._phase(tmr, "fetch", t0)
         tmr["fetch_mb"] = fetched_bytes / 1e6
         tmr["changed_rows"] = float(total)
+        # the wire home: rows on the cell-delta wire and the cells it
+        # carried; rows phase B brought home (the entry wire)
+        entry_rows = sum(m[3] for m in self._entry_marks[marks0:])
+        tmr["delta_rows"] = float(delta_rows)
+        tmr["delta_cells"] = float(delta_cells)
+        tmr["entry_rows"] = float(entry_rows)
+        t_delta, t_entries = self._wire_tally
+        t_delta.inc(delta_rows)
+        t_entries.inc(entry_rows)
+        self._cells_tally.inc(delta_cells)
+        if spec_flat is not None:
+            self._spec_tally["used" if spec_used else "drained"].inc()
 
         res = self._result_list(problems, d, bits_src, n_pad)
         self._phase(tmr, "post", t0)

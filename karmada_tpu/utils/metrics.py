@@ -543,6 +543,26 @@ fleet_batch_derived = registry.counter(
     "(another row vector, or a pack, a compaction or a growth since: "
     "derived again over every row of the batch); added once a pass",
 )
+fleet_changed_rows = registry.counter(
+    "karmada_tpu_fleet_changed_rows_total",
+    "changed rows of dispatched fleet passes by the wire that brought "
+    "their answer home: delta (the cell-delta wire, a row of at most 62 "
+    "changed cells) or entries (phase B, _fleet_entries: rows past 62 "
+    "changed cells, or every changed row where the delta buffer overflowed "
+    "or the pass had no delta wire); added once a pass",
+)
+fleet_delta_cells = registry.counter(
+    "karmada_tpu_fleet_delta_cells_total",
+    "cells the cell-delta wire of fleet passes carried home (each a site "
+    "and its new count); added once a pass",
+)
+fleet_entry_speculations = registry.counter(
+    "karmada_tpu_fleet_entry_speculations_total",
+    "speculative phase-B dispatches of fleet passes (dispatched behind the "
+    "pass where the last pass churned off the delta wire) by outcome: used "
+    "(its entry wire brought the changed rows home) or drained (the pass "
+    "folded another way, and the dispatch was waited out)",
+)
 scheduler_prologue_rows = registry.counter(
     "karmada_tpu_scheduler_prologue_rows_total",
     "positions of full-path engine passes by what the host prologue made "

@@ -232,7 +232,23 @@ SPAN_NAMES: dict[str, str] = {
         "fenced on-device execute window (compile=true when the pass "
         "minted a fresh XLA trace)"
     ),
-    "kernel.fetch": "post-device wire transfer + decode + entry folds",
+    "kernel.fetch": (
+        "post-device wire transfer + decode + entry folds (fetch_mb, "
+        "changed_rows; delta_rows = changed rows whose answer the "
+        "cell-delta wire carried, delta_cells = the cells it carried, "
+        "entry_rows = changed rows phase B brought home: delta_rows + "
+        "entry_rows = changed_rows but where a pass without the delta wire "
+        "changed only rows that lost every placement, cleared from the "
+        "meta alone)"
+    ),
+    "kernel.entries": (
+        "under kernel.fetch, at its true interval: one phase-B stretch of "
+        "a fleet pass, the entry wire (_fleet_entries): an exact dispatch, "
+        "fetch and fold (route=overflow for rows past 62 changed cells, "
+        "exact for every changed row), a speculative dispatch's fetch and "
+        "fold (route=speculative) or an unused speculation's drain "
+        "(route=drained, rows 0); rows / entries / fetch_mb attrs"
+    ),
     "kernel.bits": (
         "the lazy feasibility-bitset pass of a batch (_fleet_bits), when "
         "the first Duplicated or zero-replica result is read: dispatch + "
@@ -790,19 +806,25 @@ class WaveTracer:
 
     def record(
         self, name: str, duration: float, *,
-        start: Optional[float] = None, **attrs
+        start: Optional[float] = None, parent: Optional[Span] = None,
+        **attrs
     ) -> Span:
         """Append an already-measured region as a COMPLETED span, nested
-        under this thread's innermost open span — for code that times its
-        phases with perf_counter stamps (the fleet pass breakdown) rather
-        than nesting context managers. With ``start`` (a perf_counter
-        stamp) the span lies at its true interval ``[start, start +
-        duration]``; without, it ends now."""
-        wave, trace_id, parent = self._open_ctx()
+        under this thread's innermost open span, or under ``parent`` (a
+        span recorded before it) — for code that times its phases with
+        perf_counter stamps (the fleet pass breakdown) rather than nesting
+        context managers. With ``start`` (a perf_counter stamp) the span
+        lies at its true interval ``[start, start + duration]``; without,
+        it ends now."""
+        if parent is None:
+            wave, trace_id, parent_id = self._open_ctx()
+        else:
+            wave, trace_id = parent.wave, parent.trace_id
+            parent_id = parent.span_id
         if start is None:
             start = time.perf_counter() - duration
         sp = self._new_span(
-            name, wave, trace_id, parent, dict(attrs),
+            name, wave, trace_id, parent_id, dict(attrs),
             start=start, end=start + duration,
         )
         self._append(sp)
